@@ -194,8 +194,11 @@ def run_local_actor_process(
     ``mp.Process`` fan-out, ``main.py:399-405``, which shared memory and
     the GIL-free illusion; these are real processes talking TCP).
 
-    Forces the CPU backend first: the accelerator belongs to the learner
-    process, and actor inference on these MLPs is host-friendly.
+    Pins the CPU backend first: one process holds a chip at a time and
+    it belongs to the learner; actor inference on these MLPs is
+    host-friendly. The pin works because importing this module (and
+    everything it imports) initialises no backend — pinned by
+    ``tests/test_startup.py``.
     """
     import jax
 
@@ -279,9 +282,9 @@ def main(argv=None):
                         "pulls full frames")
     ns = p.parse_args(argv)
     if ns.actor_device == "cpu":
-        # Acting runs on host CPU; force the platform BEFORE any jax call
-        # so even backend discovery never touches a (possibly wedged)
-        # accelerator plugin on this actor host.
+        # Acting runs on host CPU; pin the platform BEFORE any jax call:
+        # one process holds a chip at a time, and an actor that merely
+        # enumerated the TPU would take it from the learner on this host.
         import jax
 
         jax.config.update("jax_platforms", "cpu")

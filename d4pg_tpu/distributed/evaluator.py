@@ -179,6 +179,9 @@ class AsyncEvaluator:
                         self._latest = result
                 except Exception as e:  # noqa: BLE001 — eval crash must not kill training
                     print(f"evaluator failed: {e!r}", flush=True)
+                    # counted, so a run that "finished" without its evals
+                    # shows threads.contained_crashes > 0
+                    contained_crash("evaluator.evaluate", e)
                 finally:
                     with self._lock:
                         self._outstanding -= 1

@@ -269,7 +269,12 @@ class ThrottledSender:
 def _process_lane_main(kwargs: dict, duration_s: float, out_queue) -> None:
     """Entry point for a subprocess lane (``mp.get_context('spawn')``):
     rebuilds the chaos stream and template from seeds, runs the same lane
-    loop for ``duration_s``, ships the summary back over the queue."""
+    loop for ``duration_s``, ships the summary back over the queue. The
+    lane is numpy + TCP only; the CPU pin is explicit rather than
+    inherited so no child of a launcher can ever take a chip."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
     from d4pg_tpu.fleet.chaos import ChaosConfig
 
     chaos = ActorChaos(ChaosConfig(**kwargs.pop("chaos_config")),
@@ -296,7 +301,7 @@ def _actor_lane_main(cfg_kwargs: dict, host: str, transitions_port: int,
     a spawned subprocess running the full ``actor_main.run_actor`` path —
     env pool, policy inference, n-step folding, coalescing transport,
     live weight pulls — against the harness's learner-side servers. CPU
-    backend forced before any jax import touches an accelerator; the
+    backend pinned explicitly before any backend-initialising jax call; the
     fleet-member degradation policy (shed-and-count) is on so a slow
     receiver costs rows, not a wedged lane."""
     import jax

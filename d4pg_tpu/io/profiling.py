@@ -20,6 +20,7 @@ a tree between layouts (``ReshardSentinel``, the dynamic twin of the
 from __future__ import annotations
 
 import contextlib
+import threading
 import time
 
 
@@ -77,10 +78,14 @@ class RecompileSentinel:
     warmup, wrap the hot loop and call :meth:`assert_clean`.
 
     Detection uses ``jax.monitoring``'s event stream — every backend
-    compile records a ``/jax/core/compile/backend_compile_duration``
-    event, and cache hits record nothing — so ANY jitted callable
+    compile (or load from the persistent compile cache) records a
+    ``/jax/core/compile/backend_compile_duration`` event, and hits in
+    the in-memory jit cache record nothing — so ANY jitted callable
     (including scans/shard_maps nested in it) is observed without
-    instrumenting the callable itself.
+    instrumenting the callable itself. ``same_thread=True`` counts only
+    compilations made by the thread that entered the region: a training
+    loop brackets its own dispatches while an evaluator thread's first
+    compile runs beside it.
 
         with RecompileSentinel() as sentinel:
             for _ in range(n):
@@ -90,16 +95,25 @@ class RecompileSentinel:
 
     _EVENT = "/jax/core/compile/backend_compile_duration"
 
-    def __init__(self):
+    def __init__(self, same_thread: bool = False):
         self.compilations = 0
         self._active = False
+        self._same_thread = same_thread
+        self._thread: int | None = None
 
     def _on_event(self, event: str, duration: float, **_kw) -> None:
-        if self._active and event == self._EVENT:
+        # jax compiles synchronously on the dispatching thread and
+        # records the event there, so the listener's thread IS the
+        # compiling thread
+        if (self._active and event == self._EVENT
+                and (not self._same_thread
+                     or threading.get_ident() == self._thread)):
             self.compilations += 1
 
     def __enter__(self) -> "RecompileSentinel":
         from jax._src import monitoring
+
+        self._thread = threading.get_ident()
 
         monitoring.register_event_duration_secs_listener(self._on_event)
         self._active = True
@@ -109,11 +123,7 @@ class RecompileSentinel:
         self._active = False
         from jax._src import monitoring
 
-        try:
-            monitoring._unregister_event_duration_listener_by_callback(
-                self._on_event)
-        except (AttributeError, ValueError):
-            pass  # older jax: listener stays registered but inert (_active)
+        monitoring.unregister_event_duration_listener(self._on_event)
         # publish the bracketed count into the unified registry: bench
         # artifacts and the fleet report read the same ledger instead of
         # each keeping a private copy of "were there recompiles"

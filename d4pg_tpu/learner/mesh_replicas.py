@@ -46,12 +46,12 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 
 from d4pg_tpu.learner.fused import fused_chunk_step
 from d4pg_tpu.learner.replica import PARAM_FIELDS
 from d4pg_tpu.learner.state import D4PGConfig, D4PGState
 from d4pg_tpu.parallel import partition, replica_mesh
-from d4pg_tpu.parallel.compat import shard_map
 
 _tree_map = jax.tree_util.tree_map
 

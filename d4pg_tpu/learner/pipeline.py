@@ -184,8 +184,8 @@ class ChunkPipeline:
         self._stager = DeviceStager(sample_fn, device=sharding,
                                     with_aux=True, put_fn=put_fn)
         # In-flight dispatch depth: the PER write-back for chunk t blocks
-        # on t's td_error, i.e. on t's whole dispatch — on a high-latency
-        # (tunneled/PCIe) link that sync dominates. Keeping up to `depth`
+        # on t's td_error, i.e. on t's whole dispatch — a blocking
+        # device->host sync per chunk. Keeping up to `depth`
         # chunks in flight amortizes it; priority staleness grows to
         # <= (depth + 1) * K steps (Ape-X-style bounded lag).
         self._depth = max(1, int(depth))

@@ -1,12 +1,14 @@
 """Startup micro-autotuner for the categorical-projection implementation.
 
-BENCH_r05 measured the K-scan update rate per ``--projection`` variant at
-the Humanoid bench shape as einsum 12.6k > pallas 10.3k > pallas_ce 8.7k
-steps/s — i.e. the best variant is an empirical fact of the (batch,
+The best ``--projection`` variant is an empirical fact of the (batch,
 atoms, chip) triple, not something a default can know. ``--projection
 auto`` (the config default) times the candidates ON THE ACTUAL SHAPES at
 startup and picks the winner; an explicit ``--projection einsum|pallas|
 pallas_ce`` remains the escape hatch and is honored verbatim.
+
+``auto`` never hides a compile error: a candidate that fails to lower
+loses the race, but its error is carried in full in the ``[autotune]``
+line, and every candidate failing is an error, not a quiet ``einsum``.
 
 What gets timed: the critic-loss core each variant actually changes —
 ``value_and_grad`` of the projected-Bellman cross-entropy at [B, A]
@@ -156,13 +158,15 @@ def autotune_projection(batch_size: int, v_min: float, v_max: float,
             timings[variant] = round(
                 _time_variant(variant, support, batch_size, repeats, iters),
                 4)
-        except Exception as e:  # a kernel that fails to lower loses, not
-            timings[variant] = None  # the whole startup
+        except Exception as e:  # noqa: BLE001 — a kernel that fails to
+            # lower loses the race; the [autotune] line prints its error
+            timings[variant] = None
             timings[f"{variant}_error"] = f"{type(e).__name__}: {e}"
     timed = {k: v for k, v in timings.items() if isinstance(v, float)}
     if not timed:
-        return AutotuneResult("einsum", "all candidates failed to time",
-                              timings)
+        raise RuntimeError(
+            f"projection autotune: every candidate failed at shape "
+            f"[{batch_size}, {n_atoms}]: {timings}")
     best = min(timed, key=timed.get)
     return AutotuneResult(best, "measured fastest grad step at shape "
                           f"[{batch_size}, {n_atoms}]", timings)
@@ -256,7 +260,8 @@ def autotune_sampler(capacity: int, k: int, batch_size: int,
         try:
             timings["pallas"] = round(_time(
                 lambda: descend_pallas(trees.sum_tree, mass, interpret)), 4)
-        except Exception as e:  # a kernel that fails to lower loses
+        except Exception as e:  # noqa: BLE001 — the kernel loses the
+            # race; the [autotune] line prints its error in full
             timings["pallas"] = None
             timings["pallas_error"] = f"{type(e).__name__}: {e}"
     else:

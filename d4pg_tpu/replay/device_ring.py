@@ -2,8 +2,8 @@
 
 TPU-native redesign of the replay data path (no reference equivalent — the
 reference's buffers are per-process Python lists, ``replay_memory.py:14-19``,
-``prioritized_replay_memory.py:164-222``): host<->device bandwidth, not
-FLOPs, bounds a tunneled/PCIe-attached learner, and shipping every sampled
+``prioritized_replay_memory.py:164-222``): host<->device traffic, not
+FLOPs, bounds a learner this small, and shipping every sampled
 batch from host RAM costs O(batch bytes) per dispatch (25MB/chunk at
 Humanoid sizes). With the ring in HBM the host keeps only the PER trees and
 picks INDICES; the device gathers rows locally:

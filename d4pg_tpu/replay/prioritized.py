@@ -94,6 +94,13 @@ class PrioritizedReplayBuffer(ReplayBuffer):
         # priority onto a brand-new transition.
         self.generation = np.zeros(self.capacity, np.int64)
 
+    @property
+    def tree_backend(self) -> str:
+        """Which host tree loaded: ``'native'`` (the C++ extension) or
+        ``'numpy'`` (its fallback when the library is absent)."""
+        return ("numpy" if isinstance(self._trees, _NumpyPerTrees)
+                else "native")
+
     def add(self, batch: TransitionBatch) -> np.ndarray:
         idx = super().add(batch)
         self.generation[idx] += 1

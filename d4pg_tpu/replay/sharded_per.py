@@ -156,20 +156,16 @@ class ShardedFusedReplay:
                 max_priority=jnp.ones((n,), jnp.float32),
             )
 
-        if self._multiproc:
-            # host-local device_put cannot address other hosts' devices;
-            # construct inside jit with sharded outputs (SPMD — every
-            # process traces the same zeros)
-            # one-shot by design (runs once in __init__): jit-with-
-            # out_shardings is the only way to materialize the buffer on
-            # every process's devices
-            self.storage = jax.jit(_zero_storage, out_shardings=shard)()  # jaxlint: disable=recompile-hazard
-            self.trees = (jax.jit(_zero_trees, out_shardings=shard)()  # jaxlint: disable=recompile-hazard
-                          if prioritized else None)
-        else:
-            self.storage = jax.device_put(_zero_storage(), shard)
-            self.trees = (jax.device_put(_zero_trees(), shard)
-                          if prioritized else None)
+        # Constructed inside jit with sharded outputs, so every device
+        # allocates only ITS shard: building the zeros eagerly and
+        # device_put-ing them would materialise the whole ring on device 0
+        # first (3 GB at 1M Humanoid rows), and a host-local device_put
+        # cannot address other hosts' devices at all. One-shot by design
+        # (runs once in __init__; SPMD — every process traces the same
+        # zeros).
+        self.storage = jax.jit(_zero_storage, out_shardings=shard)()  # jaxlint: disable=recompile-hazard
+        self.trees = (jax.jit(_zero_trees, out_shardings=shard)()  # jaxlint: disable=recompile-hazard
+                      if prioritized else None)
         # ring cursors / live sizes for the OWNED shards (host ints; the
         # device twin of sizes is the chunk's [n_shards] ``size`` operand)
         self._head = np.zeros(self.n_local, np.int64)
@@ -235,7 +231,7 @@ class ShardedFusedReplay:
         (``mode='drop'``) and the tree write (``set_leaves``'s pad-drop
         convention) discard."""
         import jax
-        from d4pg_tpu.parallel.compat import shard_map
+        from jax import shard_map
 
         from d4pg_tpu.parallel import partition
         from d4pg_tpu.replay import device_per as dper

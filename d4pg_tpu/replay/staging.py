@@ -331,12 +331,12 @@ class DeviceDealtBlockRing(DealtBlockRing):
         for block in dropped:
             for arr in (*block.batches, block.weights, block.idx,
                         block.gen):
+                # host blocks are numpy (no delete); on a jax.Array,
+                # delete() of an already-donated buffer is a no-op, so
+                # nothing here needs catching
                 delete = getattr(arr, "delete", None)
                 if delete is not None:
-                    try:
-                        delete()
-                    except Exception:
-                        pass  # already consumed/donated elsewhere
+                    delete()
         kick = self.on_room
         if dropped and kick is not None:
             kick()

@@ -83,7 +83,7 @@ class ActorConfig:
     ou_dt: float = 0.01
     # Where actor inference runs. Acting is latency-bound batch-E inference
     # dispatched every pool tick; on a TPU host every tick would round-trip
-    # PCIe (or a remote tunnel) for microseconds of MLP compute, serializing
+    # PCIe for microseconds of MLP compute, serializing
     # the env loop on transfer latency and contending with the learner's
     # dispatch queue. 'cpu' (default) pins the policy forward to the host
     # CPU backend — the D4PG production shape: the accelerator belongs to

@@ -1,0 +1,105 @@
+"""Process start-up: the one backend rule every entry point shares.
+
+``start()`` runs before the first JAX call that initialises a backend,
+in ``d4pg_tpu.train.main``, ``bench.py``, ``__graft_entry__.entry`` and
+``chip_smoke.py``. The rule has two outcomes and nothing in between:
+
+  - chip (the default): the TPU is required. ``jax_platforms`` becomes
+    ``tpu,cpu`` and an initialisation failure raises — there is no "found
+    no chip, carrying on". The CPU backend is registered BEHIND the TPU
+    because actor and evaluator inference pin themselves to
+    ``jax.local_devices(backend="cpu")[0]`` (``serving/client.py``,
+    default ``actor_device="cpu"``); with ``tpu`` alone that backend would
+    never exist.
+  - CPU, by explicit request only: ``platform="cpu"`` (``--platform
+    cpu``), or a ``JAX_PLATFORMS`` the caller set (the tier-1 command and
+    the multi-host tests set ``cpu``). A caller-set list decides the
+    default backend as given; ``cpu`` is appended when it lacks it, for
+    the same pinned-inference reason.
+
+The persistent compile cache is placed from outside: where
+``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and nothing is
+set here; otherwise the cache lives at ONE fixed path inside the checkout
+(the path is part of the cache key — a directory that moves never hits).
+Every program is cached, not only the slow-to-compile ones.
+Library code (``train()``) and the test suite never call this, so a
+tier-1 run leaves the in-checkout cache empty.
+
+One process per chip: a process that has called ``start()`` on the chip
+holds it, and must not start a child that needs it.
+"""
+
+from __future__ import annotations
+
+import os
+from importlib import metadata
+
+PLATFORMS = ("tpu", "cpu")
+
+# <checkout>/.jax_cache — resolved from the package location, never a
+# temporary name, a pid or a timestamp
+CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+
+
+def configure(platform: str = "tpu") -> None:
+    """Apply the backend rule and place the compile cache WITHOUT
+    initialising a backend — the half a multi-host process runs before
+    ``jax.distributed.initialize`` (which must precede backend init)."""
+    if platform not in PLATFORMS:
+        raise ValueError(f"unknown platform {platform!r} (want {PLATFORMS})")
+    import jax
+
+    requested = os.environ.get("JAX_PLATFORMS", "")
+    if platform == "cpu":
+        platforms = "cpu"
+    elif requested:
+        names = requested.split(",")
+        platforms = requested if "cpu" in names else requested + ",cpu"
+    else:
+        platforms = "tpu,cpu"
+    jax.config.update("jax_platforms", platforms)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+    if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
+        # jax's default keeps only programs that took >= 1 s to compile:
+        # 3 of the smoke's ~108. Caching all of them took the smoke's
+        # second run on one machine from 38.5 s to 24.3 s in train.main
+        # (chip runs, PR 21; CHANGES.md).
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+
+
+def describe() -> dict:
+    """Initialise the backends (raising when the requested one cannot)
+    and print the start-up line every entry point shares. Returns what
+    the line says: ``{"platform", "kind", "count", "jax", "jaxlib",
+    "libtpu"}`` with the device as JAX reports it."""
+    import jax
+    import jaxlib
+
+    devices = jax.devices()
+    try:
+        libtpu = metadata.version("libtpu")
+    except metadata.PackageNotFoundError:  # a CPU-only install
+        libtpu = None
+    info = {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+        "jax": jax.__version__,
+        "jaxlib": jaxlib.__version__,
+        "libtpu": libtpu,
+    }
+    print(f"[startup] platform={info['platform']} "
+          f"device_kind={info['kind']!r} devices={info['count']} "
+          f"jax={info['jax']} jaxlib={info['jaxlib']} libtpu={libtpu} "
+          f"compile_cache={jax.config.jax_compilation_cache_dir}",
+          flush=True)
+    return info
+
+
+def start(platform: str = "tpu") -> dict:
+    """``configure`` + ``describe``: what a single-process entry point
+    calls before its first JAX computation."""
+    configure(platform)
+    return describe()

@@ -1,7 +1,6 @@
-"""Driver entry-point hardening (VERDICT r4 #1): ``dryrun_multichip`` is a
-virtual-mesh correctness check and must NEVER initialize a non-CPU backend —
-the chip can be wedged (hangs init) or libtpu-mismatched (raises at first
-dispatch AFTER ``jax.devices()`` succeeds, the MULTICHIP_r04 regression).
+"""``dryrun_multichip`` is a virtual-mesh correctness check and must never
+initialize a non-CPU backend: one process holds the chip at a time, and a
+driver that runs the dry-run beside a chip job must not contend for it.
 
 Run in a subprocess: backend selection is process-global state, and the
 point is to exercise the real driver code path with NO prior CPU pinning
