@@ -54,6 +54,7 @@ import numpy as np
 
 from d4pg_tpu.core.locking import TieredCondition, TieredLock
 from d4pg_tpu.distributed.transport import decode_frame, raw_frame_meta_ex
+from d4pg_tpu.obs import trace as obs_trace
 from d4pg_tpu.obs.containment import contained_crash
 from d4pg_tpu.obs.flight import EVENT_ADMISSION_REJECT, record_event
 from d4pg_tpu.obs.registry import REGISTRY
@@ -383,7 +384,9 @@ class ReplayService:
         admitted = False
         rejected_cls: str | None = None
         pol = self._admission
-        with s.cond:
+        # ingest.admit: the producer's side of the handoff, one span an
+        # add; ``wait_ms`` only when it blocked for a deque slot
+        with obs_trace.span("ingest.admit", rows=rows) as admit_span, s.cond:
             if s.shed_at is not None:
                 # shed admission: bounded work, never blocks. The counter
                 # and the deque mutate under the same lock — the
@@ -422,8 +425,9 @@ class ReplayService:
                     shed_batches += 1
             elif len(s.q) >= s.capacity:
                 if block:
+                    began = time.monotonic()
                     deadline = (None if timeout is None
-                                else time.monotonic() + timeout)
+                                else began + timeout)
                     while (len(s.q) >= s.capacity
                            and not self._stop.is_set()):
                         remaining = (None if deadline is None
@@ -432,6 +436,8 @@ class ReplayService:
                             break
                         s.cond.wait(0.1 if remaining is None
                                     else min(remaining, 0.1))
+                    admit_span.set_metadata(
+                        wait_ms=1e3 * (time.monotonic() - began))
                 admitted = len(s.q) < s.capacity
             else:
                 admitted = True
@@ -604,8 +610,18 @@ class ReplayService:
         commit = getattr(self.buffer, "commit_staged", None)
         if commit is None:
             return 0
-        with self._buffer_lock:
-            return commit()
+        return self._under_buffer_lock(commit)
+
+    def _under_buffer_lock(self, call):
+        """``call()`` under the buffer lock, the learner's wait for the
+        lock (the commit thread holds it while it stages a group) as a
+        span of its own."""
+        with obs_trace.span("ingest.lock_wait"):
+            self._buffer_lock.acquire()
+        try:
+            return call()
+        finally:
+            self._buffer_lock.release()
 
     def ingest_stage(self) -> int:
         """Start the H2D transfer of the next staged block (ONE
@@ -618,8 +634,7 @@ class ReplayService:
         stage = getattr(self.buffer, "stage_block", None)
         if stage is None:
             return self.drain_device()
-        with self._buffer_lock:
-            return stage()
+        return self._under_buffer_lock(stage)
 
     def replay_state(self) -> dict:
         """Buffer contents + priorities for checkpointing (learner
@@ -806,6 +821,9 @@ class ReplayService:
                 "fenced_frames": self._fenced_frames,
                 "fenced_rows": self._fenced_rows,
             }
+        # rows the fused path's host staging discarded under a backlog
+        # deeper than itself (process-wide: one service a process)
+        merged["rows_dropped"] = REGISTRY.counter("fused.rows_dropped").value
         merged.update({
             "queue_depth": sum(p["queue_depth"] for p in per_shard),
             "sheds": sum(p["sheds"] for p in per_shard),
@@ -1060,7 +1078,11 @@ class ReplayService:
                         obs=self.obs_norm.normalize(batch.obs),
                         next_obs=self.obs_norm.normalize(batch.next_obs),
                     ), rows, cnt, tid)
-            with self._buffer_lock:
+            # ingest.host_stage: the commit thread's buffer-lock section,
+            # which on the fused path pushes the group into host staging
+            with obs_trace.span("ingest.host_stage", batches=len(group),
+                      rows=sum(item[3] for item in group)), \
+                    self._buffer_lock:
                 if dealer is None:
                     for _seq, _aid, batch, _rows, _cnt, _tid in group:
                         if batch is not None:  # None: already direct-staged

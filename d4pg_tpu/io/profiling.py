@@ -36,6 +36,47 @@ def xla_trace(log_dir: str | None):
         yield
 
 
+def abstract_args(args: tuple) -> tuple:
+    """``args`` with every array replaced by its ``jax.ShapeDtypeStruct``
+    (shape and dtype): what a program-table entry keeps
+    (``obs/trace.register_program``), so a trace reader can lower and
+    compile the program again while nothing on the device stays alive.
+    No sharding is carried: an argument that names one lowers to another
+    module than the call made (a compile-cache miss of seconds, my chip
+    run, PR 25), and the mesh programs state theirs in ``jax.jit``.
+    Python scalars pass through as they are."""
+    import jax
+
+    def leaf(x):
+        if isinstance(x, jax.Array):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype)
+        return x
+
+    return jax.tree_util.tree_map(leaf, args)
+
+
+def compiled_text_of(fn, args: tuple) -> str:
+    """The compiled HLO text of jitted ``fn`` for (abstract) ``args``, with
+    the ``op_name`` metadata of the source AS IT IS NOW. The persistent
+    compile cache keys a program with its metadata stripped, so a plain
+    ``fn.lower(*args).compile()`` can hand back an executable compiled
+    before a ``named_scope`` was written, under its old names (my chip
+    run, PR 25: every scope read 0). Here the key includes the metadata
+    for this one compile, and the function's in-memory executable is
+    dropped first (the next real call reloads it from the cache): the
+    first call after a source change compiles, later ones hit."""
+    import jax
+
+    key = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, key)
+    jax.config.update(key, True)
+    try:
+        fn.clear_cache()
+        return fn.lower(*args).compile().as_text()
+    finally:
+        jax.config.update(key, before)
+
+
 class StepTimer:
     """EWMA steps/sec over explicitly bracketed update spans.
 
@@ -124,12 +165,6 @@ class RecompileSentinel:
         from jax._src import monitoring
 
         monitoring.unregister_event_duration_listener(self._on_event)
-        # publish the bracketed count into the unified registry: bench
-        # artifacts and the fleet report read the same ledger instead of
-        # each keeping a private copy of "were there recompiles"
-        from d4pg_tpu.obs.registry import REGISTRY
-
-        REGISTRY.counter("profiling.recompiles").inc(self.compilations)
 
     def assert_clean(self, what: str = "steady-state region") -> None:
         if self.compilations:
@@ -262,7 +297,3 @@ class TransferSentinel:
         if self._stack is not None:
             self._stack.close()
             self._stack = None
-        from d4pg_tpu.obs.registry import REGISTRY
-
-        REGISTRY.counter("profiling.explicit_h2d").inc(self.h2d)
-        REGISTRY.counter("profiling.explicit_d2h").inc(self.d2h)

@@ -63,19 +63,26 @@ def fused_chunk_step(
         state, trees = carry
         k_sample, k_rest = jax.random.split(state.key)
         state = state._replace(key=k_rest)
+        # The four phases carry named scopes (metadata only): a trace
+        # reader turns each into device time through the program table
+        # (obs/trace.compiled_text); PERF.md section 3 names the metrics.
+        with jax.named_scope("replay.sample"):
+            if trees is not None:
+                idx = dper.sample(trees, k_sample, batch_size, size)
+                beta = dper.beta_schedule(state.step, beta0, beta_steps)
+                w = dper.is_weights(trees, idx, beta, size)
+            else:
+                idx = jax.random.randint(k_sample, (batch_size,), 0,
+                                         jnp.maximum(size, 1))
+                w = None
+        with jax.named_scope("replay.gather"):
+            batch = TransitionBatch(*[arr[idx] for arr in storage])
+        with jax.named_scope("learner.update"):
+            state, metrics = update_step(config, state, batch, w)
         if trees is not None:
-            idx = dper.sample(trees, k_sample, batch_size, size)
-            beta = dper.beta_schedule(state.step, beta0, beta_steps)
-            w = dper.is_weights(trees, idx, beta, size)
-        else:
-            idx = jax.random.randint(k_sample, (batch_size,), 0,
-                                     jnp.maximum(size, 1))
-            w = None
-        batch = TransitionBatch(*[arr[idx] for arr in storage])
-        state, metrics = update_step(config, state, batch, w)
-        if trees is not None:
-            trees = dper.update_from_td(trees, idx, metrics["td_error"],
-                                        alpha)
+            with jax.named_scope("replay.writeback"):
+                trees = dper.update_from_td(trees, idx,
+                                            metrics["td_error"], alpha)
         metrics["idx"] = idx
         return (state, trees), metrics
 
@@ -174,24 +181,31 @@ def make_sharded_fused_chunk(
     def _local_sample_per(trees, storage, size, key, beta):
         ax = jax.lax.axis_index(DATA_AXIS)
         t = _local_trees(trees)
-        idx = dper.sample(t, jax.random.fold_in(key, ax), b_local, size[0])
-        batch = TransitionBatch(*[arr[0][idx] for arr in storage])
+        with jax.named_scope("replay.sample"):
+            idx = dper.sample(t, jax.random.fold_in(key, ax), b_local,
+                              size[0])
+        with jax.named_scope("replay.gather"):
+            batch = TransitionBatch(*[arr[0][idx] for arr in storage])
         # per-draw probability of row i: q_i = (1/N_shards) * p_i/total_h.
         # The reference weight is (N_rows * q)^-beta / (N_rows * q_min)^-beta
         # — N_rows cancels, so no psum of sizes is needed; only the global
         # minimum per-draw probability crosses shards (one pmin scalar).
-        total = jnp.maximum(t.sum_tree[1], 1e-30)
-        q = t.sum_tree[t.capacity + idx] / total / n_shards
-        q_min = jax.lax.pmin(t.min_tree[1] / total / n_shards, DATA_AXIS)
-        w = (q / q_min) ** (-beta)
+        with jax.named_scope("replay.sample"):
+            total = jnp.maximum(t.sum_tree[1], 1e-30)
+            q = t.sum_tree[t.capacity + idx] / total / n_shards
+            q_min = jax.lax.pmin(t.min_tree[1] / total / n_shards,
+                                 DATA_AXIS)
+            w = (q / q_min) ** (-beta)
         return batch, w.astype(jnp.float32), idx.astype(jnp.int32)
 
     def _local_sample_uniform(storage, size, key):
         ax = jax.lax.axis_index(DATA_AXIS)
-        idx = jax.random.randint(
-            jax.random.fold_in(key, ax), (b_local,), 0,
-            jnp.maximum(size[0], 1))
-        batch = TransitionBatch(*[arr[0][idx] for arr in storage])
+        with jax.named_scope("replay.sample"):
+            idx = jax.random.randint(
+                jax.random.fold_in(key, ax), (b_local,), 0,
+                jnp.maximum(size[0], 1))
+        with jax.named_scope("replay.gather"):
+            batch = TransitionBatch(*[arr[0][idx] for arr in storage])
         return batch, idx.astype(jnp.int32)
 
     def _local_write_back(trees, idx, td):
@@ -215,16 +229,23 @@ def make_sharded_fused_chunk(
             state, trees = carry
             k_sample, k_rest = jax.random.split(state.key)
             state = state._replace(key=k_rest)
+            # same phase names as fused_chunk_step; the shard_map
+            # prologue samples and gathers in one call, so those two
+            # scopes sit inside its local functions
             if prioritized:
-                beta = dper.beta_schedule(state.step, beta0, beta_steps)
+                with jax.named_scope("replay.sample"):
+                    beta = dper.beta_schedule(state.step, beta0,
+                                              beta_steps)
                 batch, w, idx = sample_per(trees, storage, size,
                                            k_sample, beta)
             else:
                 batch, idx = sample_uniform(storage, size, k_sample)
                 w = None
-            state, metrics = update_step(config, state, batch, w)
+            with jax.named_scope("learner.update"):
+                state, metrics = update_step(config, state, batch, w)
             if prioritized:
-                trees = write_back(trees, idx, metrics["td_error"])
+                with jax.named_scope("replay.writeback"):
+                    trees = write_back(trees, idx, metrics["td_error"])
             metrics["idx"] = idx
             return (state, trees), metrics
 

@@ -28,8 +28,10 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from d4pg_tpu.io.profiling import abstract_args
 from d4pg_tpu.learner.pipeline import IngestOverlap
 from d4pg_tpu.learner.state import D4PGConfig, D4PGState
+from d4pg_tpu.obs import trace as obs_trace
 from d4pg_tpu.obs.trace import RECORDER as _trace_recorder
 
 
@@ -72,6 +74,7 @@ class FusedLoop:
         self.ingest = IngestOverlap(service) if service is not None else None
         self.steps_done = 0
         self.chunks = 0
+        self._tabled = False  # the chunk program is in the program table
 
     def fused_for(self, k: int):
         """The jitted fused-chunk fn for chunk length ``k`` (cached)."""
@@ -104,32 +107,56 @@ class FusedLoop:
         dispatch — step accounting and weight publishing live with the
         caller, which is what lets the legacy path and a replica share
         this loop while publishing through different stores."""
+        with obs_trace.span("learner.run", n=n):
+            return self._run(state, n, on_chunk)
+
+    def _run(self, state, n, on_chunk):
+        # Host spans (obs/trace.span -> the profiler's trace, nested by
+        # thread): learner.run > learner.flush and, per chunk,
+        # learner.chunk > ingest.commit, learner.dispatch, ingest.stage,
+        # learner.on_chunk. ``chunk=`` is what the spans of one chunk
+        # share; PERF.md section 3 says which metric reads which.
         buffer = self._buffer
         metrics = None
         done = 0
         if self.ingest is not None:
             # cycle boundary: every staged row lands before training
-            self.ingest.flush()
+            with obs_trace.span("learner.flush") as flush:
+                flush.set_metadata(rows=self.ingest.flush())
         while done < n:
             k = min(self.k, n - done)
             fn = self.fused_for(k)
-            if self.ingest is not None:
-                self.ingest.commit()
-            if self._prioritized:
-                state, buffer.trees, metrics = fn(
-                    state, buffer.trees, buffer.storage, buffer.size)
-            else:
-                state, metrics = fn(state, buffer.storage, buffer.size)
-            if self.ingest is not None:
-                self.ingest.stage()
-            # traces whose rows committed before this dispatch are now
-            # consumed; near-free no-op when nothing is pending
-            _trace_recorder.mark_grad()
-            done += k
-            self.steps_done += k
-            self.chunks += 1
-            if on_chunk is not None:
-                on_chunk(state, k)
+            chunk = self.chunks
+            with obs_trace.span("learner.chunk", chunk=chunk, k=k):
+                if self.ingest is not None:
+                    self.ingest.commit()
+                args = ((state, buffer.trees, buffer.storage, buffer.size)
+                        if self._prioritized
+                        else (state, buffer.storage, buffer.size))
+                if not self._tabled:  # first dispatch: enter the table
+                    obs_trace.register_program("learner.chunk", fn,
+                                     abstract_args(args))
+                    self._tabled = True
+                # the jitted call alone: where the host blocks once the
+                # runtime's queue of programs in flight is full
+                with obs_trace.span("learner.dispatch", chunk=chunk):
+                    out = fn(*args)
+                del args  # state and trees were donated
+                if self._prioritized:
+                    state, buffer.trees, metrics = out
+                else:
+                    state, metrics = out
+                if self.ingest is not None:
+                    self.ingest.stage()
+                # traces whose rows committed before this dispatch are
+                # now consumed; near-free no-op when nothing is pending
+                _trace_recorder.mark_grad()
+                done += k
+                self.steps_done += k
+                self.chunks += 1
+                if on_chunk is not None:
+                    with obs_trace.span("learner.on_chunk", chunk=chunk):
+                        on_chunk(state, k)
         return state, metrics
 
     def close(self) -> None:
@@ -177,7 +204,6 @@ class DealtLoop:
         self._stop = stop
         self._pop_timeout = float(pop_timeout)
         self.steps_done = 0
-        self.blocks = 0
 
     def run(
         self,
@@ -209,7 +235,6 @@ class DealtLoop:
             k = int(idx.shape[0])
             done += k
             self.steps_done += k
-            self.blocks += 1
             if on_chunk is not None:
                 on_chunk(state, k)
         return state, metrics

@@ -28,6 +28,7 @@ from typing import Any, Callable, Optional
 
 import numpy as np
 
+from d4pg_tpu.obs import trace as obs_trace
 from d4pg_tpu.replay.staging import DeviceStager
 
 
@@ -104,7 +105,6 @@ class IngestOverlap:
         self._busy = threading.Lock()
         self.rows_committed = 0
         self.rows_staged = 0
-        self.blocks = 0
 
     @contextmanager
     def _dispatch(self, op: str):
@@ -123,17 +123,22 @@ class IngestOverlap:
         finally:
             self._busy.release()
 
+    # ``ingest.commit`` / ``ingest.stage``: the host time of the two
+    # handoff calls, each over ``ingest.lock_wait`` (the service's buffer
+    # lock) and the buffer's own ``fused.commit_staged`` /
+    # ``fused.stage_block``, which carry the block's id.
     def commit(self) -> int:
-        with self._dispatch("commit"):
+        with obs_trace.span("ingest.commit") as sp, self._dispatch("commit"):
             n = self._service.ingest_commit()
             self.rows_committed += n
-            self.blocks += 1 if n else 0
+            sp.set_metadata(rows=n)
             return n
 
     def stage(self) -> int:
-        with self._dispatch("stage"):
+        with obs_trace.span("ingest.stage") as sp, self._dispatch("stage"):
             n = self._service.ingest_stage()
             self.rows_staged += n
+            sp.set_metadata(rows=n)
             return n
 
     def flush(self) -> int:

@@ -183,21 +183,29 @@ def update_step(
         sub, k_obs, k_next = jax.random.split(sub, 3)
         from d4pg_tpu.ops.augment import random_shift
 
-        batch = batch._replace(
-            obs=random_shift(k_obs, batch.obs, config.augment_pad),
-            next_obs=random_shift(k_next, batch.next_obs,
-                                  config.augment_pad),
-        )
+        with jax.named_scope("update.augment"):
+            batch = batch._replace(
+                obs=random_shift(k_obs, batch.obs, config.augment_pad),
+                next_obs=random_shift(k_next, batch.next_obs,
+                                      config.augment_pad),
+            )
 
-    # --- critic step -----------------------------------------------------
-    (critic_loss, td_error), critic_grads = jax.value_and_grad(
-        lambda p: _critic_loss_fn(config, p, state, batch, is_weights, sub),
-        has_aux=True,
-    )(state.critic_params)
-    critic_updates, critic_opt_state = config.optimizer(config.lr_critic).update(
-        critic_grads, state.critic_opt_state, state.critic_params
-    )
-    critic_params = optax.apply_updates(state.critic_params, critic_updates)
+    # --- critic step. The named scopes (update.augment above,
+    # update.critic, update.actor, update.optim) are metadata only; a
+    # trace reader splits the fused chunk's ``learner.update`` phase by
+    # them (PERF.md section 3) ----------------------------------------------
+    with jax.named_scope("update.critic"):
+        (critic_loss, td_error), critic_grads = jax.value_and_grad(
+            lambda p: _critic_loss_fn(config, p, state, batch, is_weights,
+                                      sub),
+            has_aux=True,
+        )(state.critic_params)
+    with jax.named_scope("update.optim"):
+        critic_updates, critic_opt_state = config.optimizer(
+            config.lr_critic).update(
+            critic_grads, state.critic_opt_state, state.critic_params)
+        critic_params = optax.apply_updates(state.critic_params,
+                                            critic_updates)
 
     # --- shared-encoder tie (SAC-AE/DrQ): the actor's encoder subtree IS
     # the critic's, refreshed right after the critic step. Done on the
@@ -223,26 +231,28 @@ def update_step(
     # D4PG variants; one-step-fresher critic is the natural fit for a
     # single fused XLA computation (like the (0.9, 0.999) Adam-b2 default,
     # ``learner/state.py:34-41``). -----------------------------------------
-    actor_loss, actor_grads = jax.value_and_grad(
-        lambda p: _actor_loss_fn(config, p, critic_params, batch)
-    )(actor_params_in)
-    actor_updates, actor_opt_state = config.optimizer(config.lr_actor).update(
-        actor_grads, state.actor_opt_state, actor_params_in
-    )
-    actor_params = optax.apply_updates(actor_params_in, actor_updates)
-    if config.share_encoder:
-        actor_params = tie_encoder(actor_params, critic_params)
+    with jax.named_scope("update.actor"):
+        actor_loss, actor_grads = jax.value_and_grad(
+            lambda p: _actor_loss_fn(config, p, critic_params, batch)
+        )(actor_params_in)
+    with jax.named_scope("update.optim"):
+        actor_updates, actor_opt_state = config.optimizer(
+            config.lr_actor).update(
+            actor_grads, state.actor_opt_state, actor_params_in)
+        actor_params = optax.apply_updates(actor_params_in, actor_updates)
+        if config.share_encoder:
+            actor_params = tie_encoder(actor_params, critic_params)
 
-    # --- soft target updates (tau, ``ddpg.py:110-116``) -------------------
-    target_actor_params = soft_update(
-        state.target_actor_params, actor_params, config.tau
-    )
-    target_critic_params = soft_update(
-        state.target_critic_params, critic_params, config.tau
-    )
-    if config.share_encoder:
-        target_actor_params = tie_encoder(
-            target_actor_params, target_critic_params)
+        # --- soft target updates (tau, ``ddpg.py:110-116``) ---------------
+        target_actor_params = soft_update(
+            state.target_actor_params, actor_params, config.tau
+        )
+        target_critic_params = soft_update(
+            state.target_critic_params, critic_params, config.tau
+        )
+        if config.share_encoder:
+            target_actor_params = tie_encoder(
+                target_actor_params, target_critic_params)
     new_state = D4PGState(
         actor_params=actor_params,
         critic_params=critic_params,

@@ -9,10 +9,13 @@ backend exists):
   histograms plus *snapshot providers* (callables that produce a
   consistent dict under their own locks — the PR-4 rule that every
   counter is read under the lock that writes it). ``replay_service``,
-  ``staging``, ``fused_buffer``, ``core.locking``, the profiling
-  sentinels and the fleet harness all publish here; the bespoke
+  ``fused_buffer``, ``core.locking``, ``ReshardSentinel`` and the fleet
+  harness all publish here; the bespoke
   ``*_stats()`` dicts survive as thin views over the same snapshots.
-- ``obs.trace`` — sampled per-frame trace spans riding the v2 wire
+- ``obs.trace`` — program spans (``span()``: the learner's and the ingest
+  plane's own boundaries, handed to the profiler's trace) and the program
+  table (a named scope inside a compiled program -> device time); and
+  sampled per-frame trace spans riding the v2 wire
   codec's header extension: birth timestamp at the actor's socket
   write, span timestamps at admission, decode, stage, merge-pop,
   commit and grad-step consumption, aggregated into per-stage latency

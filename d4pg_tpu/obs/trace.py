@@ -25,14 +25,23 @@ each stage the frame passes:
   the ordered-merge inbox on the non-fused path).
 - ``merge``      — the commit thread popped the ticket in global order.
 - ``commit``     — rows landed in replay state (buffer insert /
-  direct-stage accounting settled).
+  direct-stage accounting settled). On the FUSED path "replay state" is
+  the HOST staging ring (``FusedDeviceReplay.add``): the rows reach the
+  device ring one ``fused.stage_block`` and one ``fused.commit_staged``
+  later, i.e. one to two chunks after this stamp.
 - ``grad``       — first learner consumption after commit: the fused
   loop marks it right after each chunk dispatch
-  (``train.train_steps_fused``), the fleet harness's consumer lane
-  marks it after each concurrent ``sample()``. Dispatch time is the
-  host-side proxy for "a grad step consumed these rows" — the device
-  executes asynchronously and the host cannot observe the kernel
-  without a sync that would distort the measurement.
+  (``learner/loop.FusedLoop.run``), the fleet harness's consumer lane
+  marks it after each concurrent ``sample()``. The stamp is a DISPATCH,
+  not a completion, and it is not gated on the block that carries the
+  rows. On the fused path ``wire_to_grad`` is therefore short by one to
+  two chunks (the staged block's wait for its commit) plus one chunk's
+  run time on the device; the host-sampled paths are short by the
+  dispatch-to-completion time only. The repair (gate ``grad`` on the
+  ``block=`` that ``fused.commit_staged`` lands, stamp at the chunk's
+  completion) is entered in PERF.md section 7 against the
+  ``humanoid-mlp.learn-fleet-tcp`` cell, the first that would read it;
+  the fleet harnesses and two lint families depend on the present stamps.
 
 A shed/tombstoned/undecodable frame gets a terminal ``shed`` span so
 every admitted trace terminates — the zero-orphan invariant the K-shard
@@ -46,6 +55,12 @@ Cost: a span is one terminal-lock round trip + one dict store (~1 us);
 at the default 2% sample over 16-row frames that is ~1.3 ns/row —
 unmeasurable against the ~190 us/row ingest budget. The recorder is
 disabled by default; ``enable()`` is the only switch.
+
+Program spans and the program table (below the recorder) are a separate,
+stateless mechanism: ``span()`` hands the learner's and the ingest
+plane's own boundaries to the profiler (the profiler is the span store;
+nothing is kept here), and the table lets a trace reader turn a
+``jax.named_scope`` inside a compiled program into device time.
 """
 
 from __future__ import annotations
@@ -262,3 +277,63 @@ class TraceRecorder:
 # THE process-wide recorder (one receiver per process is the shipped
 # topology). Senders never touch it — their trace state rides the wire.
 RECORDER = TraceRecorder()
+
+
+# -- program spans ------------------------------------------------------------
+# Host spans at the learner's and the ingest plane's layer boundaries go
+# to the profiler's own trace, beside the device's op line, whenever a
+# profiler session is on; with none on, an annotation costs about a
+# microsecond and stores nothing. ``startup.describe()`` installs
+# ``jax.profiler.TraceAnnotation`` once the backend is up (this package
+# imports no jax); processes that never start a backend (actors, unit
+# tests) get the shared null span.
+
+
+class _NullSpan:
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+    def set_metadata(self, **stats) -> None:
+        """Stats known only inside the span (rows moved, time waited)."""
+
+
+NULL_SPAN = _NullSpan()
+_annotator = None
+
+
+def set_annotator(factory) -> None:
+    """``factory(name, **stats)`` -> a context manager with
+    ``set_metadata(**stats)``; ``None`` uninstalls. Also how a test
+    records spans without a profiler."""
+    global _annotator
+    _annotator = factory
+
+
+def span(name: str, **stats):
+    return NULL_SPAN if _annotator is None else _annotator(name, **stats)
+
+
+# -- program table ------------------------------------------------------------
+# name -> (jitted fn, abstract arguments), entered by the owner at the
+# program's first dispatch. The arguments are ShapeDtypeStruct trees made
+# by the caller, so nothing on the device is kept alive.
+_PROGRAMS: dict[str, tuple] = {}
+
+
+def register_program(name: str, fn, abstract_args: tuple) -> None:
+    _PROGRAMS[name] = (fn, abstract_args)
+
+
+def compiled_text(name: str) -> str:
+    """The compiled HLO text of a registered program, every instruction
+    with the ``op_name`` (named-scope path) it came from. Lowers and
+    compiles on demand from the abstract arguments; only a trace reader
+    calls it (``io/profiling.compiled_text_of`` says what it costs; jax
+    lives there, not in this package)."""
+    from d4pg_tpu.io.profiling import compiled_text_of
+
+    fn, args = _PROGRAMS[name]
+    return compiled_text_of(fn, args)

@@ -40,10 +40,14 @@ the accelerator.
 
 from __future__ import annotations
 
+import time
+from collections import deque
 from functools import partial
 
 import numpy as np
 
+from d4pg_tpu.obs.flight import record_event
+from d4pg_tpu.obs import trace as obs_trace
 from d4pg_tpu.obs.registry import REGISTRY
 from d4pg_tpu.replay import device_per as dper
 from d4pg_tpu.replay.device_ring import DeviceStore, block_write
@@ -62,7 +66,12 @@ class HostStagingRing:
 
     Bounded like the list staging it replaces: when producers outrun the
     learner by more than the ring, the OLDEST staged rows are dropped
-    (they would only be overwritten by the next drains anyway).
+    (they would only be overwritten by the next drains anyway) — and
+    counted: ``fused.rows_dropped`` in the registry, a ``staging_drop``
+    event in the flight ring.
+
+    Each push notes ``(rows written so far, perf_counter)`` so the stager
+    can say how long the oldest row of a frame waited (``oldest_push``).
 
     Reuse discipline: a popped frame's rows are rewritten only after the
     write pointer laps the ring (≥ ``(n_blocks - 1) * block_rows`` newer
@@ -80,13 +89,25 @@ class HostStagingRing:
         ]
         self._r = 0  # absolute rows consumed
         self._w = 0  # absolute rows written
+        self._pushed: deque = deque()  # (self._w after the push, its time)
 
     def __len__(self) -> int:
         return self._w - self._r
 
-    def push(self, batch: TransitionBatch) -> None:
+    def oldest_push(self) -> float | None:
+        """``perf_counter`` of the push that wrote the oldest pending row
+        (``None`` when empty)."""
+        pushed = self._pushed
+        while pushed and pushed[0][0] <= self._r:
+            pushed.popleft()
+        return pushed[0][1] if pushed else None
+
+    def push(self, batch: TransitionBatch, at: float | None = None) -> None:
+        """``at``: when the rows were first staged, for a push that only
+        moves them on (the multi-ring merge); now otherwise."""
         n = batch.obs.shape[0]
-        if n > self.size:  # keep only the newest ring-full
+        dropped = max(0, n - self.size)
+        if dropped:  # keep only the newest ring-full
             batch = TransitionBatch(*[np.asarray(v)[-self.size:]
                                       for v in batch])
             n = self.size
@@ -99,7 +120,14 @@ class HostStagingRing:
                 dst[:n - first] = src[first:]
         self._w += n
         if self._w - self._r > self.size:
+            dropped += self._w - self.size - self._r
             self._r = self._w - self.size  # drop oldest
+        self.oldest_push()  # forget the pushes whose rows are all gone
+        self._pushed.append(
+            (self._w, time.perf_counter() if at is None else at))
+        if dropped:
+            REGISTRY.counter("fused.rows_dropped").inc(dropped)
+            record_event("staging_drop", rows=dropped)
 
     def frame(self) -> tuple[TransitionBatch, int]:
         """Next pending frame as fixed-shape [block_rows] views + its
@@ -212,8 +240,13 @@ class FusedDeviceReplay:
                                              n_blocks, self.ingest_shards)
         else:
             self._staging = HostStagingRing(specs, self.block_rows, n_blocks)
-        self._inflight: tuple[TransitionBatch, int] | None = None
+        # the frame on its way to the device: (frame, rows, block id,
+        # perf_counter at stage_block). The id is what a trace follows a
+        # block by, from ``fused.stage_block`` to ``fused.commit_staged``.
+        self._inflight: tuple[TransitionBatch, int, int, float] | None = None
+        self.blocks_staged = 0
         self._commit = self._make_commit()
+        self._commit_tabled = False
 
     def _make_commit(self):
         import jax
@@ -222,8 +255,15 @@ class FusedDeviceReplay:
         capacity, block, alpha = self.capacity, self.block_rows, self.alpha
         write = partial(block_write, capacity=capacity, block_rows=block)
 
+        # Every variant names its two phases (metadata only); a trace
+        # reader splits the commit program's device time by them.
         if not self.prioritized:
-            return jax.jit(write, donate_argnums=(0,))
+            @partial(jax.jit, donate_argnums=(0,))
+            def commit_uniform(storage, frame, start, n):
+                with jax.named_scope("ingest.ring_write"):
+                    return write(storage, frame, start, n)
+
+            return commit_uniform
 
         if self.gen_tracked:
             from d4pg_tpu.replay.segment_tree import next_pow2
@@ -238,29 +278,36 @@ class FusedDeviceReplay:
             @partial(jax.jit, donate_argnums=(0, 1, 2))
             def commit_tracked(storage, trees, gen, frame, start, n,
                                p_ins, max_pri):
-                storage = write(storage, frame, start, n)
-                row = jax.lax.iota(jnp.int32, block)
-                idx = jnp.where(row < n, (start + row) % capacity, padcap)
-                # p_ins is max_priority ** alpha computed on the HOST
-                # (float64 pow, cast f32) — see the gen_tracked note in
-                # __init__; the trees only ever see host-rounded values
-                trees = dper.set_leaves(
-                    trees, idx, jnp.full((block,), p_ins, jnp.float32))
-                trees = trees._replace(max_priority=max_pri)
-                gen = gen.at[idx].add(1, mode="drop")
+                with jax.named_scope("ingest.ring_write"):
+                    storage = write(storage, frame, start, n)
+                with jax.named_scope("ingest.tree_insert"):
+                    row = jax.lax.iota(jnp.int32, block)
+                    idx = jnp.where(row < n, (start + row) % capacity,
+                                    padcap)
+                    # p_ins is max_priority ** alpha computed on the HOST
+                    # (float64 pow, cast f32) — see the gen_tracked note
+                    # in __init__; the trees only ever see host-rounded
+                    # values
+                    trees = dper.set_leaves(
+                        trees, idx, jnp.full((block,), p_ins, jnp.float32))
+                    trees = trees._replace(max_priority=max_pri)
+                    gen = gen.at[idx].add(1, mode="drop")
                 return storage, trees, gen
 
             return commit_tracked
 
         @partial(jax.jit, donate_argnums=(0, 1))
         def commit(storage, trees, frame, start, n):
-            storage = write(storage, frame, start, n)
-            row = jax.lax.iota(jnp.int32, block)
-            # pad rows repeat the first live slot: duplicate writes of the
-            # same value are harmless to the trees (see device_per.insert)
-            idx = jnp.where(row < n, (start + row) % capacity,
-                            start % capacity)
-            trees = dper.insert(trees, idx, alpha)
+            with jax.named_scope("ingest.ring_write"):
+                storage = write(storage, frame, start, n)
+            with jax.named_scope("ingest.tree_insert"):
+                row = jax.lax.iota(jnp.int32, block)
+                # pad rows repeat the first live slot: duplicate writes of
+                # the same value are harmless to the trees (see
+                # device_per.insert)
+                idx = jnp.where(row < n, (start + row) % capacity,
+                                start % capacity)
+                trees = dper.insert(trees, idx, alpha)
             return storage, trees
 
         return commit
@@ -345,13 +392,22 @@ class FusedDeviceReplay:
             return 0
         import jax
 
-        frame = (jax.device_put(views, self._device)
-                 if self._device is not None else jax.device_put(views))
-        self._staging.pop(n)
-        self._inflight = (frame, n)
-        # one registry inc per BLOCK (never per row): the unified ledger
-        # of the fused plane's H2D traffic (obs/registry)
-        REGISTRY.counter("fused.rows_staged").inc(n)
+        # how long the frame's oldest row sat in host staging: measured
+        # here, where the row waits, once per block
+        now = time.perf_counter()
+        wait_ms = 1e3 * (now - (self._staging.oldest_push() or now))
+        block = self.blocks_staged
+        with obs_trace.span("fused.stage_block", block=block, rows=n,
+                            wait_ms=wait_ms):
+            with obs_trace.span("fused.h2d"):
+                frame = (jax.device_put(views, self._device)
+                         if self._device is not None
+                         else jax.device_put(views))
+            self._staging.pop(n)
+            self._inflight = (frame, n, block, now)
+            self.blocks_staged = block + 1
+        # one registry observation per BLOCK (never per row)
+        REGISTRY.histogram("fused.staging_wait_ms").observe(wait_ms)
         return n
 
     def commit_staged(self) -> int:  # jaxlint: guarded-by=_buffer_lock
@@ -360,27 +416,40 @@ class FusedDeviceReplay:
         donated). Learner thread only. Returns rows committed."""
         if self._inflight is None:
             return 0
-        frame, n = self._inflight
+        frame, n, block, staged_at = self._inflight
         self._inflight = None
+        inflight_ms = 1e3 * (time.perf_counter() - staged_at)
         start = np.int32(self.head)
         if self.gen_tracked:
             # host-f64 pow, f32 cast: the trees only see host-rounded
             # values (bitwise twin contract — see __init__)
             p_ins = np.float32(self.max_priority ** self.alpha)
-            storage, self.trees, self.gen = self._commit(
-                self._store.arrays, self.trees, self.gen, frame, start,
-                np.int32(n), p_ins, np.float32(self.max_priority))
+            args = (self._store.arrays, self.trees, self.gen, frame, start,
+                    np.int32(n), p_ins, np.float32(self.max_priority))
         elif self.trees is not None:
-            storage, self.trees = self._commit(
-                self._store.arrays, self.trees, frame, start, np.int32(n))
+            args = (self._store.arrays, self.trees, frame, start,
+                    np.int32(n))
         else:
-            storage = self._commit(self._store.arrays, frame, start,
-                                   np.int32(n))
+            args = (self._store.arrays, frame, start, np.int32(n))
+        if not self._commit_tabled:  # first dispatch: enter the table
+            from d4pg_tpu.io.profiling import abstract_args
+
+            obs_trace.register_program("ingest.commit", self._commit,
+                             abstract_args(args))
+            self._commit_tabled = True
+        with obs_trace.span("fused.commit_staged", block=block, rows=n,
+                  inflight_ms=inflight_ms):
+            out = self._commit(*args)
+        if self.gen_tracked:
+            storage, self.trees, self.gen = out
+        elif self.trees is not None:
+            storage, self.trees = out
+        else:
+            storage = out
         self._store.swap_arrays(storage)
         self.head = int((self.head + n) % self.capacity)
         self.size = int(min(self.size + n, self.capacity))
-        REGISTRY.counter("fused.rows_committed").inc(n)
-        REGISTRY.counter("fused.blocks_committed").inc()
+        REGISTRY.histogram("fused.inflight_ms").observe(inflight_ms)
         return n
 
     # priority write-back for the dealt plane: reached from the device

@@ -23,7 +23,6 @@ from typing import Callable, Iterator
 import jax
 
 from d4pg_tpu.core.locking import TieredCondition, TieredLock
-from d4pg_tpu.obs.registry import REGISTRY
 
 
 class MultiRingStaging:
@@ -77,10 +76,6 @@ class MultiRingStaging:
     def push(self, batch, shard: int = 0, ticket: int | None = None) -> None:
         i = shard % self.shards
         ring, records = self._rings[i], self._records[i]
-        # per-frame registry inc, OUTSIDE the ring leaf lock (the obs
-        # plane is terminal-locked but ring hold times stay honest)
-        REGISTRY.counter("staging.rows_pushed").inc(
-            int(batch.obs.shape[0]))
         with self._ring_locks[i]:
             t = next(self._ticket) if ticket is None else ticket
             n = min(int(batch.obs.shape[0]), ring.size)
@@ -124,8 +119,11 @@ class MultiRingStaging:
                     # remainder (same ticket) at the head for the next
                     self._records[i].appendleft((_t, n - room))
                     n = room
+                # the rows keep the time they were first staged, so the
+                # merged frame's wait is its oldest shard row's
+                at = self._rings[i].oldest_push()
                 for piece in self._rings[i].take(n):
-                    self._merge.push(piece)
+                    self._merge.push(piece, at)
 
     # -- crash-recovery cut -------------------------------------------------
     def snapshot(self) -> dict:
@@ -149,6 +147,11 @@ class MultiRingStaging:
     def frame(self):
         self._refill()
         return self._merge.frame()
+
+    def oldest_push(self) -> float | None:
+        """When the oldest row of the merged stream was first staged in
+        its shard ring (after ``frame()`` has refilled the merge)."""
+        return self._merge.oldest_push()
 
     def pop(self, n: int) -> None:
         self._merge.pop(n)

@@ -78,6 +78,11 @@ def describe() -> dict:
     import jaxlib
 
     devices = jax.devices()
+    # the backend is up: the program's host spans now go to the profiler
+    # (obs/ imports no jax, so the annotation class is handed to it here)
+    from d4pg_tpu.obs import trace
+
+    trace.set_annotator(jax.profiler.TraceAnnotation)
     try:
         libtpu = metadata.version("libtpu")
     except metadata.PackageNotFoundError:  # a CPU-only install

@@ -1441,12 +1441,21 @@ def train(cfg: ExperimentConfig) -> dict:
                 # collective: merge every host's normalizer delta so all
                 # hosts standardize with identical statistics this cycle
                 obs_norm.sync()
-            # train (trace the first cycle when profiling is enabled)
+            # train. --profile_dir traces ONE cycle: the second where
+            # there is one (the first is the compile), with the program's
+            # own spans (obs/trace.span) beside the device's op line.
             timer.start()
-            first_cycle = epoch == 0 and cycle == 0
-            with xla_trace(cfg.profile_dir if first_cycle else None), \
+            nth_cycle = epoch * cfg.n_cycles + cycle
+            traced_cycle = min(1, cfg.n_epochs * cfg.n_cycles - 1)
+            with xla_trace(cfg.profile_dir if nth_cycle == traced_cycle
+                           else None), \
                     RecompileSentinel(same_thread=True) as compiles:
                 metrics = train_steps(cfg.train_steps_per_cycle)
+                # the fused path returns once the cycle is ENQUEUED: wait
+                # for its last metrics (fetched just below anyway), so the
+                # timer reads a completion rate and the trace holds the
+                # device's work
+                jax.block_until_ready(metrics)
             rate = timer.stop(cfg.train_steps_per_cycle)
             compiles_by_cycle.append(compiles.compilations)
             # weight staleness actors saw this cycle, measured before the
