@@ -165,6 +165,78 @@ def test_random_shift_properties(rng):
         imgs)
 
 
+def _shift_oracle(key, imgs, pad):
+    """NumPy crop of an edge-padded copy per image, offsets drawn one
+    sample at a time with the ``fold_in`` / ``randint`` of the docstring."""
+    b, h, w, _ = imgs.shape
+    out = np.empty_like(imgs)
+    for i in range(b):
+        dy, dx = np.asarray(jax.random.randint(
+            jax.random.fold_in(key, i), (2,), 0, 2 * pad + 1))
+        padded = np.pad(imgs[i], ((pad, pad), (pad, pad), (0, 0)),
+                        mode="edge")
+        out[i] = padded[dy:dy + h, dx:dx + w]
+    return out
+
+
+@pytest.mark.parametrize("jit", [False, True], ids=["eager", "jit"])
+@pytest.mark.parametrize("dtype", [np.uint8, np.float32])
+@pytest.mark.parametrize("pad", [1, 2, 4])
+@pytest.mark.parametrize("shape", [(32, 84, 84, 9), (16, 8, 8, 3),
+                                   (7, 13, 11, 3), (8, 12, 12, 3)])
+def test_random_shift_matches_numpy_oracle(rng, shape, pad, dtype, jit):
+    """Bit for bit: the batched shift moves every image where a per-image
+    crop at the same offsets moves it (benchmark/reference.py draws them
+    the same way, so anything else fails the pixel cell's loss gaps)."""
+    from d4pg_tpu.ops.augment import random_shift
+
+    imgs = (rng.integers(0, 256, shape).astype(dtype) if dtype == np.uint8
+            else rng.standard_normal(shape).astype(dtype))
+    key = jax.random.key(shape[0] + pad)
+    fn = jax.jit(random_shift, static_argnums=2) if jit else random_shift
+    out = np.asarray(fn(key, jnp.asarray(imgs), pad))
+    assert out.dtype == dtype
+    np.testing.assert_array_equal(out, _shift_oracle(key, imgs, pad))
+
+
+def test_random_shift_op_count_is_independent_of_batch():
+    """What the speed rests on: the same number of whole-batch operations
+    at any batch size, and none that indexes per sample (a vmapped
+    dynamic crop lowers to ``stablehlo.gather``, which the TPU runs as
+    one one-row update per image)."""
+    from d4pg_tpu.ops.augment import random_shift
+
+    def lowered(b):
+        imgs = jax.ShapeDtypeStruct((b, 84, 84, 9), jnp.uint8)
+        return jax.jit(random_shift, static_argnums=2).lower(
+            jax.random.key(0), imgs, 4).as_text()
+
+    small, large = lowered(8), lowered(64)
+    assert small.count("stablehlo.") == large.count("stablehlo.")
+    for text in (small, large):
+        for op in ("gather", "dynamic_slice", "dynamic_update_slice"):
+            assert "stablehlo." + op not in text, op
+
+
+def test_random_shift_sharded_over_data_equals_unsharded(rng):
+    """Elementwise in the batch axis: with the batch split over the
+    ``data`` axis of the 8-device mesh every shard sees the crops the
+    single-device computation sees."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    from d4pg_tpu.ops.augment import random_shift
+    from d4pg_tpu.parallel import MeshSpec, make_mesh
+
+    mesh = make_mesh(MeshSpec(data_parallel=8, model_parallel=1))
+    imgs = rng.integers(0, 256, (16, 12, 12, 3)).astype(np.uint8)
+    key = jax.random.key(5)
+    fn = jax.jit(random_shift, static_argnums=2)
+    want = np.asarray(fn(key, jnp.asarray(imgs), 4))
+    sharded = jax.device_put(imgs, NamedSharding(mesh, PartitionSpec("data")))
+    got = fn(key, sharded, 4)
+    assert got.sharding.is_equivalent_to(sharded.sharding, 4)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
 def test_update_step_with_shift_augmentation(rng):
     """--augment shift runs through the full jit'd pixel update: finite
     losses, and the augmented update diverges from the unaugmented one
