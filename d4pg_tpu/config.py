@@ -49,6 +49,12 @@ class ExperimentConfig:
     # tie the actor's conv encoder to the critic's, trained by the critic
     # loss only (SAC-AE/DrQ; pixels only — see learner/state.py)
     share_encoder: bool = False
+    # a sequence torso shared by actor and critic (models/torso.py): the
+    # path of a JSON file holding the torso block (``name``, ``tokens``,
+    # the widths ...), either as the whole file or under ``model.torso``
+    # as a benchmark configuration file has it. The env's observations
+    # become the history the torso reads (envs/wrappers.History).
+    torso: str = ""
     reward_scale: float = 1.0
     # replay
     memory_size: int = 1_000_000  # --rmsize
@@ -308,6 +314,16 @@ class ExperimentConfig:
             )
         return dataclasses.replace(self, **updates) if updates else self
 
+    def torso_block(self) -> dict | None:
+        """The torso block ``--torso`` names, or None."""
+        if not self.torso:
+            return None
+        import json
+
+        with open(self.torso) as f:
+            block = json.load(f)
+        return block.get("model", block).get("torso", block)
+
     def learner_config(self, obs_dim: int | tuple, act_dim: int) -> D4PGConfig:
         """``obs_dim`` is an int (vector obs) or an [H, W, C] tuple, which
         selects the conv-encoder pixel path (BASELINE.md config #4)."""
@@ -342,6 +358,7 @@ class ExperimentConfig:
             augment=self.augment,
             augment_pad=self.augment_pad,
             share_encoder=self.share_encoder,
+            torso=self.torso_block(),
             encoder_channels=(self.encoder_width,) * 4,
             lr_actor=self.lr_actor,
             lr_critic=self.lr_critic,
@@ -390,6 +407,12 @@ def build_parser() -> argparse.ArgumentParser:
     _add_bool_flag(p, "share_encoder", d.share_encoder,
                    "critic-trained shared conv encoder (SAC-AE/DrQ; "
                    "pixel envs)")
+    p.add_argument("--torso", default=d.torso,
+                   help="JSON file naming a sequence torso shared by actor "
+                        "and critic (models/torso.py): the torso block "
+                        "itself, or a benchmark configuration file with it "
+                        "under model.torso; observations become a history "
+                        "of torso.tokens values")
     p.add_argument("--rmsize", type=int, default=d.memory_size, dest="memory_size")
     p.add_argument("--bsize", type=int, default=d.batch_size, dest="batch_size")
     p.add_argument("--warmup", type=int, default=d.warmup)

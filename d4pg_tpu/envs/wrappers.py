@@ -63,6 +63,62 @@ def flatten_goal_obs(obs) -> np.ndarray:
     return np.asarray(obs)
 
 
+class History:
+    """The last steps of a state-vector env as one flat float32 vector of
+    ``width`` values: what a sequence torso tokenises (models/torso.py).
+
+    Each step contributes its observation and the action that led to it
+    (zeros at reset); as many whole steps as fit are kept, oldest first,
+    and the rest of the vector is zero: Humanoid-v4's 376 + 17 values make
+    ten steps of 4,096. ``reset`` fills the history with the first step.
+    Frame stacking for vectors, as ``FrameStack`` is for pixels: the replay
+    row stays a flat vector and the sequence lives inside the model."""
+
+    def __init__(self, env, width: int):
+        from collections import deque
+
+        import gymnasium.spaces
+
+        self.env = env
+        self._act = int(np.prod(env.action_space.shape))
+        step = int(np.prod(env.observation_space.shape)) + self._act
+        self._steps = int(width) // step
+        if self._steps < 1:
+            raise ValueError(
+                f"a history of {width} values holds no whole step of "
+                f"{step} (observation and action)")
+        self._width = int(width)
+        self._rows: "deque" = deque(maxlen=self._steps)
+        self.observation_space = gymnasium.spaces.Box(
+            low=-np.inf, high=np.inf, shape=(self._width,), dtype=np.float32)
+        self.action_space = env.action_space
+
+    def _row(self, obs, action):
+        return np.concatenate([np.asarray(obs, np.float32).ravel(),
+                               np.asarray(action, np.float32).ravel()])
+
+    def _flat(self):
+        out = np.zeros((self._width,), np.float32)
+        rows = np.concatenate(list(self._rows))
+        out[:rows.size] = rows
+        return out
+
+    def reset(self, **kw):
+        obs, info = self.env.reset(**kw)
+        row = self._row(obs, np.zeros((self._act,), np.float32))
+        for _ in range(self._steps):
+            self._rows.append(row)
+        return self._flat(), info
+
+    def step(self, action):
+        obs, reward, terminated, truncated, info = self.env.step(action)
+        self._rows.append(self._row(obs, action))
+        return self._flat(), reward, terminated, truncated, info
+
+    def close(self):
+        return self.env.close()
+
+
 class FrameStack:
     """Stack the last ``k`` pixel observations along the channel axis.
 

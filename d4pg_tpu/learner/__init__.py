@@ -15,6 +15,7 @@ from d4pg_tpu.learner.update import (
     act_ou,
     make_multi_update,
     make_update,
+    policy_params,
     update_step,
 )
 from d4pg_tpu.learner.fused import make_fused_chunk, make_sharded_fused_chunk
@@ -28,6 +29,7 @@ __all__ = [
     "act_ou",
     "make_multi_update",
     "make_update",
+    "policy_params",
     "update_step",
     "make_fused_chunk",
     "make_sharded_fused_chunk",

@@ -24,6 +24,7 @@ from d4pg_tpu.core.updates import hard_update, tie_encoder
 from d4pg_tpu.models.actor import Actor
 from d4pg_tpu.models.critic import CategoricalCritic, MixtureOfGaussianCritic
 from d4pg_tpu.models.encoder import PixelActor, PixelCategoricalCritic
+from d4pg_tpu.models.torso import TorsoCritic, TorsoSpec, build_torso
 
 
 @dataclasses.dataclass(frozen=True)
@@ -98,8 +99,27 @@ class D4PGConfig:
     # these via the startup micro-autotuner BEFORE building this config
     # (config.ExperimentConfig.learner_config -> ops/autotune.py).
     projection: str = "einsum"
+    # A sequence torso shared by actor and critic (models/torso.py): a
+    # ``TorsoSpec``, or the dict a configuration file's ``model.torso``
+    # block holds (frozen here). The observation stays a flat float32
+    # vector of ``torso.tokens`` values; the torso's parameters live in the
+    # critic's tree ONLY (stored once: parameter, gradient, two Adam
+    # moments, target copy), the actor's tree is its head, and the torso is
+    # trained by the critic loss alone (``update._torso_update_step``).
+    torso: Any = None
 
     def __post_init__(self):
+        if isinstance(self.torso, dict):
+            object.__setattr__(self, "torso", TorsoSpec.from_dict(self.torso))
+        if self.torso is not None:
+            if self.pixels or self.critic_family != "categorical":
+                raise ValueError(
+                    "a torso reads flat float32 observations under the "
+                    "categorical critic")
+            if self.obs_dim != self.torso.tokens:
+                raise ValueError(
+                    f"obs_dim {self.obs_dim} is not the torso's "
+                    f"{self.torso.tokens} tokens")
         object.__setattr__(self, "hidden", tuple(self.hidden))
         object.__setattr__(self, "obs_shape", tuple(self.obs_shape))
         object.__setattr__(self, "encoder_channels",
@@ -142,6 +162,7 @@ class D4PGConfig:
         return tuple(self.obs_shape) if self.pixels else self.obs_dim
 
     def build_actor(self) -> nn.Module:
+        """With a torso this is the head alone: it reads the latent."""
         if self.pixels:
             # share_encoder => the policy loss must not train the (tied)
             # encoder: stop the gradient at the latent. Same param tree.
@@ -151,6 +172,11 @@ class D4PGConfig:
         return Actor(self.act_dim, hidden=self.hidden, dtype=self._dtype)
 
     def build_critic(self) -> nn.Module:
+        if self.torso is not None:
+            return TorsoCritic(
+                build_torso(self.torso, self._dtype),
+                CategoricalCritic(self.n_atoms, hidden=self.hidden,
+                                  dtype=self._dtype))
         if self.critic_family == "mog":
             return MixtureOfGaussianCritic(
                 self.n_components, hidden=self.hidden, dtype=self._dtype
@@ -189,7 +215,9 @@ def init_state(config: D4PGConfig, key: Array) -> D4PGState:
     k_actor, k_critic, k_state = jax.random.split(key, 3)
     obs = config.dummy_obs()
     act = jnp.zeros((1, config.act_dim), jnp.float32)
-    actor_params = config.build_actor().init(k_actor, obs)
+    actor_params = config.build_actor().init(
+        k_actor, obs if config.torso is None
+        else jnp.zeros((1, config.torso.hidden_size), jnp.float32))
     critic_params = config.build_critic().init(k_critic, obs, act)
     if config.share_encoder:
         # the tie holds from step 0: otherwise the target actor starts as

@@ -173,6 +173,8 @@ def update_step(
     / ``actor_loss`` / ``q_mean`` and the per-sample ``td_error`` vector (the
     PER priority signal, ``ddpg.py:252-255``).
     """
+    if config.torso is not None:
+        return _torso_update_step(config, state, batch, is_weights)
     key, sub = jax.random.split(state.key)
 
     if config.augment == "shift":
@@ -272,6 +274,94 @@ def update_step(
     return new_state, metrics
 
 
+def _torso_update_step(
+    config: D4PGConfig,
+    state: D4PGState,
+    batch: TransitionBatch,
+    is_weights: Array | None,
+) -> tuple[D4PGState, dict[str, Array]]:
+    """``update_step`` for a model with a shared sequence torso
+    (``config.torso``, models/torso.py). The torso is stored once, in the
+    critic's tree, and run once per input per phase: three forward passes
+    and one backward a step.
+
+      1. the TARGET torso on ``next_obs``; target actor and target critic
+         heads read its latent (the Bellman target);
+      2. the torso on ``obs`` under the critic loss, differentiated: the
+         critic loss alone trains it (as the shared pixel encoder);
+      3. the torso just stepped on ``obs`` again, through a stop-gradient:
+         the actor head and the stepped critic head read it for the actor
+         loss, which trains the actor's head only.
+
+    Metrics gain ``route_counts`` ``[layers, num_experts]`` int32: how many
+    of pass 2's assignments the router gave each expert."""
+    key, _sub = jax.random.split(state.key)
+    actor, critic = config.build_actor(), config.build_critic()
+
+    with jax.named_scope("update.critic"):
+        z_next, _ = critic.latent(state.target_critic_params, batch.next_obs)
+        next_action = actor.apply(state.target_actor_params, z_next)
+        target_probs = critic.of_latent(state.target_critic_params, z_next,
+                                        next_action)
+        proj = jax.lax.stop_gradient(
+            _project(config, target_probs, batch.reward, batch.discount))
+
+        def critic_loss_fn(p):
+            z, counts = critic.latent(p, batch.obs)
+            loss, td = categorical_td_loss(
+                proj, critic.of_latent(p, z, batch.action),
+                weights=is_weights)
+            return loss, (td, counts)
+
+        (critic_loss, (td_error, counts)), critic_grads = jax.value_and_grad(
+            critic_loss_fn, has_aux=True)(state.critic_params)
+    with jax.named_scope("update.optim"):
+        critic_updates, critic_opt_state = config.optimizer(
+            config.lr_critic).update(
+            critic_grads, state.critic_opt_state, state.critic_params)
+        critic_params = optax.apply_updates(state.critic_params,
+                                            critic_updates)
+
+    with jax.named_scope("update.actor"):
+        z = jax.lax.stop_gradient(critic.latent(critic_params, batch.obs)[0])
+
+        def actor_loss_fn(p):
+            action = actor.apply(p, z)
+            probs = critic.of_latent(critic_params, z, action)
+            return (-jnp.mean(expected_q(config.support, probs))
+                    + config.action_l2 * jnp.mean(jnp.square(action)))
+
+        actor_loss, actor_grads = jax.value_and_grad(actor_loss_fn)(
+            state.actor_params)
+    with jax.named_scope("update.optim"):
+        actor_updates, actor_opt_state = config.optimizer(
+            config.lr_actor).update(
+            actor_grads, state.actor_opt_state, state.actor_params)
+        actor_params = optax.apply_updates(state.actor_params, actor_updates)
+        target_actor_params = soft_update(
+            state.target_actor_params, actor_params, config.tau)
+        target_critic_params = soft_update(
+            state.target_critic_params, critic_params, config.tau)
+    new_state = D4PGState(
+        actor_params=actor_params,
+        critic_params=critic_params,
+        target_actor_params=target_actor_params,
+        target_critic_params=target_critic_params,
+        actor_opt_state=actor_opt_state,
+        critic_opt_state=critic_opt_state,
+        key=key,
+        step=state.step + 1,
+    )
+    metrics = {
+        "critic_loss": critic_loss,
+        "actor_loss": actor_loss,
+        "q_mean": -actor_loss,
+        "td_error": td_error,
+        "route_counts": counts,
+    }
+    return new_state, metrics
+
+
 def make_update(config: D4PGConfig, donate: bool = True, use_is_weights: bool = True):
     """jit-compile the update with ``config`` closed over statically.
 
@@ -331,6 +421,26 @@ def make_multi_update(
     return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
 
+def policy_params(config: D4PGConfig, state: D4PGState) -> Any:
+    """What acting needs of a learner state, and what the weight plane
+    publishes: the actor's tree; with a torso, the actor's head beside the
+    critic's torso (the one place it is stored)."""
+    if config.torso is None:
+        return state.actor_params
+    return {"params": {"actor": state.actor_params["params"],
+                       "torso": state.critic_params["params"]["torso"]}}
+
+
+def _policy(config: D4PGConfig, params: Any, obs: Array) -> Array:
+    """The greedy action from ``policy_params``."""
+    if config.torso is None:
+        return config.build_actor().apply(params, obs)
+    latent, _counts = config.build_critic().torso.apply(
+        params["params"]["torso"], obs)
+    return config.build_actor().apply({"params": params["params"]["actor"]},
+                                      latent)
+
+
 @partial(jax.jit, static_argnums=(0,))
 def act(
     config: D4PGConfig,
@@ -345,7 +455,7 @@ def act(
     Batched: obs [B, obs_dim] -> actions [B, act_dim]; one key for the whole
     batch (split upstream per actor for decorrelation).
     """
-    action = config.build_actor().apply(actor_params, obs)
+    action = _policy(config, actor_params, obs)
     noise = jax.random.normal(key, action.shape) * epsilon
     return jnp.clip(action + noise, -1.0, 1.0)
 
@@ -353,7 +463,7 @@ def act(
 @partial(jax.jit, static_argnums=(0,))
 def act_deterministic(config: D4PGConfig, actor_params: Any, obs: Array) -> Array:
     """Greedy action for evaluation (``main.py:121-130``)."""
-    return config.build_actor().apply(actor_params, obs)
+    return _policy(config, actor_params, obs)
 
 
 @partial(jax.jit, static_argnums=(0,))
@@ -379,7 +489,7 @@ def act_ou(
     """
     from d4pg_tpu.core.noise import ou
 
-    greedy = config.build_actor().apply(actor_params, obs)
+    greedy = _policy(config, actor_params, obs)
     new_state, noise = ou.sample(ou_state, key, theta=theta, mu=mu,
                                  sigma=sigma, dt=dt)
     action = jnp.clip(greedy + epsilon * noise, -1.0, 1.0)

@@ -1,0 +1,462 @@
+"""Sequence torsos: a language model's blocks as the shared trunk of actor
+and critic.
+
+A torso reads a flat float32 observation (a stacked history, as the pixel
+rows stack frames), turns every value into a token, runs the tokens through
+transformer layers and pools them into one latent per row; D4PG's own heads
+(``models/actor.py``, ``models/critic.py``) read the latent as they read a
+state vector. ``D4PGConfig.torso`` names one (``TORSOS``) with its sizes
+(``TorsoSpec``, made from a configuration file's ``model.torso`` block).
+
+``mellum2`` is the Mellum2-12B-A2.5B layer: RMSNorm, grouped-query
+attention with rotary embeddings (default on sliding-window layers, YaRN on
+full ones), a float32 router over all ``num_experts`` experts with the
+largest ``num_experts_per_tok`` renormalised, SwiGLU experts. The layer is
+told which experts it holds (``experts_held``, one chip's share under
+expert parallelism, ``parallel/partition.expert_share``): it routes over
+all of them and adds its own experts' part of the result; on one chip that
+is the whole layer without the exchange. No capacity, no dropped token:
+every assignment to a held expert is computed whatever the routing.
+
+Tokens are Gato's (Reed et al. 2022, sec. 2.1): mu-law, clip to [-1, 1],
+``bins`` uniform bins, on the float32 values (bfloat16 cannot tell 1,024
+bins apart); bin ``b`` is row ``b`` of the embedding held here.
+
+Memory. A layer runs one sequence at a time (``lax.map``) and each
+sequence is rematerialised on its own in the backward pass, so only the
+``[B, T, D]`` float32 layer boundaries are kept and nothing inside a layer
+is held for more than one sequence; with no capacity the sorted expert
+buffer is sized for every assignment landing here, ``T * k`` rows. The
+bfloat16 copies of a layer's matrices are made once a layer and a pass.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from d4pg_tpu.ops import attention as attn_ops
+from d4pg_tpu.ops import grouped as grouped_ops
+
+HI = jax.lax.Precision.HIGHEST
+MU, M = 100.0, 256.0  # Gato's mu-law
+# the sorted expert buffer serves routing up to this multiple of an even
+# load before the every-assignment buffer takes over
+EXPERT_BUFFER = 1.5
+
+
+def _freeze(x):
+    if isinstance(x, dict):
+        return tuple(sorted((k, _freeze(v)) for k, v in x.items()))
+    if isinstance(x, (list, tuple)):
+        return tuple(_freeze(v) for v in x)
+    return x
+
+
+@dataclasses.dataclass(frozen=True)
+class TorsoSpec:
+    """Sizes of a torso; hashable (it is part of the jit-static config).
+    Keys follow the model's published ``config.json`` where it has one."""
+
+    name: str
+    tokens: int  # sequence length = the observation's width
+    vocab_rows: int  # rows of the embedding held here
+    hidden_size: int
+    num_attention_heads: int
+    num_key_value_heads: int
+    head_dim: int
+    layer_types: tuple  # 'sliding_attention' | 'full_attention' per layer
+    sliding_window: int
+    num_experts: int
+    num_experts_per_tok: int
+    moe_intermediate_size: int
+    experts_held: tuple  # [lo, hi) of the experts this chip holds
+    rope_parameters: Any  # layer type -> rope block, frozen
+    rms_norm_eps: float = 1e-6
+    norm_topk_prob: bool = True
+    bins: int = 1024
+
+    @classmethod
+    def from_dict(cls, d: dict) -> "TorsoSpec":
+        names = {f.name for f in dataclasses.fields(cls)}
+        unknown = sorted(set(d) - names)
+        if unknown:
+            raise ValueError(f"unknown torso keys {unknown}")
+        return cls(**{k: _freeze(v) for k, v in d.items()})
+
+    def __post_init__(self):
+        if self.name not in TORSOS:
+            raise ValueError(f"unknown torso {self.name!r}; one of "
+                             f"{sorted(TORSOS)}")
+        lo, hi = self.experts_held
+        if not 0 <= lo < hi <= self.num_experts:
+            raise ValueError(f"experts_held {self.experts_held} is not a "
+                             f"range of the {self.num_experts} experts")
+        if self.bins > self.vocab_rows:
+            raise ValueError(f"{self.bins} bins need as many embedding rows; "
+                             f"{self.vocab_rows} are held")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("query heads do not divide into key/value heads")
+
+    @property
+    def n_held(self) -> int:
+        return self.experts_held[1] - self.experts_held[0]
+
+    def rope_for(self, layer_type: str) -> dict:
+        return dict(dict(self.rope_parameters)[layer_type])
+
+
+# -- tokens -------------------------------------------------------------------
+def tokenise(spec: TorsoSpec, values):
+    """Gato's tokens of float32 ``values``: int32 in ``[0, bins)``."""
+    v = values.astype(jnp.float32)
+    v = jnp.sign(v) * jnp.log(jnp.abs(v) * MU + 1.0) / math.log(M * MU + 1.0)
+    v = jnp.clip(v, -1.0, 1.0)
+    b = jnp.floor((v + 1.0) * (0.5 * spec.bins)).astype(jnp.int32)
+    return jnp.clip(b, 0, spec.bins - 1)
+
+
+# -- rotary embeddings --------------------------------------------------------
+def rope_inv_freq(rope: dict, head_dim: int) -> tuple[np.ndarray, float]:
+    """``(inv_freq [head_dim / 2], attention_factor)`` of a rope block:
+    ``default`` or ``yarn`` as Hugging Face's ``_compute_yarn_parameters``
+    has it."""
+    half = head_dim // 2
+    base = float(rope["rope_theta"])
+    pos = base ** (-2.0 * np.arange(half, dtype=np.float64) / head_dim)
+    if rope["rope_type"] == "default":
+        return pos, 1.0
+    if rope["rope_type"] != "yarn":
+        raise ValueError(f"unknown rope_type {rope['rope_type']!r}")
+    factor = float(rope["factor"])
+    orig = float(rope["original_max_position_embeddings"])
+
+    def dim_of(rotations: float) -> float:
+        return head_dim * math.log(orig / (rotations * 2 * math.pi)) / (
+            2 * math.log(base))
+
+    low = max(math.floor(dim_of(float(rope["beta_fast"]))), 0)
+    high = min(math.ceil(dim_of(float(rope["beta_slow"]))), head_dim - 1)
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    inv = (1.0 - ramp) * pos + ramp * pos / factor
+    return inv, float(rope.get("attention_factor")
+                      or 0.1 * math.log(factor) + 1.0)
+
+
+def rope_tables(rope: dict, head_dim: int, t_len: int):
+    """``cos, sin`` of shape ``[t_len, head_dim / 2]``, float32."""
+    inv, scale = rope_inv_freq(rope, head_dim)
+    angle = (jnp.arange(t_len, dtype=jnp.float32)[:, None]
+             * jnp.asarray(inv, jnp.float32)[None, :])
+    return jnp.cos(angle) * scale, jnp.sin(angle) * scale
+
+
+def apply_rope(x, cos, sin):
+    """Rotate ``x [..., T, D]`` by halves (the Hugging Face pairing:
+    element ``i`` with ``i + D / 2``): ``x cos + rotate_half(x) sin``."""
+    half = x.shape[-1] // 2
+    turned = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+    return (x * jnp.concatenate([cos, cos], axis=-1)
+            + turned * jnp.concatenate([sin, sin], axis=-1))
+
+
+def rms_norm(x, scale, eps: float):
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                             + eps) * scale
+
+
+# -- expert layer -------------------------------------------------------------
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _to_sorted(h, top, inv, k: int):
+    """Token ``top[r] // k``'s row of ``h [T, D]`` for each of the first
+    ``rows`` sorted assignments ``top = order[:rows]``. The backward pass
+    is the inverse gather and a sum over each token's ``k`` assignments,
+    not a scatter-add."""
+    return h[top // k]
+
+
+def _to_sorted_fwd(h, top, inv, k):
+    return h[top // k], (inv, h.shape[0])
+
+
+def _to_sorted_bwd(k, res, g):
+    inv, t_len = res
+    back = jnp.take(g, inv, axis=0, mode="fill", fill_value=0)
+    back = back.reshape(t_len, k, g.shape[-1])
+    return (jnp.sum(back.astype(jnp.float32), axis=1).astype(g.dtype),
+            None, None)
+
+
+_to_sorted.defvjp(_to_sorted_fwd, _to_sorted_bwd)
+
+
+@jax.custom_vjp
+def _from_sorted(y, top, inv):
+    """Sorted rows ``y [rows, D]`` back in assignment order ``[T * k, D]``;
+    an assignment sorted past the buffer's ``rows`` reads zero."""
+    return jnp.take(y, inv, axis=0, mode="fill", fill_value=0)
+
+
+def _from_sorted_fwd(y, top, inv):
+    return _from_sorted(y, top, inv), (top,)
+
+
+def _from_sorted_bwd(res, g):
+    return g[res[0]], None, None
+
+
+_from_sorted.defvjp(_from_sorted_fwd, _from_sorted_bwd)
+
+
+def route(spec: TorsoSpec, h, router):
+    """The router on float32 ``h [T, D]``: ``(weights [T, k], experts
+    [T, k] int32, counts [num_experts] int32)``. Softmax over all experts,
+    the ``k`` largest, renormalised."""
+    logits = jnp.dot(h, router, precision=HI)
+    p = jax.nn.softmax(logits, axis=-1)
+    w, e = jax.lax.top_k(p, spec.num_experts_per_tok)
+    if spec.norm_topk_prob:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    counts = jnp.sum(e.reshape(-1, 1) == jnp.arange(spec.num_experts)[None],
+                     axis=0, dtype=jnp.int32)
+    return w, e.astype(jnp.int32), counts
+
+
+def even_load_rows(spec: TorsoSpec, t_len: int) -> int:
+    """Rows of the sorted buffer that serve a sequence whose routing is
+    within ``EXPERT_BUFFER`` of even: a multiple of the kernels' row tile,
+    at most every assignment."""
+    every = t_len * spec.num_experts_per_tok
+    even = every * spec.n_held / spec.num_experts
+    tile = grouped_ops.ROW_TILE
+    return min(every, -(-int(EXPERT_BUFFER * even) // tile) * tile)
+
+
+def expert_share(spec: TorsoSpec, p: dict, h, dtype, grouped: str = "ragged"):
+    """This chip's experts' part of the layer for one sequence: float32
+    ``h [T, D]`` (normed) -> ``(out [T, D] float32, counts [num_experts])``.
+
+    Assignments are sorted with the held experts first, so the held ones
+    are the first ``n`` rows of the sorted order whatever the routing; a
+    grouped product multiplies each held expert's rows by its matrices.
+    Nothing is dropped: the sorted buffer holds every assignment
+    (``T * k`` rows) when it must. When the ``n`` landing here fit
+    ``even_load_rows`` (the usual case) the same computation runs on that
+    many rows instead (``lax.cond``: both are compiled, one runs): gathers
+    and elementwise work follow the buffer, not the ``n`` rows in it."""
+    lo, hi = spec.experts_held
+    k, n_exp = spec.num_experts_per_tok, spec.num_experts
+    t_len = h.shape[0]
+    with jax.named_scope("torso.route"):
+        w, e, counts = route(spec, h, p["router"]["kernel"])
+        key = jnp.mod(e.reshape(-1) - lo, n_exp)
+        order = jnp.argsort(key, stable=True)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        sizes = counts[lo:hi]
+        n_held = jnp.sum(sizes)
+
+    def on(rows: int):
+        def part(h, w):
+            # Rows past the held groups belong to absent experts: the
+            # grouped product neither reads nor writes them, so they hold
+            # whatever the buffer held (on the chip: anything, NaN
+            # included), in the forward products' outputs and in the
+            # backward products' input gradients alike. Every product's
+            # input and output goes through ``keep``: a select, whose
+            # transpose is the same select on the gradient.
+            valid = (jnp.arange(rows) < n_held)[:, None]
+            keep = lambda a: jnp.where(  # noqa: E731
+                valid, a, jnp.zeros((), a.dtype))
+            top = order[:rows]
+            with jax.named_scope("torso.route"):
+                xs = _to_sorted(h.astype(dtype), top, inv, k)
+                # each row's router weight, applied where the rows are few
+                # (the buffer) and not where they are many (T * k)
+                w_rows = _to_sorted(w.reshape(-1, 1), top, inv, 1)
+            with jax.named_scope("torso.experts"):
+                dot = lambda a, b: keep(grouped_ops.grouped_matmul(  # noqa
+                    keep(a), b, sizes, impl=grouped))
+                g = dot(xs, p["gate"]["kernel"])
+                u = dot(xs, p["up"]["kernel"])
+                mid = (jax.nn.silu(g.astype(jnp.float32))
+                       * u.astype(jnp.float32)).astype(dtype)
+                y = dot(mid, p["down"]["kernel"])
+                y = (y.astype(jnp.float32) * w_rows).astype(dtype)
+            with jax.named_scope("torso.route"):
+                y = _from_sorted(y, top, inv).reshape(t_len, k, -1)
+                return jnp.sum(y, axis=1, dtype=jnp.float32)
+        return part
+
+    every, usual = t_len * k, even_load_rows(spec, t_len)
+    if usual >= every:
+        return on(every)(h, w), counts
+    return jax.lax.cond(n_held <= usual, on(usual), on(every), h, w), counts
+
+
+# -- the torso ----------------------------------------------------------------
+class MellumTorso:
+    """``init(key) -> params``; ``apply(params, obs [B, tokens]) ->
+    (latent [B, hidden_size] float32, route_counts [layers, num_experts]
+    int32)``. ``params`` is a plain dict; matrix leaves are named
+    ``kernel`` and norm gains ``scale``."""
+
+    def __init__(self, spec: TorsoSpec, dtype=jnp.float32):
+        self.spec = spec
+        self.dtype = dtype
+
+    def attention_impl(self) -> str:
+        """The splash kernel on a TPU where its tiling takes the sizes,
+        the blockwise ``jnp`` form otherwise."""
+        fits = attn_ops.splash_fits(self.spec.tokens, self.spec.head_dim)
+        return ("splash" if fits and jax.default_backend() == "tpu"
+                else "blockwise")
+
+    def grouped_impl(self) -> str:
+        """The megablox kernels on a TPU where their tiling takes the
+        widths, ``jax.lax.ragged_dot`` otherwise (ops/grouped.py)."""
+        fits = grouped_ops.megablox_fits(self.spec.hidden_size,
+                                         self.spec.moe_intermediate_size)
+        return ("megablox" if fits and jax.default_backend() == "tpu"
+                else "ragged")
+
+    def init(self, key):
+        s = self.spec
+        d, hq = s.hidden_size, s.num_attention_heads * s.head_dim
+        hkv = s.num_key_value_heads * s.head_dim
+        n, f = s.n_held, s.moe_intermediate_size
+
+        def normal(key, shape, fan_in):
+            return {"kernel": jax.random.normal(key, shape, jnp.float32)
+                    / math.sqrt(fan_in)}
+
+        keys = iter(jax.random.split(key, 1 + 8 * len(s.layer_types)))
+        gain = lambda: {"scale": jnp.ones((d,), jnp.float32)}  # noqa: E731
+        params = {"embed": normal(next(keys), (s.vocab_rows, d), 1.0),
+                  "final_norm": gain()}
+        for i in range(len(s.layer_types)):
+            params[f"layer_{i}"] = {
+                "attn_norm": gain(), "moe_norm": gain(),
+                "q": normal(next(keys), (d, hq), d),
+                "k": normal(next(keys), (d, hkv), d),
+                "v": normal(next(keys), (d, hkv), d),
+                "o": normal(next(keys), (hq, d), hq),
+                "router": normal(next(keys), (d, s.num_experts), d),
+                "gate": normal(next(keys), (n, d, f), d),
+                "up": normal(next(keys), (n, d, f), d),
+                "down": normal(next(keys), (n, f, d), f),
+            }
+        return params
+
+    def _attend(self, p: dict, x, layer_type: str):
+        """Attention of one sequence ``x [T, D]`` added to it."""
+        s, dtype = self.spec, self.dtype
+        t_len = x.shape[0]
+        hkv, dh = s.num_key_value_heads, s.head_dim
+        group = s.num_attention_heads // hkv
+        full = layer_type == "full_attention"
+        with jax.named_scope("torso.attn_full" if full
+                             else "torso.attn_window"):
+            h = rms_norm(x, p["attn_norm"]["scale"], s.rms_norm_eps).astype(
+                dtype)
+            proj = lambda name, out: jnp.dot(  # noqa: E731
+                h, p[name]["kernel"], preferred_element_type=out)
+            cos, sin = rope_tables(s.rope_for(layer_type), dh, t_len)
+            # query head i reads key/value head i // group
+            q = proj("q", jnp.float32).reshape(t_len, hkv, group, dh)
+            q = (apply_rope(q.transpose(1, 2, 0, 3), cos, sin)
+                 / math.sqrt(dh)).astype(dtype)
+            k = proj("k", jnp.float32).reshape(t_len, hkv, dh)
+            k = apply_rope(k.transpose(1, 0, 2), cos, sin).astype(dtype)
+            v = proj("v", dtype).reshape(t_len, hkv, dh).transpose(1, 0, 2)
+            a = attn_ops.causal_attention(
+                q[None], k[None], v[None],
+                window=None if full else s.sliding_window,
+                impl=self.attention_impl())[0]
+            a = a.transpose(2, 0, 1, 3).reshape(t_len, -1)
+            return x + jnp.dot(a, p["o"]["kernel"],
+                               preferred_element_type=jnp.float32)
+
+    def _sequence(self, p: dict, x, layer_type: str):
+        """One layer on one sequence: ``x [T, D] -> (x, counts)``."""
+        x = self._attend(p, x, layer_type)
+        with jax.named_scope("torso.route"):
+            h = rms_norm(x, p["moe_norm"]["scale"], self.spec.rms_norm_eps)
+        out, counts = expert_share(self.spec, p, h, self.dtype,
+                                   self.grouped_impl())
+        return x + out, counts
+
+    def _layer(self, p: dict, x, layer_type: str):
+        """One layer on the batch, a sequence at a time. The compute-dtype
+        copies of the matrices are made once, here, and live as long as
+        the layer; each sequence is rematerialised on its own in the
+        backward pass."""
+        cast = {name: ({"kernel": leaf["kernel"].astype(self.dtype)}
+                       if "kernel" in leaf and name != "router" else leaf)
+                for name, leaf in p.items()}
+        per_seq = jax.checkpoint(
+            lambda xs: self._sequence(cast, xs, layer_type))
+        x, counts = jax.lax.map(per_seq, x)
+        return x, jnp.sum(counts, axis=0)
+
+    def apply(self, params: dict, obs):
+        s = self.spec
+        with jax.named_scope("torso.embed"):
+            tokens = tokenise(s, obs)
+            x = params["embed"]["kernel"][tokens]
+        counts = []
+        for i, layer_type in enumerate(s.layer_types):
+            layer = jax.checkpoint(
+                lambda p, x, lt=layer_type: self._layer(p, x, lt))
+            x, c = layer(params[f"layer_{i}"], x)
+            counts.append(c)
+        with jax.named_scope("torso.pool"):
+            x = rms_norm(x, params["final_norm"]["scale"], s.rms_norm_eps)
+            latent = jnp.mean(x, axis=1)
+        return latent, jnp.stack(counts)
+
+
+class TorsoCritic:
+    """A torso and the critic head that reads its latent, as one network
+    with flax's two calls. Its tree, ``{"params": {"torso": ..., "critic":
+    ...}}``, is the only place the torso's parameters live: the actor's
+    tree is its head alone, and reads the latent through ``latent``."""
+
+    def __init__(self, torso, head):
+        self.torso, self.head = torso, head
+
+    def init(self, key, obs, action):
+        k_torso, k_head = jax.random.split(key)
+        latent = jnp.zeros((obs.shape[0], self.torso.spec.hidden_size),
+                           jnp.float32)
+        return {"params": {
+            "torso": self.torso.init(k_torso),
+            "critic": self.head.init(k_head, latent, action)["params"]}}
+
+    def latent(self, params, obs):
+        """``(latent, route_counts)`` of the torso in ``params``."""
+        return self.torso.apply(params["params"]["torso"], obs)
+
+    def of_latent(self, params, latent, action, logits: bool = False):
+        """The head in ``params`` on a latent."""
+        return self.head.apply({"params": params["params"]["critic"]},
+                               latent, action, logits)
+
+    def apply(self, params, obs, action, return_logits: bool = False):
+        return self.of_latent(params, self.latent(params, obs)[0], action,
+                              return_logits)
+
+
+TORSOS = {"mellum2": MellumTorso}
+
+
+def build_torso(spec: TorsoSpec, dtype=jnp.float32):
+    return TORSOS[spec.name](spec, dtype)

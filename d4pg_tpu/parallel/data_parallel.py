@@ -59,6 +59,12 @@ def check_mesh_compatible(config: D4PGConfig) -> None:
     formulation (which shards trivially); fail loudly rather than either,
     and print the rule table the mesh layout WOULD resolve to, so the fix
     (and what it buys) is in the error itself."""
+    if config.torso is not None:
+        raise ValueError(
+            "a torso (--torso) runs on one device: its expert layer is one "
+            "chip's share without the exchange, and its metrics "
+            "(route_counts) and kernels have no sharding rule yet "
+            "(ROADMAP Reach 11)")
     if config.projection in ("pallas", "pallas_ce"):
         raise ValueError(
             f"--projection {config.projection} is single-device only "

@@ -51,8 +51,26 @@ __all__ = [
     "dealt_block_sharding",
     "data_spec", "shardings_for", "state_specs", "state_shardings",
     "replica_stack_shardings", "make_shard_and_gather_fns",
-    "named_flat", "named_unflat",
+    "named_flat", "named_unflat", "expert_share",
 ]
+
+
+# --------------------------------------------------------------------------
+# expert parallelism: which experts a chip holds
+# --------------------------------------------------------------------------
+def expert_share(num_experts: int, n_shares: int, index: int) -> tuple:
+    """``[lo, hi)`` of the experts that share ``index`` of ``n_shares``
+    holds: a contiguous, equal range. An expert layer is told its range
+    (``TorsoSpec.experts_held``), routes over all ``num_experts`` and adds
+    its own experts' part of the result (``models/torso.expert_share``).
+    On one chip the layer runs without its exchange; across chips the
+    parts meet in an all-to-all that no code here stands in for."""
+    if num_experts % n_shares or not 0 <= index < n_shares:
+        raise ValueError(
+            f"{num_experts} experts do not divide into {n_shares} equal "
+            f"shares, or share {index} is not one of them")
+    held = num_experts // n_shares
+    return index * held, (index + 1) * held
 
 
 # --------------------------------------------------------------------------

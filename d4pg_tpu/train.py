@@ -46,7 +46,12 @@ from d4pg_tpu.envs import (
 from d4pg_tpu.io import CheckpointManager, CsvLogger, MetricsBus, TensorBoardSink
 from d4pg_tpu.obs.containment import contained_crash
 from d4pg_tpu.io.profiling import RecompileSentinel, StepTimer, xla_trace
-from d4pg_tpu.learner import init_state, make_multi_update, make_update
+from d4pg_tpu.learner import (
+    init_state,
+    make_multi_update,
+    make_update,
+    policy_params,
+)
 from d4pg_tpu.learner.loop import FusedLoop
 from d4pg_tpu.learner.pipeline import ChunkPipeline
 from d4pg_tpu.parallel import (
@@ -64,8 +69,23 @@ from d4pg_tpu.replay.uniform import TransitionBatch
 
 
 def make_env_fn(cfg: ExperimentConfig, seed: int):
-    """Build one env instance; gymnasium by id, with fake-env fallbacks for
-    ids 'point' and 'fake-goal' (tests/smoke, SURVEY.md §4)."""
+    """Build one env instance (``_make_env_fn``); with ``--torso`` its
+    observations are the history the torso reads (envs/wrappers.History)."""
+    make = _make_env_fn(cfg, seed)
+    block = cfg.torso_block()
+    if block is None:
+        return make
+    if cfg.her:
+        raise ValueError("--torso reads flat state vectors; --her envs have "
+                         "dict observations")
+    from d4pg_tpu.envs.wrappers import History
+
+    return lambda: History(make(), int(block["tokens"]))
+
+
+def _make_env_fn(cfg: ExperimentConfig, seed: int):
+    """gymnasium by id, with fake-env fallbacks for ids 'point' and
+    'fake-goal' (tests/smoke, SURVEY.md §4)."""
     if ((cfg.env in ("point", "fake-goal")
          or cfg.env.startswith("point-slow:")) and cfg.frame_stack > 1):
         # fail loudly rather than silently training on unstacked frames —
@@ -646,7 +666,7 @@ def train(cfg: ExperimentConfig) -> dict:
                 if obs_norm is not None else None)
 
     weights.publish(
-        state.actor_params if mesh is None else jax.device_get(state.actor_params),
+        policy_params(config, state) if mesh is None else jax.device_get(policy_params(config, state)),
         step=int(jax.device_get(state.step)),
         norm_stats=_norm_snapshot(),
     )
@@ -825,7 +845,7 @@ def train(cfg: ExperimentConfig) -> dict:
     def publish():
         if replicas or mesh_group is not None:
             return  # the merge owns the version stream (one writer)
-        p = state.actor_params if mesh is None else jax.device_get(state.actor_params)
+        p = policy_params(config, state) if mesh is None else jax.device_get(policy_params(config, state))
         weights.publish(p, step=lstep, norm_stats=_norm_snapshot())
 
     if obs_norm is not None:
@@ -894,10 +914,10 @@ def train(cfg: ExperimentConfig) -> dict:
         host arrays (a replicated global array would pin the actor's
         jit to the global mesh), so there the pull is D2H."""
         if multi_host:
-            weights.publish(jax.device_get(chunk_state.actor_params),
+            weights.publish(jax.device_get(policy_params(config, chunk_state)),
                             step=step, norm_stats=_norm_snapshot())
         else:
-            weights.publish(copy_params(chunk_state.actor_params),
+            weights.publish(copy_params(policy_params(config, chunk_state)),
                             step=step, to_host=False,
                             norm_stats=_norm_snapshot())
 
@@ -979,8 +999,8 @@ def train(cfg: ExperimentConfig) -> dict:
         nonlocal lstep
         lstep += K
         if cfg.async_actors:
-            p = (chunk_state.actor_params if mesh is None
-                 else jax.device_get(chunk_state.actor_params))
+            p = (policy_params(config, chunk_state) if mesh is None
+                 else jax.device_get(policy_params(config, chunk_state)))
             weights.publish(p, step=lstep,  # bounded staleness: lag <= K
                             norm_stats=_norm_snapshot())
 

@@ -1,0 +1,123 @@
+"""The torso's kernels, and the whole fused chunk of the benchmark's torso
+configuration, compiled for a described (not attached) TPU v5e at the real
+widths: what the chip's compiler refuses, it refuses here, at no chip time.
+The topology is described inside a fixture and every such test lives in this
+one file (one process may hold the TPU library)."""
+
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from d4pg_tpu.learner import D4PGConfig, init_state
+from d4pg_tpu.learner.fused import make_fused_chunk
+from d4pg_tpu.models import torso as torso_lib
+from d4pg_tpu.ops import attention as attn_ops
+from d4pg_tpu.replay import device_per as dper
+from d4pg_tpu.replay.uniform import TransitionBatch
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+HBM_BYTES = 15.75 * 2 ** 30
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def config(monkeypatch):
+    with open(os.path.join(
+            REPO, "benchmark/configs/humanoid-mellum2-ep4.json")) as f:
+        model = json.load(f)["model"]
+    # code that asks jax.default_backend() sees the CPU here; the torso
+    # picks its kernels by it, so the test answers for the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return D4PGConfig(**model)
+
+
+def on(sharding, tree):
+    return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=sharding), tree)
+
+
+@pytest.mark.parametrize("window", [1024, None], ids=["window", "full"])
+def test_splash_attention_and_its_backward_compile_at_real_widths(one_chip,
+                                                                  window):
+    q = jax.ShapeDtypeStruct((1, 4, 8, 4096, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4, 4096, 128), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        out = attn_ops.causal_attention(q, k, v, window=window,
+                                        impl="splash")
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    # forward and backward (full causal: one fused; windowed: dq and dkv)
+    assert text.count("tpu_custom_call") >= (2 if window is None else 3)
+
+
+@pytest.mark.parametrize("impl, kernel", [("megablox", "gmm"),
+                                          ("ragged", "ragged-dot")])
+def test_the_expert_share_and_its_backward_compile_at_real_widths(
+        one_chip, config, impl, kernel):
+    spec = config.torso
+    d, f, n = spec.hidden_size, spec.moe_intermediate_size, spec.n_held
+    stack = lambda a, b: {"kernel": jax.ShapeDtypeStruct(  # noqa: E731
+        (n, a, b), jnp.bfloat16)}
+    p = {"router": {"kernel": jax.ShapeDtypeStruct((d, spec.num_experts),
+                                                   jnp.float32)},
+         "gate": stack(d, f), "up": stack(d, f), "down": stack(f, d)}
+    h = jax.ShapeDtypeStruct((spec.tokens, d), jnp.float32)
+
+    def loss(p, h):
+        out, _counts = torso_lib.expert_share(spec, p, h, jnp.bfloat16, impl)
+        return jnp.sum(out)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        *on(one_chip, (p, h))).compile().as_text()
+    # both buffers are compiled (the usual one and every assignment), each
+    # with three products forward and six backward
+    assert text.count(kernel) >= 18
+
+
+def test_the_fused_chunk_of_the_benchmark_cell_fits_the_chip(one_chip,
+                                                             config):
+    with open(os.path.join(
+            REPO, "benchmark/configs/humanoid-mellum2-ep4.json")) as f:
+        cfg = json.load(f)
+    cap, batch = cfg["replay"]["capacity"], cfg["learner"]["batch_size"]
+    state = jax.eval_shape(lambda: init_state(config, jax.random.key(0)))
+    trees = jax.eval_shape(lambda: dper.init(cap))
+    row = lambda *s: jax.ShapeDtypeStruct((cap,) + s, jnp.float32)  # noqa
+    storage = TransitionBatch(
+        obs=row(config.obs_dim), action=row(config.act_dim), reward=row(),
+        next_obs=row(config.obs_dim), done=row(), discount=row())
+    fn = make_fused_chunk(config, k=cfg["learner"]["k"], batch_size=batch)
+    size = jax.ShapeDtypeStruct((), jnp.int32)
+    compiled = fn.lower(*on(one_chip, (state, trees, storage, size))
+                        ).compile()
+    m = compiled.memory_analysis()
+    # arguments (state 8.64 GB, ring and trees 1.08 GB) are updated in
+    # place; temporaries hold the gradient and one sequence of one layer
+    assert m.alias_size_in_bytes > 8.6e9
+    # the compiler refuses what does not fit; its own count (an upper
+    # bound: the buffer assignment packs tighter) stays under the chip's
+    held = m.argument_size_in_bytes + m.temp_size_in_bytes \
+        + m.generated_code_size_in_bytes
+    assert held < HBM_BYTES, held
+    text = compiled.as_text()
+    assert "gmm" in text and "splash" in text and "ragged-dot" not in text
