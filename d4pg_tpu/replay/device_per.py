@@ -18,10 +18,15 @@ Layout matches the host trees (``replay/segment_tree.py``): one flat
 array of ``2 * capacity`` (power of two) nodes, root at 1, leaf ``i`` at
 ``capacity + i``. All ops are batched:
 
-  - ``set_leaves``: scatter the B leaves, then repair ancestors level by
-    level — every touched parent is recomputed from its (already-written)
-    children, so duplicate parents among the B paths all write identical
-    values and need no dedup;
+  - ``set_leaves``: scatter the B leaves, then repair their ancestors.
+    Only the lowest levels, where B paths touch a vanishing share of the
+    nodes, are repaired by a gather and a scatter each (every touched
+    parent recomputed from its already-written children, so duplicate
+    parents among the B paths write identical values and need no dedup);
+    from the level that is narrow enough up to the root every level is
+    recomputed WHOLE from the one below, one pairwise reduction a level.
+    Which level that is follows from the static capacity and B
+    (``_scatter_levels``);
   - ``sample``: B stratified inverse-CDF queries descend in lock-step,
     log2(N) gather/where rounds;
   - trees are float32 (device-friendly); with ~1e6 leaves the prefix-sum
@@ -74,15 +79,98 @@ def init(capacity: int) -> PerTrees:
     )
 
 
+# Where the repair turns dense, measured on the v5e (PERF.md, PR 29). A
+# scattered level costs its B indices one after another (~80 ns each on
+# a 4M-node tree, ~20 ns under 2^19 nodes); a dense level costs its bytes
+# (12 a parent: two children read, one parent written; ~0.02 ns a
+# parent); either costs ~1.7 us at least. A level at most
+# ``_DENSE_NODES_PER_LEAF * B`` nodes wide is therefore cheaper whole.
+# Under ``_DENSE_MIN_BATCH`` leaves the fixed cost is all a scatter pays,
+# and the dense width stops shrinking with B.
+_DENSE_NODES_PER_LEAF = 4096
+_DENSE_MIN_BATCH = 16
+_LANES = 128  # a dense level is kept as rows of lanes, [width / 128, 128]
+
+
+def _scatter_levels(capacity: int, batch: int) -> int:
+    """Levels above the leaves that :func:`set_leaves` repairs by scatter
+    before the dense reduction takes over: those wider than
+    ``_DENSE_NODES_PER_LEAF * max(batch, _DENSE_MIN_BATCH)`` nodes. Static
+    shape arithmetic, so one compiled program per (capacity, B)."""
+    dense_width = _DENSE_NODES_PER_LEAF * max(batch, _DENSE_MIN_BATCH)
+    kept, width = 0, capacity // 2
+    while width > dense_width:
+        kept, width = kept + 1, width // 2
+    return kept
+
+
+def _parents(s: Array, m: Array) -> tuple[Array, Array]:
+    """One level up for both trees, ``[rows, w] -> [rows, w / 2]``: the
+    float32 ``+`` (sum tree) and ``min`` (min tree) of each adjacent pair,
+    one operation a parent (the identities a window starts from change no
+    bit of a priority). A (1, 2) window along the lanes is the one form of
+    this the TPU runs near memory speed; ``reshape(-1, 2).sum(-1)`` may be
+    merged with the next level's into one reduction of four, which rounds
+    differently, and costs 40 times as much; ``x[0::2] + x[1::2]`` 300
+    times (v5e; PERF.md, PR 29). Both trees share one window: half the
+    operations in the chunk's loop body."""
+    return jax.lax.reduce_window(
+        (s, m), (jnp.float32(0), jnp.float32(jnp.inf)),
+        lambda a, b: (a[0] + b[0], jnp.minimum(a[1], b[1])),
+        (1, 2), (1, 2), "VALID")
+
+
+def _repair_dense(s: Array, m: Array, width: int) -> tuple[Array, Array]:
+    """Recompute every level above the ``width``-wide one (already right)
+    of both trees, each from the level below it, and write nodes
+    ``[0, width)`` back as ONE static slice a tree (one write a level
+    costs the chunk a quarter more; PERF.md, PR 28). Node 0, which no
+    level owns, keeps its value."""
+    if width == 1:
+        return s, m
+    lanes = min(width, _LANES)
+    level = tuple(t[width:2 * width].reshape(width // lanes, lanes)
+                  for t in (s, m))
+    wide, narrow = [], []
+    while level[0].shape[0] > 1:  # levels of whole rows
+        rows = level[0].shape[0] // 2
+        level = tuple(x.reshape(rows, lanes) for x in _parents(*level))
+        wide.append(level)
+    while level[0].shape[1] > 1:  # the levels inside the first row
+        level = _parents(*level)
+        narrow.append(level)
+
+    def write(tree: Array, i: int) -> Array:
+        head = jnp.concatenate(
+            [tree[:1]] + [lv[i][0] for lv in reversed(narrow)])
+        block = jnp.concatenate(
+            [head[None]] + [lv[i] for lv in reversed(wide)])
+        return jax.lax.dynamic_update_slice(tree, block.reshape(-1), (0,))
+
+    return write(s, 0), write(m, 1)
+
+
 def set_leaves(trees: PerTrees, idx: Array, p_alpha: Array) -> PerTrees:
     """Write ``p_alpha`` ([B], already ``priority ** alpha``) at leaves
-    ``idx`` ([B] int) and repair both trees' ancestors.
+    ``idx`` ([B] int) and repair both trees' ancestors: the lowest
+    ``_scatter_levels(capacity, B)`` levels path by path, the rest whole.
+    Every node ends as the float32 ``+`` / ``min`` of its two children,
+    as a repair of the touched paths alone would leave it.
+
+    PRECONDITION: the trees are consistent on entry (every internal node
+    already is that ``+`` / ``min`` of its children). A dense level is
+    recomputed from all its children, touched or not, so an inconsistent
+    node elsewhere in the tree would be silently rewritten. ``init``,
+    ``FusedDeviceReplay.load_state_dict`` / ``restore`` (``init`` then
+    this function) and ``ShardedFusedReplay.load_state_dict`` (a float32
+    pairwise rebuild on the host) all hand over consistent trees.
 
     Entries with ``idx >= capacity`` are PADS and are dropped entirely —
-    their scatter node is parked out of bounds through every repair level
-    (``mode='drop'`` discards the writes; the paired gathers clamp but
-    only feed dropped writes). Callers bucket batch sizes with such pads
-    for compile-count control; a pad-only call is a no-op."""
+    their scatter node is parked out of bounds through every scattered
+    level (``mode='drop'`` discards the writes; the paired gathers clamp
+    but only feed dropped writes), and the dense levels read only the
+    tree. Callers bucket batch sizes with such pads for compile-count
+    control; a pad-only call changes nothing."""
     cap = trees.capacity
     idx32 = idx.astype(jnp.int32)
     valid = idx32 < cap
@@ -100,11 +188,13 @@ def set_leaves(trees: PerTrees, idx: Array, p_alpha: Array) -> PerTrees:
     # min_tree report a phantom minimum).
     m = trees.min_tree.at[node].set(s[jnp.minimum(node, 2 * cap - 1)],
                                     mode="drop")
-    for _ in range(_levels(cap)):
+    kept = _scatter_levels(cap, idx32.size)
+    for _ in range(kept):
         node = jnp.where(valid, node >> 1, 2 * cap)
         left = jnp.minimum(node << 1, 2 * cap - 2)
         s = s.at[node].set(s[left] + s[left | 1], mode="drop")
         m = m.at[node].set(jnp.minimum(m[left], m[left | 1]), mode="drop")
+    s, m = _repair_dense(s, m, cap >> kept)
     return PerTrees(s, m, trees.max_priority)
 
 
@@ -239,8 +329,8 @@ _set_leaves_jit = None
 
 def set_leaves_jitted(trees: PerTrees, idx, p_alpha) -> PerTrees:
     """Dispatch :func:`set_leaves` as ONE device computation (eager jnp
-    pays one dispatch per op — ~50 ops of tree repair; checkpoint
-    restore rebuilds the whole tree this way).
+    pays one dispatch per op — two or three a level of tree repair;
+    checkpoint restore rebuilds the whole tree this way).
     Donates ``trees``; caller owns the handle."""
     global _set_leaves_jit
     if _set_leaves_jit is None:

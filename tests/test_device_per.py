@@ -3,9 +3,12 @@ learner/fused.py, replay/fused_buffer.py) against the host implementations
 as oracle (replay/segment_tree.py mirrors the reference's
 prioritized_replay_memory.py:33-162)."""
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from d4pg_tpu.learner import D4PGConfig, init_state
 from d4pg_tpu.learner.fused import make_fused_chunk
@@ -113,6 +116,233 @@ def test_set_leaves_traces_at_production_capacity():
         jax.ShapeDtypeStruct((256,), jnp.int32),
         jax.ShapeDtypeStruct((256,), jnp.float32))
     assert out.sum_tree.shape == (2 * cap,)
+
+
+def _level_by_level(trees, idx, p_alpha):
+    """The ORACLE: the repair ``set_leaves`` did before the upper levels
+    went dense (PR 29), kept here verbatim. After the leaf scatter every
+    level is a gather of two children and a scatter of the touched
+    parents, so only the B paths are ever written."""
+    cap = trees.capacity
+    idx32 = idx.astype(jnp.int32)
+    valid = idx32 < cap
+    node = jnp.where(valid, idx32 + cap, 2 * cap)
+    s = trees.sum_tree.at[node].set(p_alpha.astype(jnp.float32),
+                                    mode="drop")
+    m = trees.min_tree.at[node].set(s[jnp.minimum(node, 2 * cap - 1)],
+                                    mode="drop")
+    for _ in range(int(math.log2(cap))):
+        node = jnp.where(valid, node >> 1, 2 * cap)
+        left = jnp.minimum(node << 1, 2 * cap - 2)
+        s = s.at[node].set(s[left] + s[left | 1], mode="drop")
+        m = m.at[node].set(jnp.minimum(m[left], m[left | 1]), mode="drop")
+    return dper.PerTrees(s, m, trees.max_priority)
+
+
+_ORACLE_JIT = jax.jit(_level_by_level)
+_NEW_JIT = jax.jit(dper.set_leaves)
+
+
+def _seeded_trees(cap, rng):
+    """A consistent tree with history: three quarters of the ring written
+    (the rest still 0 / inf), max_priority off its initial 1."""
+    n = max(1, 3 * cap // 4)
+    trees = _ORACLE_JIT(dper.init(cap), jnp.arange(n),
+                        jnp.asarray(rng.uniform(0.01, 5.0, n), jnp.float32))
+    return trees._replace(max_priority=jnp.float32(3.25))
+
+
+def _batch(kind, cap, rng):
+    """(idx, p_alpha) of one ``set_leaves`` call of the named kind."""
+    if kind == "one":
+        idx = rng.integers(0, cap, 1)
+    elif kind == "random256":  # duplicates certain below 256 leaves
+        idx = rng.integers(0, cap, 256)
+    elif kind == "wrapping_block":  # the commit's contiguous run, wrapped
+        n = min(cap // 2, 4096)
+        idx = (cap - n // 3 + np.arange(n)) % cap
+    elif kind == "with_pads":
+        idx = rng.integers(0, cap, 64)
+        idx[rng.random(64) < 0.4] = cap
+        idx[-1] = cap + 7  # any index past the ring is a pad
+    elif kind == "pads_only":
+        idx = np.full(32, cap)
+    else:
+        raise ValueError(kind)
+    # every duplicate of a slot carries one value: XLA leaves the winner
+    # among duplicates unspecified, and the two repairs are two programs
+    value = rng.uniform(0.01, 5.0, 2 * cap + 8).astype(np.float32)
+    return jnp.asarray(idx, jnp.int32), jnp.asarray(value[idx])
+
+
+def _assert_same_trees(got, want):
+    """Every node of both trees and the running max, to the bit."""
+    for name in ("sum_tree", "min_tree", "max_priority"):
+        g, w = np.asarray(getattr(got, name)), np.asarray(getattr(want, name))
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        np.testing.assert_array_equal(g.view(np.uint32), w.view(np.uint32),
+                                      err_msg=name)
+
+
+BATCH_KINDS = ("one", "random256", "wrapping_block", "with_pads",
+               "pads_only")
+
+
+@pytest.mark.parametrize("jit", (False, True), ids=("eager", "jit"))
+@pytest.mark.parametrize("kind", BATCH_KINDS)
+@pytest.mark.parametrize("cap", (16, 256, 4096, 65536))
+def test_set_leaves_is_bitwise_the_level_by_level_repair(cap, kind, jit, rng):
+    trees = _seeded_trees(cap, rng)
+    idx, p = _batch(kind, cap, rng)
+    new, oracle = ((_NEW_JIT, _ORACLE_JIT) if jit
+                   else (dper.set_leaves, _level_by_level))
+    got, want = new(trees, idx, p), oracle(trees, idx, p)
+    _assert_same_trees(got, want)
+    if kind == "pads_only":  # changes nothing
+        _assert_same_trees(got, trees)
+
+
+@pytest.mark.parametrize("batch", (1, 48))
+@pytest.mark.parametrize("levels", (16, 17, 18, 19, 20))
+def test_set_leaves_at_the_split(levels, batch, rng):
+    """Trees a level shorter and longer than where the repair turns dense:
+    at B <= 16 a level of 65,536 nodes is the widest recomputed whole
+    (capacity 2^17: every level dense; 2^18: one level scattered below
+    it); at B = 48, 196,608 (2^18: all dense; 2^19 and 2^20: one and
+    two)."""
+    cap = 1 << levels
+    dense_width = dper._DENSE_NODES_PER_LEAF * max(batch,
+                                                   dper._DENSE_MIN_BATCH)
+    kept = dper._scatter_levels(cap, batch)
+    assert kept == max(0, levels - 1 - int(math.log2(dense_width)))
+    trees = _seeded_trees(cap, rng)
+    for _ in range(2):  # the second call starts from the first's trees
+        idx = jnp.asarray(rng.integers(0, cap, batch), jnp.int32)
+        p = jnp.asarray(rng.uniform(0.01, 5.0, batch), jnp.float32)
+        got, want = _NEW_JIT(trees, idx, p), _ORACLE_JIT(trees, idx, p)
+        _assert_same_trees(got, want)
+        trees = got
+
+
+@pytest.mark.parametrize("ratio", (1, 8, 64))
+def test_set_leaves_joins_scattered_and_dense_levels_anywhere(
+        ratio, rng, monkeypatch):
+    """With the constants turned down a small tree has the join in its
+    middle (capacity 4,096, B 4: 9, 6 and 3 levels scattered), where a
+    sequence of inserts, duplicate-laden updates and padded batches must
+    still leave the oracle's trees."""
+    monkeypatch.setattr(dper, "_DENSE_NODES_PER_LEAF", ratio)
+    monkeypatch.setattr(dper, "_DENSE_MIN_BATCH", 1)
+    cap = 4096
+    assert dper._scatter_levels(cap, 4) == {1: 9, 8: 6, 64: 3}[ratio]
+    new = jax.jit(dper.set_leaves)  # traced under these constants
+    got = want = dper.init(cap)
+    for step in range(6):
+        kind = ("wrapping_block", "random256", "with_pads")[step % 3]
+        idx, p = _batch(kind, cap, rng)
+        idx, p = (idx[:4], p[:4]) if step == 5 else (idx, p)
+        got, want = new(got, idx, p), _ORACLE_JIT(want, idx, p)
+        _assert_same_trees(got, want)
+
+
+def test_set_leaves_duplicates_agree_between_the_trees(rng):
+    """Duplicates with DIFFERENT values: whichever write wins, the min
+    tree's leaf is the sum tree's, and every node of both trees is the
+    float32 op of its two children."""
+    cap = 1024
+    idx = jnp.asarray(rng.integers(0, 64, 256), jnp.int32)
+    p = jnp.asarray(rng.uniform(0.01, 5.0, 256), jnp.float32)
+    trees = _NEW_JIT(_seeded_trees(cap, rng), idx, p)
+    s, m = np.asarray(trees.sum_tree), np.asarray(trees.min_tree)
+    touched = cap + np.unique(np.asarray(idx))
+    np.testing.assert_array_equal(s[touched], m[touched])
+    kids_s, kids_m = s[2:].reshape(-1, 2), m[2:].reshape(-1, 2)
+    np.testing.assert_array_equal(s[1:cap], kids_s[:, 0] + kids_s[:, 1])
+    np.testing.assert_array_equal(m[1:cap],
+                                  np.minimum(kids_m[:, 0], kids_m[:, 1]))
+    assert s[0] == 0.0 and m[0] == np.inf  # node 0 belongs to no level
+
+
+def _lowered_counts(cap, batch):
+    t = dper.PerTrees(
+        jax.ShapeDtypeStruct((2 * cap,), jnp.float32),
+        jax.ShapeDtypeStruct((2 * cap,), jnp.float32),
+        jax.ShapeDtypeStruct((), jnp.float32))
+    text = jax.jit(dper.set_leaves).lower(
+        t, jax.ShapeDtypeStruct((batch,), jnp.int32),
+        jax.ShapeDtypeStruct((batch,), jnp.float32)).as_text()
+    return (text.count('"stablehlo.scatter"('),
+            text.count('"stablehlo.reduce_window"('))
+
+
+@pytest.mark.parametrize("batch", (256, 4096))
+def test_set_leaves_scatter_count_at_production_capacity(batch):
+    """The structure of the repair, read off the lowered module (trace and
+    lower only): 2 x (levels kept) + 2 scatters, the 2 being the leaf
+    writes, where the level-by-level repair had 2 x 21 + 2 = 44; one
+    window a dense level, both trees in it. At the benchmark's shapes
+    (2,097,152 leaves; the chunk's B = 256, the commit's 4,096) no level
+    is kept, and a ring twice or four times the size adds at most a
+    level each, not two scatters a level of the whole tree."""
+    levels = 21
+    for grow in (0, 1, 2):
+        cap = 1 << (levels + grow)
+        kept = dper._scatter_levels(cap, batch)
+        scatters, windows = _lowered_counts(cap, batch)
+        assert scatters == 2 * kept + 2
+        assert windows == levels + grow - kept
+        if grow == 0:
+            assert kept == 0
+        assert kept <= grow
+
+
+def test_set_leaves_scatter_count_follows_the_batch():
+    """Few leaves on a large tree keep the lowest levels path by path: a
+    per-row insert (``drain_per_row``) into the 2M-leaf ring scatters 4
+    levels and reduces 17."""
+    assert _lowered_counts(1 << 21, 1) == (2 * 4 + 2, 17)
+    assert _lowered_counts(1 << 21, 64) == (2 * 2 + 2, 19)
+    assert _lowered_counts(1 << 16, 512) == (2, 16)  # the pixel cell
+    assert _lowered_counts(1 << 15, 4) == (2, 15)  # cell 4
+
+
+def test_commit_program_compiles_once_across_block_shapes(rng):
+    """One commit program serves a full block, a partial block, an empty
+    tick and a block that wraps the ring: ``n`` and ``start`` are traced
+    scalars and the tree insert's split follows the static block."""
+    from d4pg_tpu.io.profiling import RecompileSentinel
+
+    cap, block = 256, 32
+    buf = FusedDeviceReplay(cap, 4, 2, alpha=0.6, block_rows=block)
+
+    def rows(n):
+        return TransitionBatch(
+            obs=rng.standard_normal((n, 4)).astype(np.float32),
+            action=rng.uniform(-1, 1, (n, 2)).astype(np.float32),
+            reward=rng.standard_normal(n).astype(np.float32),
+            next_obs=rng.standard_normal((n, 4)).astype(np.float32),
+            done=np.zeros(n, np.float32),
+            discount=np.full(n, 0.99, np.float32))
+
+    buf.add(rows(block))
+    assert buf.drain() == block  # warm-up: the one compile
+    with RecompileSentinel() as sentinel:
+        for n in (block, 5, 0, block):  # full, partial, empty, full
+            if n:
+                buf.add(rows(n))
+            assert buf.stage_block() == n
+            assert buf.commit_staged() == n
+        while buf.head + block <= cap:  # up to the ring's end
+            buf.add(rows(block))
+            buf.drain()
+        assert cap - block < buf.head < cap
+        buf.add(rows(block))  # this block wraps
+        assert buf.drain() == block
+    assert buf.head < block and buf.size == cap
+    assert sentinel.compilations == 0
+    want = _ORACLE_JIT(dper.init(cap), jnp.arange(cap),
+                       jnp.ones(cap, jnp.float32))
+    _assert_same_trees(buf.trees, want)
 
 
 def test_insert_and_update_semantics():

@@ -121,3 +121,35 @@ def test_the_fused_chunk_of_the_benchmark_cell_fits_the_chip(one_chip,
     assert held < HBM_BYTES, held
     text = compiled.as_text()
     assert "gmm" in text and "splash" in text and "ragged-dot" not in text
+
+
+@pytest.mark.parametrize("batch", [256, 4096], ids=["chunk", "commit"])
+def test_the_dense_tree_repair_updates_the_trees_in_place_on_the_chip(
+        one_chip, batch):
+    """``set_leaves`` at the humanoid-mlp cells' shapes (2,097,152 leaves;
+    the chunk's B = 256 and the commit's 4,096) inside a scan over donated,
+    loop-carried trees, compiled for the v5e: the block of recomputed
+    ancestors is a static slice update, which the chip's compiler must not
+    answer with a copy of a 16 MB tree a step; and every dense level is
+    one window over both trees (PR 29)."""
+    import re
+
+    cap = 1 << 21
+
+    def loop(trees, idx, td):
+        def body(trees, x):
+            return dper.update_from_td(trees, x[0], x[1], 0.6), None
+        return jax.lax.scan(body, trees, (idx, td))[0]
+
+    trees = on(one_chip, jax.eval_shape(lambda: dper.init(cap)))
+    text = jax.jit(loop, donate_argnums=(0,)).lower(
+        trees,
+        jax.ShapeDtypeStruct((4, batch), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((4, batch), jnp.float32, sharding=one_chip),
+    ).compile().as_text()
+    body = re.search(r"\bwhile\(.*?body=%?([\w.\-]+)", text).group(1)
+    lines = re.search(r"^%?" + re.escape(body) + r" [^\n]*\{\n(.*?)^\}", text,
+                      re.S | re.M).group(1).splitlines()
+    whole_tree = re.compile(r"= f32\[%d\]\S* copy\(" % (2 * cap))
+    assert not [ln for ln in lines if whole_tree.search(ln)][:2]
+    assert sum(" reduce-window(" in ln for ln in lines) == 21
