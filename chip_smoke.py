@@ -15,10 +15,7 @@ result line), then:
      cycles x 80 train steps (two K=40 dispatches each), one eval trial
      and one checkpoint per cycle (the default cadence: a save is then
      followed by a dispatch that donates the saved state);
-  2. checks what came out (see ``check_train``);
-  3. runs every shipped Pallas kernel once, compiled, at the shape the
-     default configuration gives it, against its plain ``jax.numpy``
-     reference (see ``check_kernels``).
+  2. checks what came out (see ``check_train``).
 
 The last line of standard output is one JSON object,
 ``{"ok": true, "device": {"platform", "kind", "count"}}``, with the
@@ -26,9 +23,8 @@ device as JAX reports it. Any failed check prints what failed and exits 1.
 The run directory goes under ``chiprun_out/`` next to this file (ignored
 by git), never into a tracked path.
 
-``tests/test_chip_smoke.py`` drives ``run()`` at ``TINY`` size on the CPU
-with interpret-mode kernels, so this file's control flow is exercised
-before chip time is spent on it.
+``tests/test_chip_smoke.py`` drives ``run()`` at ``TINY`` size on the CPU,
+so this file's control flow is exercised before chip time is spent on it.
 """
 
 from __future__ import annotations
@@ -62,8 +58,8 @@ TINY = {
 
 def train_argv(size: dict, platform: str, log_dir: str) -> list[str]:
     """The ``d4pg_tpu.train`` command line for ``size``: selectors stay at
-    their defaults (``--replay_storage auto --fused_replay auto
-    --projection auto``) unless ``size['extra']`` says otherwise."""
+    their defaults (``--replay_storage auto --fused_replay auto``) unless
+    ``size['extra']`` says otherwise."""
     return [
         "--platform", platform, "--env", size["env"],
         "--bsize", str(size["bsize"]), "--rmsize", str(size["rmsize"]),
@@ -116,100 +112,9 @@ def check_train(result: dict, size: dict, platform: str, run_dir: str,
     return failures
 
 
-def check_kernels(cfg, interpret: bool) -> list[str]:
-    """Each shipped Pallas kernel, compiled (``interpret=False`` on the
-    chip), against its jnp reference at the shapes the resolved
-    ``ExperimentConfig`` ``cfg`` gives it. Prints first-call (compile +
-    run) time per kernel."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from d4pg_tpu.core.distribution import (CategoricalSupport,
-                                            categorical_projection)
-    from d4pg_tpu.core.losses import cross_entropy_per_sample
-    from d4pg_tpu.ops.projection import projection_pallas
-    from d4pg_tpu.ops.projection_ce import projection_ce_pallas
-    from d4pg_tpu.ops.sampler_descent import descend_pallas
-    from d4pg_tpu.replay import device_per as dper
-
-    support = CategoricalSupport(float(cfg.v_min), float(cfg.v_max),
-                                 cfg.n_atoms)
-    b, a = cfg.batch_size, cfg.n_atoms
-    rng = np.random.default_rng(0)
-
-    def probs():
-        p = rng.random((b, a)).astype(np.float32)
-        return jnp.asarray(p / p.sum(-1, keepdims=True))
-
-    target, pred = probs(), probs()
-    span = float(cfg.v_max) - float(cfg.v_min)
-    reward = jnp.asarray(
-        (rng.standard_normal(b) * 0.05 * span).astype(np.float32))
-    discount = jnp.full((b,), 0.99, jnp.float32)
-    failures = []
-
-    def first_call(name, fn):
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(fn())
-        print(f"[kernel] {name}: first call (compile + run) "
-              f"{time.perf_counter() - t0:.2f} s", flush=True)
-        return out
-
-    def close(name, got, want, tol=1e-5):
-        got, want = np.asarray(got), np.asarray(want)
-        scale = max(1.0, float(np.abs(want).max()))
-        err = float(np.abs(got - want).max())
-        if not (np.isfinite(got).all() and err <= tol * scale):
-            failures.append(f"{name}: max |diff| {err:.3e} vs reference "
-                            f"(scale {scale:.3e})")
-
-    want_proj = categorical_projection(support, target, reward, discount)
-    close(f"projection_pallas [{b}, {a}]",
-          first_call(f"projection_pallas [{b}, {a}]",
-                     lambda: projection_pallas(support, target, reward,
-                                               discount, interpret)),
-          want_proj)
-
-    def ce_ref(q):
-        proj = jax.lax.stop_gradient(
-            categorical_projection(support, target, reward, discount))
-        return cross_entropy_per_sample(proj, q).sum()
-
-    def ce_kernel(q):
-        return projection_ce_pallas(support, target, reward, discount, q,
-                                    interpret).sum()
-
-    want_v, want_g = jax.jit(jax.value_and_grad(ce_ref))(pred)
-    got_v, got_g = first_call(
-        f"projection_ce_pallas fwd+vjp [{b}, {a}]",
-        lambda: jax.jit(jax.value_and_grad(ce_kernel))(pred))
-    close("projection_ce_pallas forward", got_v, want_v)
-    close("projection_ce_pallas vjp", got_g, want_g)
-
-    # the descent at the ring's tree size and the dealt plane's query
-    # count (K x B); bitwise by the kernel's own contract
-    trees = dper.init(cfg.memory_size)
-    n = trees.capacity
-    trees = dper.set_leaves_jitted(
-        trees, jnp.arange(n),
-        jnp.asarray(rng.random(n).astype(np.float32) + 1e-3))
-    q = cfg.updates_per_dispatch * b
-    mass = jnp.asarray(
-        (rng.random(q) * float(trees.sum_tree[1])).astype(np.float32))
-    want_idx = jax.jit(dper.descend)(trees.sum_tree, mass)
-    got_idx = first_call(
-        f"descend_pallas {n} slots x {q} queries",
-        lambda: descend_pallas(trees.sum_tree, mass, interpret))
-    if not np.array_equal(np.asarray(got_idx), np.asarray(want_idx)):
-        failures.append("descend_pallas is not bitwise-equal to "
-                        "device_per.descend")
-    return failures
-
-
 def run(size: dict, platform: str, out_dir: str) -> list[str]:
-    """The smoke's body: train through ``d4pg_tpu.train.main``, check the
-    result, check the kernels. Returns every failure as one line."""
+    """The smoke's body: train through ``d4pg_tpu.train.main`` and check
+    the result. Returns every failure as one line."""
     import jax
 
     from d4pg_tpu import train
@@ -236,7 +141,6 @@ def run(size: dict, platform: str, out_dir: str) -> list[str]:
     print(f"[smoke] device memory: peak_bytes_in_use="
           f"{stats.get('peak_bytes_in_use')} "
           f"bytes_limit={stats.get('bytes_limit')}", flush=True)
-    failures += check_kernels(cfg, interpret=platform != "tpu")
     if not failures:
         # three checkpoints of a run that passed are ~20 MB nobody reads;
         # a failed run keeps its directory for the post-mortem

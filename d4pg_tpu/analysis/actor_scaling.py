@@ -10,7 +10,7 @@ arriving env steps over a fixed window:
     python -m d4pg_tpu.analysis.actor_scaling --procs 1 2 4 --seconds 10
 
 It also renders the FLEET scaling curve from a ``bench_fleet`` artifact
-(``python bench.py --fleet``, ``d4pg_tpu/fleet``) — rows/s vs N with p99
+(``python -m d4pg_tpu.fleet.sweep``, ``d4pg_tpu/fleet``) — rows/s vs N with p99
 send latency and the per-N loss/recovery counters, as a table and
 optionally a PNG:
 

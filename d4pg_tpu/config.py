@@ -103,16 +103,14 @@ class ExperimentConfig:
     # generation-fenced write-back queue. Requires the host replay path
     # (--fused_replay off) with prioritized replay.
     sample_on_ingest: bool = False
-    # Sample-path arm for --sample_on_ingest (the third autotune
-    # surface, ops/autotune.select_sampler): 'auto' resolves via the
-    # static policy + (on TPU) a startup descent micro-benchmark;
-    # 'scan' = device jnp gather descent fused behind the commit
-    # dispatch; 'pallas' = the VMEM-resident descent kernel
-    # (ops/sampler_descent.py); 'host' = the PR-12 host SampleDealer
-    # (the fallback arm — host tree math, pinned bitwise-equal to the
-    # device path under the seeded-stream oracle). Device arms require
-    # --fused_replay with --ingest_shards 1 (the commit thread owns
-    # every device handle); 'host' requires the host replay path.
+    # Sample-path arm for --sample_on_ingest
+    # (replay/device_sampler.resolve_sampler): 'auto' is 'scan' on a TPU
+    # and 'host' elsewhere; 'scan' = device gather descent fused behind
+    # the commit dispatch; 'host' = the PR-12 host SampleDealer (host tree
+    # math, pinned bitwise-equal to the device path under the
+    # seeded-stream oracle). 'scan' requires --ingest_shards 1 and no mesh
+    # (the commit thread owns every device handle); 'host' requires the
+    # host replay path.
     sampler: str = "auto"
     # 'async': clipped importance-weighted staleness correction, no
     # barrier; 'sync': plain N-way averaging barrier per round
@@ -142,15 +140,6 @@ class ExperimentConfig:
     v_max: float | None = None  # --v_max
     n_atoms: int = 51  # --n_atoms
     critic_family: str = "categorical"
-    # Categorical Bellman-projection impl: 'auto' (default) runs the
-    # startup micro-autotuner (ops/autotune.py) which times einsum /
-    # pallas / pallas_ce on the actual shapes and picks the winner (which
-    # one wins is a measured fact of (batch, atoms, chip), not a
-    # constant); an explicit variant is the escape hatch and is honored
-    # verbatim. Non-TPU backends and
-    # mesh learners resolve to einsum without timing (see ops/autotune.py
-    # policy). The selection is logged at startup.
-    projection: str = "auto"
     hidden: tuple = (256, 256, 256)
     compute_dtype: str = "float32"  # 'bfloat16' for MXU-native matmuls
     # exploration
@@ -329,21 +318,6 @@ class ExperimentConfig:
         selects the conv-encoder pixel path (BASELINE.md config #4)."""
         resolved = self.resolve()
         pixels = not np.isscalar(obs_dim)
-        projection = self.projection
-        if projection == "auto":
-            # D4PGConfig is the jit-static config — 'auto' must resolve to
-            # a concrete variant BEFORE it is built. The autotuner times
-            # the candidates on the actual (batch, atoms) shapes on TPU;
-            # mesh/multi-host and non-TPU backends resolve statically to
-            # einsum (see ops/autotune.py). Explicit flags bypass all this.
-            from d4pg_tpu.ops.autotune import select_projection
-
-            mesh = (self.data_parallel > 1 or self.num_processes > 1
-                    or bool(self.coordinator))
-            projection = select_projection(
-                "auto", batch_size=self.batch_size,
-                v_min=float(resolved.v_min), v_max=float(resolved.v_max),
-                n_atoms=self.n_atoms, mesh=mesh).selected
         return D4PGConfig(
             obs_dim=int(np.prod(obs_dim)) if pixels else obs_dim,
             pixels=pixels,
@@ -354,7 +328,6 @@ class ExperimentConfig:
             n_atoms=self.n_atoms,
             hidden=tuple(self.hidden),
             critic_family=self.critic_family,
-            projection=projection,
             augment=self.augment,
             augment_pad=self.augment_pad,
             share_encoder=self.share_encoder,
@@ -439,15 +412,6 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--n_atoms", type=int, default=d.n_atoms)
     p.add_argument("--critic_family", choices=("categorical", "mog"),
                    default=d.critic_family)
-    p.add_argument("--projection",
-                   choices=("auto", "einsum", "pallas", "pallas_ce"),
-                   default=d.projection,
-                   help="categorical Bellman-projection impl: 'auto' "
-                        "(default) micro-autotunes on the actual shapes "
-                        "at startup; or pin the MXU einsum, the VMEM "
-                        "Pallas projection kernel, or pallas_ce "
-                        "(projection fused into the cross-entropy loss, "
-                        "forward + backward)")
     p.add_argument("--compute_dtype", choices=("float32", "bfloat16"),
                    default=d.compute_dtype)
     p.add_argument("--noise", choices=("gaussian", "ou"), default=d.noise)
@@ -553,14 +517,12 @@ def build_parser() -> argparse.ArgumentParser:
                    "fuse PER sampling into the receive path: the commit "
                    "thread deals ready-to-train blocks to the learner "
                    "replicas (host replay + prioritized only)")
-    p.add_argument("--sampler", choices=("auto", "scan", "pallas", "host"),
+    p.add_argument("--sampler", choices=("auto", "scan", "host"),
                    default=d.sampler,
                    help="sample-path arm for --sample_on_ingest: 'scan' = "
-                        "device jnp gather descent fused behind the commit "
-                        "dispatch, 'pallas' = VMEM-resident descent kernel, "
-                        "'host' = PR-12 host SampleDealer (fallback), "
-                        "'auto' = static policy + TPU descent "
-                        "micro-benchmark (ops/autotune.select_sampler)")
+                        "device gather descent fused behind the commit "
+                        "dispatch, 'host' = PR-12 host SampleDealer, "
+                        "'auto' = scan on a TPU, host elsewhere")
     p.add_argument("--profile_dir", default=d.profile_dir)
     p.add_argument("--log_dir", default=d.log_dir)
     p.add_argument("--seed", type=int, default=d.seed)

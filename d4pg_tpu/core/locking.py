@@ -35,7 +35,7 @@ In debug mode (``enable_debug``) every acquisition checks descent and
 counts contention — acquisitions, contended acquisitions (the lock was
 held when we arrived), cumulative wait time, max hold time — keyed by
 tier name so the fleet artifact can attribute time to lock waits
-(``bench.py --fleet`` → ``locks`` block). Production mode delegates
+(``fleet/sweep.py`` → ``locks`` block). Production mode delegates
 straight to ``threading`` with no bookkeeping.
 """
 

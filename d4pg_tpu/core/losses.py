@@ -40,9 +40,9 @@ def cross_entropy_per_sample(proj: Array, pred_probs: Array) -> Array:
 
 def weighted_mean(td: Array, weights: Array | None = None) -> Array:
     """THE loss reduction: mean of per-sample errors, PER IS-weighted when
-    ``weights`` is given. One definition shared by every critic-loss path
-    (einsum, fused Pallas, MoG) so the weighting convention cannot
-    diverge between them."""
+    ``weights`` is given. One definition shared by both critic families
+    (categorical, MoG) so the weighting convention cannot diverge between
+    them."""
     return jnp.mean(td if weights is None else weights * td)
 
 

@@ -8,7 +8,7 @@ TCP, a seeded ``ChaosPolicy`` injects drops/delays/crashes/receiver
 stalls at the transport boundary, and the harness reports what survived:
 rows/s, latency percentiles, every counted loss, and recovery times.
 ``sweep.run_sweep`` walks N ∈ {8..256} and emits the ``bench_fleet``
-artifact (``python bench.py --fleet``). See docs/architecture.md
+artifact (``python -m d4pg_tpu.fleet.sweep``). See docs/architecture.md
 "Fleet plane".
 """
 

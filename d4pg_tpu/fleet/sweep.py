@@ -8,7 +8,9 @@ default chaos mix injecting drops, stragglers, crashes and receiver
 stalls the whole way. Run it:
 
     python -m d4pg_tpu.fleet.sweep --ns 8 32 64 128 256 --seconds 10
-    python bench.py --fleet           # same sweep, persisted artifact
+    python -m d4pg_tpu.fleet.sweep --out docs/evidence/fleet
+        # --out DIRECTORY: the same sweep plus every block below
+        # (run_fleet), written there stamped and pruned
 
 Per-N rows of the artifact are ``FleetHarness._report`` dicts minus the
 raw chaos log (the log is deterministic from the seed — regenerate it by
@@ -20,6 +22,9 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import os
+import sys
+import time
 
 from d4pg_tpu.fleet.chaos import ChaosConfig
 from d4pg_tpu.fleet.harness import FleetConfig, FleetHarness
@@ -382,8 +387,8 @@ def run_mesh_learners(
     latency p50/p95, the measurement that attributes the mesh-native
     transport's win to the transport (grad work is identical by
     construction). Needs a JAX backend with >= max(ns) devices;
-    bench.py runs it in a virtual-device child process so the rest of
-    the fleet suite stays accelerator-free."""
+    ``run_fleet`` runs it in a virtual-device child process so the rest
+    of the fleet suite stays accelerator-free."""
     import jax
 
     from d4pg_tpu.fleet.mesh_ab import run_mesh_ab
@@ -581,6 +586,154 @@ def run_elastic(seed: int = 0, **overrides) -> dict:
     }
 
 
+def run_latency(n_actors: int = 64, duration_s: float = 10.0,
+                seed: int = 0, chaos: ChaosConfig | None = None,
+                rows_per_sec: float = 60.0) -> dict:
+    """The wire-to-grad latency block (docs/architecture.md
+    "Observability plane"): a seeded N>=64 chaos run over the sharded
+    (K=2, v2 raw) plane with trace sampling at the default rate —
+    per-stage latency histograms p50/p95/p99 with end-to-end
+    wire-to-grad as the headline — plus the measured tracing overhead:
+    an identical untraced twin run (same seed, same chaos script) prices
+    the rows/s loss of sampling + span recording + the concurrent
+    consumer lane, and a host microbench times the per-chunk learner
+    hook (mark_grad + two registry incs), the ONLY code tracing adds to
+    the fused learner loop."""
+    from d4pg_tpu.obs.registry import REGISTRY
+    from d4pg_tpu.obs.trace import DEFAULT_SAMPLE, RECORDER
+
+    chaos = default_chaos(seed) if chaos is None else chaos
+
+    def run(sample: float) -> dict:
+        cfg = FleetConfig(n_actors=n_actors, duration_s=duration_s,
+                          rows_per_sec=rows_per_sec, ingest_shards=2,
+                          chaos=chaos, trace_sample=sample)
+        return FleetHarness(cfg).run()
+
+    traced = run(DEFAULT_SAMPLE)
+    untraced = run(0.0)
+    rps_t, rps_u = traced["rows_per_sec"], untraced["rows_per_sec"]
+    RECORDER.disable()
+    c = REGISTRY.counter("bench.calibration")
+    reps = 200_000
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        RECORDER.mark_grad()
+        c.inc()
+        c.inc()
+    hook_ns = 1e9 * (time.perf_counter() - t0) / reps
+    block = dict(traced["latency"] or {})
+    block["overhead"] = {
+        "rows_per_sec_traced": rps_t,
+        "rows_per_sec_untraced": rps_u,
+        "rows_loss_pct": (round(100.0 * (rps_u - rps_t) / rps_u, 2)
+                          if rps_u else None),
+        "hook_ns_per_chunk": round(hook_ns, 1),
+        "sample_rate": DEFAULT_SAMPLE,
+    }
+    block["n_actors"] = n_actors
+    block["ingest_shards"] = 2
+    block["frames_traced"] = traced["frames_traced"]
+    block["seed"] = chaos.seed
+    return block
+
+
+def _mesh_learners_child(seed: int) -> dict:
+    """``run_mesh_learners`` in a child with 8 virtual CPU devices (the
+    fleet parent keeps JAX uninitialized by design). A failed child
+    returns an error stub instead of sinking the whole artifact — the
+    schema gate on the committed artifact still catches it."""
+    import subprocess
+
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    flags = env.get("XLA_FLAGS", "")
+    if "host_platform_device_count" not in flags:
+        env["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count=8".strip())
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))  # `-c` imports the package from cwd
+    stub = {"metric": "fleet_mesh_learners", "schema": 1}
+    code = ("import json; from d4pg_tpu.fleet.sweep import "
+            f"run_mesh_learners as r; print(json.dumps(r(seed={int(seed)})))")
+    try:
+        proc = subprocess.run([sys.executable, "-c", code], env=env,
+                              cwd=root, capture_output=True, text=True,
+                              timeout=1800)
+    except subprocess.TimeoutExpired:
+        return {**stub, "error": "child timed out"}
+    if proc.returncode != 0:
+        return {**stub, "error": (proc.stderr or proc.stdout)[-2000:]}
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def run_fleet(ns=SWEEP_NS, duration_s: float = 10.0, seed: int = 0,
+              chaos: ChaosConfig | None = None) -> dict:
+    """The combined ``bench_fleet`` artifact ``docs/evidence/fleet/``
+    holds: the N sweep at K=1, the ``ingest_shards`` sweep K ∈ {1, 2, 4}
+    at N=max(ns) with offered load raised to 60 rows/s per lane so the
+    RECEIVER saturates, and one
+    block per plane (latency, recovery, weights, learners, serving,
+    sampler, elastic, mesh_learners), each schema-checked in tier-1 by
+    the test file of its plane. Every row carries a ``locks`` block (the
+    ``core/locking.py`` tier sentinels run armed through the whole
+    sweep). Pure host+TCP plane apart from the serving, elastic and
+    mesh-learners blocks, which use the CPU backend."""
+    cc = default_chaos(seed) if chaos is None else chaos
+    n_block = max(64, min(ns))
+    shard_rows_per_sec = 60.0
+    artifact = run_sweep(ns=ns, duration_s=duration_s, chaos=cc)
+    artifact["shard_sweep"] = shard_sweep(
+        ks=(1, 2, 4), n_actors=max(ns), duration_s=duration_s,
+        rows_per_sec=shard_rows_per_sec, chaos=cc)
+    for row in artifact["shard_sweep"]["sweep"]:
+        row.pop("chaos_log", None)
+    artifact["latency"] = run_latency(
+        n_actors=n_block, duration_s=duration_s, seed=seed, chaos=cc,
+        rows_per_sec=shard_rows_per_sec)
+    artifact["recovery"] = run_recovery(
+        n_actors=n_block, duration_s=duration_s, ingest_shards=2, seed=seed)
+    artifact["weights"] = run_weights(
+        n_pullers=n_block, relay_depth=2, duration_s=duration_s, seed=seed,
+        learner_kills=1)
+    artifact["learners"] = run_learners(
+        ns=(1, 2, 4), duration_s=min(duration_s, 4.0), seed=seed,
+        replica_kills=2)
+    artifact["serving"] = run_serving(
+        lane_counts=(1, 2, 4), duration_s=min(duration_s, 4.0), seed=seed,
+        server_kills=1)
+    artifact["sampler"] = run_sampler(
+        n_actors=n_block, duration_s=min(duration_s, 6.0), seed=seed,
+        learner_kills=2, stale_frames=8)
+    # safe in this parent: run_serving above already initialized the
+    # single-core CPU backend this block shares
+    artifact["elastic"] = run_elastic(seed=seed)
+    artifact["mesh_learners"] = _mesh_learners_child(seed)
+    return artifact
+
+
+def write_evidence(artifact: dict, directory: str) -> None:
+    """Write a ``run_fleet`` artifact as ``fleet_<stamp>_<pid>.json`` in
+    ``directory`` and its elastic block as ``elastic_<stamp>_<pid>.json``
+    in the sibling ``elastic/`` (``tests/test_elastic.py`` reads it
+    without parsing the whole artifact). The pid keeps same-second
+    writers apart while lexical order stays chronological; each
+    directory keeps its newest 8."""
+    from d4pg_tpu.obs.flight import prune_artifacts
+
+    tag = f"{time.strftime('%Y%m%d-%H%M%S')}_{os.getpid():07d}"
+    blocks = [("fleet", directory, artifact)]
+    if "elastic" in artifact:
+        blocks.append(("elastic", os.path.join(
+            os.path.dirname(os.path.abspath(directory)), "elastic"),
+            artifact["elastic"]))
+    for prefix, where, block in blocks:
+        os.makedirs(where, exist_ok=True)
+        with open(os.path.join(where, f"{prefix}_{tag}.json"), "w") as f:
+            json.dump(block, f, indent=2)
+        prune_artifacts(where, f"{prefix}_", 8)
+
+
 def _lock_wait_ms(row: dict) -> float | None:
     """Total contended-acquisition wait across every tiered lock."""
     locks = row.get("locks")
@@ -656,11 +809,18 @@ def main(argv=None):
     ap.add_argument("--no_chaos", action="store_true",
                     help="clean-plane control run (all fault probs 0)")
     ap.add_argument("--out", default=None,
-                    help="also write the artifact JSON to this path")
+                    help="also write the artifact JSON to this path; an "
+                         "existing DIRECTORY (docs/evidence/fleet) gets "
+                         "the N sweep with every block attached "
+                         "(run_fleet), stamped and pruned")
     ns = ap.parse_args(argv)
     chaos = (ChaosConfig(seed=ns.seed) if ns.no_chaos
              else default_chaos(ns.seed))
-    if ns.elastic:
+    combined = bool(ns.out) and os.path.isdir(ns.out)
+    if combined:
+        artifact = run_fleet(ns=tuple(ns.ns), duration_s=ns.seconds,
+                             seed=ns.seed, chaos=chaos)
+    elif ns.elastic:
         artifact = run_elastic(seed=ns.seed)
     elif ns.sampler:
         artifact = run_sampler(
@@ -696,7 +856,9 @@ def main(argv=None):
                              block_rows=ns.block_rows, mode=ns.mode,
                              ingest_shards=ns.ingest_shards, codec=ns.codec,
                              trace_sample=ns.trace_sample or 0.0)
-    if ns.out:
+    if combined:
+        write_evidence(artifact, ns.out)
+    elif ns.out:
         with open(ns.out, "w") as f:
             json.dump(artifact, f, indent=2)
     print(json.dumps(artifact))

@@ -9,7 +9,7 @@ to leave on.
 The sentinels are the runtime complement of the static ``jaxlint``
 pass (``d4pg_tpu/lint``): the linter catches hazards it can see in the
 AST; the sentinels catch what it can't — a hot loop that recompiles in
-steady state (``RecompileSentinel``, wired into ``bench.py`` and the
+steady state (``RecompileSentinel``, wired into ``train.py`` and the
 learner tests), round-trips data between host and device per step
 (``TransferSentinel``), or compiles to a program that silently reshards
 a tree between layouts (``ReshardSentinel``, the dynamic twin of the

@@ -138,9 +138,6 @@ def make_sharded_fused_chunk(
     """The fused chunk over a data-parallel mesh — the production
     configuration with the replay data plane ON the mesh.
 
-    Rejects ``projection='pallas'`` (no GSPMD partitioning rule — mesh
-    learners use the einsum formulation, which shards trivially).
-
     Storage/trees come from ``replay/sharded_per.ShardedFusedReplay``
     (leading axis = shard, sharded over ``data``). Per step, a
     ``shard_map`` prologue lets every device sample B/N rows from ITS

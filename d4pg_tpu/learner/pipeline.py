@@ -1,8 +1,8 @@
 """Pipelined K-chunk learner loop — the shipped hot path.
 
 One place implements the sample -> stage -> scanned-update -> priority
-write-back pipeline so ``train.py`` and ``bench.py`` measure and ship the
-SAME loop (the reference scope per step is ``ddpg.py:200-255``: sample,
+write-back pipeline, so what ``train.py`` ships and what the tests and
+``benchmark/`` drive is the SAME loop (the reference scope per step is ``ddpg.py:200-255``: sample,
 nets, projection, optimizer, priorities). Schedule per chunk t:
 
   1. take the staged chunk t (sampled/device_put while t-1 computed),
@@ -56,8 +56,7 @@ class IngestOverlap:
                                # rides under chunk t's compute
 
     giving a hard bound of ≤ 1 explicit H2D per chunk in steady state
-    (verified by ``TransferSentinel`` in bench.py and
-    tests/test_ingest.py). Backpressure is structural: at most
+    (verified by ``TransferSentinel`` in tests/test_ingest.py). Backpressure is structural: at most
     ``block_rows`` rows land per chunk; a deeper backlog drains at cycle
     boundaries (``flush``), and the staging ring drops oldest beyond its
     bound. Works against ``ReplayService`` (whose ``ingest_stage`` falls

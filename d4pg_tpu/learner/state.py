@@ -86,18 +86,8 @@ class D4PGConfig:
     # Params, optimizer state, losses and the projection stay float32;
     # bf16 matmuls measure ~1.5x the fused-dispatch update throughput.
     compute_dtype: str = "float32"
-    # Categorical-projection implementation: 'einsum' (dense MXU
-    # interpolation-weight matmul, core/distribution.py — the default; XLA
-    # fuses it fully on-chip), 'pallas' (the VMEM-resident projection
-    # kernel, ops/projection.py — measured ~1.2-1.7x slower at A=51
-    # because pallas_call dispatch dominates at this op size), or
-    # 'pallas_ce' (projection FUSED into the cross-entropy reduction with
-    # a custom VJP, ops/projection_ce.py — removes the proj round trip in
-    # both passes; see README "Projection kernels"). Categorical family
-    # only; ignored by MoG. This field is jit-static and must be CONCRETE:
-    # the experiment-level '--projection auto' default resolves to one of
-    # these via the startup micro-autotuner BEFORE building this config
-    # (config.ExperimentConfig.learner_config -> ops/autotune.py).
+    # One value, 'einsum' (core/distribution.categorical_projection). Kept
+    # only because benchmark/configs/*.json carry the key into D4PGConfig.
     projection: str = "einsum"
     # A sequence torso shared by actor and critic (models/torso.py): a
     # ``TorsoSpec``, or the dict a configuration file's ``model.torso``
@@ -128,7 +118,7 @@ class D4PGConfig:
             raise ValueError(f"unknown critic_family {self.critic_family!r}")
         if self.compute_dtype not in ("float32", "bfloat16"):
             raise ValueError(f"unknown compute_dtype {self.compute_dtype!r}")
-        if self.projection not in ("einsum", "pallas", "pallas_ce"):
+        if self.projection != "einsum":
             raise ValueError(f"unknown projection {self.projection!r}")
         if self.augment not in ("none", "shift"):
             raise ValueError(f"unknown augment {self.augment!r}")

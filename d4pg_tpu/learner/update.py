@@ -33,57 +33,10 @@ from jax import Array
 
 from d4pg_tpu.core import mog as mog_ops
 from d4pg_tpu.core.distribution import categorical_projection
-from d4pg_tpu.core.losses import (
-    categorical_td_loss,
-    expected_q,
-    weighted_mean,
-)
+from d4pg_tpu.core.losses import categorical_td_loss, expected_q
 from d4pg_tpu.core.updates import soft_update, tie_encoder
 from d4pg_tpu.learner.state import D4PGConfig, D4PGState
 from d4pg_tpu.replay.uniform import TransitionBatch
-
-
-def _pallas_backend(flag: str) -> str | None:
-    """Resolve whether a ``--projection pallas*`` choice can run on the
-    current backend, with the shared trace-time warnings (fire once per
-    compile, not per step): interpret-mode emulation on CPU is for kernel
-    verification only — a silent orders-of-magnitude slowdown in a real
-    CPU training run (ADVICE r3) — and backends with no Pallas lowering
-    (e.g. CUDA) fall back to the einsum formulation. Returns the backend
-    name to run the kernel on, or None for the einsum fallback."""
-    import warnings
-
-    backend = jax.default_backend()
-    if backend == "cpu":
-        warnings.warn(
-            f"--projection {flag} on the CPU backend runs the kernel in "
-            "interpret (emulation) mode — orders of magnitude slower than "
-            "the einsum projection; use it for kernel verification only",
-            stacklevel=3)
-    if backend in ("tpu", "cpu"):
-        return backend
-    warnings.warn(
-        f"--projection {flag} has no {backend} path; using the einsum "
-        "formulation", stacklevel=3)
-    return None
-
-
-def _project(
-    config: D4PGConfig, target_probs: Array, rewards: Array, discounts: Array
-) -> Array:
-    """Bellman projection through the configured implementation: the MXU
-    einsum (default) or the fused Pallas kernel (``--projection pallas``;
-    interpret mode keeps it runnable on the CPU backend for tests)."""
-    if config.projection == "pallas":
-        backend = _pallas_backend("pallas")
-        if backend is not None:
-            from d4pg_tpu.ops.projection import projection_pallas
-
-            return projection_pallas(
-                config.support, target_probs, rewards, discounts,
-                backend == "cpu",
-            )
-    return categorical_projection(config.support, target_probs, rewards, discounts)
 
 
 def _critic_loss_fn(
@@ -113,22 +66,9 @@ def _critic_loss_fn(
         state.target_critic_params, batch.next_obs, next_action
     )
     pred_probs = critic.apply(critic_params, batch.obs, batch.action)
-    if config.projection == "pallas_ce":
-        backend = _pallas_backend("pallas_ce")
-        if backend is not None:
-            # fully-fused projection + cross-entropy (ops/projection_ce.py):
-            # the interpolation weights AND the projected target live only
-            # in VMEM, forward and backward. Kernel contract == the
-            # stop_gradient(projection) semantics below.
-            from d4pg_tpu.ops.projection_ce import projection_ce_pallas
-
-            td = projection_ce_pallas(
-                config.support, jax.lax.stop_gradient(target_probs),
-                batch.reward, batch.discount, pred_probs,
-                backend == "cpu")
-            return weighted_mean(td, is_weights), td
     proj = jax.lax.stop_gradient(
-        _project(config, target_probs, batch.reward, batch.discount)
+        categorical_projection(
+            config.support, target_probs, batch.reward, batch.discount)
     )
     return categorical_td_loss(proj, pred_probs, weights=is_weights)
 
@@ -304,7 +244,8 @@ def _torso_update_step(
         target_probs = critic.of_latent(state.target_critic_params, z_next,
                                         next_action)
         proj = jax.lax.stop_gradient(
-            _project(config, target_probs, batch.reward, batch.discount))
+            categorical_projection(config.support, target_probs,
+                                   batch.reward, batch.discount))
 
         def critic_loss_fn(p):
             z, counts = critic.latent(p, batch.obs)

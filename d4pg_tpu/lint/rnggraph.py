@@ -80,9 +80,9 @@ RNG_RULES = (
 _STREAM_OWNER = re.compile(r"#\s*jaxlint:\s*stream-owner=([\w\.\-,]+)")
 
 # Determinism scope roots: package directories whose code carries a
-# seeded-replay contract, plus module stems that do wherever they live
-# (bench.py sits at the package root).  lint/ is never scoped — its
-# sources *name* these APIs without running them.
+# seeded-replay contract, plus module stems that do wherever they live.
+# lint/ is never scoped — its sources *name* these APIs without running
+# them.
 _SCOPE_DIRS = {"fleet", "elastic", "replay", "obs", "analysis"}
 _SCOPE_STEM = re.compile(r"(chaos|traffic|sampler|ledger|bench)")
 

@@ -1,20 +1,8 @@
-"""Hand-written TPU kernels (Pallas).
+"""Device ops the models call, each chosen from the platform it runs on.
 
-The compute path is XLA-compiled JAX; these kernels cover the few ops where
-explicit fusion/layout control beats the compiler. Each kernel has a
-reference JAX formulation it is tested against, and callers can select the
-implementation (``method='einsum' | 'pallas'``) — or leave the config
-default ``'auto'``, which runs the startup micro-autotuner
-(``ops/autotune.py``) to time the variants on the actual shapes and pick
-the winner.
+- ``augment``: the DrQ random shift as two batched one-hot matrix products.
+- ``attention``: softmax attention for the torso, blockwise ``jax.numpy`` or
+  the splash kernel on a TPU.
+- ``grouped``: the experts' grouped matrix products, ``ragged_dot`` or
+  megablox ``gmm`` on a TPU.
 """
-
-from d4pg_tpu.ops.autotune import (
-    AutotuneResult,
-    autotune_projection,
-    select_projection,
-)
-from d4pg_tpu.ops.projection import projection_pallas
-
-__all__ = ["AutotuneResult", "autotune_projection", "projection_pallas",
-           "select_projection"]

@@ -53,25 +53,14 @@ def shard_stacked(batches, mesh: Mesh):
 
 
 def check_mesh_compatible(config: D4PGConfig) -> None:
-    """The Pallas projection kernel has no GSPMD partitioning rule — under
-    a sharded jit it would fail to compile or silently all-gather the
-    batch onto every device. Mesh learners must use the einsum
-    formulation (which shards trivially); fail loudly rather than either,
-    and print the rule table the mesh layout WOULD resolve to, so the fix
-    (and what it buys) is in the error itself."""
+    """What a mesh learner cannot run: refused loudly, before anything
+    compiles."""
     if config.torso is not None:
         raise ValueError(
             "a torso (--torso) runs on one device: its expert layer is one "
             "chip's share without the exchange, and its metrics "
             "(route_counts) and kernels have no sharding rule yet "
             "(ROADMAP Reach 11)")
-    if config.projection in ("pallas", "pallas_ce"):
-        raise ValueError(
-            f"--projection {config.projection} is single-device only "
-            "(pallas_call does not partition under a sharded jit); use "
-            "--projection einsum with a device mesh. Resolved partition "
-            "rules for this mesh:\n" + partition.format_rules()
-        )
 
 
 def make_sharded_update(

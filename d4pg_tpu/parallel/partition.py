@@ -121,8 +121,8 @@ def per_tree_spec() -> PS:
     every query touches every level, so splitting the tree over any mesh
     axis would turn each of the log2(cap) gathers into a collective.
     Keeping the tree replicated keeps the jitted deal dispatch at zero
-    all-to-alls (the ReshardSentinel pin in bench.py's device-dealt
-    block) at a memory cost of 8 bytes/slot/device."""
+    all-to-alls (the ReshardSentinel pin in tests/test_devsample.py) at
+    a memory cost of 8 bytes/slot/device."""
     return PS()
 
 

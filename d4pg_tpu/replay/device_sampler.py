@@ -11,9 +11,9 @@ already maintains (``replay/fused_buffer.FusedDeviceReplay`` in
 ``gen_tracked`` mode), the seeded stratified descent runs on device
 immediately after the commit dispatch, and dealt blocks are emitted as
 device-resident gathers — zero host tree math, zero sampled-row H2D
-(TransferSentinel-checked in bench.py), and the replica sample path
-keeps PR 12's zero buffer-lock acquisitions (ring pop + sampler-tier
-write-back enqueue only).
+(TransferSentinel-checked in tests/test_devsample.py), and the replica
+sample path keeps PR 12's zero buffer-lock acquisitions (ring pop +
+sampler-tier write-back enqueue only).
 
 Division of labor per ``ingest_and_deal`` tick (commit thread, inside
 the ONE buffer-lock window the commit already owned):
@@ -46,11 +46,10 @@ consequence of float32 device trees, not of the descent logic (the tie
 rule ``mass >= left_sum`` -> RIGHT is shared by every implementation,
 see ``device_per.descend``).
 
-The descent implementation is an autotune surface (``--sampler``,
-``ops/autotune.select_sampler``): ``'scan'`` is the jnp gather descent,
-``'pallas'`` the VMEM-resident kernel (``ops/sampler_descent``), and
-``'host'`` the PR-12 host dealer as the fallback arm (constructed by the
-caller, not here). Host->device bytes on the deal path are the [K, B]
+The descent is ``device_per.descend`` (the gather descent the fused chunk
+runs); the PR-12 host dealer is the other ``--sampler`` arm, constructed
+by the caller, and :func:`resolve_sampler` says which one ``auto`` means.
+Host->device bytes on the deal path are the [K, B]
 float32 uniforms and two scalars per block — O(K*B) floats against the
 O(K*B*obs_dim) row bytes the host dealer ships, and none of it an
 explicit ``device_put`` of sampled rows.
@@ -72,6 +71,22 @@ import numpy as np
 
 from d4pg_tpu.replay import device_per as dper
 from d4pg_tpu.replay.sampler import DealtBlock, SampleDealer
+
+def resolve_sampler(flag: str) -> str:
+    """The ``--sampler`` arm a flag means. ``'scan'`` (this module's
+    dealer) and ``'host'`` (``SampleDealer``) pass through; ``'auto'`` is
+    ``'scan'`` on a TPU, where the descent rides the commit dispatch the
+    trees already live behind, and ``'host'`` elsewhere, where a dispatch
+    per deal saturates the commit thread (``docs/evidence/fleet/``)."""
+    if flag == "auto":
+        import jax
+
+        return "scan" if jax.default_backend() == "tpu" else "host"
+    if flag not in ("scan", "host"):
+        raise ValueError(f"unknown --sampler arm {flag!r} "
+                         "(want 'auto', 'scan' or 'host')")
+    return flag
+
 
 # Write-back scatter bucket: settles pad (idx = tree capacity, dropped)
 # or split to this many rows so the jitted scatter compiles ONCE.
@@ -108,25 +123,13 @@ class DeviceSampleDealer(SampleDealer):
     def __init__(self, capacity: int, rings, *, k: int, batch_size: int,
                  alpha: float = 0.6, beta_schedule=None, min_size: int = 1,
                  seed: int = 0, ring_capacity: int = 4,
-                 max_deals_per_tick: int = 1, audit: bool = False,
-                 arm: str = "scan", interpret: bool | None = None):
-        if arm not in ("scan", "pallas"):
-            raise ValueError(f"unknown device sampler arm {arm!r} "
-                             "(want 'scan' or 'pallas'; 'host' is the "
-                             "plain SampleDealer, constructed by the "
-                             "caller)")
+                 max_deals_per_tick: int = 1, audit: bool = False):
         super().__init__(capacity, rings, n_shards=1, k=k,
                          batch_size=batch_size, alpha=alpha,
                          beta_schedule=beta_schedule, min_size=min_size,
                          seed=seed, ring_capacity=ring_capacity,
                          max_deals_per_tick=max_deals_per_tick,
                          audit=audit, scheme="device")
-        self.arm = arm
-        if interpret is None:
-            import jax
-
-            interpret = jax.default_backend() == "cpu"
-        self._interpret = bool(interpret)
         self._buffer = None
         self._deal_fn = self._make_deal()
 
@@ -135,26 +138,12 @@ class DeviceSampleDealer(SampleDealer):
         import jax
         import jax.numpy as jnp
 
-        k, b, arm = self.k, self.batch_size, self.arm
         treecap = self._trees.capacity  # next_pow2(ring capacity)
-        interpret = self._interpret
-
-        if arm == "pallas":
-            from d4pg_tpu.ops.sampler_descent import descend_pallas
-
-            def _descend(sum_tree, mass):
-                # flat [K*B] queries; bitwise-equal to the jnp arm by
-                # the kernel's one-hot-gather construction
-                return descend_pallas(sum_tree, mass.reshape(-1),
-                                      interpret).reshape(k, b)
-        else:
-            def _descend(sum_tree, mass):
-                return dper.descend(sum_tree, mass)
 
         def deal(storage, sum_tree, min_tree, gen, u, size):
             total = sum_tree[1]
             mass = dper.strata_mass(u, total)  # [K, B] float32
-            idx = _descend(sum_tree, mass)
+            idx = dper.descend(sum_tree, mass)
             idx = jnp.minimum(idx, jnp.maximum(size - 1, 0))
             # device-resident gathers: the dealt rows never exist on the
             # host (DealtBlock.batches are device arrays [K, B, ...])
@@ -167,7 +156,7 @@ class DeviceSampleDealer(SampleDealer):
 
     @property
     def deal_fn(self):
-        """The jitted deal dispatch — exposed so bench/tests can run
+        """The jitted deal dispatch — exposed so harnesses and tests can run
         ``ReshardSentinel.inspect`` over its compiled HLO (the fused
         sample dispatch must contain 0 resharding collectives)."""
         return self._deal_fn

@@ -26,7 +26,7 @@ boundaries, checkpointing); the overlapped schedule in
 ``learner/pipeline.IngestOverlap`` interleaves the two calls with fused
 chunks so steady state pays ≤ 1 explicit H2D per chunk. ``drain_per_row``
 keeps the old one-dispatch-per-row path as the measured baseline and the
-bitwise-equivalence oracle (tests/test_ingest.py, bench.py).
+bitwise-equivalence oracle (tests/test_ingest.py).
 
 The generation guard the host path needs (``prioritized.py`` — a sampled
 slot overwritten before its priority lands) is structurally unnecessary
@@ -378,7 +378,7 @@ class FusedDeviceReplay:
     # ingest_commit/drain_device/load_replay_state, i.e. UNDER the
     # service's buffer lock; the guarded-by annotations declare that
     # caller contract to the unguarded-shared-write lock-graph rule
-    # (bench.py drives the buffer directly, single-threaded).
+    # (tests drive the buffer directly, single-threaded).
     def stage_block(self) -> int:  # jaxlint: guarded-by=_buffer_lock
         """Start the H2D transfer of ONE pending block frame (a single
         ``jax.device_put`` of the fixed-shape [block_rows] views) — the
@@ -475,11 +475,9 @@ class FusedDeviceReplay:
 
     def drain_per_row(self) -> int:
         """The pre-block reference drain: one scatter dispatch + one tree
-        insert PER ROW. Kept as the measured baseline for
-        ``bench.py``'s ``ingest_rows_per_sec`` speedup claim and as the
-        bitwise-equivalence oracle for the block path (the block drain
-        must land exactly these bytes and priorities). Not used by any
-        shipped loop."""
+        insert PER ROW. Kept as the bitwise-equivalence oracle for the
+        block path (the block drain must land exactly these bytes and
+        priorities). Not used by any shipped loop."""
         total = self.commit_staged()  # a device-staged frame goes block-wise
         while True:
             frame, n = self._staging.frame()

@@ -1,7 +1,7 @@
 """Process start-up: the one backend rule every entry point shares.
 
 ``start()`` runs before the first JAX call that initialises a backend,
-in ``d4pg_tpu.train.main``, ``bench.py``, ``__graft_entry__.entry`` and
+in ``d4pg_tpu.train.main``, ``__graft_entry__.entry`` and
 ``chip_smoke.py``. The rule has two outcomes and nothing in between:
 
   - chip (the default): the TPU is required. ``jax_platforms`` becomes

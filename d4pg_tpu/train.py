@@ -373,30 +373,26 @@ def train(cfg: ExperimentConfig) -> dict:
             raise ValueError(
                 "--replay_storage device with --data_parallel > 1 requires "
                 "the fused path (--fused_replay auto/on)")
-    # Sample-path arm for --sample_on_ingest (ops/autotune.select_sampler,
-    # the third arbitration surface): resolved BEFORE buffer construction
-    # because the device arms ('scan'/'pallas') change what the service
-    # owns — a gen-tracked fused device ring whose commit thread runs the
-    # stratified descent fused behind the commit dispatch, dealing
+    # Sample-path arm for --sample_on_ingest: resolved BEFORE buffer
+    # construction because the device arm ('scan') changes what the
+    # service owns — a gen-tracked fused device ring whose commit thread
+    # runs the stratified descent fused behind the commit dispatch, dealing
     # device-resident blocks. 'host' keeps the PR-12 host SampleDealer
-    # against host replay storage (the fallback arm).
+    # against host replay storage.
     dealt_arm = None
     if cfg.sample_on_ingest and cfg.prioritized_replay:
-        from d4pg_tpu.ops.autotune import select_sampler
+        from d4pg_tpu.replay.device_sampler import resolve_sampler
 
-        dealt_arm = select_sampler(
-            cfg.sampler, capacity=cfg.memory_size,
-            k=max(1, cfg.updates_per_dispatch),
-            batch_size=cfg.batch_size).selected
-        if dealt_arm in ("scan", "pallas"):
+        dealt_arm = resolve_sampler(cfg.sampler)
+        if dealt_arm == "scan":
             if mesh is not None or multi_host:
                 raise ValueError(
-                    "--sampler scan/pallas (device-dealt) makes the commit "
+                    "--sampler scan (device-dealt) makes the commit "
                     "thread the single owner of every device handle — "
                     "mesh/multi-host learners need --sampler host")
             if cfg.ingest_shards != 1:
                 raise ValueError(
-                    "--sampler scan/pallas needs --ingest_shards 1: the "
+                    "--sampler scan needs --ingest_shards 1: the "
                     "gen-tracked ring pre-assigns slots under ONE commit "
                     "thread (shard it with --sampler host instead)")
             if cfg.fused_replay == "on":
@@ -433,7 +429,7 @@ def train(cfg: ExperimentConfig) -> dict:
                                    prioritized=cfg.prioritized_replay,
                                    obs_dtype=obs_dtype,
                                    ingest_shards=cfg.ingest_shards)
-    elif dealt_arm in ("scan", "pallas"):
+    elif dealt_arm == "scan":
         from d4pg_tpu.replay.fused_buffer import FusedDeviceReplay
 
         # the device-dealt service buffer: slots pre-assigned on the
@@ -457,7 +453,6 @@ def train(cfg: ExperimentConfig) -> dict:
         "platform": jax.default_backend(),
         "storage": storage,
         "fused": fused,
-        "projection": config.projection,
         "K": max(1, cfg.updates_per_dispatch),
         "devices": ([d.id for d in mesh.devices.flat]
                     if mesh is not None else [jax.devices()[0].id]),
@@ -967,8 +962,7 @@ def train(cfg: ExperimentConfig) -> dict:
     # Double-buffered host->device staging (SURVEY.md §7 "hard parts"):
     # while the device runs chunk t's scanned update, the host samples and
     # device_puts chunk t+1; PER priority staleness is bounded by (depth+1)K steps.
-    # The pipeline itself lives in learner/pipeline.py, shared with bench.py
-    # so the benchmarked loop IS the shipped loop.
+    # The pipeline itself lives in learner/pipeline.py.
     def _per_write_back(aux, td):
         idx, gen = aux
         for i in range(len(idx)):
@@ -1088,8 +1082,8 @@ def train(cfg: ExperimentConfig) -> dict:
                 "--learners > 1 / --sample_on_ingest need the host-sampled "
                 "replay path (the FusedLoop learner is single-consumer by "
                 "construction — pass --fused_replay off; device-resident "
-                "sampling under --sample_on_ingest is --sampler "
-                "scan/pallas, which owns its fused ring via the dealer)")
+                "sampling under --sample_on_ingest is --sampler scan, "
+                "which owns its fused ring via the dealer)")
         # Merge transport (--agg_transport): 'collective' runs the
         # replicas mesh-native (learner/mesh_replicas.py — full states
         # stacked along the 'replica' mesh axis by partition rule, the
@@ -1167,7 +1161,7 @@ def train(cfg: ExperimentConfig) -> dict:
 
             dealt_rings: list = []
             if cfg.sample_on_ingest:
-                if dealt_arm in ("scan", "pallas"):
+                if dealt_arm == "scan":
                     # device-dealt plane: the dealer runs the stratified
                     # descent on device fused behind the commit dispatch
                     # and deals device-resident blocks; rings delete
@@ -1182,8 +1176,7 @@ def train(cfg: ExperimentConfig) -> dict:
                         cfg.memory_size, dealt_rings, k=K,
                         batch_size=cfg.batch_size, alpha=cfg.per_alpha,
                         beta_schedule=beta_sched,
-                        min_size=max(1, cfg.batch_size), seed=cfg.seed,
-                        arm=dealt_arm)
+                        min_size=max(1, cfg.batch_size), seed=cfg.seed)
                 else:
                     from d4pg_tpu.replay.sampler import SampleDealer
                     from d4pg_tpu.replay.staging import DealtBlockRing

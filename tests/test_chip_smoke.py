@@ -1,6 +1,6 @@
 """``chip_smoke.py``'s body at a tiny size on the CPU: ``--platform cpu``,
-the ``point`` env, interpret-mode kernels and the same assertions, so the
-smoke's control flow is exercised here before chip time is spent on it.
+the ``point`` env and the same assertions, so the smoke's control flow is
+exercised here before chip time is spent on it.
 The full-width run needs the chip (``python chip_smoke.py`` through the
 chip tool); that it REFUSES to run without one is pinned in
 ``tests/test_startup.py``."""

@@ -1,5 +1,5 @@
 """Device-resident PER sampling (replay/device_per.py descent +
-replay/device_sampler.DeviceSampleDealer + ops/sampler_descent.py).
+replay/device_sampler.DeviceSampleDealer).
 
 The load-bearing oracle is the seeded-stream lockstep: the device dealer
 and its float32 host twin (``SampleDealer(scheme='device')`` — numpy
@@ -206,34 +206,6 @@ def test_descent_tie_rule_on_duplicate_prefixes():
     np.testing.assert_array_equal(got, [0, 0, 3, 3])
 
 
-# ------------------------------------------------ pallas kernel parity
-
-
-def test_pallas_descent_bitwise_equals_scan(rng):
-    """The Pallas one-hot-contraction descent vs the jnp gather descent:
-    bitwise-identical indices (0*x=0 and x+0=x are exact in IEEE f32,
-    so the contraction IS a gather). Random trees with zero runs, plus
-    the all-zero tree, across capacities including non-tile-multiple
-    query counts."""
-    from d4pg_tpu.ops.sampler_descent import descend_pallas
-
-    for cap in (8, 64, 256):
-        vals = rng.random(cap).astype(np.float32)
-        vals[rng.random(cap) < 0.5] = 0.0
-        trees = dper.set_leaves(dper.init(cap), jnp.arange(cap),
-                                jnp.asarray(vals))
-        total = float(trees.sum_tree[1])
-        mass = jnp.asarray((rng.random(300) * total).astype(np.float32))
-        want = np.asarray(dper.descend(trees.sum_tree, mass))
-        got = np.asarray(descend_pallas(trees.sum_tree, mass, True))
-        np.testing.assert_array_equal(got, want)
-    zero = dper.init(16)
-    mass = jnp.zeros((5,), jnp.float32)
-    np.testing.assert_array_equal(
-        np.asarray(descend_pallas(zero.sum_tree, mass, True)),
-        np.asarray(dper.descend(zero.sum_tree, mass)))
-
-
 # ------------------------------------- write-back fencing, device tree
 
 
@@ -347,39 +319,31 @@ def test_device_sampler_chaos_smoke():
     assert rep["consumer"]["blocks_consumed"] > 0
 
 
-# ------------------------------------------- autotune arbitration
+# ------------------------------------------------- the --sampler rule
 
 
-def test_select_sampler_policy_and_validation():
-    from d4pg_tpu.ops import autotune as at
+@pytest.mark.parametrize("flag,backend,want", [
+    ("auto", None, "host"),   # off a TPU (tier-1 runs on the CPU)
+    ("auto", "tpu", "scan"),
+    ("scan", None, "scan"),
+    ("host", None, "host"),
+    ("pallas", None, ValueError),
+    ("einsum", None, ValueError),
+])
+def test_resolve_sampler_policy_and_validation(monkeypatch, flag, backend,
+                                              want):
+    """``auto`` reads the platform and nothing else: no timing pass, so
+    nothing compiles; the two arms pass through; anything else is
+    refused."""
+    from d4pg_tpu.io.profiling import RecompileSentinel
+    from d4pg_tpu.replay.device_sampler import resolve_sampler
 
-    r = at.select_sampler("auto", capacity=CAP, k=K, batch_size=B)
-    if jax.default_backend() != "tpu":
-        # off-accelerator the three-arm A/B shows per-deal dispatch
-        # saturating the commit thread: auto falls back to the PR-12
-        # host dealer, no timing pass
-        assert r.selected == "host" and r.timings_ms is None
-    assert at.select_sampler("scan", capacity=CAP, k=K,
-                             batch_size=B).selected == "scan"
-    with pytest.raises(ValueError, match="unknown --sampler arm"):
-        at.select_sampler("einsum", capacity=CAP, k=K, batch_size=B)
-
-
-def test_autotune_block_unified_schema():
-    """Satellite contract: ONE schema-versioned ``autotune`` bench block
-    carrying every arbitration surface's decision — projection AND
-    sampler — each with (selected, reason, timings_ms)."""
-    from d4pg_tpu.ops import autotune as at
-
-    at.select_projection("einsum", batch_size=B, v_min=0.0, v_max=1.0,
-                         n_atoms=11)
-    at.select_sampler("scan", capacity=CAP, k=K, batch_size=B)
-    blk = at.autotune_block()
-    assert blk["metric"] == "autotune"
-    assert blk["schema"] == at.AUTOTUNE_SCHEMA == 1
-    for surface in ("projection", "sampler"):
-        row = blk["surfaces"][surface]
-        assert set(row) == {"selected", "reason", "timings_ms"}
-        assert row["selected"]
-    assert blk["surfaces"]["projection"]["selected"] == "einsum"
-    assert blk["surfaces"]["sampler"]["selected"] == "scan"
+    if backend is not None:
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if want is ValueError:
+        with pytest.raises(ValueError, match="unknown --sampler arm"):
+            resolve_sampler(flag)
+        return
+    with RecompileSentinel() as compiles:
+        assert resolve_sampler(flag) == want
+    assert compiles.compilations == 0

@@ -5,7 +5,7 @@ reproducibility of the seeded fault script, determinism of the chaos
 primitives, and the degradation bookkeeping (every lost row lands in a
 named counter). The wide sweeps (N up to 256) are ``slow``; their real
 run is the committed ``docs/evidence/fleet/`` artifact from
-``python bench.py --fleet``.
+``python -m d4pg_tpu.fleet.sweep --out docs/evidence/fleet``.
 """
 
 import dataclasses
@@ -306,8 +306,8 @@ def test_fleet_actor_mode_smoke():
 @pytest.mark.fleet
 def test_shard_sweep_slow():
     """A bounded K ∈ {1, 2} shard sweep through the real sweep runner
-    (the full K ∈ {1, 2, 4} x N=256 version is ``python bench.py
-    --fleet``; its artifact is committed under docs/evidence/fleet/)."""
+    (the full K ∈ {1, 2, 4} x N=256 version is ``fleet.sweep.run_fleet``;
+    its artifact is committed under docs/evidence/fleet/)."""
     from d4pg_tpu.fleet import shard_sweep
 
     artifact = shard_sweep(ks=(1, 2), n_actors=16, duration_s=2.0,
@@ -333,7 +333,7 @@ def test_shard_sweep_slow():
 @pytest.mark.fleet
 def test_fleet_sweep_slow():
     """A bounded two-point sweep through the real sweep runner (the full
-    {8..256} x 10 s version is ``python bench.py --fleet``; its artifact
+    {8..256} x 10 s version is ``fleet.sweep.run_fleet``; its artifact
     is committed under docs/evidence/fleet/)."""
     artifact = run_sweep(ns=(8, 32), duration_s=2.0,
                          chaos=SMOKE_CHAOS, obs_dim=24, act_dim=4,
@@ -352,17 +352,23 @@ def test_fleet_sweep_slow():
 
 
 def test_bench_fleet_entrypoint_importable():
-    """bench.bench_fleet is the integration point the artifact pipeline
-    calls; it must resolve without an accelerator backend."""
-    import importlib.util
+    """``d4pg_tpu.fleet.sweep`` is the entry point the artifact pipeline
+    calls (``python -m d4pg_tpu.fleet.sweep``): importing it and reaching
+    ``run_fleet`` / ``main`` initialises no backend, so the host-only
+    blocks run on a machine whose accelerator another process holds."""
     import os
+    import subprocess
+    import sys
 
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    assert callable(bench.bench_fleet)
+    code = ("import d4pg_tpu.fleet.sweep as s\n"
+            "from jax._src import xla_bridge\n"
+            "assert callable(s.run_fleet) and callable(s.main)\n"
+            "assert callable(s.write_evidence)\n"
+            "assert not xla_bridge._backends, xla_bridge._backends\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run([sys.executable, "-c", code], cwd=root,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
 
 
 def test_fleet_analysis_table_and_plot(tmp_path):

@@ -1,7 +1,7 @@
 """Ingest-plane tests: the batched block drain (replay/fused_buffer.py +
 device_ring.block_write), the overlapped ≤1-H2D-per-chunk schedule
-(learner/pipeline.IngestOverlap), the coalescing transport, and the
-projection autotuner policy. The per-row drain the block path replaced is
+(learner/pipeline.IngestOverlap), the coalescing transport, and what
+``ExperimentConfig.learner_config`` builds. The per-row drain the block path replaced is
 kept as the bitwise oracle (``drain_per_row``)."""
 
 import threading
@@ -326,45 +326,42 @@ def test_replay_service_coalesced_ingest_counts_env_steps(rng):
     service.close()
 
 
-# ------------------------------------------------- projection autotune ----
+# ------------------------------------------- what learner_config builds ----
 
-def test_autotune_explicit_override_passes_through():
-    from d4pg_tpu.ops.autotune import select_projection
-
-    r = select_projection("pallas_ce", batch_size=64, v_min=0, v_max=1,
-                          n_atoms=11)
-    assert r.selected == "pallas_ce" and "override" in r.reason
-
-
-def test_autotune_static_policy_off_tpu_and_on_mesh():
-    from d4pg_tpu.ops.autotune import select_projection
-
-    r = select_projection("auto", batch_size=64, v_min=0, v_max=1,
-                          n_atoms=11)
-    assert r.selected == "einsum"  # CPU backend: nothing real to time
-    assert r.timings_ms is None
-    r = select_projection("auto", batch_size=64, v_min=0, v_max=1,
-                          n_atoms=11, mesh=True)
-    assert r.selected == "einsum" and "GSPMD" in r.reason
-
-
-def test_autotune_measured_path_agrees_with_loss_core():
-    """The timed micro-kernels themselves must run and pick SOME variant
-    (exercised here on CPU where pallas runs interpreted — slow but
-    correct; the policy path never does this, it is forced for
-    coverage)."""
-    from d4pg_tpu.ops.autotune import autotune_projection
-
-    r = autotune_projection(batch_size=8, v_min=0, v_max=1, n_atoms=11,
-                            repeats=1, iters=1)
-    assert r.selected in ("einsum", "pallas", "pallas_ce")
-    assert isinstance(r.timings_ms["einsum"], float)
-
-
-def test_config_auto_resolves_before_learner_config():
+@pytest.mark.parametrize("backend", [None, "tpu"])
+def test_config_auto_resolves_before_learner_config(monkeypatch, backend):
+    """``learner_config`` builds the one projection and measures nothing,
+    on any backend: no start-up timing pass, no compile."""
     from d4pg_tpu.config import ExperimentConfig
+    from d4pg_tpu.io.profiling import RecompileSentinel
 
+    if backend is not None:
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
     cfg = ExperimentConfig(env="point", v_min=-10.0, v_max=10.0)
-    assert cfg.projection == "auto"
-    config = cfg.learner_config(OBS, ACT)
-    assert config.projection in ("einsum", "pallas", "pallas_ce")
+    with RecompileSentinel() as compiles:
+        config = cfg.learner_config(OBS, ACT)
+    assert config.projection == "einsum"
+    assert compiles.compilations == 0
+
+
+def test_train_flags_build_the_measured_learner_config():
+    """What ``train.main`` builds from the flags for the sizes
+    ``benchmark/configs/humanoid-mlp.json`` names is, field for field of
+    that file's ``model`` block, the ``D4PGConfig`` the benchmark's cells
+    measure."""
+    import json
+    import os
+
+    from benchmark import cellbuild
+    from d4pg_tpu.config import parse_args
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "humanoid-mlp.json")) as f:
+        cell = json.load(f)
+    want = cellbuild.learner_config(cell)
+    got = parse_args(["--env", "Humanoid-v4", "--v_min", "0", "--v_max",
+                      "800", "--compute_dtype", "bfloat16"]
+                     ).learner_config(376, 17)
+    for field in cell["model"]:
+        assert getattr(got, field) == getattr(want, field), field

@@ -33,9 +33,11 @@ def test_package_lints_clean():
 
 @pytest.mark.lint
 def test_bench_and_entrypoints_lint_clean():
-    """The scripts feeding the headline numbers are held to the same bar."""
-    files = [os.path.join(REPO_ROOT, n) for n in ("bench.py",)]
-    result = lint_paths([f for f in files if os.path.exists(f)])
+    """The entry scripts at the repository root are held to the same bar."""
+    files = [os.path.join(REPO_ROOT, n)
+             for n in ("chip_smoke.py", "__graft_entry__.py")]
+    assert all(os.path.exists(f) for f in files), files
+    result = lint_paths(files)
     msgs = [f.format() for f in result.findings] + result.errors
     assert result.clean, (
         "jaxlint found unsuppressed hazards:\n" + "\n".join(msgs))
@@ -44,7 +46,7 @@ def test_bench_and_entrypoints_lint_clean():
 @pytest.mark.lint
 def test_suppression_audit():
     """Audit every ``# jaxlint: disable`` AND ``# jaxlint: guarded-by``
-    in the package + bench.py: a disable must name only REGISTERED rules
+    in the package: a disable must name only REGISTERED rules
     (a typo'd rule id suppresses nothing and rots silently), a
     guarded-by must name a lock the whole-program lock graph actually
     knows (a typo'd lock name vouches for nothing), a ``contained-by``
@@ -76,11 +78,10 @@ def test_suppression_audit():
     known_locks = set(graph.nodes) | set(_DEFAULT_TIERS)
     fail_graph, _errors = build_fail_graph([PACKAGE_DIR])
     mesh_graph, _errors = build_mesh_graph([PACKAGE_DIR])
-    rng_graph, _errors = build_rng_graph(
-        [PACKAGE_DIR, os.path.join(REPO_ROOT, "bench.py")])
+    rng_graph, _errors = build_rng_graph([PACKAGE_DIR])
     audited = 0
     problems = []
-    files = [os.path.join(REPO_ROOT, "bench.py")]
+    files = []
     for dirpath, _dirs, names in os.walk(PACKAGE_DIR):
         files.extend(os.path.join(dirpath, n) for n in names
                      if n.endswith(".py"))

@@ -6,8 +6,7 @@ composition fault, caught only on the single-device path).
 
 Tiny shapes on the 8-virtual-CPU-device mesh: --share_encoder
 --frame_stack 3 --augment shift resolved through ExperimentConfig (the
-real flag path, including '--projection auto' resolving statically to
-einsum for mesh learners), uint8 pixel rows in the sharded device ring,
+real flag path), uint8 pixel rows in the sharded device ring,
 one fused chunk through make_sharded_fused_chunk.
 
 Plus the real-shape EQUIVALENCE gate (ISSUE 14): the same 84x84xstack
@@ -75,9 +74,6 @@ def test_pixel_share_encoder_fused_chunk_on_data_model_mesh(rng):
     config = _pixel_config(dp=4)
     assert config.pixels and config.share_encoder
     assert config.augment == "shift"
-    # '--projection auto' must resolve STATICALLY to einsum under a mesh
-    # (the Pallas kernels have no GSPMD partitioning rule)
-    assert config.projection == "einsum"
 
     buf = ShardedFusedReplay(64, SHAPE, ACT, mesh, alpha=0.6,
                              obs_dtype=np.uint8)

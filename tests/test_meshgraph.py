@@ -482,7 +482,7 @@ def test_reshard_sentinel_clean_and_publishes_counter():
 
 
 def test_fused_learner_path_has_zero_reshards(rng):
-    """The headline invariant bench.py asserts, pinned in-tree: the fused
+    """The headline invariant, pinned in-tree: the fused
     chunk dispatch must compile to zero resharding collectives — the
     runtime proof that no tree crosses layouts mid-program (family 20's
     dynamic twin)."""

@@ -458,8 +458,7 @@ _LATENCY_STAGES = ("wire_to_admission", "admission_to_decode",
                    "decode_to_stage", "stage_to_merge", "merge_to_commit",
                    "commit_to_grad", "wire_to_commit", "wire_to_grad")
 _OVERHEAD_KEYS = {"rows_per_sec_traced", "rows_per_sec_untraced",
-                  "rows_loss_pct", "hook_ns_per_chunk",
-                  "fused_steps_loss_pct_bound", "sample_rate"}
+                  "rows_loss_pct", "hook_ns_per_chunk", "sample_rate"}
 
 
 def test_fleet_artifact_latency_schema():
@@ -485,7 +484,6 @@ def test_fleet_artifact_latency_schema():
     # the acceptance bound: <= 2% throughput loss at the default rate
     assert lat["overhead"]["rows_loss_pct"] is not None
     assert lat["overhead"]["rows_loss_pct"] <= 2.0
-    assert lat["overhead"]["fused_steps_loss_pct_bound"] <= 2.0
     # the shard-sweep scaling table carries stage attribution next to
     # lock_wait_ms on every traced (K>=2) row
     for row in artifact["shard_sweep"]["scaling"]:

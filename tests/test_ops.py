@@ -1,133 +1,9 @@
-"""Pallas kernel tests (interpret mode on the CPU backend).
-
-The einsum projection (core/distribution.py, itself oracle-tested against
-the reference's per-atom loop in test_projection.py) is the oracle here.
-"""
+"""``ops/augment.py``: the DrQ random shift against a per-sample crop."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-from d4pg_tpu.core.distribution import CategoricalSupport, categorical_projection
-from d4pg_tpu.ops.projection import projection_pallas
-
-
-def _rand_dist(rng, b, a):
-    p = rng.random((b, a))
-    return (p / p.sum(-1, keepdims=True)).astype(np.float32)
-
-
-@pytest.mark.parametrize("batch", [1, 64, 100])
-def test_pallas_projection_matches_einsum(rng, batch):
-    sup = CategoricalSupport(-10.0, 0.0, 51)
-    p = jnp.asarray(_rand_dist(rng, batch, 51))
-    r = jnp.asarray(rng.uniform(-12, 2, batch), jnp.float32)  # incl. out-of-range
-    done = rng.random(batch) < 0.3
-    d = jnp.asarray((0.99**3) * ~done, jnp.float32)
-    ref = categorical_projection(sup, p, r, d)
-    out = projection_pallas(sup, p, r, d, True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
-    np.testing.assert_allclose(np.asarray(out).sum(-1), 1.0, atol=1e-5)
-
-
-def test_pallas_projection_terminal_delta(rng):
-    """Terminal transitions (discount 0) collapse to a delta at clip(r)."""
-    sup = CategoricalSupport(0.0, 10.0, 11)
-    p = jnp.asarray(_rand_dist(rng, 8, 11))
-    r = jnp.asarray(np.full(8, 5.0), jnp.float32)
-    d = jnp.zeros(8, jnp.float32)
-    out = np.asarray(projection_pallas(sup, p, r, d, True))
-    want = np.zeros((8, 11), np.float32)
-    want[:, 5] = 1.0  # atom exactly at 5.0
-    np.testing.assert_allclose(out, want, atol=1e-6)
-
-
-def test_pallas_ce_forward_matches_einsum_ce(rng):
-    """Fused projection+cross-entropy == einsum projection then CE."""
-    from d4pg_tpu.core.losses import cross_entropy_per_sample
-    from d4pg_tpu.ops.projection_ce import projection_ce_pallas
-
-    sup = CategoricalSupport(-10.0, 0.0, 51)
-    for batch in (1, 64, 100):
-        p = jnp.asarray(_rand_dist(rng, batch, 51))
-        q = jnp.asarray(_rand_dist(rng, batch, 51))
-        r = jnp.asarray(rng.uniform(-12, 2, batch), jnp.float32)
-        done = rng.random(batch) < 0.3
-        d = jnp.asarray((0.99**3) * ~done, jnp.float32)
-        ref = cross_entropy_per_sample(categorical_projection(sup, p, r, d), q)
-        out = projection_ce_pallas(sup, p, r, d, q, True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=1e-4, rtol=1e-5)
-
-
-def test_pallas_ce_gradient_matches_stop_gradient_reference(rng):
-    """The custom VJP must equal autodiff of CE(stop_gradient(proj), q) —
-    the exact gradient convention of learner/update.py's critic loss."""
-    from d4pg_tpu.core.losses import cross_entropy_per_sample
-    from d4pg_tpu.ops.projection_ce import projection_ce_pallas
-
-    sup = CategoricalSupport(-5.0, 0.0, 31)
-    batch = 64
-    p = jnp.asarray(_rand_dist(rng, batch, 31))
-    q = jnp.asarray(_rand_dist(rng, batch, 31))
-    r = jnp.asarray(rng.uniform(-6, 1, batch), jnp.float32)
-    d = jnp.asarray(np.full(batch, 0.99), jnp.float32)
-    w = jnp.asarray(rng.random(batch), jnp.float32)  # IS-weighted mean
-
-    def ref_loss(q_):
-        proj = jax.lax.stop_gradient(categorical_projection(sup, p, r, d))
-        return jnp.mean(w * cross_entropy_per_sample(proj, q_))
-
-    def fused_loss(q_):
-        return jnp.mean(w * projection_ce_pallas(sup, p, r, d, q_, True))
-
-    g_ref = jax.grad(ref_loss)(q)
-    g_fused = jax.grad(fused_loss)(q)
-    np.testing.assert_allclose(np.asarray(g_fused), np.asarray(g_ref),
-                               atol=1e-5, rtol=1e-5)
-    # and no gradient leaks through the Bellman operands
-    gp = jax.grad(lambda p_: jnp.sum(
-        projection_ce_pallas(sup, p_, r, d, q, True)))(p)
-    np.testing.assert_array_equal(np.asarray(gp), 0.0)
-
-
-def test_update_step_pallas_ce_matches_einsum(rng):
-    """One full update with --projection pallas_ce equals the einsum path
-    (same batch, same seed) to float tolerance."""
-    import warnings
-
-    from d4pg_tpu.learner import D4PGConfig, init_state, make_update
-    from d4pg_tpu.replay.uniform import TransitionBatch
-
-    b, obs_dim, act_dim = 64, 6, 2
-    batch = TransitionBatch(
-        obs=rng.standard_normal((b, obs_dim)).astype(np.float32),
-        action=rng.uniform(-1, 1, (b, act_dim)).astype(np.float32),
-        reward=rng.standard_normal(b).astype(np.float32),
-        next_obs=rng.standard_normal((b, obs_dim)).astype(np.float32),
-        done=np.zeros(b, np.float32),
-        discount=np.full(b, 0.99, np.float32),
-    )
-    weights = np.ones(b, np.float32)
-    outs = {}
-    for proj in ("einsum", "pallas_ce"):
-        config = D4PGConfig(obs_dim=obs_dim, act_dim=act_dim, v_min=-5.0,
-                            v_max=0.0, n_atoms=11, hidden=(16, 16),
-                            projection=proj)
-        state = init_state(config, jax.random.key(0))
-        update = make_update(config, donate=False, use_is_weights=True)
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")  # interpret-mode warning on CPU
-            state, metrics = update(state, batch, weights)
-        outs[proj] = (state, metrics)
-    np.testing.assert_allclose(
-        float(outs["pallas_ce"][1]["critic_loss"]),
-        float(outs["einsum"][1]["critic_loss"]), rtol=1e-5)
-    for a, b_ in zip(jax.tree_util.tree_leaves(outs["einsum"][0].critic_params),
-                     jax.tree_util.tree_leaves(outs["pallas_ce"][0].critic_params)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
-                                   atol=1e-5, rtol=1e-4)
 
 
 def test_random_shift_properties(rng):

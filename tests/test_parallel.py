@@ -168,23 +168,3 @@ def test_train_mesh_with_updates_per_dispatch(tmp_path):
     metrics = train(cfg)
     assert np.isfinite(metrics["critic_loss"])
     assert "avg_test_reward" in metrics
-
-
-def test_sharded_factories_reject_pallas_projection():
-    """pallas_call has no GSPMD partitioning rule; the mesh factories must
-    fail loudly instead of compiling a silently-broken sharded kernel."""
-    import pytest
-
-    from d4pg_tpu.learner.state import D4PGConfig
-    from d4pg_tpu.parallel.data_parallel import (
-        make_sharded_multi_update,
-        make_sharded_update,
-    )
-    from d4pg_tpu.parallel.mesh import make_mesh
-
-    config = D4PGConfig(obs_dim=3, act_dim=1, n_atoms=11, hidden=(8,),
-                        projection="pallas")
-    mesh = make_mesh()
-    for factory in (make_sharded_update, make_sharded_multi_update):
-        with pytest.raises(ValueError, match="pallas"):
-            factory(config, mesh)

@@ -6,6 +6,7 @@ support, linear interpolation of mass between floor/ceil bins, terminal
 transitions collapsing to a delta at clip(r).
 """
 
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -43,8 +44,8 @@ def random_dist(rng, shape):
     return p / p.sum(axis=-1, keepdims=True)
 
 
-def test_matches_oracle(rng, support):
-    b = 37
+@pytest.mark.parametrize("b", [1, 64, 100])
+def test_matches_oracle(rng, support, b):
     probs = random_dist(rng, (b, support.n_atoms)).astype(np.float32)
     rewards = rng.normal(0, 5, b).astype(np.float32)
     dones = (rng.random(b) < 0.3).astype(np.float32)
@@ -103,3 +104,60 @@ def test_mean_contraction(rng, support):
     # small interpolation error is expected (projection is not mean-exact
     # once mass is redistributed, but with these scales it's tight)
     np.testing.assert_allclose(np.asarray(expected_q(support, got)), want, atol=0.05)
+
+
+def test_critic_loss_gradient_matches_oracle_and_stops_at_targets(rng):
+    """The critic loss is ``mean(w * -sum(stop_gradient(proj) * log q))``:
+    its gradient into the critic equals the gradient of that expression
+    with the ORACLE's projection held constant, and nothing flows into the
+    networks that produced the Bellman target."""
+    from d4pg_tpu.learner import D4PGConfig, init_state
+    from d4pg_tpu.learner.update import _critic_loss_fn
+    from d4pg_tpu.replay.uniform import TransitionBatch
+
+    b, obs_dim, act_dim = 64, 6, 2
+    config = D4PGConfig(obs_dim=obs_dim, act_dim=act_dim, v_min=-5.0,
+                        v_max=0.0, n_atoms=31, hidden=(16, 16))
+    state = init_state(config, jax.random.key(0))
+    done = (rng.random(b) < 0.3).astype(np.float32)
+    batch = TransitionBatch(
+        obs=rng.standard_normal((b, obs_dim)).astype(np.float32),
+        action=rng.uniform(-1, 1, (b, act_dim)).astype(np.float32),
+        reward=rng.uniform(-6, 1, b).astype(np.float32),
+        next_obs=rng.standard_normal((b, obs_dim)).astype(np.float32),
+        done=done,
+        discount=(0.99 * (1.0 - done)).astype(np.float32),
+    )
+    w = jnp.asarray(rng.random(b), jnp.float32)  # IS-weighted mean
+
+    def loss(critic_params, targets):
+        st = state._replace(target_critic_params=targets[0],
+                            target_actor_params=targets[1])
+        return _critic_loss_fn(config, critic_params, st, batch, w,
+                               jax.random.key(1))[0]
+
+    got, into_targets = jax.grad(loss, argnums=(0, 1))(
+        state.critic_params,
+        (state.target_critic_params, state.target_actor_params))
+    for leaf in jax.tree_util.tree_leaves(into_targets):
+        np.testing.assert_array_equal(np.asarray(leaf), 0.0)
+
+    actor, critic = config.build_actor(), config.build_critic()
+    target = critic.apply(
+        state.target_critic_params, batch.next_obs,
+        actor.apply(state.target_actor_params, batch.next_obs))
+    proj = oracle_projection(config.v_min, config.v_max, config.n_atoms,
+                             np.asarray(target), batch.reward, batch.discount)
+
+    def oracle_loss(critic_params):
+        q = critic.apply(critic_params, batch.obs, batch.action)
+        return jnp.mean(w * -jnp.sum(proj * jnp.log(q + 1e-10), axis=-1))
+
+    want = jax.grad(oracle_loss)(state.critic_params)
+    # the gradient is not vacuous
+    assert max(float(jnp.abs(g).max())
+               for g in jax.tree_util.tree_leaves(want)) > 1e-4
+    for g, o in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(o),
+                                   atol=1e-5, rtol=1e-4)

@@ -5,7 +5,7 @@ twin.
 Fixture halves drive each family on a known-bad snippet and its
 known-good variant (parsed, never executed — determinism scope is
 entered by giving the fixture a ``fleet/`` path); the package halves
-gate the real tree: the rng graph over ``d4pg_tpu/`` + ``bench.py``
+gate the real tree: the rng graph over ``d4pg_tpu/``
 must discover streams and branch sites, resolve every declared stream
 owner, and carry zero findings, and the ``--rng``/``--all`` CLI
 artifacts must exit 0. The runtime half pins DrawLedger semantics
@@ -318,14 +318,13 @@ def test_key_split_across_call_boundary_clean():
 @pytest.mark.lint
 def test_rng_graph_clean_over_package():
     """Tier-1 gate for the determinism surface: the whole-program rng
-    graph over ``d4pg_tpu/`` + ``bench.py`` must discover the component
+    graph over ``d4pg_tpu/`` must discover the component
     streams and their SeedSequence branch sites, resolve every declared
     stream owner, and carry zero findings."""
     from d4pg_tpu.lint.engine import build_rng_graph
     from d4pg_tpu.lint.rnggraph import format_rnggraph
 
-    graph, errors = build_rng_graph(
-        [PACKAGE_DIR, os.path.join(REPO_ROOT, "bench.py")])
+    graph, errors = build_rng_graph([PACKAGE_DIR])
     assert not errors, errors
     assert graph.findings == [], format_rnggraph(graph)
     assert graph.streams, "no RNG streams discovered — walker rot?"

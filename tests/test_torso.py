@@ -431,12 +431,10 @@ def test_train_flag_names_the_torso_from_a_block_or_a_configuration_file(
     whole = tmp_path / "config.json"
     whole.write_text(json.dumps({"model": {"obs_dim": 32, "torso": SMALL}}))
     for path in (block, whole):
-        cfg = parse_args(["--env", "point", "--torso", str(path),
-                          "--projection", "einsum"])
+        cfg = parse_args(["--env", "point", "--torso", str(path)])
         config = cfg.learner_config(32, 3)
         assert config.torso == small_config().torso
-    assert parse_args(["--env", "point", "--projection", "einsum"]
-                      ).learner_config(4, 2).torso is None
+    assert parse_args(["--env", "point"]).learner_config(4, 2).torso is None
 
 
 def test_history_stacks_whole_steps_of_observation_and_action():
