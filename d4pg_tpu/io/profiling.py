@@ -41,15 +41,25 @@ def abstract_args(args: tuple) -> tuple:
     (shape and dtype): what a program-table entry keeps
     (``obs/trace.register_program``), so a trace reader can lower and
     compile the program again while nothing on the device stays alive.
-    No sharding is carried: an argument that names one lowers to another
-    module than the call made (a compile-cache miss of seconds, my chip
-    run, PR 25), and the mesh programs state theirs in ``jax.jit``.
-    Python scalars pass through as they are."""
+    An array committed to one device carries its ``format`` (device and
+    layout), as the call resolved it: the fused buffer pins its ring's
+    layout that way (``replay/device_ring.py``), and a program lowered
+    for the default layout is another program, with other instructions.
+    Nothing else carries a sharding: an uncommitted argument that names
+    one lowers to another module than the call made (a compile-cache
+    miss of seconds, my chip run, PR 25), and the mesh programs state
+    theirs in ``jax.jit``. Python scalars pass through as they are."""
     import jax
+    from jax.sharding import SingleDeviceSharding
 
     def leaf(x):
         if isinstance(x, jax.Array):
-            return jax.ShapeDtypeStruct(x.shape, x.dtype)
+            pinned = x.committed and isinstance(x.sharding,
+                                                SingleDeviceSharding)
+            # (a typed key array has a sharding and no format)
+            return jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=getattr(
+                    x, "format", x.sharding) if pinned else None)
         return x
 
     return jax.tree_util.tree_map(leaf, args)
@@ -75,6 +85,58 @@ def compiled_text_of(fn, args: tuple) -> str:
         return fn.lower(*args).compile().as_text()
     finally:
         jax.config.update(key, before)
+
+
+@contextlib.contextmanager
+def fresh_compile():
+    """Compile what runs inside without the persistent compile cache,
+    neither read nor written. For a program that RETURNS an array in a
+    pinned (non-default) layout: an executable read back from the cache
+    (jax 0.9.0, libtpu 0.0.34) hands out arrays that report the device's
+    default layout whatever layout they have, and ``jax.jit`` lays out
+    the next program's parameters by that report, so the next program
+    refuses the array ("expected parameter 0 of size ... but got buffer
+    with incompatible size"; my chip run, PR 31). A freshly compiled
+    executable tells the truth, call after call. The cache's state is
+    process-wide: a compile another thread starts meanwhile is not
+    cached either, which costs that thread a compile and nothing else."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    flag = "jax_enable_compilation_cache"
+    before = getattr(jax.config, flag)
+    jax.config.update(flag, False)
+    compilation_cache.reset_cache()  # the decision is taken once a process
+    try:
+        yield
+    finally:
+        jax.config.update(flag, before)
+        compilation_cache.reset_cache()
+
+
+class FreshProgram:
+    """A jitted ``fn`` compiled ahead of its first call under
+    ``fresh_compile``, once per argument signature, and called through
+    the compiled object from then on: nothing that clears or refills the
+    function's own caches (``compiled_text_of``) can put an executable
+    from the persistent cache behind it. ``fn`` stays reachable for the
+    program table."""
+
+    def __init__(self, fn):
+        self.fn = fn
+        self._compiled: dict = {}
+
+    def __call__(self, *args):
+        import jax
+
+        key = tuple((getattr(a, "shape", ()), getattr(a, "dtype", type(a)))
+                    for a in jax.tree_util.tree_leaves(args))
+        compiled = self._compiled.get(key)
+        if compiled is None:
+            with fresh_compile():
+                compiled = self.fn.lower(*args).compile()
+            self._compiled[key] = compiled
+        return compiled(*args)
 
 
 class StepTimer:

@@ -105,7 +105,12 @@ def make_fused_chunk(
     """jit the fused chunk. PER: ``fn(state, trees, storage, size) ->
     (state, trees, metrics)``; uniform: ``fn(state, storage, size) ->
     (state, metrics)``. ``state`` and ``trees`` are donated (updated in
-    place in HBM); the ring is read-only and never copied."""
+    place in HBM). The ring is read-only and taken in the formats the
+    store keeps it in (``replay/device_ring.py``, "Layout"): the gather
+    reads B rows from the parameter itself, and only those rows are ever
+    cast (``core/precision.to_compute`` at the models' inputs). Until PR
+    31 "never copied" was false on the chip: a rows-minor ring was
+    transposed and narrowed whole, once a dispatch."""
     if prioritized:
         def fn(state, trees, storage, size):
             return fused_chunk_step(

@@ -119,6 +119,15 @@ class FusedLoop:
         buffer = self._buffer
         metrics = None
         done = 0
+        home = getattr(buffer, "home", None)
+        if home is not None:
+            # the one-device buffer's arrays are committed to its device
+            # (replay/device_ring.py), so what a chunk returns is too: a
+            # state that went in uncommitted would make the second chunk
+            # another program. No copy: the same buffers, committed.
+            import jax
+
+            state = jax.device_put(state, home)
         if self.ingest is not None:
             # cycle boundary: every staged row lands before training
             with obs_trace.span("learner.flush") as flush:

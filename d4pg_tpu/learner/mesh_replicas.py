@@ -188,6 +188,9 @@ class MeshReplicaGroup:
         payload = (buffer.storage, buffer.trees) if self._prioritized \
             else (buffer.storage,)
         out_sh = partition.replica_stack_shardings(self.mesh, payload)
+        # the buffer's arrays are committed to its one device
+        # (replay/device_ring.py): hand them to the mesh first
+        payload = jax.device_put(payload, partition.replicated(self.mesh))
         # one-shot per load (startup / test fill): jit-with-out_shardings
         # is what materializes the broadcast on every replica's device
         placed = jax.jit(  # jaxlint: disable=recompile-hazard

@@ -85,8 +85,9 @@ _AXIS_VALUES = set(_DECLARED_AXES.values())
 # resolution targets of family 20.  Mirrored (subset of
 # partition.__all__; pinned by tests/test_meshgraph.py).
 _FACTORIES = {
-    "spec", "sharding", "replicated", "batch_sharding", "stacked_sharding",
-    "replica_sharding", "replicated_spec", "batch_spec", "data_spec",
+    "spec", "sharding", "replicated", "one_device", "batch_sharding",
+    "stacked_sharding", "replica_sharding", "replicated_spec", "batch_spec",
+    "data_spec",
     "stacked_spec", "replica_spec", "shardings_for", "state_specs",
     "state_shardings", "replica_stack_shardings", "match_partition_rules",
 }

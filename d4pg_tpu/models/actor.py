@@ -19,6 +19,7 @@ from typing import Sequence
 import flax.linen as nn
 import jax.numpy as jnp
 
+from d4pg_tpu.core.precision import to_compute
 from d4pg_tpu.models.init import fanin_init, scaled_normal
 
 
@@ -30,7 +31,7 @@ class Actor(nn.Module):
 
     @nn.compact
     def __call__(self, obs: jnp.ndarray) -> jnp.ndarray:
-        x = obs.astype(self.dtype)
+        x = to_compute(obs, self.dtype)
         for i, width in enumerate(self.hidden):
             x = nn.Dense(
                 width, kernel_init=fanin_init(), dtype=self.dtype, name=f"fc{i + 1}"

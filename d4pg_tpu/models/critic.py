@@ -24,6 +24,7 @@ from typing import NamedTuple, Sequence
 import flax.linen as nn
 import jax.numpy as jnp
 
+from d4pg_tpu.core.precision import to_compute
 from d4pg_tpu.models.init import fanin_init, scaled_normal
 
 
@@ -35,11 +36,11 @@ class _CriticTorso(nn.Module):
 
     @nn.compact
     def __call__(self, obs: jnp.ndarray, action: jnp.ndarray) -> jnp.ndarray:
-        x = obs.astype(self.dtype)
+        x = to_compute(obs, self.dtype)
         x = nn.relu(
             nn.Dense(self.hidden[0], kernel_init=fanin_init(), dtype=self.dtype, name="fc1")(x)
         )
-        x = jnp.concatenate([x, action.astype(self.dtype)], axis=-1)
+        x = jnp.concatenate([x, to_compute(action, self.dtype)], axis=-1)
         for i, width in enumerate(self.hidden[1:]):
             x = nn.relu(
                 nn.Dense(width, kernel_init=fanin_init(), dtype=self.dtype, name=f"fc{i + 2}")(x)

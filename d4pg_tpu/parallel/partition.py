@@ -40,12 +40,14 @@ import numpy as np
 
 from d4pg_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, REPLICA_AXIS
 
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as PS,
+                          SingleDeviceSharding)
 
 __all__ = [
     "PS", "D4PG_RULES", "named_tree_map", "tree_names",
     "match_partition_rules", "format_rules", "spec", "sharding",
-    "replicated", "batch_sharding", "stacked_sharding", "replica_sharding",
+    "replicated", "one_device", "batch_sharding", "stacked_sharding",
+    "replica_sharding",
     "batch_spec", "replicated_spec", "stacked_spec", "replica_spec",
     "per_tree_spec", "dealt_block_spec", "per_tree_sharding",
     "dealt_block_sharding",
@@ -137,6 +139,17 @@ def dealt_block_spec() -> PS:
 
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, replicated_spec())
+
+
+def one_device(device=None) -> SingleDeviceSharding:
+    """The whole array on one device (the process's first when none is
+    named): what the one-device replay ring, and the trees and learner
+    state that travel with it, are committed to. A pinned layout is
+    honoured only on a committed array (``replay/device_ring.py``)."""
+    import jax
+
+    return SingleDeviceSharding(
+        device if device is not None else jax.local_devices()[0])
 
 
 def batch_sharding(mesh: Mesh) -> NamedSharding:

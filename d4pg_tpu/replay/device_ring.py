@@ -26,6 +26,19 @@ Two write paths:
     mirrored into the ring head — wraparound as a second masked slice
     instead of a modular scatter. Partial blocks mask by ``n``; the shape
     is static, so steady-state ingest never recompiles.
+
+Layout (PR 31): a wide rank-2 float field is stored rows-major (a row's
+values along the lanes), whatever the compiler would choose. The TPU's
+compact layout for ``f32[2101248, 376]`` puts the ROWS on the lanes
+(nothing to pad; rows-major pads 376 to 384), and a row gather from that
+is strided: the fused chunk answered with a transposing copy of the whole
+ring once a dispatch (14.4 of its 26.7 ms; PERF.md). ``ring_layout`` is
+the rule, from the static shape alone; every program that returns the
+ring returns it in the store's formats, so donation still aliases. Those
+programs (the writers, the re-layout, the fused commit) are compiled
+outside the persistent compile cache where a field is pinned
+(``io/profiling.fresh_compile`` says why): it costs each start their
+compiles, a fraction of a second together.
 """
 
 from __future__ import annotations
@@ -34,8 +47,59 @@ from functools import partial
 
 import numpy as np
 
+from d4pg_tpu.io.profiling import FreshProgram, fresh_compile
+from d4pg_tpu.obs import trace as obs_trace
 from d4pg_tpu.replay.segment_tree import next_pow2 as _bucket
 from d4pg_tpu.replay.uniform import TransitionBatch
+
+_LANES = 128
+
+
+def ring_layout(shape: tuple, dtype) -> tuple | None:
+    """``major_to_minor`` a ring field of this static shape is pinned to,
+    or ``None`` for the compiler's own layout. Rows-major for a rank-2
+    float field whose width pads at most an eighth on the 128 lanes (376
+    -> 384: yes; a 17-wide action would pad 7.5x: no). Scalars a row keep
+    the compiler's layout, and so do rank-4 ``uint8`` frames: rows-major
+    would put 9 channels on the lanes (14x), and the layout their gather
+    wants pads 1.74x, a storage decision of its own (PERF.md section 7)."""
+    if len(shape) != 2 or not np.issubdtype(np.dtype(dtype), np.floating):
+        return None
+    w = int(shape[1])
+    return (0, 1) if -(-w // _LANES) * _LANES <= 1.125 * w else None
+
+
+def ring_specs(rows: int, obs_shape: tuple, act_dim: int,
+               obs_dtype) -> TransitionBatch:
+    """``(shape, dtype)`` of each ring field."""
+    f32 = np.dtype(np.float32)
+    return TransitionBatch(
+        obs=((rows, *obs_shape), np.dtype(obs_dtype)),
+        action=((rows, act_dim), f32),
+        reward=((rows,), f32),
+        next_obs=((rows, *obs_shape), np.dtype(obs_dtype)),
+        done=((rows,), f32),
+        discount=((rows,), f32),
+    )
+
+
+def ring_formats(specs: TransitionBatch, home) -> TransitionBatch:
+    """The ``Format`` each field is pinned to on the one-device sharding
+    ``home`` (a layout is honoured only on a committed array, so a pinned
+    field names its device), ``None`` where ``ring_layout`` leaves the
+    compiler's layout."""
+    from jax.experimental.layout import Format, Layout
+
+    return TransitionBatch(*[
+        None if mtm is None else Format(Layout(major_to_minor=mtm), home)
+        for mtm in (ring_layout(*spec) for spec in specs)])
+
+
+def ring_program(fn, formats: TransitionBatch):
+    """Jitted ``fn``, which returns the ring in ``formats``, as it is to be
+    called: through ``FreshProgram`` where a field is pinned, as it is
+    where none is."""
+    return FreshProgram(fn) if any(f is not None for f in formats) else fn
 
 
 def block_write(storage: TransitionBatch, frame: TransitionBatch,
@@ -78,6 +142,18 @@ class DeviceStore:
     additionally compiles the two-slice block writer (and allocates that
     many shadow rows — consumers must index only ``[0, capacity)``, which
     every sampler already does).
+
+    Each field has one format (``formats``: a pinned
+    ``jax.experimental.layout.Format`` where ``ring_layout`` names one,
+    else ``None``). A new store's ring is as the allocator lays it out, so
+    that a program that knows nothing of the formats can fill it in place
+    (donated in, the same layout out: the benchmark's seeded fill, which
+    has no room for a second ring). It is in its formats from the first
+    write of the store's own (``pinned``) or the first ``swap_arrays``,
+    and for life after: returned in them by ``_insert``, ``_write_block``
+    and the fused commit (``formats`` are their ``out_shardings``). Where
+    the pinned layout is the device's default anyway (the CPU) nothing is
+    ever re-laid.
     """
 
     def __init__(
@@ -92,6 +168,8 @@ class DeviceStore:
         import jax
         import jax.numpy as jnp
 
+        from d4pg_tpu.parallel import partition
+
         self.capacity = int(capacity)
         self.block_rows = int(block_rows)
         if self.block_rows > self.capacity:
@@ -102,20 +180,19 @@ class DeviceStore:
         # guaranteed-out-of-bounds scatter-drop index either way
         rows = self.capacity + self.block_rows
         self._rows = rows
-        storage = TransitionBatch(
-            obs=jnp.zeros((rows, *obs_shape), obs_dtype),
-            action=jnp.zeros((rows, act_dim), jnp.float32),
-            reward=jnp.zeros((rows,), jnp.float32),
-            next_obs=jnp.zeros((rows, *obs_shape), obs_dtype),
-            done=jnp.zeros((rows,), jnp.float32),
-            discount=jnp.zeros((rows,), jnp.float32),
-        )
-        self._storage = (
-            jax.device_put(storage, device) if device is not None else
-            jax.device_put(storage)
-        )
+        specs = ring_specs(rows, tuple(obs_shape), act_dim, obs_dtype)
+        # Every field is committed to the store's device: a layout is
+        # honoured only on a committed array, and what a program returns
+        # is committed once an argument is, so a ring committed in part
+        # would change a program's argument signature (one more compile)
+        # at its second call.
+        self.home = partition.one_device(device)
+        self.formats = ring_formats(specs, self.home)
+        self._storage = jax.device_put(TransitionBatch(*[
+            jnp.zeros(shape, dtype) for shape, dtype in specs]), self.home)
+        self._pinned = False  # in its formats: see ``pinned``
 
-        @partial(jax.jit, donate_argnums=(0,))
+        @partial(jax.jit, donate_argnums=(0,), out_shardings=self.formats)
         def _insert(storage, idx, batch):
             return TransitionBatch(*[
                 arr.at[idx].set(val.astype(arr.dtype), mode="drop")
@@ -126,7 +203,7 @@ class DeviceStore:
         def _gather(storage, idx):
             return TransitionBatch(*[arr[idx] for arr in storage])
 
-        self._insert = _insert
+        self._insert = ring_program(_insert, self.formats)
         self._gather = _gather
         self._write_block = (
             self._make_write_block() if self.block_rows else None)
@@ -134,16 +211,24 @@ class DeviceStore:
     def _make_write_block(self):
         import jax
 
-        return jax.jit(
+        return ring_program(jax.jit(
             partial(block_write, capacity=self.capacity,
                     block_rows=self.block_rows),
-            donate_argnums=(0,))
+            donate_argnums=(0,), out_shardings=self.formats), self.formats)
 
     @property
     def arrays(self) -> TransitionBatch:
         """The raw [capacity (+ shadow), ...] device arrays (read-only
         input to the fused learner path, ``learner/fused.py``; samplers
         index only ``[0, capacity)``)."""
+        return self._storage
+
+    def pinned(self) -> TransitionBatch:
+        """The ring in the store's formats, for a program that writes it
+        (re-laid now if it was never written: twice ~10 ms at 2 M
+        Humanoid rows)."""
+        if not self._pinned:
+            self.swap_arrays(self._storage)
         return self._storage
 
     def write(self, idx: np.ndarray, batch: TransitionBatch) -> None:
@@ -161,7 +246,7 @@ class DeviceStore:
                 for v in batch
             ])
         self._storage = self._insert(
-            self._storage, np.asarray(idx, np.int32), batch)
+            self.pinned(), np.asarray(idx, np.int32), batch)
 
     def write_block(self, start: int, frame: TransitionBatch, n: int) -> None:
         """Land ``n`` valid rows of a fixed-shape [block_rows] ``frame``
@@ -171,13 +256,40 @@ class DeviceStore:
         if self._write_block is None:
             raise RuntimeError("DeviceStore built without block_rows")
         self._storage = self._write_block(
-            self._storage, frame, np.int32(start), np.int32(n))
+            self.pinned(), frame, np.int32(start), np.int32(n))
 
     def swap_arrays(self, storage: TransitionBatch) -> None:
-        """Adopt updated storage handles (the fused commit in
+        """Adopt updated storage handles. The fused commit in
         ``replay/fused_buffer.py`` runs the block write inside its own
-        dispatch, fused with the tree insert, and hands the result back)."""
-        self._storage = storage
+        dispatch, fused with the tree insert, and hands the result back in
+        the store's formats: nothing happens to it here. This is also the
+        one door a foreign layout can come through (a ring filled by a
+        program that does not know the formats, a restored checkpoint): a
+        field that is not in the store's format is re-laid, one field at a
+        time and with its source donated (a second whole ring does not fit
+        beside the first), under a ``ring.relayout`` span."""
+        import jax
+
+        fields = list(storage)
+        for i, (name, fmt) in enumerate(zip(storage._fields, self.formats)):
+            arr = fields[i]
+            if fmt is not None and (arr.format.layout.major_to_minor
+                                    != fmt.layout.major_to_minor):
+                with obs_trace.span("ring.relayout", field=name,
+                                    bytes=int(arr.nbytes)), fresh_compile():
+                    # (a field at a time, never a row: six at most)
+                    fields[i] = jax.block_until_ready(jax.device_put(  # jaxlint: disable=device-put-in-loop
+                        arr, fmt, donate=True))
+                    # a donation that cannot alias (another layout) is
+                    # dropped and the source lives on with its holder:
+                    # release it, or the next field finds no room
+                    if not arr.is_deleted():
+                        arr.delete()
+            elif not arr.committed:
+                # the same buffer, committed like the rest (once a field)
+                fields[i] = jax.device_put(arr, fmt or self.home)  # jaxlint: disable=device-put-in-loop
+        self._storage = TransitionBatch(*fields)
+        self._pinned = True
 
     def read(self, idx: np.ndarray) -> TransitionBatch:
         """Gather rows on device; idx [B] or [K, B] (host or device ints)."""

@@ -50,7 +50,8 @@ from d4pg_tpu.obs.flight import record_event
 from d4pg_tpu.obs import trace as obs_trace
 from d4pg_tpu.obs.registry import REGISTRY
 from d4pg_tpu.replay import device_per as dper
-from d4pg_tpu.replay.device_ring import DeviceStore, block_write
+from d4pg_tpu.replay.device_ring import (DeviceStore, block_write,
+                                         ring_program)
 from d4pg_tpu.replay.uniform import TransitionBatch
 
 
@@ -161,6 +162,77 @@ class HostStagingRing:
         return out
 
 
+def make_commit(capacity: int, block: int, alpha: float, ring, *,
+                prioritized: bool = True, gen_tracked: bool = False):
+    """The jitted block commit behind ``FusedDeviceReplay.commit_staged``:
+    the two-slice ring write fused with the PER tree insert, ring and
+    trees donated. ``ring`` is the store's ``formats``: the ring goes out
+    in the formats it came in (device_ring.py "Layout"), so the donation
+    aliases and no commit re-lays it."""
+    import jax
+    import jax.numpy as jnp
+
+    write = partial(block_write, capacity=capacity, block_rows=block)
+
+    # Every variant names its two phases (metadata only); a trace
+    # reader splits the commit program's device time by them.
+    if not prioritized:
+        @partial(jax.jit, donate_argnums=(0,), out_shardings=ring)
+        def commit_uniform(storage, frame, start, n):
+            with jax.named_scope("ingest.ring_write"):
+                return write(storage, frame, start, n)
+
+        return commit_uniform
+
+    if gen_tracked:
+        from d4pg_tpu.replay.segment_tree import next_pow2
+
+        # pads park at the TREE capacity (>= ring capacity): dropped
+        # by set_leaves' idx < capacity guard AND out of bounds for
+        # the [capacity] generation array, so one pad value silences
+        # both scatters. (The non-tracked path's repeat-the-first-
+        # slot pad would bump that slot's generation spuriously.)
+        padcap = next_pow2(capacity)
+
+        @partial(jax.jit, donate_argnums=(0, 1, 2),
+                 out_shardings=(ring, None, None))
+        def commit_tracked(storage, trees, gen, frame, start, n,
+                           p_ins, max_pri):
+            with jax.named_scope("ingest.ring_write"):
+                storage = write(storage, frame, start, n)
+            with jax.named_scope("ingest.tree_insert"):
+                row = jax.lax.iota(jnp.int32, block)
+                idx = jnp.where(row < n, (start + row) % capacity,
+                                padcap)
+                # p_ins is max_priority ** alpha computed on the HOST
+                # (float64 pow, cast f32) — see the gen_tracked note
+                # in FusedDeviceReplay.__init__; the trees only ever
+                # see host-rounded values
+                trees = dper.set_leaves(
+                    trees, idx, jnp.full((block,), p_ins, jnp.float32))
+                trees = trees._replace(max_priority=max_pri)
+                gen = gen.at[idx].add(1, mode="drop")
+            return storage, trees, gen
+
+        return commit_tracked
+
+    @partial(jax.jit, donate_argnums=(0, 1), out_shardings=(ring, None))
+    def commit(storage, trees, frame, start, n):
+        with jax.named_scope("ingest.ring_write"):
+            storage = write(storage, frame, start, n)
+        with jax.named_scope("ingest.tree_insert"):
+            row = jax.lax.iota(jnp.int32, block)
+            # pad rows repeat the first live slot: duplicate writes of
+            # the same value are harmless to the trees (see
+            # device_per.insert)
+            idx = jnp.where(row < n, (start + row) % capacity,
+                            start % capacity)
+            trees = dper.insert(trees, idx, alpha)
+        return storage, trees
+
+    return commit
+
+
 class FusedDeviceReplay:
     """Fixed-capacity device ring + (optionally) device PER trees."""
 
@@ -188,9 +260,20 @@ class FusedDeviceReplay:
         self._store = DeviceStore(self.capacity, obs_shape, act_dim,
                                   obs_dtype, device=device,
                                   block_rows=self.block_rows)
+        # The CPU backend's device_put hands back arrays that ALIAS aligned
+        # host memory (``may_alias=False`` does not stop it), and the host
+        # staging ring rewrites a frame's rows before an asynchronous commit
+        # has read them: a torn block, one test run in six. There (tests,
+        # development) a staged frame is copied first; a TPU always copies.
+        self._host_aliased = next(iter(
+            self._store.home.device_set)).platform == "cpu"
         self.prioritized = bool(prioritized)
         self.alpha = float(alpha)
-        self.trees = dper.init(self.capacity) if prioritized else None
+        # what the buffer keeps on the device is committed to the store's
+        # device, like the ring (device_ring.py): trees that came back
+        # committed from their first commit would compile it a second time
+        self.trees = self._own(dper.init(self.capacity)) if prioritized \
+            else None
         self.size = 0
         self.head = 0
         # Generation-tracked mode (the device-dealt sample plane,
@@ -218,7 +301,7 @@ class FusedDeviceReplay:
 
             self.max_priority = 1.0
             self.generation = np.zeros(self.capacity, np.int64)
-            self.gen = jnp.zeros(self.capacity, jnp.int32)
+            self.gen = self._own(jnp.zeros(self.capacity, jnp.int32))
             self._next_slot = 0
         obs_dtype = np.dtype(obs_dtype)
         # staging covers ~one ring (small buffers) capped at
@@ -245,72 +328,28 @@ class FusedDeviceReplay:
         # block by, from ``fused.stage_block`` to ``fused.commit_staged``.
         self._inflight: tuple[TransitionBatch, int, int, float] | None = None
         self.blocks_staged = 0
-        self._commit = self._make_commit()
+        self._commit_fn = make_commit(
+            self.capacity, self.block_rows, self.alpha, self._store.formats,
+            prioritized=self.prioritized, gen_tracked=self.gen_tracked)
+        self._commit = ring_program(self._commit_fn, self._store.formats)
         self._commit_tabled = False
 
-    def _make_commit(self):
+    def _own(self, tree):
         import jax
-        import jax.numpy as jnp
 
-        capacity, block, alpha = self.capacity, self.block_rows, self.alpha
-        write = partial(block_write, capacity=capacity, block_rows=block)
+        return jax.device_put(tree, self._store.home)
 
-        # Every variant names its two phases (metadata only); a trace
-        # reader splits the commit program's device time by them.
-        if not self.prioritized:
-            @partial(jax.jit, donate_argnums=(0,))
-            def commit_uniform(storage, frame, start, n):
-                with jax.named_scope("ingest.ring_write"):
-                    return write(storage, frame, start, n)
+    @property
+    def home(self):
+        """The one-device sharding every device array of the buffer is
+        committed to."""
+        return self._store.home
 
-            return commit_uniform
-
-        if self.gen_tracked:
-            from d4pg_tpu.replay.segment_tree import next_pow2
-
-            # pads park at the TREE capacity (>= ring capacity): dropped
-            # by set_leaves' idx < capacity guard AND out of bounds for
-            # the [capacity] generation array, so one pad value silences
-            # both scatters. (The non-tracked path's repeat-the-first-
-            # slot pad would bump that slot's generation spuriously.)
-            padcap = next_pow2(capacity)
-
-            @partial(jax.jit, donate_argnums=(0, 1, 2))
-            def commit_tracked(storage, trees, gen, frame, start, n,
-                               p_ins, max_pri):
-                with jax.named_scope("ingest.ring_write"):
-                    storage = write(storage, frame, start, n)
-                with jax.named_scope("ingest.tree_insert"):
-                    row = jax.lax.iota(jnp.int32, block)
-                    idx = jnp.where(row < n, (start + row) % capacity,
-                                    padcap)
-                    # p_ins is max_priority ** alpha computed on the HOST
-                    # (float64 pow, cast f32) — see the gen_tracked note
-                    # in __init__; the trees only ever see host-rounded
-                    # values
-                    trees = dper.set_leaves(
-                        trees, idx, jnp.full((block,), p_ins, jnp.float32))
-                    trees = trees._replace(max_priority=max_pri)
-                    gen = gen.at[idx].add(1, mode="drop")
-                return storage, trees, gen
-
-            return commit_tracked
-
-        @partial(jax.jit, donate_argnums=(0, 1))
-        def commit(storage, trees, frame, start, n):
-            with jax.named_scope("ingest.ring_write"):
-                storage = write(storage, frame, start, n)
-            with jax.named_scope("ingest.tree_insert"):
-                row = jax.lax.iota(jnp.int32, block)
-                # pad rows repeat the first live slot: duplicate writes of
-                # the same value are harmless to the trees (see
-                # device_per.insert)
-                idx = jnp.where(row < n, (start + row) % capacity,
-                                start % capacity)
-                trees = dper.insert(trees, idx, alpha)
-            return storage, trees
-
-        return commit
+    @property
+    def formats(self) -> TransitionBatch:
+        """The ``Format`` each ring field is pinned to (``None``: the
+        compiler's layout); ``device_ring.py`` "Layout"."""
+        return self._store.formats
 
     # -- ingest side (any thread, under the service's buffer lock) ---------
     def add(self, batch: TransitionBatch):
@@ -400,6 +439,8 @@ class FusedDeviceReplay:
         with obs_trace.span("fused.stage_block", block=block, rows=n,
                             wait_ms=wait_ms):
             with obs_trace.span("fused.h2d"):
+                if self._host_aliased:
+                    views = TransitionBatch(*[np.array(v) for v in views])
                 frame = (jax.device_put(views, self._device)
                          if self._device is not None
                          else jax.device_put(views))
@@ -424,17 +465,17 @@ class FusedDeviceReplay:
             # host-f64 pow, f32 cast: the trees only see host-rounded
             # values (bitwise twin contract — see __init__)
             p_ins = np.float32(self.max_priority ** self.alpha)
-            args = (self._store.arrays, self.trees, self.gen, frame, start,
+            args = (self._store.pinned(), self.trees, self.gen, frame, start,
                     np.int32(n), p_ins, np.float32(self.max_priority))
         elif self.trees is not None:
-            args = (self._store.arrays, self.trees, frame, start,
+            args = (self._store.pinned(), self.trees, frame, start,
                     np.int32(n))
         else:
-            args = (self._store.arrays, frame, start, np.int32(n))
+            args = (self._store.pinned(), frame, start, np.int32(n))
         if not self._commit_tabled:  # first dispatch: enter the table
             from d4pg_tpu.io.profiling import abstract_args
 
-            obs_trace.register_program("ingest.commit", self._commit,
+            obs_trace.register_program("ingest.commit", self._commit_fn,
                              abstract_args(args))
             self._commit_tabled = True
         with obs_trace.span("fused.commit_staged", block=block, rows=n,
@@ -561,8 +602,8 @@ class FusedDeviceReplay:
                 trees = dper.set_leaves_jitted(
                     trees, jnp.arange(size),
                     jnp.asarray(d["leaf_priorities"], jnp.float32))
-            self.trees = trees._replace(
-                max_priority=jnp.float32(d.get("max_priority", 1.0)))
+            self.trees = self._own(trees._replace(
+                max_priority=jnp.float32(d.get("max_priority", 1.0))))
         if self.gen_tracked:
             # restore opens a fresh generation epoch: live rows at 1,
             # everything else 0, host mirror and device copy in lockstep
@@ -572,4 +613,4 @@ class FusedDeviceReplay:
             self._next_slot = self.head
             self.generation = np.zeros(self.capacity, np.int64)
             self.generation[:self.size] = 1
-            self.gen = jnp.asarray(self.generation, jnp.int32)
+            self.gen = self._own(jnp.asarray(self.generation, jnp.int32))

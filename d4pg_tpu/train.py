@@ -279,6 +279,17 @@ def _platforms(x: jax.Array) -> str:
     return ",".join(sorted({d.platform for d in x.devices()}))
 
 
+def _layouts(formats) -> str:
+    """The layout each ring field is pinned to, major to minor:
+    ``obs:01,action:-,...`` (``01``: a row's values along the lanes, what
+    ``replay/device_ring.ring_layout`` pins a wide float field to; ``-``:
+    left to the compiler)."""
+    return ",".join(
+        name + ":" + ("-" if fmt is None else
+                      "".join(map(str, fmt.layout.major_to_minor)))
+        for name, fmt in zip(formats._fields, formats))
+
+
 def train(cfg: ExperimentConfig) -> dict:
     cfg = cfg.resolve()
     # Multi-host SPMD (parallel/multihost.py): every host runs this same
@@ -461,6 +472,8 @@ def train(cfg: ExperimentConfig) -> dict:
     }
     if fused:
         plan["ring_on"] = _platforms(buffer.storage.obs)
+        if hasattr(buffer, "formats"):  # the one-device ring
+            plan["ring_layout"] = _layouts(buffer.formats)
     if isinstance(buffer, PrioritizedReplayBuffer):
         # the only buffer with a host tree (the fused path has none):
         # name the backend that loaded — the C++ library is built on

@@ -153,3 +153,69 @@ def test_the_dense_tree_repair_updates_the_trees_in_place_on_the_chip(
     whole_tree = re.compile(r"= f32\[%d\]\S* copy\(" % (2 * cap))
     assert not [ln for ln in lines if whole_tree.search(ln)][:2]
     assert sum(" reduce-window(" in ln for ln in lines) == 21
+
+
+@pytest.mark.parametrize("program", ["jit_fn", "jit_commit"])
+def test_the_mlp_cells_programs_take_the_ring_as_it_is_stored(one_chip,
+                                                              program):
+    """The ``humanoid-mlp`` cells' chunk and block commit at the real shapes,
+    ring fields in the formats ``DeviceStore`` gives them, compiled for the
+    v5e (PR 31). The chunk gathers from the parameter itself: the compiler's
+    own layout for ``f32[2101248, 376]`` has the rows on the lanes, and the
+    parent's chunk transposed and narrowed both wide fields whole, once a
+    dispatch (two ``copy`` to ``bf16[2101248,376]{1,0}``, 3.33 GB of
+    temporaries, 14.4 of its 26.7 ms). The commit writes its block into the
+    donated ring in place and returns it in the same formats."""
+    import re
+
+    from d4pg_tpu.replay.device_ring import ring_formats, ring_specs
+    from d4pg_tpu.replay.fused_buffer import make_commit
+
+    with open(os.path.join(REPO, "benchmark/configs/humanoid-mlp.json")) as f:
+        cfg = json.load(f)
+    model = dict(cfg["model"], hidden=tuple(cfg["model"]["hidden"]))
+    config = D4PGConfig(**model)
+    cap, block = cfg["replay"]["capacity"], cfg["replay"]["block_rows"]
+    rows = cap + block
+    specs = ring_specs(rows, (config.obs_dim,), config.act_dim, jnp.float32)
+    formats = ring_formats(specs, one_chip)
+    assert [f is not None for f in formats] == [True, False, False,
+                                                True, False, False]
+    storage = TransitionBatch(*[
+        jax.ShapeDtypeStruct(shape, dtype, sharding=fmt or one_chip)
+        for (shape, dtype), fmt in zip(specs, formats)])
+    trees = on(one_chip, jax.eval_shape(lambda: dper.init(cap)))
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    if program == "jit_fn":
+        state = on(one_chip, jax.eval_shape(
+            lambda: init_state(config, jax.random.key(0))))
+        fn = make_fused_chunk(config, k=cfg["learner"]["k"],
+                              batch_size=cfg["learner"]["batch_size"])
+        compiled = fn.lower(state, trees, storage, i32).compile()
+        in_place = ()
+    else:
+        frame = TransitionBatch(*[
+            jax.ShapeDtypeStruct((block,) + shape[1:], dtype,
+                                 sharding=one_chip) for shape, dtype in specs])
+        fn = make_commit(cap, block, cfg["learner"]["per_alpha"], formats)
+        compiled = fn.lower(storage, trees, frame, i32, i32).compile()
+        # the block lands by dynamic-update-slice (alone or fused) on the
+        # donated ring; anything else of the ring's size is a copy of it
+        in_place = ("dynamic-update-slice", "fusion")
+    text = compiled.as_text()
+    assert text.startswith("HloModule " + program)
+    # (c) the program receives the wide fields rows-major
+    for field in ("obs", "next_obs"):
+        assert re.search(r"storage_%s\S* = f32\[%d,376\]\{1,0:T\(8,128\)\} "
+                         r"parameter\(" % (field, rows), text), field
+    # (a) nothing of the ring's row count and rank >= 2 is computed
+    whole = re.compile(r"^\s*(?:ROOT )?%%?\S+ = \(?[a-z0-9]+\[%d,\d+[\],]\S* "
+                       r"([\w\-]+)\(" % rows)
+    made = {m.group(1) for m in map(whole.match, text.splitlines()) if m}
+    assert made <= {"parameter", "get-tuple-element", "bitcast", "tuple",
+                    *in_place}, made
+    m = compiled.memory_analysis()
+    # (b) no second ring among the temporaries (3.33 GB at the parent)
+    assert m.temp_size_in_bytes < 0.5e9, m.temp_size_in_bytes
+    if in_place:  # the whole ring (6.7 GB) and both trees are aliased
+        assert m.alias_size_in_bytes > 6.7e9, m.alias_size_in_bytes
