@@ -1,0 +1,9 @@
+"""``experts_us_per_step`` in a sparse-attention torso cell: device time a
+gradient step spends under ``torso.experts`` (the grouped products of the
+held experts; all passes)."""
+
+from benchmark import sparse_trace
+
+
+def read(ctx):
+    return sparse_trace.scope_us(ctx, "torso.experts")

@@ -228,13 +228,21 @@ def _torso_update_step(
       1. the TARGET torso on ``next_obs``; target actor and target critic
          heads read its latent (the Bellman target);
       2. the torso on ``obs`` under the critic loss, differentiated: the
-         critic loss alone trains it (as the shared pixel encoder);
+         critic loss alone trains it (as the shared pixel encoder), but for
+         a sparse-attention layer's indexer: nothing of the critic loss
+         reaches it (the selection is not differentiable), so the torso's
+         ``index_loss`` is added to the critic loss with no coefficient;
+         the two losses' parameter sets are disjoint and both step at
+         ``lr_critic``;
       3. the torso just stepped on ``obs`` again, through a stop-gradient:
          the actor head and the stepped critic head read it for the actor
          loss, which trains the actor's head only.
 
     Metrics gain ``route_counts`` ``[layers, num_experts]`` int32: how many
-    of pass 2's assignments the router gave each expert."""
+    of pass 2's assignments the router gave each expert; with
+    sparse-attention layers also ``select_counts`` ``[sparse layers, tokens /
+    kv_chunk_size]`` int32 (pass 2's selections by block of keys) and
+    ``index_loss``. ``critic_loss`` stays the TD loss alone."""
     key, _sub = jax.random.split(state.key)
     actor, critic = config.build_actor(), config.build_critic()
 
@@ -248,13 +256,14 @@ def _torso_update_step(
                                    batch.reward, batch.discount))
 
         def critic_loss_fn(p):
-            z, counts = critic.latent(p, batch.obs)
+            z, aux = critic.latent(p, batch.obs, train=True)
             loss, td = categorical_td_loss(
                 proj, critic.of_latent(p, z, batch.action),
                 weights=is_weights)
-            return loss, (td, counts)
+            total = loss + aux["index_loss"] if "index_loss" in aux else loss
+            return total, (loss, td, aux)
 
-        (critic_loss, (td_error, counts)), critic_grads = jax.value_and_grad(
+        (_, (critic_loss, td_error, aux)), critic_grads = jax.value_and_grad(
             critic_loss_fn, has_aux=True)(state.critic_params)
     with jax.named_scope("update.optim"):
         critic_updates, critic_opt_state = config.optimizer(
@@ -298,7 +307,7 @@ def _torso_update_step(
         "actor_loss": actor_loss,
         "q_mean": -actor_loss,
         "td_error": td_error,
-        "route_counts": counts,
+        **aux,  # route_counts; select_counts and index_loss where sparse
     }
     return new_state, metrics
 
@@ -376,7 +385,7 @@ def _policy(config: D4PGConfig, params: Any, obs: Array) -> Array:
     """The greedy action from ``policy_params``."""
     if config.torso is None:
         return config.build_actor().apply(params, obs)
-    latent, _counts = config.build_critic().torso.apply(
+    latent, _aux = config.build_critic().torso.apply(
         params["params"]["torso"], obs)
     return config.build_actor().apply({"params": params["params"]["actor"]},
                                       latent)

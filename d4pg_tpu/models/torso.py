@@ -8,15 +8,32 @@ transformer layers and pools them into one latent per row; D4PG's own heads
 state vector. ``D4PGConfig.torso`` names one (``TORSOS``) with its sizes
 (``TorsoSpec``, made from a configuration file's ``model.torso`` block).
 
-``mellum2`` is the Mellum2-12B-A2.5B layer: RMSNorm, grouped-query
-attention with rotary embeddings (default on sliding-window layers, YaRN on
-full ones), a float32 router over all ``num_experts`` experts with the
-largest ``num_experts_per_tok`` renormalised, SwiGLU experts. The layer is
-told which experts it holds (``experts_held``, one chip's share under
-expert parallelism, ``parallel/partition.expert_share``): it routes over
-all of them and adds its own experts' part of the result; on one chip that
-is the whole layer without the exchange. No capacity, no dropped token:
-every assignment to a held expert is computed whatever the routing.
+Two models share the one layer path, told apart by the data in the spec
+(``layer_types``, ``qk_norm``, ``sa_config``), not by code of their own:
+
+- ``mellum2``, the Mellum2-12B-A2.5B layer: RMSNorm, grouped-query
+  attention with rotary embeddings (default on ``sliding_attention``
+  layers, YaRN on ``full_attention`` ones) under a static mask
+  (``ops/attention.py``).
+- ``keye2``, the Keye-VL-2.0-30B-A3B language-model layer
+  (``sparse_attention``): the same grouped-query attention with RMSNorm on
+  every head of ``q`` and ``k``, over keys an indexer chooses at run time
+  (``ops/sparse_attention.py``): ``indexer_num_heads`` small heads score
+  every earlier position on the layer's normed input through a
+  stop-gradient, the ``topk`` best are read. The selection carries no
+  gradient; the indexer's own parameters (``index_q``, ``index_k``,
+  ``index_k_norm``, ``index_w``) are trained by an alignment loss that
+  ``apply(..., train=True)`` hands up beside the counters (``index_loss``,
+  ``select_counts``) and that reaches nothing else.
+
+Both end in the same expert layer: a float32 router over all
+``num_experts`` experts with the largest ``num_experts_per_tok``
+renormalised, SwiGLU experts. The layer is told which experts it holds
+(``experts_held``, one chip's share under expert parallelism,
+``parallel/partition.expert_share``): it routes over all of them and adds
+its own experts' part of the result; on one chip that is the whole layer
+without the exchange. No capacity, no dropped token: every assignment to a
+held expert is computed whatever the routing.
 
 Tokens are Gato's (Reed et al. 2022, sec. 2.1): mu-law, clip to [-1, 1],
 ``bins`` uniform bins, on the float32 values (bfloat16 cannot tell 1,024
@@ -28,6 +45,15 @@ sequence is rematerialised on its own in the backward pass, so only the
 is held for more than one sequence; with no capacity the sorted expert
 buffer is sized for every assignment landing here, ``T * k`` rows. The
 bfloat16 copies of a layer's matrices are made once a layer and a pass.
+At ``keye2``'s 16,384 tokens the scheme is the same and a sequence is four
+times as long: a layer boundary is 134 MB a sequence; inside a sequence the
+selection is a ``[T, T]`` bool (268 MB, and twice more in the kernel's
+block order) and everything score-shaped lives for one block of
+``q_chunk_size`` queries (``[512, 16, 16384]`` float32 index scores, 537 MB;
+in the differentiated pass ``[8, 512, 16384]`` main scores a key/value
+head); the expert layer takes the sequence ``EXPERT_TOKENS`` tokens at a
+time, so its sorted buffers are cell 4's (32,768 assignments), not four
+times that.
 """
 
 from __future__ import annotations
@@ -43,12 +69,20 @@ import numpy as np
 
 from d4pg_tpu.ops import attention as attn_ops
 from d4pg_tpu.ops import grouped as grouped_ops
+from d4pg_tpu.ops import sparse_attention as sparse_ops
 
 HI = jax.lax.Precision.HIGHEST
 MU, M = 100.0, 256.0  # Gato's mu-law
 # the sorted expert buffer serves routing up to this multiple of an even
 # load before the every-assignment buffer takes over
 EXPERT_BUFFER = 1.5
+# a longer sequence goes through the expert layer this many tokens at a
+# time (cell 4's whole sequence): the every-assignment buffer of 16,384
+# tokens, 131,072 rows, takes 4.5 GB that the chip does not have
+EXPERT_TOKENS = 4096
+LAYER_TYPES = ("sliding_attention", "full_attention", "sparse_attention")
+SA_KEYS = ("indexer_head_dim", "indexer_num_heads", "indexer_num_kv_heads",
+           "kv_chunk_size", "q_chunk_size", "topk")
 
 
 def _freeze(x):
@@ -71,8 +105,7 @@ class TorsoSpec:
     num_attention_heads: int
     num_key_value_heads: int
     head_dim: int
-    layer_types: tuple  # 'sliding_attention' | 'full_attention' per layer
-    sliding_window: int
+    layer_types: tuple  # one of LAYER_TYPES per layer
     num_experts: int
     num_experts_per_tok: int
     moe_intermediate_size: int
@@ -81,6 +114,9 @@ class TorsoSpec:
     rms_norm_eps: float = 1e-6
     norm_topk_prob: bool = True
     bins: int = 1024
+    sliding_window: int = 0  # 'sliding_attention' layers
+    qk_norm: bool = False  # RMSNorm with a gain on every head of q and k
+    sa_config: Any = None  # 'sparse_attention' layers: SA_KEYS, frozen
 
     @classmethod
     def from_dict(cls, d: dict) -> "TorsoSpec":
@@ -103,10 +139,30 @@ class TorsoSpec:
                              f"{self.vocab_rows} are held")
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError("query heads do not divide into key/value heads")
+        unknown = sorted(set(self.layer_types) - set(LAYER_TYPES))
+        if unknown:
+            raise ValueError(f"unknown layer types {unknown}; one of "
+                             f"{LAYER_TYPES}")
+        if "sliding_attention" in self.layer_types \
+                and self.sliding_window < 1:
+            raise ValueError("sliding_attention layers need sliding_window")
+        if "sparse_attention" in self.layer_types:
+            sa = dict(self.sa_config or ())
+            if sorted(sa) != sorted(SA_KEYS):
+                raise ValueError(f"sparse_attention layers need sa_config "
+                                 f"with {SA_KEYS}; got {sorted(sa)}")
+            if sa["indexer_num_kv_heads"] != 1:
+                raise ValueError("the indexer has one key head")
+            sparse_ops.block_plan(self.tokens, sa["q_chunk_size"],
+                                  sa["kv_chunk_size"])
 
     @property
     def n_held(self) -> int:
         return self.experts_held[1] - self.experts_held[0]
+
+    @property
+    def sa(self) -> dict:
+        return dict(self.sa_config)
 
     def rope_for(self, layer_type: str) -> dict:
         return dict(dict(self.rope_parameters)[layer_type])
@@ -173,6 +229,13 @@ def rms_norm(x, scale, eps: float):
                              + eps) * scale
 
 
+def layer_norm(x, p: dict, eps: float):
+    x = x.astype(jnp.float32)
+    x = x - jnp.mean(x, axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                             + eps) * p["scale"] + p["bias"]
+
+
 # -- expert layer -------------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _to_sorted(h, top, inv, k: int):
@@ -237,7 +300,9 @@ def even_load_rows(spec: TorsoSpec, t_len: int) -> int:
     every = t_len * spec.num_experts_per_tok
     even = every * spec.n_held / spec.num_experts
     tile = grouped_ops.ROW_TILE
-    return min(every, -(-int(EXPERT_BUFFER * even) // tile) * tile)
+    # static sizes: Python numbers, never traced
+    rows = int(EXPERT_BUFFER * even)  # jaxlint: disable=host-sync-in-jit
+    return min(every, -(-rows // tile) * tile)
 
 
 def expert_share(spec: TorsoSpec, p: dict, h, dtype, grouped: str = "ragged"):
@@ -303,11 +368,16 @@ def expert_share(spec: TorsoSpec, p: dict, h, dtype, grouped: str = "ragged"):
 
 
 # -- the torso ----------------------------------------------------------------
-class MellumTorso:
+class SequenceTorso:
     """``init(key) -> params``; ``apply(params, obs [B, tokens]) ->
-    (latent [B, hidden_size] float32, route_counts [layers, num_experts]
-    int32)``. ``params`` is a plain dict; matrix leaves are named
-    ``kernel`` and norm gains ``scale``."""
+    (latent [B, hidden_size] float32, aux)``. ``aux["route_counts"]
+    [layers, num_experts]`` int32 is how many assignments the router gave
+    each expert; with ``sparse_attention`` layers and ``train=True`` (the
+    differentiated pass) it also holds ``select_counts [sparse layers,
+    tokens / kv_chunk_size]`` int32, the selections by block of keys summed
+    over queries and sequences, and ``index_loss``, the indexer's alignment
+    loss, the mean over layers, sequences and positions. ``params`` is a
+    plain dict; matrix leaves are named ``kernel``, norm gains ``scale``."""
 
     def __init__(self, spec: TorsoSpec, dtype=jnp.float32):
         self.spec = spec
@@ -317,6 +387,13 @@ class MellumTorso:
         """The splash kernel on a TPU where its tiling takes the sizes,
         the blockwise ``jnp`` form otherwise."""
         fits = attn_ops.splash_fits(self.spec.tokens, self.spec.head_dim)
+        return ("splash" if fits and jax.default_backend() == "tpu"
+                else "blockwise")
+
+    def sparse_impl(self) -> str:
+        """As ``attention_impl``, for the kernel's dynamic-mask form."""
+        fits = sparse_ops.splash_fits(self.spec.tokens, self.spec.head_dim,
+                                      self.spec.sa["kv_chunk_size"])
         return ("splash" if fits and jax.default_backend() == "tpu"
                 else "blockwise")
 
@@ -339,11 +416,27 @@ class MellumTorso:
                     / math.sqrt(fan_in)}
 
         keys = iter(jax.random.split(key, 1 + 8 * len(s.layer_types)))
-        gain = lambda: {"scale": jnp.ones((d,), jnp.float32)}  # noqa: E731
+        gain = lambda n=d: {"scale": jnp.ones((n,), jnp.float32)}  # noqa
         params = {"embed": normal(next(keys), (s.vocab_rows, d), 1.0),
                   "final_norm": gain()}
-        for i in range(len(s.layer_types)):
+        for i, layer_type in enumerate(s.layer_types):
+            extra = {}
+            if s.qk_norm:
+                extra.update(q_norm=gain(s.head_dim), k_norm=gain(s.head_dim))
+            if layer_type == "sparse_attention":
+                hi, di = s.sa["indexer_num_heads"], s.sa["indexer_head_dim"]
+                # keys of their own: the other leaves draw what they drew
+                # before this layer type existed
+                k_q, k_k, k_w = jax.random.split(
+                    jax.random.fold_in(key, i + 1), 3)
+                extra.update(
+                    index_q=normal(k_q, (d, hi * di), d),
+                    index_k=normal(k_k, (d, di), d),
+                    index_k_norm={**gain(di),
+                                  "bias": jnp.zeros((di,), jnp.float32)},
+                    index_w=normal(k_w, (d, hi), d))
             params[f"layer_{i}"] = {
+                **extra,
                 "attn_norm": gain(), "moe_norm": gain(),
                 "q": normal(next(keys), (d, hq), d),
                 "k": normal(next(keys), (d, hkv), d),
@@ -356,45 +449,110 @@ class MellumTorso:
             }
         return params
 
-    def _attend(self, p: dict, x, layer_type: str):
-        """Attention of one sequence ``x [T, D]`` added to it."""
+    def _qkv(self, p: dict, h, layer_type: str):
+        """``q [Hkv, G, T, D]`` (scaled), ``k``, ``v [Hkv, T, D]`` of the
+        normed ``h [T, D]``: query head i reads key/value head i // G."""
         s, dtype = self.spec, self.dtype
-        t_len = x.shape[0]
+        t_len = h.shape[0]
         hkv, dh = s.num_key_value_heads, s.head_dim
         group = s.num_attention_heads // hkv
+        proj = lambda name, out: jnp.dot(  # noqa: E731
+            h, p[name]["kernel"], preferred_element_type=out)
+        cos, sin = rope_tables(s.rope_for(layer_type), dh, t_len)
+        q = proj("q", jnp.float32).reshape(t_len, hkv, group, dh)
+        if s.qk_norm:
+            q = rms_norm(q, p["q_norm"]["scale"], s.rms_norm_eps)
+        q = (apply_rope(q.transpose(1, 2, 0, 3), cos, sin)
+             / math.sqrt(dh)).astype(dtype)
+        k = proj("k", jnp.float32).reshape(t_len, hkv, dh)
+        if s.qk_norm:
+            k = rms_norm(k, p["k_norm"]["scale"], s.rms_norm_eps)
+        k = apply_rope(k.transpose(1, 0, 2), cos, sin).astype(dtype)
+        v = proj("v", dtype).reshape(t_len, hkv, dh).transpose(1, 0, 2)
+        return q, k, v
+
+    def _attend(self, p: dict, x, layer_type: str):
+        """Attention of one sequence ``x [T, D]`` added to it."""
+        s = self.spec
         full = layer_type == "full_attention"
         with jax.named_scope("torso.attn_full" if full
                              else "torso.attn_window"):
             h = rms_norm(x, p["attn_norm"]["scale"], s.rms_norm_eps).astype(
-                dtype)
-            proj = lambda name, out: jnp.dot(  # noqa: E731
-                h, p[name]["kernel"], preferred_element_type=out)
-            cos, sin = rope_tables(s.rope_for(layer_type), dh, t_len)
-            # query head i reads key/value head i // group
-            q = proj("q", jnp.float32).reshape(t_len, hkv, group, dh)
-            q = (apply_rope(q.transpose(1, 2, 0, 3), cos, sin)
-                 / math.sqrt(dh)).astype(dtype)
-            k = proj("k", jnp.float32).reshape(t_len, hkv, dh)
-            k = apply_rope(k.transpose(1, 0, 2), cos, sin).astype(dtype)
-            v = proj("v", dtype).reshape(t_len, hkv, dh).transpose(1, 0, 2)
+                self.dtype)
+            q, k, v = self._qkv(p, h, layer_type)
             a = attn_ops.causal_attention(
                 q[None], k[None], v[None],
                 window=None if full else s.sliding_window,
                 impl=self.attention_impl())[0]
-            a = a.transpose(2, 0, 1, 3).reshape(t_len, -1)
+            a = a.transpose(2, 0, 1, 3).reshape(x.shape[0], -1)
             return x + jnp.dot(a, p["o"]["kernel"],
                                preferred_element_type=jnp.float32)
 
-    def _sequence(self, p: dict, x, layer_type: str):
-        """One layer on one sequence: ``x [T, D] -> (x, counts)``."""
-        x = self._attend(p, x, layer_type)
+    def _attend_sparse(self, p: dict, x, train: bool):
+        """``_attend`` over the keys the indexer selects: ``(x, (counts
+        [T / kv_chunk_size], loss))``, the loss summed over positions and 0
+        unless ``train``. The indexer reads the normed input through a
+        stop-gradient and the selection is a bool: the main attention's
+        gradient cannot reach the indexer, nor the loss anything else."""
+        s, dtype, sa = self.spec, self.dtype, self.spec.sa
+        t_len = x.shape[0]
+        hi, di = sa["indexer_num_heads"], sa["indexer_head_dim"]
+        with jax.named_scope("torso.attn_sparse"):
+            h = rms_norm(x, p["attn_norm"]["scale"], s.rms_norm_eps).astype(
+                dtype)
+            q, k, v = self._qkv(p, h, "sparse_attention")
+        with jax.named_scope("torso.indexer"):
+            hx = jax.lax.stop_gradient(h)
+            proj = lambda name: jnp.dot(  # noqa: E731
+                hx, p[name]["kernel"], preferred_element_type=jnp.float32)
+            cos, sin = rope_tables(s.rope_for("sparse_attention"), di, t_len)
+            qi = apply_rope(proj("index_q").reshape(t_len, hi, di).transpose(
+                1, 0, 2), cos, sin).transpose(1, 0, 2).astype(dtype)
+            ki = apply_rope(layer_norm(proj("index_k"), p["index_k_norm"],
+                                       s.rms_norm_eps), cos, sin).astype(dtype)
+            wi = proj("index_w") / math.sqrt(hi * di)
+            chunks = dict(q_chunk=sa["q_chunk_size"],
+                          kv_chunk=sa["kv_chunk_size"])
+            keep, counts = sparse_ops.select_keys(qi, ki, wi,
+                                                  topk=sa["topk"], **chunks)
+            loss = (sparse_ops.alignment_loss(
+                qi, ki, wi, keep, q, k, v, impl=self.sparse_impl(), **chunks)
+                if train else jnp.zeros((), jnp.float32))
+        with jax.named_scope("torso.attn_sparse"):
+            a = sparse_ops.masked_attention(
+                q, k, v, keep, impl=self.sparse_impl(), **chunks)
+            a = a.transpose(2, 0, 1, 3).reshape(t_len, -1)
+            x = x + jnp.dot(a, p["o"]["kernel"],
+                            preferred_element_type=jnp.float32)
+        return x, (counts, loss)
+
+    def _sequence(self, p: dict, x, layer_type: str, train: bool):
+        """One layer on one sequence: ``x [T, D] -> (x, counts, selected)``;
+        ``selected`` is ``()`` but for a sparse layer."""
+        selected = ()
+        if layer_type == "sparse_attention":
+            x, selected = self._attend_sparse(p, x, train)
+        else:
+            x = self._attend(p, x, layer_type)
         with jax.named_scope("torso.route"):
             h = rms_norm(x, p["moe_norm"]["scale"], self.spec.rms_norm_eps)
-        out, counts = expert_share(self.spec, p, h, self.dtype,
-                                   self.grouped_impl())
-        return x + out, counts
+        out, counts = self._experts(p, h)
+        return x + out, counts, selected
 
-    def _layer(self, p: dict, x, layer_type: str):
+    def _experts(self, p: dict, h):
+        """``expert_share`` of one sequence, ``EXPERT_TOKENS`` at a time
+        where it is longer (the layer works token by token, so the parts
+        are the whole), each part rematerialised on its own."""
+        share = lambda hs: expert_share(  # noqa: E731
+            self.spec, p, hs, self.dtype, self.grouped_impl())
+        t_len = h.shape[0]
+        if t_len <= EXPERT_TOKENS or t_len % EXPERT_TOKENS:
+            return share(h)
+        out, counts = jax.lax.map(jax.checkpoint(share), h.reshape(
+            -1, EXPERT_TOKENS, h.shape[-1]))
+        return out.reshape(h.shape), jnp.sum(counts, axis=0)
+
+    def _layer(self, p: dict, x, layer_type: str, train: bool):
         """One layer on the batch, a sequence at a time. The compute-dtype
         copies of the matrices are made once, here, and live as long as
         the layer; each sequence is rematerialised on its own in the
@@ -403,25 +561,34 @@ class MellumTorso:
                        if "kernel" in leaf and name != "router" else leaf)
                 for name, leaf in p.items()}
         per_seq = jax.checkpoint(
-            lambda xs: self._sequence(cast, xs, layer_type))
-        x, counts = jax.lax.map(per_seq, x)
-        return x, jnp.sum(counts, axis=0)
+            lambda xs: self._sequence(cast, xs, layer_type, train))
+        x, counts, selected = jax.lax.map(per_seq, x)
+        return x, jnp.sum(counts, axis=0), selected
 
-    def apply(self, params: dict, obs):
+    def apply(self, params: dict, obs, train: bool = False):
         s = self.spec
         with jax.named_scope("torso.embed"):
             tokens = tokenise(s, obs)
             x = params["embed"]["kernel"][tokens]
-        counts = []
+        counts, selected = [], []
         for i, layer_type in enumerate(s.layer_types):
             layer = jax.checkpoint(
-                lambda p, x, lt=layer_type: self._layer(p, x, lt))
-            x, c = layer(params[f"layer_{i}"], x)
+                lambda p, x, lt=layer_type: self._layer(p, x, lt, train))
+            x, c, sel = layer(params[f"layer_{i}"], x)
             counts.append(c)
+            if sel:
+                selected.append(sel)
         with jax.named_scope("torso.pool"):
             x = rms_norm(x, params["final_norm"]["scale"], s.rms_norm_eps)
             latent = jnp.mean(x, axis=1)
-        return latent, jnp.stack(counts)
+        aux = {"route_counts": jnp.stack(counts)}
+        if selected and train:
+            with jax.named_scope("torso.indexer"):
+                aux["select_counts"] = jnp.stack(
+                    [jnp.sum(c, axis=0) for c, _loss in selected])
+                aux["index_loss"] = jnp.mean(jnp.stack(
+                    [loss for _c, loss in selected])) / s.tokens
+        return latent, aux
 
 
 class TorsoCritic:
@@ -441,9 +608,9 @@ class TorsoCritic:
             "torso": self.torso.init(k_torso),
             "critic": self.head.init(k_head, latent, action)["params"]}}
 
-    def latent(self, params, obs):
-        """``(latent, route_counts)`` of the torso in ``params``."""
-        return self.torso.apply(params["params"]["torso"], obs)
+    def latent(self, params, obs, train: bool = False):
+        """``(latent, aux)`` of the torso in ``params``."""
+        return self.torso.apply(params["params"]["torso"], obs, train)
 
     def of_latent(self, params, latent, action, logits: bool = False):
         """The head in ``params`` on a latent."""
@@ -455,7 +622,7 @@ class TorsoCritic:
                               return_logits)
 
 
-TORSOS = {"mellum2": MellumTorso}
+TORSOS = {"mellum2": SequenceTorso, "keye2": SequenceTorso}
 
 
 def build_torso(spec: TorsoSpec, dtype=jnp.float32):

@@ -3,6 +3,9 @@
 - ``augment``: the DrQ random shift as two batched one-hot matrix products.
 - ``attention``: softmax attention for the torso, blockwise ``jax.numpy`` or
   the splash kernel on a TPU.
+- ``sparse_attention``: attention over keys an indexer selects at run time
+  (an exact top-k threshold, the splash kernel's dynamic-mask form or a
+  blockwise ``jax.numpy`` one, the indexer's alignment loss).
 - ``grouped``: the experts' grouped matrix products, ``ragged_dot`` or
   megablox ``gmm`` on a TPU.
 """

@@ -1,0 +1,379 @@
+"""Causal attention over keys the model chooses at run time: a small scorer
+(the indexer of DeepSeek-V3.2-Exp's sparse attention, as Keye-VL-2.0's
+``sa_config`` sizes it) ranks every earlier position for every query, and
+the main attention reads the ``topk`` best of them and nothing else.
+
+Three steps a sequence, the first and the last a block of ``q_chunk``
+queries at a time so that no ``[heads, T, T]`` array is ever made (16,384 x
+16,384 float32 is 1 GB a head):
+
+1. ``select_keys``: index scores ``I[t, s] = sum_j w[t, j] relu(qI[t, j] .
+   kI[s])`` against the keys a block can see, the exact selection
+   ``S_t`` (the ``topk`` largest ``I[t, s]``, ``s <= t``; all of them while
+   there are fewer; ties to the lower position) as a ``[T, T]`` bool mask,
+   and how many selections fell on each ``kv_chunk`` keys. The selection
+   carries no gradient.
+2. ``masked_attention``: softmax attention under that mask.
+3. ``alignment_loss`` (the differentiated pass only): the loss the indexer
+   is trained by, ``sum_t KL(p_t || softmax_{S_t} I[t, .])``, ``p_t`` the
+   main attention's probabilities over ``S_t`` summed over its heads and
+   L1-normalised, a constant. The index scores are made again here, with
+   their gradient.
+
+The selection is a threshold, not a sort: ``lax.top_k`` at ``k`` 2,048 of
+16,384 is a full sort on the TPU (291 ms a sequence on the v5e against 19
+ms, 6 of them the scores; the same set; my chip run, PR 32). Scores become
+integers in float order, 32 counting passes find each row's ``topk``-th
+largest bit by bit, and a running count of the scores equal to it hands the
+places that are left to the lowest positions: exactly ``lax.top_k``'s set.
+
+Blocks of queries are grouped by how far they can see (``GROUPS`` static
+key extents, multiples of ``kv_chunk``), so steps 1 and 3 and the blockwise
+step 2 touch 9/16 of the square, not all of it, in ``GROUPS`` compiled
+bodies.
+Neither the selection nor the output depends on ``q_chunk``, ``kv_chunk``
+or ``GROUPS``.
+
+``masked_attention`` implementations:
+
+- ``splash``: the Pallas splash-attention kernel jax ships, in its
+  dynamic-mask form. ``process_dynamic_mask`` would lay the mask out once a
+  query head; the selection is one for all heads, so the mask's blocks are
+  laid out once and every head's block table points at them. The kernel
+  skips the blocks the causal order empties and masks inside the others:
+  it visits every causal block whatever was selected. Backward is the
+  kernel's own (dq and dkv apart). TPU only: blocks are multiples of 128.
+  A sequence on the v5e: 34 ms forward, 110 forward and backward (blocks
+  of 512; 256: 65 / 214; 1,024 does not fit VMEM; a static causal mask
+  over the same pairs: 17 forward).
+- ``blockwise``: plain ``jax.numpy``, a block of queries against the keys
+  its group sees, rematerialised in the backward pass. Runs anywhere (441
+  ms forward there).
+
+Shapes: ``q [Hkv, G, T, D]`` already scaled, ``k``, ``v`` ``[Hkv, T, D]``;
+``qi [T, Hi, Di]``, ``ki [T, Di]`` (one key head for all ``Hi``), ``wi
+[T, Hi]`` float32 with the score's scale factors folded in.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+
+IMPLS = ("splash", "blockwise")
+GROUPS = 8
+SPLASH_BLOCK = 512
+
+
+def block_plan(t_len: int, q_chunk: int, kv_chunk: int) -> list:
+    """``[(first query, query blocks, keys seen)]``: at most ``GROUPS``
+    runs of query blocks, each with the static number of keys its last
+    query sees, rounded up to ``kv_chunk``."""
+    if t_len % q_chunk or t_len % kv_chunk:
+        raise ValueError(f"{t_len} positions do not divide into blocks of "
+                         f"{q_chunk} queries and {kv_chunk} keys")
+    blocks = t_len // q_chunk
+    groups = min(GROUPS, blocks)
+    plan, first = [], 0
+    for g in range(groups):
+        n = blocks // groups + (g < blocks % groups)
+        end = (first + n) * q_chunk
+        plan.append((first * q_chunk, n, -(-end // kv_chunk) * kv_chunk))
+        first += n
+    return plan
+
+
+def _by_blocks(fn, plan, q_chunk: int, per_query: tuple, per_key: tuple):
+    """``fn(start, query blocks..., keys..., extent=)`` over every block of
+    the plan (``lax.map`` inside a group: one compiled body a group), each
+    rematerialised on its own in the backward pass. ``per_query`` arrays
+    lead with ``T``; ``per_key`` arrays have ``T`` second to last. Returns
+    ``(rows, sums)``: ``fn``'s first result concatenated over queries, its
+    second summed over blocks."""
+    rows, sums = [], None
+    for first, n, extent in plan:
+        last = first + n * q_chunk
+        xs = tuple(a[first:last].reshape((n, q_chunk) + a.shape[1:])
+                   for a in per_query)
+        keys = tuple(a[..., :extent, :] for a in per_key)
+        starts = first + q_chunk * jnp.arange(n, dtype=jnp.int32)
+        body = jax.checkpoint(functools.partial(fn, extent=extent))
+        out, part = jax.lax.map(
+            lambda args, keys=keys, body=body: body(args[0], *args[1],
+                                                    *keys), (starts, xs))
+        rows.append(out.reshape((n * q_chunk,) + out.shape[2:]))
+        part = jax.tree_util.tree_map(lambda a: jnp.sum(a, axis=0), part)
+        sums = part if sums is None else jax.tree_util.tree_map(
+            jnp.add, sums, part)
+    return jnp.concatenate(rows, axis=0), sums
+
+
+# -- scores and selection -----------------------------------------------------
+def index_scores(qi, ki, wi):
+    """``[Tq, S]`` float32 from ``qi [Tq, Hi, Di]``, ``ki [S, Di]``, ``wi
+    [Tq, Hi]``."""
+    s = jnp.einsum("qhd,kd->qhk", qi, ki, preferred_element_type=jnp.float32)
+    return jnp.sum(jax.nn.relu(s) * wi[:, :, None], axis=1)
+
+
+def select(scores, causal, topk: int):
+    """The exact selection of float32 ``scores [rows, S]`` among the
+    positions ``causal [rows, S]`` allows: bool ``[rows, S]`` (module
+    docstring)."""
+    # -0.0 and 0.0 are one score; then signed integers in float order,
+    # then unsigned, with 0 for what may not be chosen (below -inf's key)
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(scores == 0, jnp.zeros((), scores.dtype), scores),
+        jnp.int32)
+    key = bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+    u = jnp.where(causal, jax.lax.bitcast_convert_type(key, jnp.uint32)
+                  ^ jnp.uint32(0x80000000), jnp.uint32(0))
+
+    def at_least(v):
+        return jnp.sum(u >= v[:, None], axis=-1, dtype=jnp.int32)
+
+    def bit(i, v):
+        cand = v | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        return jnp.where(at_least(cand) >= topk, cand, v)
+
+    # the largest value that at least topk scores reach: the topk-th
+    # largest (0, which everything reaches, where there are fewer)
+    v = jax.lax.fori_loop(0, 32, bit, jnp.zeros(u.shape[:1], jnp.uint32))
+    above = u > v[:, None]
+    equal = u == v[:, None]
+    left = topk - jnp.sum(above, axis=-1, dtype=jnp.int32)
+    before = jnp.cumsum(equal, axis=-1, dtype=jnp.int32) - equal.astype(
+        jnp.int32)
+    return (above | (equal & (before < left[:, None]))) & causal
+
+
+def _select_block(start, qi, wi, ki, *, extent, topk, t_len, kv_chunk):
+    scores = index_scores(qi, ki, wi)
+    t = start + jnp.arange(qi.shape[0], dtype=jnp.int32)[:, None]
+    causal = jnp.arange(extent, dtype=jnp.int32)[None, :] <= t
+    keep = select(scores, causal, topk)
+    counts = jnp.sum(keep, axis=0, dtype=jnp.int32).reshape(
+        extent // kv_chunk, kv_chunk).sum(axis=-1)
+    counts = jnp.pad(counts, (0, (t_len - extent) // kv_chunk))
+    return jnp.pad(keep, ((0, 0), (0, t_len - extent))), counts
+
+
+def select_keys(qi, ki, wi, *, topk: int, q_chunk: int, kv_chunk: int):
+    """``(keep [T, T] bool, counts [T / kv_chunk] int32)``: the selection
+    of every query, and the selections by block of ``kv_chunk`` keys summed
+    over queries. No gradient."""
+    qi, ki, wi = jax.lax.stop_gradient((qi, ki, wi))
+    t_len = qi.shape[0]
+    fn = functools.partial(_select_block, topk=topk, t_len=t_len,
+                           kv_chunk=kv_chunk)
+    return _by_blocks(fn, block_plan(t_len, q_chunk, kv_chunk), q_chunk,
+                      (qi, wi), (ki,))
+
+
+# -- the indexer's loss -------------------------------------------------------
+def _head_mean_probs(q, k, keep):
+    """The main attention's probabilities over ``keep [bq, S]``, the mean
+    over heads: ``q [Hkv, G, bq, D]`` (scaled), ``k [Hkv, S, D]``. Plain
+    ``jnp``, one key/value head's scores at a time."""
+    def head(total, xs):
+        qh, kh = xs
+        s = jnp.einsum("gqd,kd->gqk", qh, kh,
+                       preferred_element_type=jnp.float32)
+        p = jax.nn.softmax(jnp.where(keep[None], s, -jnp.inf), axis=-1)
+        return total + jnp.sum(p, axis=0), None
+
+    p, _ = jax.lax.scan(head, jnp.zeros(keep.shape, jnp.float32), (q, k))
+    return p / (q.shape[0] * q.shape[1])
+
+
+def head_mean_probs_kernel(q, k, lse, keep, *, interpret: bool = False):
+    """``_head_mean_probs`` as a Pallas kernel, given every head's
+    log-sum-exp over its selection (``lse [Hkv, G, bq]``, the splash
+    kernel's own residual): a tile of keys at a time, the heads' ``exp(q .
+    k - lse)`` summed in the output tile while it stays in VMEM, so no
+    ``[heads, bq, S]`` array reaches HBM (on the v5e a 16,384-token
+    sequence's loss takes 462 ms in the ``jnp`` form, 50 ms in this one, 35
+    of them the forward pass that gives the lse; my chip run, PR 32)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    hkv, group, bq, d = q.shape
+    s_len = k.shape[1]
+    bkv = math.gcd(SPLASH_BLOCK, s_len)
+    lanes = min(128, bkv)
+    # a query's lse on every lane, as the splash kernel keeps it
+    lse = jnp.broadcast_to(lse[..., None], lse.shape + (lanes,))
+
+    def kernel(q_ref, k_ref, lse_ref, keep_ref, out_ref):
+        head = pl.program_id(1)
+
+        # (a Pallas kernel writes its output through the ref it is given)
+        @pl.when(head == 0)
+        def _():
+            out_ref[...] = jnp.zeros_like(out_ref)  # jaxlint: disable=tracer-leak
+
+        keys = k_ref[0]
+        total = out_ref[...]
+        for g in range(group):
+            logits = jax.lax.dot_general(
+                q_ref[0, g], keys, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            # a selected score never exceeds its row's lse; what is not
+            # selected is dropped below, and must not overflow before
+            total += jnp.exp(jnp.minimum(
+                logits - pltpu.repeat(lse_ref[0, g], bkv // lanes, axis=1),
+                0.0))
+        out_ref[...] = total
+
+        @pl.when(head == hkv - 1)
+        def _():
+            # the kernel's output ref again, not a leak
+            out_ref[...] = jnp.where(  # jaxlint: disable=tracer-leak
+                keep_ref[...], out_ref[...] * (1.0 / (hkv * group)), 0.0)
+
+    return pl.pallas_call(
+        kernel, grid=(s_len // bkv, hkv),
+        in_specs=[
+            pl.BlockSpec((1, group, bq, d), lambda j, h: (h, 0, 0, 0)),
+            pl.BlockSpec((1, bkv, d), lambda j, h: (h, j, 0)),
+            pl.BlockSpec((1, group, bq, lanes), lambda j, h: (h, 0, 0, 0)),
+            pl.BlockSpec((bq, bkv), lambda j, h: (0, j))],
+        out_specs=pl.BlockSpec((bq, bkv), lambda j, h: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((bq, s_len), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret, name="head_mean_probs")(q, k, lse, keep)
+
+
+def _align_block(_start, qi, wi, keep, q, lse, ki, k, *, extent, interpret):
+    """``sum_t KL(p_t || softmax_{S_t} I[t, .])`` of one block of queries:
+    ``q [bq, Hkv, G, D]``, ``lse [bq, Hkv, G]`` or ``None`` (the ``jnp``
+    form)."""
+    scores = index_scores(qi, ki, wi)
+    keep = keep[:, :extent]
+    log_q = jax.nn.log_softmax(jnp.where(keep, scores, -jnp.inf), axis=-1)
+    q = jnp.moveaxis(q, 0, 2)
+    p = (_head_mean_probs(q, k, keep) if lse is None
+         else head_mean_probs_kernel(q, k, jnp.moveaxis(lse, 0, 2), keep,
+                                     interpret=interpret))
+    p = jax.lax.stop_gradient(p)
+    log_p = jnp.log(jnp.where(p > 0, p, 1.0))
+    loss = jnp.sum(p * (log_p - jnp.where(keep, log_q, 0.0)))
+    return jnp.zeros((qi.shape[0], 0), jnp.float32), loss
+
+
+def alignment_loss(qi, ki, wi, keep, q, k, v, *, impl: str, q_chunk: int,
+                   kv_chunk: int, interpret: bool = False):
+    """The indexer's loss summed over the queries of a sequence: the KL
+    divergence from the main attention's probabilities over the selection
+    (the mean over heads, a constant) to ``softmax_{S_t} I[t, .]``.
+    Gradient reaches ``qi``, ``ki``, ``wi`` and nothing else. ``impl``
+    ``splash`` takes every head's log-sum-exp from the splash kernel (a
+    forward pass that keeps its residuals) and sums the heads in
+    ``head_mean_probs_kernel``; ``blockwise`` is plain ``jnp``."""
+    if impl not in IMPLS:
+        raise ValueError(f"unknown attention impl {impl!r}; one of {IMPLS}")
+    q, k, v = jax.lax.stop_gradient((q, k, v))
+    plan = block_plan(qi.shape[0], q_chunk, kv_chunk)
+    per_query = (qi, wi, keep, jnp.moveaxis(q, 2, 0))
+    if impl == "splash":
+        _out, (lse,) = _splash_kernel(keep, q.shape[1], q.shape[2],
+                                      residuals=True, interpret=interpret)(
+            q, k, v)
+        fn = functools.partial(_align_block, interpret=interpret)
+        per_query += (jnp.moveaxis(lse, 2, 0),)
+    else:
+        fn = lambda start, qi, wi, keep, q, ki, k, *, extent: (  # noqa: E731
+            _align_block(start, qi, wi, keep, q, None, ki, k, extent=extent,
+                         interpret=False))
+    _, loss = _by_blocks(fn, plan, q_chunk, per_query, (ki, k))
+    return loss
+
+
+# -- attention under the selection --------------------------------------------
+def _attend_block(_start, q, keep, k, v, *, extent):
+    """``q [bq, Hkv, G, D]`` against ``k, v [Hkv, extent, D]``."""
+    s = jnp.einsum("qhgd,hkd->hgqk", q, k,
+                   preferred_element_type=jnp.float32)
+    s = jnp.where(keep[None, None, :, :extent], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("hgqk,hkd->qhgd", p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.astype(q.dtype), ()
+
+
+def blockwise_masked_attention(q, k, v, keep, *, q_chunk: int,
+                               kv_chunk: int):
+    plan = block_plan(q.shape[2], q_chunk, kv_chunk)
+    out, _ = _by_blocks(_attend_block, plan, q_chunk,
+                        (jnp.moveaxis(q, 2, 0), keep), (k, v))
+    return jnp.moveaxis(out, 0, 2)
+
+
+def splash_fits(t_len: int, head_dim: int, kv_chunk: int) -> bool:
+    """Whether the kernels' tiling takes these sizes (a group's extent of
+    keys is a multiple of ``kv_chunk``)."""
+    return (t_len % SPLASH_BLOCK == 0 and head_dim % 128 == 0
+            and kv_chunk % 128 == 0)
+
+
+def _shared_mask_info(keep, heads: int, block: int, dkv: bool):
+    """``keep``'s ``MaskInfo`` for ``heads`` query heads that share it: the
+    mask's blocks laid out once, every head's tables pointing at them."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask_info as mask_info)
+
+    process = (mask_info.process_dynamic_mask_dkv if dkv
+               else mask_info.process_dynamic_mask)
+    info, _ = process(keep[None], (block, block), downcast_smem_data=True,
+                      head_shards=1, q_seq_shards=1)
+    every = lambda a: jnp.broadcast_to(a, (heads,) + a.shape[1:])  # noqa
+    return info._replace(data_next=every(info.data_next),
+                         mask_next=every(info.mask_next),
+                         block_mask=every(info.block_mask))
+
+
+def _splash_kernel(keep, group: int, t_len: int, *, residuals: bool,
+                   interpret: bool):
+    """The kernel for one key/value head and its ``group`` query heads
+    under ``keep``; ``residuals``: the forward pass alone, returning
+    ``(out, (logsumexp [G, T],))`` (not differentiable)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sa)
+
+    b = min(SPLASH_BLOCK, t_len)
+    # the fused backward hands dq back once a block of keys ([T / b, G, T,
+    # D] a key/value head: 4 GB at 16,384 tokens); dq and dkv apart do not
+    sizes = sa.BlockSizes(
+        block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b,
+        block_kv_dkv=b, block_kv_dkv_compute=b, block_q_dq=b, block_kv_dq=b)
+    by_query = _shared_mask_info(keep, group, b, False)
+    by_key = None if residuals else _shared_mask_info(keep, group, b, True)
+    kernel = sa.SplashAttentionKernel(
+        by_query, None if residuals else by_query, by_key,
+        block_sizes=sizes, is_mqa=True, save_residuals=residuals,
+        mask_value=sa.DEFAULT_MASK_VALUE, attn_logits_soft_cap=None,
+        residual_checkpoint_name=None, mask_function=None,
+        interpret=interpret)
+    return jax.vmap(kernel)
+
+
+def splash_masked_attention(q, k, v, keep, *, interpret: bool = False):
+    _hkv, group, t_len, _d = q.shape
+    return _splash_kernel(keep, group, t_len, residuals=False,
+                          interpret=interpret)(q, k, v)
+
+
+def masked_attention(q, k, v, keep, *, impl: str, q_chunk: int,
+                     kv_chunk: int):
+    """Softmax attention of ``q [Hkv, G, T, D]`` over the positions ``keep
+    [T, T]`` allows each query (every row keeps at least its own)."""
+    if impl not in IMPLS:
+        raise ValueError(f"unknown attention impl {impl!r}; one of {IMPLS}")
+    if impl == "splash":
+        return splash_masked_attention(q, k, v, keep)
+    return blockwise_masked_attention(q, k, v, keep, q_chunk=q_chunk,
+                                      kv_chunk=kv_chunk)

@@ -181,7 +181,9 @@ def test_forward_pass_matches_the_reference():
     config = small_config()
     params = init_state(config, jax.random.key(3)).critic_params
     obs = small_batch().obs
-    latent, counts = config.build_critic().latent(params, obs)
+    latent, aux = config.build_critic().latent(params, obs)
+    counts = aux["route_counts"]
+    assert set(aux) == {"route_counts"}  # no sparse layer: no other counter
     want, want_counts = rt.torso(rt.EXACT_OPS, SMALL,
                                  params["params"]["torso"], obs)
     np.testing.assert_allclose(np.asarray(latent), np.asarray(want),
@@ -260,17 +262,20 @@ def _layer_inputs(bias=None):
     return p, h
 
 
+@pytest.mark.parametrize("shares", [4, 8], ids=["four", "eight"])
 @pytest.mark.parametrize("bias", [None, 50.0], ids=["seeded", "biased"])
-def test_the_four_shares_add_up_to_the_uncut_layer(bias):
-    """Each share routes over all 8 experts and computes its own 2; summed
-    they are the reference's whole layer, and the assignments the shares
-    computed are all of them, even when two experts get every token."""
+def test_the_four_shares_add_up_to_the_uncut_layer(bias, shares):
+    """Each share routes over all 8 experts and computes its own (2 of four
+    shares, as ``humanoid-mellum2-ep4`` stands for; 1 of eight, as
+    ``humanoid-keye2-ep8``); summed they are the reference's whole layer,
+    and the assignments the shares computed are all of them, even when two
+    experts get every token."""
     p, h = _layer_inputs(bias)
     w, e, counts = rt.route(SMALL, h, p["router"]["kernel"])
     whole = rt.experts(rt.EXACT_OPS, SMALL, p, h, w, e, held=(0, 8))
     total, computed = jnp.zeros_like(h), 0
-    for index in range(4):
-        lo, hi = partition.expert_share(8, 4, index)
+    for index in range(shares):
+        lo, hi = partition.expert_share(8, shares, index)
         spec = small_config(experts_held=[lo, hi]).torso
         mine = {"router": p["router"], **{
             name: {"kernel": p[name]["kernel"][lo:hi]}
@@ -294,6 +299,8 @@ def test_the_four_shares_add_up_to_the_uncut_layer(bias):
 def test_expert_share_names_a_contiguous_range():
     assert [partition.expert_share(64, 4, i) for i in range(4)] == [
         (0, 16), (16, 32), (32, 48), (48, 64)]
+    assert [partition.expert_share(128, 8, i) for i in (0, 7)] == [
+        (0, 16), (112, 128)]
     with pytest.raises(ValueError):
         partition.expert_share(64, 5, 0)
 

@@ -16,6 +16,7 @@ from d4pg_tpu.learner import D4PGConfig, init_state
 from d4pg_tpu.learner.fused import make_fused_chunk
 from d4pg_tpu.models import torso as torso_lib
 from d4pg_tpu.ops import attention as attn_ops
+from d4pg_tpu.ops import sparse_attention as sparse_ops
 from d4pg_tpu.replay import device_per as dper
 from d4pg_tpu.replay.uniform import TransitionBatch
 
@@ -121,6 +122,65 @@ def test_the_fused_chunk_of_the_benchmark_cell_fits_the_chip(one_chip,
     assert held < HBM_BYTES, held
     text = compiled.as_text()
     assert "gmm" in text and "splash" in text and "ragged-dot" not in text
+
+
+def test_the_kernels_dynamic_mask_form_compiles_at_real_widths(one_chip):
+    """Splash attention under a mask that is an argument (``humanoid-keye2-
+    ep8``: 16,384 positions, 8 query heads a key/value head): forward, dq
+    and dkv kernels, the mask's blocks laid out once for the eight heads
+    (two layouts, by query and by key: not one a head)."""
+    q = jax.ShapeDtypeStruct((4, 8, 16384, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((4, 16384, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    keep = jax.ShapeDtypeStruct((16384, 16384), jnp.bool_, sharding=one_chip)
+
+    def loss(q, k, v, keep):
+        out = sparse_ops.masked_attention(q, k, v, keep, impl="splash",
+                                          q_chunk=512, kv_chunk=512)
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv, keep).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    # the kernels read the mask as int32 blocks: 1 GiB a layout, two
+    # layouts; a layout a head would be eight times that
+    assert text.count("s32[1024,512,512]") >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 4.5e9
+
+
+def test_a_sparse_attention_layer_and_its_backward_compile_at_real_widths(
+        one_chip, monkeypatch):
+    """One ``sparse_attention`` layer of ``humanoid-keye2-ep8`` on one
+    16,384-token sequence, differentiated: index scores, the threshold
+    selection, the alignment loss, the kernel under the selection and the
+    expert layer 4,096 tokens at a time, in the memory the chip has beside
+    8.4 GiB of state and ring. (The whole chunk of that cell compiles in
+    ~3 minutes here: PR 32's builder did it by hand, not this suite.)"""
+    with open(os.path.join(
+            REPO, "benchmark/configs/humanoid-keye2-ep8.json")) as f:
+        model = json.load(f)["model"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    config = D4PGConfig(**model)
+    torso = config.build_critic().torso
+    assert torso.sparse_impl() == "splash" \
+        and torso.grouped_impl() == "megablox"
+    params = jax.eval_shape(lambda: torso.init(jax.random.key(0)))["layer_0"]
+    x = jax.ShapeDtypeStruct((1, 16384, 2048), jnp.float32)
+
+    def loss(p, x):
+        out, _counts, (_selected, index_loss) = torso._layer(
+            p, x, "sparse_attention", True)
+        return jnp.sum(out) + jnp.sum(index_loss)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        *on(one_chip, (params, x))).compile()
+    text = compiled.as_text()
+    assert "gmm" in text and "splash" in text and "ragged-dot" not in text
+    # the every-assignment buffer is a 4,096-token part's, not a sequence's
+    assert "[131072,2048]" not in text and "[32768,2048]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 7e9
 
 
 @pytest.mark.parametrize("batch", [256, 4096], ids=["chunk", "commit"])
