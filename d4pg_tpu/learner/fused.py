@@ -107,10 +107,11 @@ def make_fused_chunk(
     (state, metrics)``. ``state`` and ``trees`` are donated (updated in
     place in HBM). The ring is read-only and taken in the formats the
     store keeps it in (``replay/device_ring.py``, "Layout"): the gather
-    reads B rows from the parameter itself, and only those rows are ever
-    cast (``core/precision.to_compute`` at the models' inputs). Until PR
-    31 "never copied" was false on the chip: a rows-minor ring was
-    transposed and narrowed whole, once a dispatch."""
+    reads B rows from the parameter itself, float rows and ``uint8``
+    frames alike, and only those rows are ever cast
+    (``core/precision.to_compute`` at the models' inputs). Until PR 31
+    (frames: PR 33) "never copied" was false on the chip: a ring with its
+    rows on the lanes was re-laid whole, once a dispatch."""
     if prioritized:
         def fn(state, trees, storage, size):
             return fused_chunk_step(

@@ -27,18 +27,24 @@ Two write paths:
     instead of a modular scatter. Partial blocks mask by ``n``; the shape
     is static, so steady-state ingest never recompiles.
 
-Layout (PR 31): a wide rank-2 float field is stored rows-major (a row's
-values along the lanes), whatever the compiler would choose. The TPU's
-compact layout for ``f32[2101248, 376]`` puts the ROWS on the lanes
-(nothing to pad; rows-major pads 376 to 384), and a row gather from that
-is strided: the fused chunk answered with a transposing copy of the whole
-ring once a dispatch (14.4 of its 26.7 ms; PERF.md). ``ring_layout`` is
-the rule, from the static shape alone; every program that returns the
-ring returns it in the store's formats, so donation still aliases. Those
-programs (the writers, the re-layout, the fused commit) are compiled
-outside the persistent compile cache where a field is pinned
-(``io/profiling.fresh_compile`` says why): it costs each start their
-compiles, a fraction of a second together.
+Layout (PR 31, 33): a field is stored the way its row gather reads it,
+whatever the compiler would choose. ``ring_layout`` is the rule, from the
+field's static shape and dtype and the platform of the store's device.
+The TPU's compact layout for ``f32[2101248, 376]`` puts the ROWS on the
+lanes (nothing to pad; rows-major pads 376 to 384), and for
+``u8[40256, 84, 84, 9]`` the FRAMES (``{0,2,3,1}``); a row gather from
+either is strided, and the fused chunk answered with a re-laid copy of
+the whole field once a dispatch (14.4 of the MLP chunk's 26.7 ms, 31.9
+of the pixel chunk's 91.5; PERF.md). So a wide rank-2 float field is
+pinned rows-major (a row's values along the lanes), and on a TPU a
+rank-4 ``uint8`` field ``[rows, H, W, C]`` is pinned ``(0, 3, 1, 2)``:
+a frame contiguous, its channels as planes, H on the sublanes and W on
+the lanes, which is the layout the compiler itself gave the gather's
+operand. Every program that returns the ring returns it in the store's
+formats, so donation still aliases. Those programs (the writers, the
+re-layout, the fused commit) are compiled outside the persistent compile
+cache where a field is pinned (``io/profiling.fresh_compile`` says why):
+it costs each start their compiles, a fraction of a second together.
 """
 
 from __future__ import annotations
@@ -55,15 +61,32 @@ from d4pg_tpu.replay.uniform import TransitionBatch
 _LANES = 128
 
 
-def ring_layout(shape: tuple, dtype) -> tuple | None:
-    """``major_to_minor`` a ring field of this static shape is pinned to,
-    or ``None`` for the compiler's own layout. Rows-major for a rank-2
-    float field whose width pads at most an eighth on the 128 lanes (376
-    -> 384: yes; a 17-wide action would pad 7.5x: no). Scalars a row keep
-    the compiler's layout, and so do rank-4 ``uint8`` frames: rows-major
-    would put 9 channels on the lanes (14x), and the layout their gather
-    wants pads 1.74x, a storage decision of its own (PERF.md section 7)."""
-    if len(shape) != 2 or not np.issubdtype(np.dtype(dtype), np.floating):
+def ring_layout(shape: tuple, dtype, platform: str) -> tuple | None:
+    """``major_to_minor`` a ring field of this static shape is pinned to on
+    a device of ``platform``, or ``None`` for the compiler's own layout.
+
+    Rows-major for a rank-2 float field whose width pads at most an eighth
+    on the 128 lanes (376 -> 384: yes; a 17-wide action would pad 7.5x:
+    no). Scalars a row keep the compiler's layout.
+
+    ``(0, 3, 1, 2)`` (XLA's ``{2,1,3,0}``) for a rank-4 ``uint8`` field
+    ``[rows, H, W, C]`` on a TPU: rows-major would put the 9 channels on
+    the lanes (14x). The pin is a statement about the TPU's ``(8,128)(4,1)``
+    tiles, so the platform is part of the rule's input: it is not the
+    CPU's default, and pinned there every pixel store would transpose its
+    ring and compile outside the cache for a layout no CPU user wants.
+    It pads H to a multiple of 8 and W of 128 (84 x 84: 1.60x, 4.08 GB a
+    field at 40,256 rows), and there is no padding threshold as for float
+    rows: the resident padded field replaces a transient padded copy of
+    the same size BESIDE the compact field, so the pin never raises a
+    chunk's footprint, whatever H, W and C are. The pixel cell's chunk
+    compiled for a v5e holds 5.51 GB of arguments and 8.57 GB of
+    temporaries unpinned, 8.31 GB and 0.42 GB pinned
+    (``tests/test_torso_v5e_compile.py``)."""
+    dtype = np.dtype(dtype)
+    if len(shape) == 4 and dtype == np.uint8:
+        return (0, 3, 1, 2) if platform == "tpu" else None
+    if len(shape) != 2 or not np.issubdtype(dtype, np.floating):
         return None
     w = int(shape[1])
     return (0, 1) if -(-w // _LANES) * _LANES <= 1.125 * w else None
@@ -86,13 +109,14 @@ def ring_specs(rows: int, obs_shape: tuple, act_dim: int,
 def ring_formats(specs: TransitionBatch, home) -> TransitionBatch:
     """The ``Format`` each field is pinned to on the one-device sharding
     ``home`` (a layout is honoured only on a committed array, so a pinned
-    field names its device), ``None`` where ``ring_layout`` leaves the
-    compiler's layout."""
+    field names its device, and the rule reads that device's platform),
+    ``None`` where ``ring_layout`` leaves the compiler's layout."""
     from jax.experimental.layout import Format, Layout
 
+    platform = next(iter(home.device_set)).platform
     return TransitionBatch(*[
         None if mtm is None else Format(Layout(major_to_minor=mtm), home)
-        for mtm in (ring_layout(*spec) for spec in specs)])
+        for mtm in (ring_layout(*spec, platform) for spec in specs)])
 
 
 def ring_program(fn, formats: TransitionBatch):
@@ -152,8 +176,8 @@ class DeviceStore:
     write of the store's own (``pinned``) or the first ``swap_arrays``,
     and for life after: returned in them by ``_insert``, ``_write_block``
     and the fused commit (``formats`` are their ``out_shardings``). Where
-    the pinned layout is the device's default anyway (the CPU) nothing is
-    ever re-laid.
+    every pinned layout is the device's default anyway (the CPU: rows-major
+    is, and frames are pinned on a TPU alone) nothing is ever re-laid.
     """
 
     def __init__(

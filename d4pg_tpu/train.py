@@ -282,8 +282,9 @@ def _platforms(x: jax.Array) -> str:
 def _layouts(formats) -> str:
     """The layout each ring field is pinned to, major to minor:
     ``obs:01,action:-,...`` (``01``: a row's values along the lanes, what
-    ``replay/device_ring.ring_layout`` pins a wide float field to; ``-``:
-    left to the compiler)."""
+    ``replay/device_ring.ring_layout`` pins a wide float field to;
+    ``0312``: a frame's channels as planes with W along the lanes, what it
+    pins ``uint8`` frames to on a TPU; ``-``: left to the compiler)."""
     return ",".join(
         name + ":" + ("-" if fmt is None else
                       "".join(map(str, fmt.layout.major_to_minor)))

@@ -1,9 +1,12 @@
-"""The ring as it is stored (PR 31): the rule that pins a wide float field
-rows-major, the store that keeps every field in one format for life
-(``replay/device_ring.py``), and the input cast that stays behind the
-gather (``core/precision.to_compute``). CPU, tiny sizes: the pinned layout
-is the CPU's default, so a *foreign* layout here is column-major. What the
-chip's compiler makes of it is in ``tests/test_torso_v5e_compile.py``."""
+"""The ring as it is stored (PR 31, 33): the rule that pins a wide float
+field rows-major and, on a TPU, a rank-4 ``uint8`` field W-minor; the store
+that keeps every field in one format for life (``replay/device_ring.py``);
+and the input cast that stays behind the gather
+(``core/precision.to_compute``). CPU, tiny sizes: the float pin is the
+CPU's default, so a *foreign* layout here is column-major; the frames' pin
+is not, so a store of frames under the TPU's rule (``frames`` below) is in
+the chip's situation as it stands. What the chip's compiler makes of it is
+in ``tests/test_torso_v5e_compile.py``."""
 
 import jax
 import jax.numpy as jnp
@@ -124,6 +127,10 @@ def test_a_chunk_is_bitwise_the_chunk_of_the_plain_cast(monkeypatch, dtype):
 
 # ------------------------------------------------------------- the rule ---
 
+FRAMES = (0, 3, 1, 2)  # XLA's {2,1,3,0}: W on the lanes, H on the sublanes
+
+
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
 @pytest.mark.parametrize("shape, dtype, want", [
     ((2101248, 376), np.float32, (0, 1)),   # Humanoid rows: 376 -> 384
     ((33024, 4096), np.float32, (0, 1)),    # the torso cell's histories
@@ -133,11 +140,20 @@ def test_a_chunk_is_bitwise_the_chunk_of_the_plain_cast(monkeypatch, dtype):
     ((2101248, 17), np.float32, None),      # actions: 7.5x on the lanes
     ((40256, 6), np.float32, None),
     ((2101248,), np.float32, None),         # reward, done, discount
-    ((40256, 84, 84, 9), np.uint8, None),   # frames: a decision of their own
+    ((40256, 84, 84, 9), np.uint8, {"tpu": FRAMES}),  # the pixel cell's
+    ((576, 20, 20, 3), np.uint8, {"tpu": FRAMES}),    # its rehearsal's
+    ((576, 20, 20, 3), np.float32, None),   # rank 4, not frames
+    ((576, 20, 20, 3), np.int8, None),
+    ((576, 20, 60), np.uint8, None),        # uint8, not rank 4
     ((80, 376), np.uint8, None),
 ], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else None)
-def test_the_rule_pins_wide_float_rows_and_nothing_else(shape, dtype, want):
-    assert ring_layout(shape, dtype) == want
+def test_the_rule_pins_wide_float_rows_and_tpu_frames_and_nothing_else(
+        shape, dtype, want, platform):
+    """The float pin is the same on every platform (it is the CPU's default
+    layout); the frames' pin is the TPU's alone."""
+    if isinstance(want, dict):
+        want = want.get(platform)
+    assert ring_layout(shape, dtype, platform) == want
 
 
 # ------------------------------------------------------------ the store ---
@@ -169,13 +185,51 @@ def spans():
 
 
 def _rows(rng, n, obs=WIDE):
+    """``n`` seeded rows: float observations ``obs`` wide, or ``uint8``
+    frames where ``obs`` is a frame's shape."""
+    if np.isscalar(obs):
+        draw = lambda: rng.standard_normal((n, obs)).astype(np.float32)  # noqa
+    else:
+        draw = lambda: rng.integers(0, 256, (n, *obs), dtype=np.uint8)  # noqa
     return TransitionBatch(
-        obs=rng.standard_normal((n, obs)).astype(np.float32),
+        obs=draw(),
         action=rng.uniform(-1, 1, (n, ACT)).astype(np.float32),
         reward=rng.standard_normal(n).astype(np.float32),
-        next_obs=rng.standard_normal((n, obs)).astype(np.float32),
+        next_obs=draw(),
         done=np.zeros(n, np.float32),
         discount=np.full(n, 0.99, np.float32))
+
+
+FRAME = (20, 20, 3)  # the pixel cell's rehearsal frame
+
+
+@pytest.fixture(params=["rows", "frames"])
+def obs(request, monkeypatch):
+    """What a row's observation is: ``WIDE`` floats, pinned rows-major (the
+    CPU's default: nothing is foreign until a test makes it so), or a
+    ``FRAME`` under the TPU's rule, pinned ``(0, 3, 1, 2)`` (not the CPU's
+    default: a new ring is re-laid by its first write, as on the chip)."""
+    if request.param == "rows":
+        return WIDE
+    from d4pg_tpu.replay import device_ring
+
+    rule = device_ring.ring_layout
+    monkeypatch.setattr(device_ring, "ring_layout",
+                        lambda shape, dtype, platform: rule(shape, dtype,
+                                                            "tpu"))
+    return FRAME
+
+
+def _pin(obs):
+    return (0, 1) if obs == WIDE else FRAMES
+
+
+def _store(obs):
+    store = DeviceStore(CAP, (WIDE,) if obs == WIDE else obs, ACT,
+                        np.float32 if obs == WIDE else np.uint8,
+                        block_rows=BLOCK)
+    assert store.formats.obs.layout.major_to_minor == _pin(obs)
+    return store
 
 
 def _layouts(storage):
@@ -193,7 +247,8 @@ def _in_the_stores_formats(store):
 
 def _foreign(values, home):
     """The values as a program that knows nothing of the formats would
-    leave them: rank-2 fields column-major, on the same device."""
+    leave them: rank-2 fields column-major, frames in the device's default
+    layout, on the same device."""
     col = Format(Layout(major_to_minor=(1, 0)), home)
     return TransitionBatch(*[
         jax.device_put(v, col) if np.ndim(v) == 2 else jnp.asarray(v)
@@ -212,9 +267,10 @@ def test_the_store_pins_obs_and_next_obs_and_leaves_the_rest():
 
 
 @pytest.mark.parametrize("path", ["write", "write_block", "swap_foreign"])
-def test_every_path_leaves_the_ring_in_the_stores_formats(rng, spans, path):
-    store = DeviceStore(CAP, (WIDE,), ACT, np.float32, block_rows=BLOCK)
-    vals = _rows(rng, CAP + BLOCK)
+def test_every_path_leaves_the_ring_in_the_stores_formats(rng, spans, path,
+                                                          obs):
+    store = _store(obs)
+    vals = _rows(rng, CAP + BLOCK, obs)
     if path == "write":
         store.write(np.arange(5, dtype=np.int32),
                     TransitionBatch(*[v[:5] for v in vals]))
@@ -231,23 +287,26 @@ def test_every_path_leaves_the_ring_in_the_stores_formats(rng, spans, path):
     assert _in_the_stores_formats(store)
     np.testing.assert_array_equal(np.asarray(store.arrays.obs), want)
     relaid = [s for s in spans.seen if s[0] == "ring.relayout"]
-    assert len(relaid) == (2 if path == "swap_foreign" else 0)
+    # a pin that is the device's default re-lays only what came in foreign;
+    # one that is not re-lays a new ring at its first write too
+    assert len(relaid) == (2 if path == "swap_foreign" or obs != WIDE else 0)
 
 
-def test_swap_arrays_relays_a_foreign_field_once_and_only_then(rng, spans):
+def test_swap_arrays_relays_a_foreign_field_once_and_only_then(rng, spans,
+                                                               obs):
     """The one door a foreign layout comes through: each pinned field that
     arrives in another layout is re-laid under one ``ring.relayout`` span
     naming it and its bytes, its source donated; a second swap of what the
     store now holds, and a swap of arrays already in its formats, open
     none."""
-    store = DeviceStore(CAP, (WIDE,), ACT, np.float32, block_rows=BLOCK)
-    vals = _rows(rng, CAP + BLOCK)
+    store = _store(obs)
+    vals = _rows(rng, CAP + BLOCK, obs)
     foreign = _foreign(vals, store.home)
-    assert _layouts(foreign)[0] == (1, 0)
+    assert _layouts(foreign)[0] == ((1, 0) if obs == WIDE else (0, 1, 2, 3))
     store.swap_arrays(foreign)
     relaid = [s for s in spans.seen if s[0] == "ring.relayout"]
     assert [s[1]["field"] for s in relaid] == ["obs", "next_obs"]
-    assert all(s[1]["bytes"] == (CAP + BLOCK) * WIDE * 4 for s in relaid)
+    assert all(s[1]["bytes"] == vals.obs.nbytes for s in relaid)
     assert foreign.obs.is_deleted() and foreign.next_obs.is_deleted()
     assert not foreign.action.is_deleted()  # unpinned: the same buffer
     for got, want in zip(store.arrays, vals):
@@ -260,19 +319,24 @@ def test_swap_arrays_relays_a_foreign_field_once_and_only_then(rng, spans):
 
 
 def test_a_fused_commit_returns_the_ring_in_its_formats_and_in_place(
-        rng, spans):
-    buf = FusedDeviceReplay(CAP, WIDE, ACT, alpha=0.6, block_rows=BLOCK)
+        rng, spans, obs):
+    buf = FusedDeviceReplay(CAP, obs, ACT, alpha=0.6, block_rows=BLOCK)
     assert buf.home == buf._store.home
     assert all(t.committed for t in jax.tree_util.tree_leaves(buf.trees))
-    buf.add(_rows(rng, 40))
+    # (a pin that is not the device's default re-lays a never-written ring
+    # at its first commit: the last test of this file; here, the ring after)
+    buf._store.pinned()
+    spans.seen.clear()
+    buf.add(_rows(rng, 40, obs))
     before = buf.storage.obs.unsafe_buffer_pointer()
     assert buf.drain() == 40
     assert _in_the_stores_formats(buf._store)
+    assert _layouts(buf.storage)[0] == _pin(obs)
     # donated in, the same format out: the commit updated the ring in place
     assert buf.storage.obs.unsafe_buffer_pointer() == before
     assert not [s for s in spans.seen if s[0] == "ring.relayout"]
     # a restore (the scatter write) keeps them too
-    other = FusedDeviceReplay(CAP, WIDE, ACT, alpha=0.6, block_rows=BLOCK)
+    other = FusedDeviceReplay(CAP, obs, ACT, alpha=0.6, block_rows=BLOCK)
     other.load_state_dict(buf.state_dict())
     assert _in_the_stores_formats(other._store)
     np.testing.assert_array_equal(np.asarray(other.storage.obs[:40]),
@@ -280,16 +344,21 @@ def test_a_fused_commit_returns_the_ring_in_its_formats_and_in_place(
     assert all(t.committed for t in jax.tree_util.tree_leaves(other.trees))
 
 
-def test_the_loop_compiles_one_chunk_program_for_a_foreign_filled_ring(rng):
+def test_the_loop_compiles_one_chunk_program_for_a_foreign_filled_ring(rng,
+                                                                       obs):
     """The benchmark's fill and a restored checkpoint hand the store a ring
     it did not lay out; the loop then commits the state it is given to the
     ring's device, so the second chunk is the first chunk's program (what a
     program returns is committed once an argument is) and the program
     table's abstract arguments carry the ring's formats."""
-    config = D4PGConfig(obs_dim=WIDE, act_dim=ACT, v_min=-10, v_max=10,
-                        n_atoms=11, hidden=(16, 16))
-    buf = FusedDeviceReplay(CAP, WIDE, ACT, alpha=0.6, block_rows=BLOCK)
-    buf._store.swap_arrays(_foreign(_rows(rng, CAP + BLOCK), buf.home))
+    model = dict(obs_dim=WIDE) if obs == WIDE else dict(
+        obs_dim=0, pixels=True, obs_shape=FRAME, encoder_channels=(4, 4),
+        augment="shift", augment_pad=2)
+    config = D4PGConfig(act_dim=ACT, v_min=-10, v_max=10, n_atoms=11,
+                        hidden=(16, 16), **model)
+    buf = FusedDeviceReplay(CAP, obs, ACT, alpha=0.6, block_rows=BLOCK)
+    vals = _rows(rng, CAP + BLOCK, obs)
+    buf._store.swap_arrays(_foreign(vals, buf.home))
     buf.size, buf.head = CAP, 0
     buf.trees = dper.set_leaves_jitted(
         buf.trees, jnp.arange(CAP), jnp.ones(CAP, jnp.float32))
@@ -298,15 +367,19 @@ def test_the_loop_compiles_one_chunk_program_for_a_foreign_filled_ring(rng):
     assert not state.step.committed
     state, _m = loop.run(state, 2)
     with RecompileSentinel() as sentinel:
-        state, _m = loop.run(state, 4)
+        state, m = loop.run(state, 4)
         jax.block_until_ready(state)
     sentinel.assert_clean("the second and third chunk")
     _fn, args = trace._PROGRAMS["learner.chunk"]
     ring = args[2]
-    assert ring.obs.format.layout.major_to_minor == (0, 1)
+    assert ring.obs.format.layout.major_to_minor == _pin(obs)
     assert ring.obs.sharding == buf.home
     plain = abstract_args((jnp.zeros((3, 3)),))[0]
     assert plain.sharding is None
+    # and the rows the chunk drew read back as they were filled
+    idx = np.asarray(m["idx"][-1])
+    np.testing.assert_array_equal(np.asarray(buf._store.read(idx).obs),
+                                  vals.obs[idx])
 
 
 # ------------------------------------------- outside the persistent cache ---
@@ -376,13 +449,44 @@ def test_only_a_store_with_a_pinned_field_goes_round_the_cache():
 
     wide = FusedDeviceReplay(CAP, WIDE, ACT, block_rows=BLOCK)
     narrow = FusedDeviceReplay(CAP, 5, ACT, block_rows=BLOCK)
+    frames = FusedDeviceReplay(CAP, FRAME, ACT, block_rows=BLOCK)
     assert isinstance(wide._commit, FreshProgram)
     assert isinstance(wide._store._insert, FreshProgram)
     assert isinstance(wide._store._write_block, FreshProgram)
     assert wide._commit.fn is wide._commit_fn
-    for prog in (narrow._commit, narrow._store._insert,
-                 narrow._store._write_block):
-        assert not isinstance(prog, FreshProgram)
+    # (frames are pinned on a TPU alone: here the store's device is a CPU)
+    for buf in (narrow, frames):
+        for prog in (buf._commit, buf._store._insert,
+                     buf._store._write_block):
+            assert not isinstance(prog, FreshProgram)
+
+
+def test_a_pixel_store_on_the_cpu_is_never_relaid(rng, spans):
+    """``(0, 3, 1, 2)`` is not the CPU's default layout: pinned here, every
+    pixel store of the suite would transpose its ring. The rule reads the
+    platform of the store's device, so nothing is pinned, every path keeps
+    the default layout and no ``ring.relayout`` span opens."""
+    buf = FusedDeviceReplay(CAP, FRAME, ACT, alpha=0.6, block_rows=BLOCK)
+    store = buf._store
+    assert next(iter(store.home.device_set)).platform == "cpu"
+    assert all(f is None for f in store.formats)
+    vals = _rows(rng, CAP + BLOCK, FRAME)
+    store.swap_arrays(_foreign(vals, store.home))
+    buf.size, buf.head = CAP, 0
+    more = _rows(rng, 2 * BLOCK, FRAME)
+    buf.add(TransitionBatch(*[v[:BLOCK] for v in more]))
+    assert buf.drain() == BLOCK
+    store.write_block(BLOCK, TransitionBatch(*[v[BLOCK:] for v in more]),
+                      BLOCK)
+    store.write(np.arange(40, 44, dtype=np.int32),
+                TransitionBatch(*[v[:4] for v in more]))
+    assert _layouts(store.arrays)[0] == _layouts(store.arrays)[3] \
+        == (0, 1, 2, 3)
+    assert not [s for s in spans.seen if s[0] == "ring.relayout"]
+    want = vals.obs.copy()
+    want[:2 * BLOCK] = more.obs
+    want[40:44] = more.obs[:4]
+    np.testing.assert_array_equal(np.asarray(store.arrays.obs), want)
 
 
 # ------------------------------------ the chip's situation, on the CPU ---
@@ -396,8 +500,8 @@ def pinned_is_not_the_default(monkeypatch):
 
     monkeypatch.setattr(
         device_ring, "ring_layout",
-        lambda shape, dtype: (1, 0) if len(shape) == 2 and shape[1] >= WIDE
-        else None)
+        lambda shape, dtype, platform: (1, 0)
+        if len(shape) == 2 and shape[1] >= WIDE else None)
 
 
 def test_a_new_ring_is_the_allocators_until_the_first_write(

@@ -215,45 +215,87 @@ def test_the_dense_tree_repair_updates_the_trees_in_place_on_the_chip(
     assert sum(" reduce-window(" in ln for ln in lines) == 21
 
 
-@pytest.mark.parametrize("program", ["jit_fn", "jit_commit"])
-def test_the_mlp_cells_programs_take_the_ring_as_it_is_stored(one_chip,
-                                                              program):
-    """The ``humanoid-mlp`` cells' chunk and block commit at the real shapes,
-    ring fields in the formats ``DeviceStore`` gives them, compiled for the
-    v5e (PR 31). The chunk gathers from the parameter itself: the compiler's
-    own layout for ``f32[2101248, 376]`` has the rows on the lanes, and the
-    parent's chunk transposed and narrowed both wide fields whole, once a
-    dispatch (two ``copy`` to ``bf16[2101248,376]{1,0}``, 3.33 GB of
-    temporaries, 14.4 of its 26.7 ms). The commit writes its block into the
-    donated ring in place and returns it in the same formats."""
-    import re
+# configuration: the wide ring field's type as the compiled text prints it
+# (rows filled in), its pinned XLA layout, the bounds on the program's
+# temporaries and on the commit's aliased bytes, and what the chunk may
+# still make of a NARROW field of the ring's row count: the pixel chunk
+# copies its 6-wide actions rows-major (1 MB read; the parent's does too);
+# and how ``train``'s ``plan:`` line spells the pin
+RING_CELLS = {
+    "humanoid-mlp": dict(wide="f32[%d,376]", layout="{1,0:T(8,128)}",
+                         temp=0.5e9, alias=6.7e9, narrow=(), plan="01"),
+    "dmc-pixels-drq": dict(wide="u8[%d,84,84,9]",
+                           layout="{2,1,3,0:T(8,128)(4,1)}",
+                           temp=1e9, alias=8.1e9, narrow=("copy",),
+                           plan="0312"),
+}
 
+
+def _ring_programs(name, one_chip, capacity=None):
+    """The configuration (``capacity`` in place of its own), its
+    ``D4PGConfig``, and its ring as ``DeviceStore`` would describe it on
+    the described chip: specs, formats, abstract arrays."""
+    from benchmark.cellbuild import learner_config, row_spec
     from d4pg_tpu.replay.device_ring import ring_formats, ring_specs
-    from d4pg_tpu.replay.fused_buffer import make_commit
 
-    with open(os.path.join(REPO, "benchmark/configs/humanoid-mlp.json")) as f:
+    with open(os.path.join(REPO, "benchmark/configs", name + ".json")) as f:
         cfg = json.load(f)
-    model = dict(cfg["model"], hidden=tuple(cfg["model"]["hidden"]))
-    config = D4PGConfig(**model)
-    cap, block = cfg["replay"]["capacity"], cfg["replay"]["block_rows"]
-    rows = cap + block
-    specs = ring_specs(rows, (config.obs_dim,), config.act_dim, jnp.float32)
+    if capacity:
+        cfg["replay"]["capacity"] = capacity
+    config = learner_config(cfg)
+    specs = ring_specs(
+        cfg["replay"]["capacity"] + cfg["replay"]["block_rows"],
+        row_spec(cfg, config)["obs_shape"], config.act_dim,
+        jnp.uint8 if config.pixels else jnp.float32)
     formats = ring_formats(specs, one_chip)
     assert [f is not None for f in formats] == [True, False, False,
                                                 True, False, False]
     storage = TransitionBatch(*[
         jax.ShapeDtypeStruct(shape, dtype, sharding=fmt or one_chip)
         for (shape, dtype), fmt in zip(specs, formats)])
-    trees = on(one_chip, jax.eval_shape(lambda: dper.init(cap)))
+    return cfg, config, specs, formats, storage
+
+
+def _chunk(cfg, config, storage, one_chip):
+    state = on(one_chip, jax.eval_shape(
+        lambda: init_state(config, jax.random.key(0))))
+    trees = on(one_chip, jax.eval_shape(
+        lambda: dper.init(cfg["replay"]["capacity"])))
+    fn = make_fused_chunk(config, k=cfg["learner"]["k"],
+                          batch_size=cfg["learner"]["batch_size"])
     i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    return fn.lower(state, trees, storage, i32).compile()
+
+
+@pytest.mark.parametrize("program", ["jit_fn", "jit_commit"])
+@pytest.mark.parametrize("name", sorted(RING_CELLS, reverse=True))
+def test_the_cells_programs_take_the_ring_as_it_is_stored(one_chip, name,
+                                                          program):
+    """The ``humanoid-mlp`` and ``dmc-pixels-drq`` cells' chunk and block
+    commit at the real shapes, ring fields in the formats ``DeviceStore``
+    gives them on a TPU, compiled for the v5e (PR 31, 33). The chunk
+    gathers from the parameter itself: the compiler's own layout for
+    ``f32[2101248, 376]`` has the rows on the lanes and for
+    ``u8[40256, 84, 84, 9]`` the frames, and the parents' chunks re-laid
+    both wide fields whole, once a dispatch (two ``copy`` to
+    ``bf16[2101248,376]{1,0}``, 3.33 GB of temporaries, 14.4 of 26.7 ms;
+    two ``copy`` to ``u8[40256,84,84,9]{2,1,3,0}``, 8.57 GB, 31.9 of 91.5
+    ms). The commit writes its block into the donated ring in place and
+    returns it in the same formats."""
+    import re
+
+    from d4pg_tpu.replay.fused_buffer import make_commit
+
+    want = RING_CELLS[name]
+    cfg, config, specs, formats, storage = _ring_programs(name, one_chip)
+    cap, block = cfg["replay"]["capacity"], cfg["replay"]["block_rows"]
+    rows = cap + block
     if program == "jit_fn":
-        state = on(one_chip, jax.eval_shape(
-            lambda: init_state(config, jax.random.key(0))))
-        fn = make_fused_chunk(config, k=cfg["learner"]["k"],
-                              batch_size=cfg["learner"]["batch_size"])
-        compiled = fn.lower(state, trees, storage, i32).compile()
+        compiled = _chunk(cfg, config, storage, one_chip)
         in_place = ()
     else:
+        trees = on(one_chip, jax.eval_shape(lambda: dper.init(cap)))
+        i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
         frame = TransitionBatch(*[
             jax.ShapeDtypeStruct((block,) + shape[1:], dtype,
                                  sharding=one_chip) for shape, dtype in specs])
@@ -264,18 +306,48 @@ def test_the_mlp_cells_programs_take_the_ring_as_it_is_stored(one_chip,
         in_place = ("dynamic-update-slice", "fusion")
     text = compiled.as_text()
     assert text.startswith("HloModule " + program)
-    # (c) the program receives the wide fields rows-major
+    # (c) the program receives the wide fields as the gather reads them
+    wide = want["wide"] % rows
     for field in ("obs", "next_obs"):
-        assert re.search(r"storage_%s\S* = f32\[%d,376\]\{1,0:T\(8,128\)\} "
-                         r"parameter\(" % (field, rows), text), field
+        assert re.search(r"storage_%s\S* = %s parameter\(" % (
+            field, re.escape(wide + want["layout"])), text), field
     # (a) nothing of the ring's row count and rank >= 2 is computed
-    whole = re.compile(r"^\s*(?:ROOT )?%%?\S+ = \(?[a-z0-9]+\[%d,\d+[\],]\S* "
+    whole = re.compile(r"^\s*(?:ROOT )?%%?\S+ = \(?([a-z0-9]+\[%d,[\d,]+\])\S* "
                        r"([\w\-]+)\(" % rows)
-    made = {m.group(1) for m in map(whole.match, text.splitlines()) if m}
-    assert made <= {"parameter", "get-tuple-element", "bitcast", "tuple",
-                    *in_place}, made
+    made = {m.groups() for m in map(whole.match, text.splitlines()) if m}
+    passed = {"parameter", "get-tuple-element", "bitcast", "tuple", *in_place}
+    assert (wide, "parameter") in made
+    assert {op for field, op in made if field == wide} <= passed, made
+    assert {op for field, op in made if field != wide} \
+        <= passed | set(want["narrow"]), made
     m = compiled.memory_analysis()
-    # (b) no second ring among the temporaries (3.33 GB at the parent)
-    assert m.temp_size_in_bytes < 0.5e9, m.temp_size_in_bytes
-    if in_place:  # the whole ring (6.7 GB) and both trees are aliased
-        assert m.alias_size_in_bytes > 6.7e9, m.alias_size_in_bytes
+    # (b) no second ring among the temporaries
+    assert m.temp_size_in_bytes < want["temp"], m.temp_size_in_bytes
+    if in_place:  # the whole ring and both trees are aliased
+        assert m.alias_size_in_bytes > want["alias"], m.alias_size_in_bytes
+
+
+@pytest.mark.parametrize("name", sorted(RING_CELLS))
+def test_the_plan_line_names_what_each_field_is_pinned_to(one_chip, name):
+    from d4pg_tpu.train import _layouts
+
+    pin = RING_CELLS[name]["plan"]
+    formats = _ring_programs(name, one_chip)[3]
+    assert _layouts(formats) == (f"obs:{pin},action:-,reward:-,"
+                                 f"next_obs:{pin},done:-,discount:-")
+
+
+def test_the_pixel_chunk_fits_the_chip_at_70256_rows(one_chip):
+    """What the pin buys the ring's size: with no second padded copy among
+    the temporaries the pixel chunk compiles at 70,256 rows (14.39 GB of
+    arguments, 0.42 GB of temporaries) where 40,256 was the most the
+    parent's program fitted (14.1 GB). DrQ's 100,000 frames would be 20.3
+    GB pinned: they need the flat form (PERF.md section 7)."""
+    cfg, config, _specs, _formats, storage = _ring_programs(
+        "dmc-pixels-drq", one_chip, capacity=70000)
+    assert storage.obs.shape[0] == 70256
+    m = _chunk(cfg, config, storage, one_chip).memory_analysis()
+    assert m.temp_size_in_bytes < 1e9, m.temp_size_in_bytes
+    held = m.argument_size_in_bytes + m.temp_size_in_bytes \
+        + m.generated_code_size_in_bytes
+    assert 14e9 < held < HBM_BYTES, held
