@@ -238,11 +238,13 @@ def _torso_update_step(
          the actor head and the stepped critic head read it for the actor
          loss, which trains the actor's head only.
 
-    Metrics gain ``route_counts`` ``[layers, num_experts]`` int32: how many
-    of pass 2's assignments the router gave each expert; with
-    sparse-attention layers also ``select_counts`` ``[sparse layers, tokens /
-    kv_chunk_size]`` int32 (pass 2's selections by block of keys) and
-    ``index_loss``. ``critic_loss`` stays the TD loss alone."""
+    Metrics gain ``route_counts`` ``[layers with experts, num_experts]``
+    int32: how many of pass 2's assignments the router gave each expert;
+    with sparse-attention layers also ``select_counts`` ``[sparse layers,
+    tokens / kv_chunk_size]`` int32 (pass 2's selections by block of keys)
+    and ``index_loss``; with a routing bias ``bias_swapped`` ``[layers with
+    experts]`` int32, pass 2's assignments that the bias changed.
+    ``critic_loss`` stays the TD loss alone."""
     key, _sub = jax.random.split(state.key)
     actor, critic = config.build_actor(), config.build_critic()
 
@@ -271,6 +273,11 @@ def _torso_update_step(
             critic_grads, state.critic_opt_state, state.critic_params)
         critic_params = optax.apply_updates(state.critic_params,
                                             critic_updates)
+        # a router's load-balancing bias: the first torso state that no
+        # loss trains (its gradient is exactly zero: it enters a top-k
+        # only) and no optimizer steps; this pass's own load counter moves
+        # it, after the step, and the target's follows by soft_update
+        critic_params = critic.balance(critic_params, aux["route_counts"])
 
     with jax.named_scope("update.actor"):
         z = jax.lax.stop_gradient(critic.latent(critic_params, batch.obs)[0])
@@ -307,7 +314,7 @@ def _torso_update_step(
         "actor_loss": actor_loss,
         "q_mean": -actor_loss,
         "td_error": td_error,
-        **aux,  # route_counts; select_counts and index_loss where sparse
+        **aux,  # route_counts; the sparse and the biased layers' counters
     }
     return new_state, metrics
 

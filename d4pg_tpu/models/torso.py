@@ -8,8 +8,10 @@ transformer layers and pools them into one latent per row; D4PG's own heads
 state vector. ``D4PGConfig.torso`` names one (``TORSOS``) with its sizes
 (``TorsoSpec``, made from a configuration file's ``model.torso`` block).
 
-Two models share the one layer path, told apart by the data in the spec
-(``layer_types``, ``qk_norm``, ``sa_config``), not by code of their own:
+Three models share the one layer path, told apart by the data in the spec
+(``layer_types``, ``qk_norm``, ``sa_config``, ``num_dense_layers``,
+``router_scores``, ``use_expert_bias``), not by code of their own. Every
+layer is ``x + Op(RMSNorm(x))`` then ``x + FF(RMSNorm(x))``:
 
 - ``mellum2``, the Mellum2-12B-A2.5B layer: RMSNorm, grouped-query
   attention with rotary embeddings (default on ``sliding_attention``
@@ -25,10 +27,34 @@ Two models share the one layer path, told apart by the data in the spec
   ``index_k_norm``, ``index_w``) are trained by an alignment loss that
   ``apply(..., train=True)`` hands up beside the counters (``index_loss``,
   ``select_counts``) and that reaches nothing else.
+- ``lfm2``, LFM2-8B-A1B's layers: ``conv`` layers whose operator is a gated
+  short convolution with no attention at all (``ops/short_conv.py``: ``[b,
+  c, u] = h W_in``, a depthwise causal convolution of ``conv_L_cache`` taps
+  over ``b * u``, ``out_proj`` of ``c`` times it) round ``full_attention``
+  layers of 64-wide heads with q/k norm; the first ``num_dense_layers``
+  layers' feed-forward is a dense SwiGLU of ``intermediate_size``, the
+  others' the expert layer under a ``sigmoid`` router with a
+  load-balancing bias.
 
-Both end in the same expert layer: a float32 router over all
-``num_experts`` experts with the largest ``num_experts_per_tok``
-renormalised, SwiGLU experts. The layer is told which experts it holds
+Leaves. A layer has only the leaves its kind has: the operator's are
+``attn_norm``, ``q``, ``k``, ``v``, ``o`` (with ``qk_norm`` also ``q_norm``,
+``k_norm``; a sparse layer's indexer beside them) or ``conv_norm``,
+``in_proj``, ``conv`` (the taps, ``[D, conv_L_cache]``), ``out_proj``; the
+feed-forward's ``mlp_norm``, ``w1``, ``w3``, ``w2`` or ``moe_norm``,
+``router``, ``gate``, ``up``, ``down``. ``init`` draws eight keys a layer
+whatever its kind, so a layer's draws do not depend on its neighbours'.
+
+The expert layer: a float32 router over all ``num_experts`` experts,
+SwiGLU experts. ``softmax`` scores are a softmax over the experts with the
+largest ``num_experts_per_tok`` renormalised; ``sigmoid`` scores are one
+sigmoid an expert, the largest of score + ``router["bias"]`` selected and
+weighed by their *scores* over (their sum + 1e-6). That bias
+(``use_expert_bias``) is the one torso state no loss trains: it enters a
+top-k only, so its gradient is exactly zero and the optimizer leaves it;
+``balance`` moves it after the optimizer step by ``bias_update_rate *
+sign(mean(n) - n)``, ``n`` the differentiated pass's load a layer
+(``aux["route_counts"]``), and ``aux["bias_swapped"]`` counts the
+assignments it changed. The layer is told which experts it holds
 (``experts_held``, one chip's share under expert parallelism,
 ``parallel/partition.expert_share``): it routes over all of them and adds
 its own experts' part of the result; on one chip that is the whole layer
@@ -53,7 +79,11 @@ block order) and everything score-shaped lives for one block of
 in the differentiated pass ``[8, 512, 16384]`` main scores a key/value
 head); the expert layer takes the sequence ``EXPERT_TOKENS`` tokens at a
 time, so its sorted buffers are cell 4's (32,768 assignments), not four
-times that.
+times that. At ``lfm2``'s 8,192 tokens a layer boundary is 67 MB a
+sequence; inside a sequence the largest arrays are ``in_proj``'s ``[8192,
+6144]`` in the compute dtype (the gates and taps are one fused pass over
+it) and the dense layer's two ``[8192, 7168]`` float32 products; the
+expert layer takes the sequence in two parts of 16,384 assignments.
 """
 
 from __future__ import annotations
@@ -69,6 +99,7 @@ import numpy as np
 
 from d4pg_tpu.ops import attention as attn_ops
 from d4pg_tpu.ops import grouped as grouped_ops
+from d4pg_tpu.ops import short_conv as conv_ops
 from d4pg_tpu.ops import sparse_attention as sparse_ops
 
 HI = jax.lax.Precision.HIGHEST
@@ -80,7 +111,9 @@ EXPERT_BUFFER = 1.5
 # time (cell 4's whole sequence): the every-assignment buffer of 16,384
 # tokens, 131,072 rows, takes 4.5 GB that the chip does not have
 EXPERT_TOKENS = 4096
-LAYER_TYPES = ("sliding_attention", "full_attention", "sparse_attention")
+LAYER_TYPES = ("sliding_attention", "full_attention", "sparse_attention",
+               "conv")
+ROUTER_SCORES = ("softmax", "sigmoid")
 SA_KEYS = ("indexer_head_dim", "indexer_num_heads", "indexer_num_kv_heads",
            "kv_chunk_size", "q_chunk_size", "topk")
 
@@ -117,6 +150,13 @@ class TorsoSpec:
     sliding_window: int = 0  # 'sliding_attention' layers
     qk_norm: bool = False  # RMSNorm with a gain on every head of q and k
     sa_config: Any = None  # 'sparse_attention' layers: SA_KEYS, frozen
+    conv_L_cache: int = 0  # 'conv' layers: taps a channel
+    num_dense_layers: int = 0  # leading layers whose feed-forward is dense
+    intermediate_size: int = 0  # the dense feed-forward's width
+    router_scores: str = "softmax"  # one of ROUTER_SCORES
+    use_expert_bias: bool = False  # a bias a layer that enters the selection
+    routed_scaling_factor: float = 1.0
+    bias_update_rate: float = 0.0  # gamma of the load-balancing rule
 
     @classmethod
     def from_dict(cls, d: dict) -> "TorsoSpec":
@@ -155,6 +195,20 @@ class TorsoSpec:
                 raise ValueError("the indexer has one key head")
             sparse_ops.block_plan(self.tokens, sa["q_chunk_size"],
                                   sa["kv_chunk_size"])
+        if "conv" in self.layer_types and self.conv_L_cache < 1:
+            raise ValueError("conv layers need conv_L_cache taps")
+        if not 0 <= self.num_dense_layers < len(self.layer_types):
+            raise ValueError(f"num_dense_layers {self.num_dense_layers} "
+                             f"leaves no expert layer of "
+                             f"{len(self.layer_types)}")
+        if self.num_dense_layers and self.intermediate_size < 1:
+            raise ValueError("dense layers need intermediate_size")
+        if self.router_scores not in ROUTER_SCORES:
+            raise ValueError(f"unknown router_scores "
+                             f"{self.router_scores!r}; one of "
+                             f"{ROUTER_SCORES}")
+        if self.use_expert_bias and self.router_scores != "sigmoid":
+            raise ValueError("use_expert_bias is the sigmoid router's")
 
     @property
     def n_held(self) -> int:
@@ -163,6 +217,11 @@ class TorsoSpec:
     @property
     def sa(self) -> dict:
         return dict(self.sa_config)
+
+    @property
+    def expert_layers(self) -> tuple:
+        """Indices of the layers whose feed-forward is the expert layer."""
+        return tuple(range(self.num_dense_layers, len(self.layer_types)))
 
     def rope_for(self, layer_type: str) -> dict:
         return dict(dict(self.rope_parameters)[layer_type])
@@ -279,18 +338,48 @@ def _from_sorted_bwd(res, g):
 _from_sorted.defvjp(_from_sorted_fwd, _from_sorted_bwd)
 
 
-def route(spec: TorsoSpec, h, router):
-    """The router on float32 ``h [T, D]``: ``(weights [T, k], experts
-    [T, k] int32, counts [num_experts] int32)``. Softmax over all experts,
-    the ``k`` largest, renormalised."""
-    logits = jnp.dot(h, router, precision=HI)
-    p = jax.nn.softmax(logits, axis=-1)
-    w, e = jax.lax.top_k(p, spec.num_experts_per_tok)
-    if spec.norm_topk_prob:
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
-    counts = jnp.sum(e.reshape(-1, 1) == jnp.arange(spec.num_experts)[None],
-                     axis=0, dtype=jnp.int32)
-    return w, e.astype(jnp.int32), counts
+def route(spec: TorsoSpec, h, router: dict):
+    """The router (``{"kernel": [D, num_experts]}``, with
+    ``use_expert_bias`` also ``"bias" [num_experts]``) on float32
+    ``h [T, D]``: ``(weights [T, k], experts [T, k] int32, stats)``;
+    ``stats["route_counts"] [num_experts]`` int32.
+
+    ``softmax``: softmax over all experts, the ``k`` largest, renormalised.
+    ``sigmoid`` (LFM2's): a sigmoid score an expert; the ``k`` largest of
+    score + bias are selected and weigh in by their *scores* (the bias
+    enters the selection only, so its gradient is exactly zero), divided by
+    their sum + 1e-6, times ``routed_scaling_factor``. With a bias
+    ``stats["bias_swapped"]`` counts the assignments it changed: selected,
+    and not among the ``k`` largest scores."""
+    k = spec.num_experts_per_tok
+    logits = jnp.dot(h, router["kernel"], precision=HI)
+    stats = {}
+    if spec.router_scores == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        biased = scores + router["bias"] if spec.use_expert_bias else scores
+        _, e = jax.lax.top_k(biased, k)
+        w = jnp.take_along_axis(scores, e, axis=-1)
+        if spec.use_expert_bias:
+            # an assignment is outside the k largest scores when k experts
+            # are ahead of it (a tie goes to the lower index, as top_k's):
+            # compares, not a second sort
+            rivals, mine = scores[:, None, :], w[:, :, None]
+            first = jnp.arange(spec.num_experts) < e[:, :, None]
+            ahead = jnp.sum((rivals > mine) | ((rivals == mine) & first),
+                            axis=-1)
+            stats["bias_swapped"] = jnp.sum(ahead >= k, dtype=jnp.int32)
+        if spec.norm_topk_prob:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+        w = w * spec.routed_scaling_factor
+    else:
+        p = jax.nn.softmax(logits, axis=-1)
+        w, e = jax.lax.top_k(p, k)
+        if spec.norm_topk_prob:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+    stats["route_counts"] = jnp.sum(
+        e.reshape(-1, 1) == jnp.arange(spec.num_experts)[None], axis=0,
+        dtype=jnp.int32)
+    return w, e.astype(jnp.int32), stats
 
 
 def even_load_rows(spec: TorsoSpec, t_len: int) -> int:
@@ -307,7 +396,8 @@ def even_load_rows(spec: TorsoSpec, t_len: int) -> int:
 
 def expert_share(spec: TorsoSpec, p: dict, h, dtype, grouped: str = "ragged"):
     """This chip's experts' part of the layer for one sequence: float32
-    ``h [T, D]`` (normed) -> ``(out [T, D] float32, counts [num_experts])``.
+    ``h [T, D]`` (normed) -> ``(out [T, D] float32, stats)``, the stats
+    ``route``'s.
 
     Assignments are sorted with the held experts first, so the held ones
     are the first ``n`` rows of the sorted order whatever the routing; a
@@ -321,7 +411,8 @@ def expert_share(spec: TorsoSpec, p: dict, h, dtype, grouped: str = "ragged"):
     k, n_exp = spec.num_experts_per_tok, spec.num_experts
     t_len = h.shape[0]
     with jax.named_scope("torso.route"):
-        w, e, counts = route(spec, h, p["router"]["kernel"])
+        w, e, stats = route(spec, h, p["router"])
+        counts = stats["route_counts"]
         key = jnp.mod(e.reshape(-1) - lo, n_exp)
         order = jnp.argsort(key, stable=True)
         inv = jnp.zeros_like(order).at[order].set(
@@ -363,16 +454,18 @@ def expert_share(spec: TorsoSpec, p: dict, h, dtype, grouped: str = "ragged"):
 
     every, usual = t_len * k, even_load_rows(spec, t_len)
     if usual >= every:
-        return on(every)(h, w), counts
-    return jax.lax.cond(n_held <= usual, on(usual), on(every), h, w), counts
+        return on(every)(h, w), stats
+    return jax.lax.cond(n_held <= usual, on(usual), on(every), h, w), stats
 
 
 # -- the torso ----------------------------------------------------------------
 class SequenceTorso:
     """``init(key) -> params``; ``apply(params, obs [B, tokens]) ->
-    (latent [B, hidden_size] float32, aux)``. ``aux["route_counts"]
-    [layers, num_experts]`` int32 is how many assignments the router gave
-    each expert; with ``sparse_attention`` layers and ``train=True`` (the
+    (latent [B, hidden_size] float32, aux)``. ``aux["route_counts"] [layers
+    with experts, num_experts]`` int32 is how many assignments the router
+    gave each expert; with ``use_expert_bias`` ``aux["bias_swapped"] [layers
+    with experts]`` int32 counts those the bias changed; with
+    ``sparse_attention`` layers and ``train=True`` (the
     differentiated pass) it also holds ``select_counts [sparse layers,
     tokens / kv_chunk_size]`` int32, the selections by block of keys summed
     over queries and sequences, and ``index_loss``, the indexer's alignment
@@ -420,33 +513,52 @@ class SequenceTorso:
         params = {"embed": normal(next(keys), (s.vocab_rows, d), 1.0),
                   "final_norm": gain()}
         for i, layer_type in enumerate(s.layer_types):
-            extra = {}
-            if s.qk_norm:
-                extra.update(q_norm=gain(s.head_dim), k_norm=gain(s.head_dim))
+            # eight keys a layer whatever leaves it has: a layer's draws do
+            # not depend on the kinds of the layers before it
+            k_q, k_k, k_v, k_o, k_router, k_gate, k_up, k_down = (
+                next(keys) for _ in range(8))
+            if layer_type == "conv":
+                op = {"conv_norm": gain(),
+                      "in_proj": normal(k_q, (d, 3 * d), d),
+                      "conv": normal(k_k, (d, s.conv_L_cache),
+                                     s.conv_L_cache),
+                      "out_proj": normal(k_o, (d, d), d)}
+            else:
+                op = {"attn_norm": gain(),
+                      "q": normal(k_q, (d, hq), d),
+                      "k": normal(k_k, (d, hkv), d),
+                      "v": normal(k_v, (d, hkv), d),
+                      "o": normal(k_o, (hq, d), hq)}
+                if s.qk_norm:
+                    op.update(q_norm=gain(s.head_dim),
+                              k_norm=gain(s.head_dim))
             if layer_type == "sparse_attention":
                 hi, di = s.sa["indexer_num_heads"], s.sa["indexer_head_dim"]
                 # keys of their own: the other leaves draw what they drew
                 # before this layer type existed
                 k_q, k_k, k_w = jax.random.split(
                     jax.random.fold_in(key, i + 1), 3)
-                extra.update(
+                op.update(
                     index_q=normal(k_q, (d, hi * di), d),
                     index_k=normal(k_k, (d, di), d),
                     index_k_norm={**gain(di),
                                   "bias": jnp.zeros((di,), jnp.float32)},
                     index_w=normal(k_w, (d, hi), d))
-            params[f"layer_{i}"] = {
-                **extra,
-                "attn_norm": gain(), "moe_norm": gain(),
-                "q": normal(next(keys), (d, hq), d),
-                "k": normal(next(keys), (d, hkv), d),
-                "v": normal(next(keys), (d, hkv), d),
-                "o": normal(next(keys), (hq, d), hq),
-                "router": normal(next(keys), (d, s.num_experts), d),
-                "gate": normal(next(keys), (n, d, f), d),
-                "up": normal(next(keys), (n, d, f), d),
-                "down": normal(next(keys), (n, f, d), f),
-            }
+            if i < s.num_dense_layers:
+                wide = s.intermediate_size
+                ff = {"mlp_norm": gain(),
+                      "w1": normal(k_gate, (d, wide), d),
+                      "w3": normal(k_up, (d, wide), d),
+                      "w2": normal(k_down, (wide, d), wide)}
+            else:
+                router = normal(k_router, (d, s.num_experts), d)
+                if s.use_expert_bias:
+                    router["bias"] = jnp.zeros((s.num_experts,), jnp.float32)
+                ff = {"moe_norm": gain(), "router": router,
+                      "gate": normal(k_gate, (n, d, f), d),
+                      "up": normal(k_up, (n, d, f), d),
+                      "down": normal(k_down, (n, f, d), f)}
+            params[f"layer_{i}"] = {**op, **ff}
         return params
 
     def _qkv(self, p: dict, h, layer_type: str):
@@ -526,18 +638,48 @@ class SequenceTorso:
                             preferred_element_type=jnp.float32)
         return x, (counts, loss)
 
-    def _sequence(self, p: dict, x, layer_type: str, train: bool):
-        """One layer on one sequence: ``x [T, D] -> (x, counts, selected)``;
-        ``selected`` is ``()`` but for a sparse layer."""
+    def _conv(self, p: dict, x):
+        """LFM2's gated short convolution of one sequence ``x [T, D]``
+        added to it (ops/short_conv.py)."""
+        dtype = self.dtype
+        with jax.named_scope("torso.conv"):
+            h = rms_norm(x, p["conv_norm"]["scale"],
+                         self.spec.rms_norm_eps).astype(dtype)
+            bcu = jnp.dot(h, p["in_proj"]["kernel"],
+                          preferred_element_type=dtype)
+            y = conv_ops.gated_short_conv(bcu, p["conv"]["kernel"])
+            return x + jnp.dot(y, p["out_proj"]["kernel"],
+                               preferred_element_type=jnp.float32)
+
+    def _mlp(self, p: dict, x):
+        """The dense SwiGLU feed-forward of one sequence."""
+        with jax.named_scope("torso.mlp"):
+            h = rms_norm(x, p["mlp_norm"]["scale"],
+                         self.spec.rms_norm_eps).astype(self.dtype)
+            proj = lambda name: jnp.dot(  # noqa: E731
+                h, p[name]["kernel"], preferred_element_type=jnp.float32)
+            mid = (jax.nn.silu(proj("w1")) * proj("w3")).astype(self.dtype)
+            return jnp.dot(mid, p["w2"]["kernel"],
+                           preferred_element_type=jnp.float32)
+
+    def _sequence(self, p: dict, x, layer_type: str, dense: bool,
+                  train: bool):
+        """One layer on one sequence: ``x [T, D] -> (x, stats, selected)``;
+        ``stats`` is the router's (``{}`` of a dense layer), ``selected``
+        ``()`` but for a sparse layer."""
         selected = ()
-        if layer_type == "sparse_attention":
+        if layer_type == "conv":
+            x = self._conv(p, x)
+        elif layer_type == "sparse_attention":
             x, selected = self._attend_sparse(p, x, train)
         else:
             x = self._attend(p, x, layer_type)
+        if dense:
+            return x + self._mlp(p, x), {}, selected
         with jax.named_scope("torso.route"):
             h = rms_norm(x, p["moe_norm"]["scale"], self.spec.rms_norm_eps)
-        out, counts = self._experts(p, h)
-        return x + out, counts, selected
+        out, stats = self._experts(p, h)
+        return x + out, stats, selected
 
     def _experts(self, p: dict, h):
         """``expert_share`` of one sequence, ``EXPERT_TOKENS`` at a time
@@ -548,40 +690,44 @@ class SequenceTorso:
         t_len = h.shape[0]
         if t_len <= EXPERT_TOKENS or t_len % EXPERT_TOKENS:
             return share(h)
-        out, counts = jax.lax.map(jax.checkpoint(share), h.reshape(
+        out, stats = jax.lax.map(jax.checkpoint(share), h.reshape(
             -1, EXPERT_TOKENS, h.shape[-1]))
-        return out.reshape(h.shape), jnp.sum(counts, axis=0)
+        return out.reshape(h.shape), _summed(stats)
 
-    def _layer(self, p: dict, x, layer_type: str, train: bool):
+    def _layer(self, p: dict, x, layer_type: str, dense: bool, train: bool):
         """One layer on the batch, a sequence at a time. The compute-dtype
         copies of the matrices are made once, here, and live as long as
         the layer; each sequence is rematerialised on its own in the
         backward pass."""
         cast = {name: ({"kernel": leaf["kernel"].astype(self.dtype)}
-                       if "kernel" in leaf and name != "router" else leaf)
+                       if "kernel" in leaf and name not in ("router", "conv")
+                       else leaf)
                 for name, leaf in p.items()}
         per_seq = jax.checkpoint(
-            lambda xs: self._sequence(cast, xs, layer_type, train))
-        x, counts, selected = jax.lax.map(per_seq, x)
-        return x, jnp.sum(counts, axis=0), selected
+            lambda xs: self._sequence(cast, xs, layer_type, dense, train))
+        x, stats, selected = jax.lax.map(per_seq, x)
+        return x, _summed(stats), selected
 
     def apply(self, params: dict, obs, train: bool = False):
         s = self.spec
         with jax.named_scope("torso.embed"):
             tokens = tokenise(s, obs)
             x = params["embed"]["kernel"][tokens]
-        counts, selected = [], []
+        stats, selected = [], []
         for i, layer_type in enumerate(s.layer_types):
             layer = jax.checkpoint(
-                lambda p, x, lt=layer_type: self._layer(p, x, lt, train))
-            x, c, sel = layer(params[f"layer_{i}"], x)
-            counts.append(c)
+                lambda p, x, lt=layer_type, dense=i < s.num_dense_layers:
+                self._layer(p, x, lt, dense, train))
+            x, st, sel = layer(params[f"layer_{i}"], x)
+            if st:
+                stats.append(st)
             if sel:
                 selected.append(sel)
         with jax.named_scope("torso.pool"):
             x = rms_norm(x, params["final_norm"]["scale"], s.rms_norm_eps)
             latent = jnp.mean(x, axis=1)
-        aux = {"route_counts": jnp.stack(counts)}
+        aux = {name: jnp.stack([st[name] for st in stats])
+               for name in stats[0]}
         if selected and train:
             with jax.named_scope("torso.indexer"):
                 aux["select_counts"] = jnp.stack(
@@ -589,6 +735,32 @@ class SequenceTorso:
                 aux["index_loss"] = jnp.mean(jnp.stack(
                     [loss for _c, loss in selected])) / s.tokens
         return latent, aux
+
+    def balance(self, params: dict, route_counts):
+        """The load-balancing rule on the routers' biases (DeepSeek-V3's,
+        arXiv:2412.19437 sec. 2.1.2): ``bias += bias_update_rate *
+        sign(mean(n) - n)``, ``n`` the ``route_counts [expert layers,
+        num_experts]`` of the tokens here. No loss trains the bias and no
+        optimizer steps it; a torso without one comes back as it is."""
+        s = self.spec
+        if not s.use_expert_bias:
+            return params
+        n = route_counts.astype(jnp.float32)
+        move = s.bias_update_rate * jnp.sign(
+            jnp.mean(n, axis=-1, keepdims=True) - n)
+        out = dict(params)
+        for row, i in enumerate(s.expert_layers):
+            layer = params[f"layer_{i}"]
+            router = layer["router"]
+            out[f"layer_{i}"] = {**layer, "router": {
+                **router, "bias": router["bias"] + move[row]}}
+        return out
+
+
+def _summed(stats: dict) -> dict:
+    """A layer's counters summed over the leading axis ``lax.map`` gave
+    them."""
+    return {name: jnp.sum(c, axis=0) for name, c in stats.items()}
 
 
 class TorsoCritic:
@@ -612,6 +784,13 @@ class TorsoCritic:
         """``(latent, aux)`` of the torso in ``params``."""
         return self.torso.apply(params["params"]["torso"], obs, train)
 
+    def balance(self, params, route_counts):
+        """``params`` with the torso's routing biases moved by its
+        load-balancing rule (``SequenceTorso.balance``)."""
+        inner = params["params"]
+        return {**params, "params": {**inner, "torso": self.torso.balance(
+            inner["torso"], route_counts)}}
+
     def of_latent(self, params, latent, action, logits: bool = False):
         """The head in ``params`` on a latent."""
         return self.head.apply({"params": params["params"]["critic"]},
@@ -622,7 +801,8 @@ class TorsoCritic:
                               return_logits)
 
 
-TORSOS = {"mellum2": SequenceTorso, "keye2": SequenceTorso}
+TORSOS = {"mellum2": SequenceTorso, "keye2": SequenceTorso,
+          "lfm2": SequenceTorso}
 
 
 def build_torso(spec: TorsoSpec, dtype=jnp.float32):

@@ -8,4 +8,6 @@
   blockwise ``jax.numpy`` one, the indexer's alignment loss).
 - ``grouped``: the experts' grouped matrix products, ``ragged_dot`` or
   megablox ``gmm`` on a TPU.
+- ``short_conv``: LFM2's gated depthwise causal convolution of a few taps,
+  plain ``jax.numpy`` that XLA fuses on every platform.
 """

@@ -262,29 +262,58 @@ def _layer_inputs(bias=None):
     return p, h
 
 
+# the hybrid model's router (models/torso.route, "sigmoid" with a routing
+# bias) and what every chip of it computes alike, a dense feed-forward
+LFM2 = dict(router_scores="sigmoid", use_expert_bias=True,
+            bias_update_rate=1e-3, rms_norm_eps=1e-5)
+
+
+@pytest.mark.parametrize("router", ["softmax", "sigmoid"])
 @pytest.mark.parametrize("shares", [4, 8], ids=["four", "eight"])
 @pytest.mark.parametrize("bias", [None, 50.0], ids=["seeded", "biased"])
-def test_the_four_shares_add_up_to_the_uncut_layer(bias, shares):
+def test_the_four_shares_add_up_to_the_uncut_layer(bias, shares, router):
     """Each share routes over all 8 experts and computes its own (2 of four
-    shares, as ``humanoid-mellum2-ep4`` stands for; 1 of eight, as
-    ``humanoid-keye2-ep8``); summed they are the reference's whole layer,
-    and the assignments the shares computed are all of them, even when two
-    experts get every token."""
+    shares, as ``humanoid-mellum2-ep4`` and ``humanoid-lfm2-ep4`` stand for;
+    1 of eight, as ``humanoid-keye2-ep8``); summed they are the reference's
+    whole layer, and the assignments the shares computed are all of them,
+    even when two experts get every token. ``sigmoid``: LFM2's router with
+    its selection bias, and the dense feed-forward that every chip computes
+    alike counted once."""
     p, h = _layer_inputs(bias)
-    w, e, counts = rt.route(SMALL, h, p["router"]["kernel"])
-    whole = rt.experts(rt.EXACT_OPS, SMALL, p, h, w, e, held=(0, 8))
-    total, computed = jnp.zeros_like(h), 0
+    if router == "sigmoid":
+        from benchmark import reference_hybrid as rh
+
+        k = jax.random.split(jax.random.key(12), 4)
+        p["router"]["bias"] = 0.05 * jax.random.normal(k[0], (8,))
+        if bias is not None:  # saturated scores tie: the bias decides
+            p["router"]["bias"] = p["router"]["bias"].at[:2].add(10.0)
+        dense = {name: {"kernel": jax.random.normal(k[i], shape) / 8}
+                 for i, (name, shape) in enumerate(
+                     (("w1", (64, 96)), ("w3", (64, 96)), ("w2", (96, 64))),
+                     1)}
+        over, block = LFM2, {**SMALL, **LFM2}
+        w, e, counts, swapped = rh.route(block, h, p["router"])
+        alike = rh.dense_ff(rh.EXACT_OPS, dense, h)
+    else:
+        over, block = {}, SMALL
+        w, e, counts = rt.route(SMALL, h, p["router"]["kernel"])
+        alike = jnp.zeros_like(h)
+    whole = alike + rt.experts(rt.EXACT_OPS, block, p, h, w, e, held=(0, 8))
+    total, computed = alike, 0  # counted once, not once a share
     for index in range(shares):
         lo, hi = partition.expert_share(8, shares, index)
-        spec = small_config(experts_held=[lo, hi]).torso
+        spec = small_config(experts_held=[lo, hi], **over).torso
         mine = {"router": p["router"], **{
             name: {"kernel": p[name]["kernel"][lo:hi]}
             for name in ("gate", "up", "down")}}
-        out, seen = torso_lib.expert_share(spec, mine, h, jnp.float32)
+        out, stats = torso_lib.expert_share(spec, mine, h, jnp.float32)
+        seen = stats["route_counts"]
         # every share sees the same routing over all the experts
         np.testing.assert_array_equal(np.asarray(seen), np.asarray(counts))
+        if router == "sigmoid":
+            assert int(stats["bias_swapped"]) == int(swapped)
         # and gives what the reference gives for its experts alone
-        part = rt.experts(rt.EXACT_OPS, SMALL, mine, h, w, e, held=(lo, hi))
+        part = rt.experts(rt.EXACT_OPS, block, mine, h, w, e, held=(lo, hi))
         np.testing.assert_allclose(np.asarray(out), np.asarray(part),
                                    rtol=1e-4, atol=1e-5)
         total = total + out

@@ -434,8 +434,8 @@ def test_the_expert_layer_a_part_of_the_sequence_at_a_time_is_the_whole(
 
     def run():
         def loss(p, h):
-            out, counts = torso._experts(p, h)
-            return jnp.sum(jnp.square(out)), counts
+            out, stats = torso._experts(p, h)
+            return jnp.sum(jnp.square(out)), stats["route_counts"]
         (value, counts), grads = jax.value_and_grad(
             loss, argnums=(0, 1), has_aux=True)(p, h)
         return value, counts, grads

@@ -170,8 +170,8 @@ def test_a_sparse_attention_layer_and_its_backward_compile_at_real_widths(
     x = jax.ShapeDtypeStruct((1, 16384, 2048), jnp.float32)
 
     def loss(p, x):
-        out, _counts, (_selected, index_loss) = torso._layer(
-            p, x, "sparse_attention", True)
+        out, _stats, (_selected, index_loss) = torso._layer(
+            p, x, "sparse_attention", False, True)
         return jnp.sum(out) + jnp.sum(index_loss)
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
@@ -181,6 +181,47 @@ def test_a_sparse_attention_layer_and_its_backward_compile_at_real_widths(
     # the every-assignment buffer is a 4,096-token part's, not a sequence's
     assert "[131072,2048]" not in text and "[32768,2048]" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 7e9
+
+
+@pytest.mark.parametrize("index, layer_type, kernels", [
+    (0, "conv", ()), (1, "full_attention", ("splash", "gmm")),
+    (2, "conv", ("gmm",))], ids=["conv-dense", "attention-experts",
+                                 "conv-experts"])
+def test_each_kind_of_lfm2_layer_and_its_backward_compile_at_real_widths(
+        one_chip, monkeypatch, index, layer_type, kernels):
+    """One layer of ``humanoid-lfm2-ep4`` on one 8,192-token sequence,
+    differentiated: the short convolution's projections, gates and taps and
+    the dense feed-forward of 7,168 (plain XLA, no kernel); the splash kernel
+    at 8 key/value heads of 64 with 4 queries each; the grouped products at
+    2048 x 1792, the first whose tile does not hold K whole, 4,096 tokens at
+    a time. (The whole chunk of that cell compiles in ~80 s here: PR 34's
+    builder did it by hand, not this suite.)"""
+    with open(os.path.join(
+            REPO, "benchmark/configs/humanoid-lfm2-ep4.json")) as f:
+        model = json.load(f)["model"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    config = D4PGConfig(**model)
+    torso = config.build_critic().torso
+    assert torso.attention_impl() == "splash" \
+        and torso.grouped_impl() == "megablox"
+    params = jax.eval_shape(lambda: torso.init(jax.random.key(0)))[
+        f"layer_{index}"]
+    x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.float32)
+    dense = index < config.torso.num_dense_layers
+
+    def loss(p, x):
+        out, _stats, _selected = torso._layer(p, x, layer_type, dense, True)
+        return jnp.sum(out)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        *on(one_chip, (params, x))).compile()
+    text = compiled.as_text()
+    for kernel in ("splash", "gmm"):
+        assert (kernel in text) == (kernel in kernels), kernel
+    assert "ragged-dot" not in text
+    if "gmm" in kernels:  # a 4,096-token part's every-assignment buffer
+        assert "[16384,2048]" in text and "[32768,2048]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
 
 
 @pytest.mark.parametrize("batch", [256, 4096], ids=["chunk", "commit"])
