@@ -68,9 +68,14 @@ def fused_chunk_step(
         # (obs/trace.compiled_text); PERF.md section 3 names the metrics.
         with jax.named_scope("replay.sample"):
             if trees is not None:
-                idx = dper.sample(trees, k_sample, batch_size, size)
-                beta = dper.beta_schedule(state.step, beta0, beta_steps)
-                w = dper.is_weights(trees, idx, beta, size)
+                # the two halves of the phase, for whoever reads a trace
+                # by hand (PERF.md section 5); no metric reads them
+                with jax.named_scope("sample.descend"):
+                    idx = dper.sample(trees, k_sample, batch_size, size)
+                with jax.named_scope("sample.weights"):
+                    beta = dper.beta_schedule(state.step, beta0,
+                                              beta_steps)
+                    w = dper.is_weights(trees, idx, beta, size)
             else:
                 idx = jax.random.randint(k_sample, (batch_size,), 0,
                                          jnp.maximum(size, 1))
@@ -184,7 +189,8 @@ def make_sharded_fused_chunk(
     def _local_sample_per(trees, storage, size, key, beta):
         ax = jax.lax.axis_index(DATA_AXIS)
         t = _local_trees(trees)
-        with jax.named_scope("replay.sample"):
+        with jax.named_scope("replay.sample"), \
+                jax.named_scope("sample.descend"):
             idx = dper.sample(t, jax.random.fold_in(key, ax), b_local,
                               size[0])
         with jax.named_scope("replay.gather"):
@@ -193,7 +199,8 @@ def make_sharded_fused_chunk(
         # The reference weight is (N_rows * q)^-beta / (N_rows * q_min)^-beta
         # — N_rows cancels, so no psum of sizes is needed; only the global
         # minimum per-draw probability crosses shards (one pmin scalar).
-        with jax.named_scope("replay.sample"):
+        with jax.named_scope("replay.sample"), \
+                jax.named_scope("sample.weights"):
             total = jnp.maximum(t.sum_tree[1], 1e-30)
             q = t.sum_tree[t.capacity + idx] / total / n_shards
             q_min = jax.lax.pmin(t.min_tree[1] / total / n_shards,
@@ -236,7 +243,8 @@ def make_sharded_fused_chunk(
             # prologue samples and gathers in one call, so those two
             # scopes sit inside its local functions
             if prioritized:
-                with jax.named_scope("replay.sample"):
+                with jax.named_scope("replay.sample"), \
+                        jax.named_scope("sample.weights"):
                     beta = dper.beta_schedule(state.step, beta0,
                                               beta_steps)
                 batch, w, idx = sample_per(trees, storage, size,

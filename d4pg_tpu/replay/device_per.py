@@ -28,7 +28,11 @@ array of ``2 * capacity`` (power of two) nodes, root at 1, leaf ``i`` at
     Which level that is follows from the static capacity and B
     (``_scatter_levels``);
   - ``sample``: B stratified inverse-CDF queries descend in lock-step,
-    log2(N) gather/where rounds;
+    seven levels at a gather: the 128 nodes seven levels below node ``n``
+    are row ``n`` of the tree read as ``[2N / 128, 128]``, and the six
+    levels between are that row's pairwise sums again (``descend``:
+    log2(N) / 7 row gathers and log2(N) compare/where rounds, the slots
+    of a walk that gathers B scalars a level);
   - trees are float32 (device-friendly); with ~1e6 leaves the prefix-sum
     rounding error is ~1e-7 of total mass per level — sampling noise well
     below the stochasticity already present. IS weights read exact leaf
@@ -231,6 +235,56 @@ def strata_mass(u: Array, total: Array) -> Array:
     return (jnp.arange(b) + u) * (total / b)
 
 
+_ROW_LEVELS = _LANES.bit_length() - 1  # 7: binary levels a row spans
+
+
+def _walk_row(row: Array, p: Array) -> tuple[Array, Array]:
+    """The binary decisions below a node whose ``w`` descendants log2(w)
+    levels down hold ``row`` ([B, w], or [1, w] where every query stands
+    at one node), for prefix masses ``p`` ([B]): ``(p, pos)``, ``pos``
+    the descendant each query reaches and ``p`` what is left of its mass.
+
+    Nothing is looked up. Every lane ``j`` of the row walks the path to
+    descendant ``j``, all of them at once:
+
+    - the levels between node and row are rebuilt from the row, in place
+      on its lanes: ``left`` holds, in lane ``j``, the sum under the LEFT
+      child of ``j``'s ancestor at that level, and ``left + right`` the
+      sum under the ancestor: one float32 ``+`` of two children, the add
+      ``set_leaves`` made (its invariant), never a longer sum (a reduction
+      XLA may merge with the next level's rounds differently; PR 29);
+    - from the top down each lane makes the walk's own compare and
+      subtract against its ancestor's left child. Lanes under one node
+      hold one mass, so they decide alike, and the half whose own bit is
+      the other way leave the path: one lane is left on it, the one the
+      level-by-level walk reaches, with that walk's mass to the bit. The
+      two sums that read it off are exact (every other term is 0).
+
+    Of the forms measured on the v5e (PERF.md, PR 35) this is the one the
+    chunk runs fastest: elementwise work on [B, 128] that fuses, against
+    a compare-select-sum a level for a looked-up ``left`` (as fast alone,
+    0.7 ms a 40-step chunk slower: with it the compiler moves both trees
+    into ``S(1)`` behind the update, where they wait for each other) and
+    ``take_along_axis``, a lane gather (3 times the walk's time)."""
+    width = row.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    steps, under, span = [], row, 1
+    while span < width:
+        is_right = (lane & span) != 0  # lane j's ancestor at this level
+        left = jnp.where(is_right, jnp.roll(under, span, axis=-1), under)
+        right = jnp.where(is_right, under, jnp.roll(under, -span, axis=-1))
+        steps.insert(0, (left, is_right))
+        under, span = left + right, 2 * span
+    mass = jnp.broadcast_to(p[:, None], (p.shape[0], width))
+    on_path = jnp.ones(mass.shape, bool)
+    for left, is_right in steps:  # from the top down
+        go_right = mass >= left
+        on_path &= go_right == is_right
+        mass = jnp.where(go_right, mass - left, mass)
+    pos = jnp.sum(jnp.where(on_path, lane, 0), axis=-1)
+    return jnp.sum(jnp.where(on_path, mass, jnp.float32(0)), axis=-1), pos
+
+
 def descend(sum_tree: Array, mass: Array) -> Array:
     """Lock-step inverse-CDF descent of prefix masses ``mass`` (any
     shape) through ``sum_tree`` ([2 * capacity]); returns leaf slots.
@@ -243,17 +297,34 @@ def descend(sum_tree: Array, mass: Array) -> Array:
     RIGHT subtree — in particular a zero-mass query at a zero-priority
     left leaf skips to the first nonzero leaf, and duplicate prefix
     values (two strata colliding after float rounding) resolve to the
-    same slot on host and device alike."""
+    same slot on host and device alike.
+
+    HOW THE TREE IS READ. Not a node a level (log2(capacity) dependent
+    gathers of B scalars, ~7 us each at 2^21 leaves on the v5e) but a row
+    of ``_LANES`` nodes every ``_ROW_LEVELS`` levels: in the flat heap the
+    128 descendants seven levels below node ``n`` are nodes ``128 n ..
+    128 n + 127``, row ``n`` of ``sum_tree.reshape(-1, 128)`` (the same
+    bytes: no copy), and ``set_leaves``'s invariant lets :func:`_walk_row`
+    rebuild the six levels between from the row. The levels left over
+    when log2(capacity) is no multiple of seven come first, from ONE
+    static slice under the root (a tree of under 128 leaves is that slice
+    and nothing else); the step count follows the static capacity, so
+    there is one compiled walk a (capacity, B). Every decision is the
+    float32 compare and subtract the level-by-level walk makes, on the
+    same values in the same order, so the slots are that walk's to the
+    bit (tests/test_device_per.py keeps it as the oracle)."""
     cap = sum_tree.shape[0] // 2
-    p = mass
-    node = jnp.ones(mass.shape, jnp.int32)
-    for _ in range(_levels(cap)):
-        left = node << 1
-        left_sum = sum_tree[left]
-        go_right = p >= left_sum
-        p = jnp.where(go_right, p - left_sum, p)
-        node = jnp.where(go_right, left | 1, left)
-    return node - cap
+    levels = _levels(cap)
+    first = min(levels, levels % _ROW_LEVELS or _ROW_LEVELS)
+    p = mass.reshape(-1)
+    node = jnp.ones(p.shape, jnp.int32)
+    if first:
+        p, pos = _walk_row(sum_tree[1 << first:2 << first][None], p)
+        node = (1 << first) + pos
+    for _ in range((levels - first) // _ROW_LEVELS):
+        p, pos = _walk_row(sum_tree.reshape(-1, _LANES)[node], p)
+        node = node * _LANES + pos
+    return (node - cap).reshape(mass.shape)
 
 
 def sample_from_uniforms(trees: PerTrees, u: Array, limit: Array) -> Array:

@@ -106,13 +106,8 @@ def test_set_leaves_traces_at_production_capacity():
     """The pad sentinel must not overflow int32 at real buffer sizes
     (1M-slot ring -> tree capacity 2^20): trace-only check."""
     cap = 1 << 20
-    t = dper.PerTrees(
-        jax.ShapeDtypeStruct((2 * cap,), jnp.float32),
-        jax.ShapeDtypeStruct((2 * cap,), jnp.float32),
-        jax.ShapeDtypeStruct((), jnp.float32),
-    )
     out = jax.eval_shape(
-        dper.set_leaves, t,
+        dper.set_leaves, _abstract_trees(cap),
         jax.ShapeDtypeStruct((256,), jnp.int32),
         jax.ShapeDtypeStruct((256,), jnp.float32))
     assert out.sum_tree.shape == (2 * cap,)
@@ -263,13 +258,16 @@ def test_set_leaves_duplicates_agree_between_the_trees(rng):
     assert s[0] == 0.0 and m[0] == np.inf  # node 0 belongs to no level
 
 
-def _lowered_counts(cap, batch):
-    t = dper.PerTrees(
+def _abstract_trees(cap):
+    return dper.PerTrees(
         jax.ShapeDtypeStruct((2 * cap,), jnp.float32),
         jax.ShapeDtypeStruct((2 * cap,), jnp.float32),
         jax.ShapeDtypeStruct((), jnp.float32))
+
+
+def _lowered_counts(cap, batch):
     text = jax.jit(dper.set_leaves).lower(
-        t, jax.ShapeDtypeStruct((batch,), jnp.int32),
+        _abstract_trees(cap), jax.ShapeDtypeStruct((batch,), jnp.int32),
         jax.ShapeDtypeStruct((batch,), jnp.float32)).as_text()
     return (text.count('"stablehlo.scatter"('),
             text.count('"stablehlo.reduce_window"('))
@@ -304,6 +302,266 @@ def test_set_leaves_scatter_count_follows_the_batch():
     assert _lowered_counts(1 << 21, 64) == (2 * 2 + 2, 19)
     assert _lowered_counts(1 << 16, 512) == (2, 16)  # the pixel cell
     assert _lowered_counts(1 << 15, 4) == (2, 15)  # cell 4
+
+
+def _walk_level_by_level(sum_tree, mass):
+    """The ORACLE: the descent ``descend`` was before it read the tree a
+    row at a time (PR 35), kept here verbatim: one gather of a scalar a
+    query at every level, at the node the level above chose."""
+    cap = sum_tree.shape[0] // 2
+    p = mass
+    node = jnp.ones(mass.shape, jnp.int32)
+    for _ in range(int(math.log2(cap))):
+        left = node << 1
+        left_sum = sum_tree[left]
+        go_right = p >= left_sum
+        p = jnp.where(go_right, p - left_sum, p)
+        node = jnp.where(go_right, left | 1, left)
+    return node - cap
+
+
+_WALK_ORACLE_JIT = jax.jit(_walk_level_by_level)
+_DESCEND_JIT = jax.jit(dper.descend)
+# every log2(capacity) mod 7 from 0 to 6: 1, 4, 6, 0, 1, 5, 0, 1, 2, 0
+DESCEND_CAPS = (2, 16, 64, 128, 256, 4096, 1 << 14, 1 << 15, 1 << 16,
+                1 << 21)
+
+
+def _priorities(cap, rng, zero_share=0.1):
+    vals = rng.uniform(0.01, 5.0, cap).astype(np.float32)
+    vals[rng.random(cap) < zero_share] = 0.0
+    return vals
+
+
+def _full_trees(vals):
+    return _NEW_JIT(dper.init(vals.size), jnp.arange(vals.size),
+                    jnp.asarray(vals))
+
+
+def _masses(sum_tree, shape, rng):
+    u = jnp.asarray(rng.random(shape), jnp.float32)
+    return dper.strata_mass(u, sum_tree[1])
+
+
+def _assert_same_slots(got, want):
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == want.shape
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("jit", (False, True), ids=("eager", "jit"))
+@pytest.mark.parametrize("blocks", (None, 3), ids=("B", "blocksxB"))
+@pytest.mark.parametrize("cap", DESCEND_CAPS)
+def test_descend_is_bitwise_the_level_by_level_walk(cap, blocks, jit, rng):
+    """The slots of the row-at-a-time walk are those of (a) the
+    level-by-level walk and (b) the float32 host twin's
+    ``find_prefixsum`` (``ShardSlicePerTrees``, bitwise
+    ``SumTree.find_prefixsum`` over float32 nodes), with a tenth of the
+    leaves zero, for ``mass`` of shape [B] and [blocks, B]."""
+    from d4pg_tpu.replay.sampler import ShardSlicePerTrees
+
+    vals = _priorities(cap, rng)
+    trees = _full_trees(vals)
+    batch = min(64, 2 * cap)
+    mass = _masses(trees.sum_tree,
+                   (batch,) if blocks is None else (blocks, batch), rng)
+    new, oracle = ((_DESCEND_JIT, _WALK_ORACLE_JIT) if jit
+                   else (dper.descend, _walk_level_by_level))
+    got = new(trees.sum_tree, mass)
+    assert got.dtype == jnp.int32
+    _assert_same_slots(got, oracle(trees.sum_tree, mass))
+    twin = ShardSlicePerTrees(cap, 1, dtype=np.float32)
+    twin.set(np.arange(cap), vals)
+    assert np.float32(twin.total()) == np.asarray(trees.sum_tree[1])
+    _assert_same_slots(got, twin.find_prefixsum(np.asarray(mass))
+                       .astype(np.int32))
+
+
+@pytest.mark.parametrize("cap", (16, 256, 1 << 15, 1 << 16))
+def test_descend_under_vmap_over_a_shard_axis(cap, rng):
+    """The sharded chunk's shape: trees stacked on a leading shard axis,
+    each shard's queries through its own tree."""
+    shards = [_full_trees(_priorities(cap, rng)).sum_tree for _ in range(3)]
+    stacked = jnp.stack(shards)
+    mass = jnp.stack([_masses(t, (32,), rng) for t in shards])
+    got = jax.jit(jax.vmap(dper.descend))(stacked, mass)
+    want = jnp.stack([_WALK_ORACLE_JIT(t, m) for t, m in zip(shards, mass)])
+    _assert_same_slots(got, want)
+
+
+@pytest.mark.parametrize("depth", range(1, 15))
+def test_descend_goes_right_on_a_tie_at_every_level(depth, rng):
+    """A query EQUAL to a left subtree's sum descends right (the TIE RULE),
+    at each of the seven levels of the slice under the root and of the one
+    gathered row of a 2^14-leaf tree. Integer priorities keep every sum
+    exact: over all-ones leaves the mass ``2^(14 - depth)`` meets its tie
+    ``depth`` levels down and lands on leaf ``2^(14 - depth)``; over
+    random small integers every prefix sum is a tie somewhere, and lands
+    on the next leaf that has any mass."""
+    cap = 1 << 14
+    ones = _full_trees(np.ones(cap, np.float32)).sum_tree
+    tie = jnp.full((8,), float(cap >> depth), jnp.float32)
+    _assert_same_slots(_DESCEND_JIT(ones, tie),
+                       np.full(8, cap >> depth, np.int32))
+    vals = rng.integers(0, 4, cap).astype(np.float32)
+    vals[-1] = 1.0
+    tree = _full_trees(vals).sum_tree
+    prefix = np.cumsum(vals, dtype=np.float64)
+    # ties whose deciding node is `depth` levels down: the prefix sums
+    # at the right edge of each left subtree at that depth
+    edges = np.arange(cap >> depth, cap, cap >> (depth - 1))[:64] - 1
+    mass = jnp.asarray(prefix[edges], jnp.float32)
+    got = _DESCEND_JIT(tree, mass)
+    _assert_same_slots(got, _WALK_ORACLE_JIT(tree, mass))
+    _assert_same_slots(got, np.searchsorted(prefix, prefix[edges],
+                                            side="right").astype(np.int32))
+    assert (np.asarray(got) > edges).all()  # never the left subtree
+
+
+@pytest.mark.parametrize("cap", (16, 128, 4096, 1 << 15))
+def test_descend_past_the_total_lands_on_the_last_leaf(cap, rng):
+    """``mass >= total`` goes right at every node, onto the last leaf;
+    ``sample_from_uniforms`` then clips it onto the written ones."""
+    vals = rng.integers(1, 4, cap).astype(np.float32)
+    trees = _full_trees(vals)
+    total = trees.sum_tree[1]
+    mass = jnp.stack([total, 2 * total, jnp.float32(jnp.inf)])
+    got = _DESCEND_JIT(trees.sum_tree, mass)
+    _assert_same_slots(got, _WALK_ORACLE_JIT(trees.sum_tree, mass))
+    _assert_same_slots(got, np.full(3, cap - 1, np.int32))
+    limit = jnp.int32(cap // 2)
+    u = jnp.full((8,), np.nextafter(np.float32(1), np.float32(0)))
+    idx = np.asarray(dper.sample_from_uniforms(trees, u, limit))
+    assert idx.max() == cap // 2 - 1 and idx.min() >= 0
+
+
+@pytest.mark.parametrize("ratio", (1, 64, None),
+                         ids=("9_scattered", "3_scattered", "all_dense"))
+def test_descend_after_fifty_set_leaves_and_inserts(ratio, rng, monkeypatch):
+    """The pairwise rebuild inside a row relies on ``set_leaves``'s
+    invariant; it holds after fifty random ``set_leaves`` / ``insert``
+    calls through the scattered and the dense repair alike (constants
+    turned down as in the join test above; ``None`` leaves them)."""
+    cap = 4096
+    if ratio is not None:
+        monkeypatch.setattr(dper, "_DENSE_NODES_PER_LEAF", ratio)
+        monkeypatch.setattr(dper, "_DENSE_MIN_BATCH", 1)
+    set_leaves = jax.jit(dper.set_leaves)  # traced under these constants
+    insert = jax.jit(dper.insert, static_argnames=("alpha",))
+    trees = dper.init(cap)
+    for call in range(50):
+        idx = jnp.asarray(rng.integers(0, cap, 4), jnp.int32)
+        if call % 2:
+            trees = insert(trees, idx, alpha=0.6)
+        else:
+            p = rng.uniform(0.0, 5.0, 4) * (rng.random(4) > 0.1)
+            trees = set_leaves(trees, idx, jnp.asarray(p, jnp.float32))
+            trees = trees._replace(max_priority=jnp.float32(1 + call))
+        if call % 7 == 0 or call == 49:
+            mass = _masses(trees.sum_tree, (64,), rng)
+            _assert_same_slots(_DESCEND_JIT(trees.sum_tree, mass),
+                               _WALK_ORACLE_JIT(trees.sum_tree, mass))
+
+
+def _sample_and_weigh(descend, batch):
+    """``sample`` + ``is_weights`` as the chunk calls them, over a given
+    descent."""
+    def fn(trees, key, size):
+        u = jax.random.uniform(key, (batch,))
+        idx = descend(trees.sum_tree,
+                      dper.strata_mass(u, trees.sum_tree[1]))
+        idx = jnp.minimum(idx, jnp.maximum(size - 1, 0))
+        return idx, dper.is_weights(trees, idx, jnp.float32(0.5), size)
+    return fn
+
+
+def _tree_gathers(fn, cap):
+    """(row gathers, scalar gathers) out of a ``2 * cap``-node tree in
+    ``fn``'s lowered module (trace and lower only)."""
+    text = jax.jit(fn).lower(
+        _abstract_trees(cap),
+        jax.ShapeDtypeStruct((), jax.random.key(0).dtype),
+        jax.ShapeDtypeStruct((), jnp.int32)).as_text()
+    gathers = [ln for ln in text.splitlines() if '"stablehlo.gather"(' in ln]
+    rows = [ln for ln in gathers
+            if f"(tensor<{2 * cap // 128}x128xf32>," in ln]
+    scalars = [ln for ln in gathers if f"(tensor<{2 * cap}xf32>," in ln]
+    assert len(rows) + len(scalars) == len(gathers)
+    assert all("slice_sizes = array<i64: 1, 128>" in ln for ln in rows)
+    assert all("slice_sizes = array<i64: 1>" in ln for ln in scalars)
+    return len(rows), len(scalars)
+
+
+@pytest.mark.parametrize("levels, batch", [(21, 256), (16, 512)],
+                         ids=("mlp_cells", "pixel_cell"))
+def test_sample_gathers_rows_not_scalars_at_production_capacity(levels,
+                                                                batch):
+    """The structure of the walk, read off the lowered module: at the
+    benchmark's shapes two gathers of B rows of 128 nodes (21 = 7 static
+    + 2 x 7; 16 = 2 static + 2 x 7) and the one scalar gather of
+    ``is_weights``'s leaves, where the level-by-level walk has a scalar
+    gather a level and that one."""
+    cap = 1 << levels
+    assert _tree_gathers(_sample_and_weigh(dper.descend, batch), cap) \
+        == (2, 1)
+    assert _tree_gathers(_sample_and_weigh(_walk_level_by_level, batch),
+                         cap) == (0, levels + 1)
+    # what dper.sample itself lowers to is the walk counted above
+    direct = lambda t, k, n: (  # noqa: E731
+        dper.sample(t, k, batch, n), jnp.float32(0))
+    assert _tree_gathers(direct, cap) == (2, 0)
+
+
+@pytest.mark.parametrize("levels, rows", [(1, 0), (6, 0), (7, 0), (8, 1),
+                                          (14, 1), (15, 2), (22, 3)])
+def test_descend_row_gathers_follow_the_capacity(levels, rows):
+    """``log2(capacity) // 7`` row gathers, one fewer where the levels
+    divide by seven (the first row is then the static slice); none under
+    256 leaves."""
+    cap = 1 << levels
+    text = jax.jit(dper.descend).lower(
+        jax.ShapeDtypeStruct((2 * cap,), jnp.float32),
+        jax.ShapeDtypeStruct((32,), jnp.float32)).as_text()
+    assert text.count('"stablehlo.gather"(') == rows
+    assert rows == (levels - 1) // 7
+
+
+@pytest.mark.parametrize("levels, batch", [(21, 256), (16, 512)],
+                         ids=("mlp_cells", "pixel_cell"))
+def test_the_scan_body_never_copies_the_tree_for_its_row_view(levels, batch):
+    """``[2N] -> [2N / 128, 128]`` is the same bytes. Compiled (here for
+    the CPU; ``tests/test_torso_v5e_compile.py`` asks the chip's compiler)
+    inside a scan over donated, loop-carried trees that samples, weighs
+    and writes back, the program makes a whole tree only where the
+    write-back does: no copy, transpose or convert of one, and no more
+    whole-tree instructions than the same loop over the level-by-level
+    walk has."""
+    import re
+
+    cap = 1 << levels
+
+    def whole_tree_outputs(descend):
+        def loop(trees, key, size):
+            def body(carry, _):
+                trees, key = carry
+                key, k = jax.random.split(key)
+                idx, w = _sample_and_weigh(descend, batch)(trees, k, size)
+                return (dper.update_from_td(trees, idx, w, 0.6), key), idx
+            return jax.lax.scan(body, (trees, key), None, length=4)
+
+        text = jax.jit(loop, donate_argnums=(0,)).lower(
+            _abstract_trees(cap),
+            jax.ShapeDtypeStruct((), jax.random.key(0).dtype),
+            jax.ShapeDtypeStruct((), jnp.int32)).compile().as_text()
+        made = re.findall(
+            r"= f32\[(?:%d|%d,128)\]\S* ([\w\-]+)\(" % (2 * cap,
+                                                       2 * cap // 128), text)
+        return sorted(op for op in made if op not in (
+            "parameter", "get-tuple-element", "bitcast", "reshape"))
+
+    new = whole_tree_outputs(dper.descend)
+    assert new and not {"copy", "transpose", "convert"} & set(new)
+    assert len(new) <= len(whole_tree_outputs(_walk_level_by_level))
 
 
 def test_commit_program_compiles_once_across_block_shapes(rng):

@@ -256,6 +256,59 @@ def test_the_dense_tree_repair_updates_the_trees_in_place_on_the_chip(
     assert sum(" reduce-window(" in ln for ln in lines) == 21
 
 
+@pytest.mark.parametrize("levels, batch", [(21, 256), (16, 512)],
+                         ids=["mlp_cells", "pixel_cell"])
+def test_the_descent_reads_rows_of_the_tree_in_place_on_the_chip(
+        one_chip, levels, batch):
+    """``descend`` reads the sum tree as ``[2N / 128, 128]`` rows: the same
+    bytes under the chip's tilings (``T(1024)`` and ``T(8,128)``), so the
+    compiler must answer the view with a bitcast, not with a copy of the
+    tree a step. Sample, weights and write-back in a scan over donated,
+    loop-carried trees at the cells' shapes: nothing in the loop body makes
+    a whole tree but the write-back's two scatters and two slice updates
+    (and the moves in and out of ``S(1)``: ``copy-done`` and a
+    ``ConcatBitcast`` of four parts, none of them a ``copy``), and
+    sampling gathers from the tree twice by the row and once by the leaf,
+    where the level-by-level walk gathered ``levels + 1`` times (PR 35)."""
+    import re
+
+    cap = 1 << levels
+
+    def loop(trees, key, size):
+        def body(carry, _):
+            trees, key = carry
+            key, k = jax.random.split(key)
+            idx = dper.sample(trees, k, batch, size)
+            w = dper.is_weights(trees, idx, jnp.float32(0.5), size)
+            return (dper.update_from_td(trees, idx, w, 0.6), key), idx
+        return jax.lax.scan(body, (trees, key), None, length=4)
+
+    trees = on(one_chip, jax.eval_shape(lambda: dper.init(cap)))
+    text = jax.jit(loop, donate_argnums=(0,)).lower(
+        trees,
+        jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+    ).compile().as_text()
+    body = re.search(r"\bwhile\(.*?body=%?([\w.\-]+)", text).group(1)
+    lines = re.search(r"^%?" + re.escape(body) + r" [^\n]*\{\n(.*?)^\}", text,
+                      re.S | re.M).group(1).splitlines()
+    made = re.compile(r"= f32\[(?:%d|%d,128)\]\S* ([\w\-]+)\("
+                      % (2 * cap, 2 * cap // 128))
+    whole = [(m.group(1), ln) for ln in lines for m in [made.search(ln)] if m]
+    ops = [op for op, _ln in whole]
+    assert ops.count("fusion") == 4 and "copy" not in ops
+    assert set(ops) <= {"fusion", "get-tuple-element", "bitcast",
+                        "copy-done", "custom-call"}
+    assert all("ConcatBitcast" in ln for op, ln in whole
+               if op == "custom-call")  # a move into S(1), in four parts
+    gathers = [ln.split(" fusion(")[0] for ln in lines
+               if re.search(r'kind=kCustom.*op_name="[^"]*/gather"', ln)]
+    # the fourth is the write-back's own: the min tree's leaves read the
+    # sum tree's after its scatter
+    assert sorted(g.split("= ")[1].split("{")[0] for g in gathers) == [
+        "f32[%d,128]" % batch] * 2 + ["f32[%d]" % batch] * 2
+
+
 # configuration: the wide ring field's type as the compiled text prints it
 # (rows filled in), its pinned XLA layout, the bounds on the program's
 # temporaries and on the commit's aliased bytes, and what the chunk may
