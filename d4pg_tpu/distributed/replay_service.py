@@ -247,6 +247,12 @@ class ReplayService:
         self._skip: set[int] = set()
         self._next_seq = 0
         self._seq = itertools.count()
+        # a buffer that stages on the host says where its newest row stands
+        # and how many rows staging has dropped (fused_buffer.py); the
+        # second as the commit thread last saw it
+        self._staged_position = getattr(buffer, "staged_position", None)
+        self._staging_dropped = (self._staged_position()[1]
+                                 if self._staged_position else 0)
         self.order_breaks = 0
         self._stop = threading.Event()
         self._workers = [
@@ -385,7 +391,9 @@ class ReplayService:
         rejected_cls: str | None = None
         pol = self._admission
         # ingest.admit: the producer's side of the handoff, one span an
-        # add; ``wait_ms`` only when it blocked for a deque slot
+        # add; ``wait_ms`` only when it blocked for a deque slot, ``seq``
+        # (the admission ticket a trace follows the add by) only when
+        # admitted
         with obs_trace.span("ingest.admit", rows=rows) as admit_span, s.cond:
             if s.shed_at is not None:
                 # shed admission: bounded work, never blocks. The counter
@@ -444,6 +452,7 @@ class ReplayService:
             if admitted:
                 seq = next(self._seq)
                 s.q.append((seq, data, codec, actor_id, rows, count, trace))
+                admit_span.set_metadata(seq=seq)
                 s.rows_in += rows
                 s.cond.notify_all()
             else:
@@ -909,8 +918,12 @@ class ReplayService:
                 if self._direct_stage:
                     # rows land in the buffer's per-shard staging ring
                     # HERE, on the shard core; the commit thread only
-                    # settles the ordered accounting for this ticket
-                    self.buffer.add_sharded(batch, s.idx, ticket=seq)
+                    # settles the ordered accounting for this ticket (the
+                    # push is this path's host staging, so the span that
+                    # says so is opened here, by ticket)
+                    with obs_trace.span("ingest.host_stage", batches=1,
+                                        rows=rows, seq_lo=seq, seq_hi=seq):
+                        self.buffer.add_sharded(batch, s.idx, ticket=seq)
                     staged += rows
                     batch = None
                 if tid is not None:
@@ -1057,6 +1070,7 @@ class ReplayService:
     def _insert_group(self, group: list) -> None:
         dealer = self._dealer
         dealt: list = []
+        through = None  # where the group's last row stands in host staging
         try:
             if self.obs_norm is not None:
                 # Only obs rows feed the estimator; next_obs is
@@ -1079,10 +1093,14 @@ class ReplayService:
                         next_obs=self.obs_norm.normalize(batch.next_obs),
                     ), rows, cnt, tid)
             # ingest.host_stage: the commit thread's buffer-lock section,
-            # which on the fused path pushes the group into host staging
+            # which on the fused path pushes the group into host staging.
+            # It says the tickets it holds (ticket order: first and last)
+            # and, for a buffer that stages, the position of the group's
+            # last row after the push and the rows dropped to admit it.
             with obs_trace.span("ingest.host_stage", batches=len(group),
-                      rows=sum(item[3] for item in group)), \
-                    self._buffer_lock:
+                                rows=sum(item[3] for item in group),
+                                seq_lo=group[0][0], seq_hi=group[-1][0]
+                                ) as host_span, self._buffer_lock:
                 if dealer is None:
                     for _seq, _aid, batch, _rows, _cnt, _tid in group:
                         if batch is not None:  # None: already direct-staged
@@ -1098,6 +1116,12 @@ class ReplayService:
                             inserts.append(
                                 (self.buffer.add(batch), _seq, _tid))
                     dealt = dealer.ingest_and_deal(inserts, self.buffer)
+                if self._staged_position is not None:
+                    through, dropped = self._staged_position()
+                    host_span.set_metadata(
+                        through=through,
+                        dropped=dropped - self._staging_dropped)
+                    self._staging_dropped = dropped
         finally:
             committed = 0
             with self._lock:
@@ -1114,7 +1138,8 @@ class ReplayService:
             # path — the K=1↔K=2 counter-equivalence test pins this).
             REGISTRY.counter("ingest.rows_committed").inc(committed)
             _tracer.mark_committed(
-                [tid for *_rest, tid in group if tid is not None])
+                [tid for *_rest, tid in group if tid is not None],
+                through=through)
         if dealt:
             # ring pushes + deal spans AFTER every service lock released
             dealer.publish(dealt)

@@ -14,8 +14,10 @@ Schedule per fused chunk t (``learner/pipeline.IngestOverlap``):
     dispatch chunk t    # K scanned grad steps in ONE device dispatch
     ingest.stage()      # ONE device_put of block t+1, riding under
                         # chunk t's compute
-    trace mark_grad     # traces committed before this dispatch are now
-                        # consumed (wire-to-grad span terminal)
+    trace mark_grad     # traces whose rows LANDED before this dispatch
+                        # (``buffer.landed``, which ``learner.dispatch``
+                        # says too) are consumed: ``grad`` now, ``done``
+                        # when the chunk ends on the device
 
 giving ≤ 1 explicit H2D per chunk in steady state. The jitted chunk
 fns are cached per remainder size k (the final sub-K chunk of an ``n``
@@ -147,8 +149,14 @@ class FusedLoop:
                                      abstract_args(args))
                     self._tabled = True
                 # the jitted call alone: where the host blocks once the
-                # runtime's queue of programs in flight is full
-                with obs_trace.span("learner.dispatch", chunk=chunk):
+                # runtime's queue of programs in flight is full. ``landed``:
+                # the position in host staging up to which rows are in the
+                # ring this chunk samples (what ``ingest.commit`` above and
+                # the flush before it have dispatched; 0 from the sharded
+                # buffer, which keeps no positions).
+                landed = buffer.landed
+                with obs_trace.span("learner.dispatch", chunk=chunk,
+                                    landed=landed):
                     out = fn(*args)
                 del args  # state and trees were donated
                 if self._prioritized:
@@ -157,9 +165,10 @@ class FusedLoop:
                     state, metrics = out
                 if self.ingest is not None:
                     self.ingest.stage()
-                # traces whose rows committed before this dispatch are
-                # now consumed; near-free no-op when nothing is pending
-                _trace_recorder.mark_grad()
+                # traces whose rows landed before this dispatch are now
+                # consumed; near-free no-op when nothing is pending
+                _trace_recorder.mark_grad(landed=landed,
+                                          done=metrics["critic_loss"])
                 done += k
                 self.steps_done += k
                 self.chunks += 1

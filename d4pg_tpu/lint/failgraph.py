@@ -70,7 +70,9 @@ _CONTAINED_BY = re.compile(r"#\s*jaxlint:\s*contained-by=([\w\.\-,]+)")
 _TRACE_RECV = re.compile(r"(?i)(trace|recorder|tracer)")
 
 # Trace-terminal methods: reaching one settles a span's lifecycle.
-_TERMINALS = {"terminal_shed", "mark_committed", "mark_grad"}
+# (``mark_through`` stamps the fused path's ``h2d`` / ``land`` stages on
+# traces that ``mark_committed`` has already terminated: not a terminal.)
+_TERMINALS = {"terminal_shed", "shed_dropped", "mark_committed", "mark_grad"}
 
 # Frame-admission counters (family 18 anchors).  Declared, like the wire
 # registry: these are the names whose increment means "work entered the
@@ -100,7 +102,8 @@ _COUNT_NAMES = {"record_event", "contained_crash"}
 # including ``with``-enters (tiered-lock hierarchy checks raise) — gets
 # an exception edge.
 _NO_RAISE_ATTRS = {
-    "begin", "record_span", "terminal_shed", "mark_committed", "mark_grad",
+    "begin", "record_span", "terminal_shed", "shed_dropped",
+    "mark_committed", "mark_through", "mark_grad",
     "record", "record_event", "inc", "observe", "set", "clear",
     "is_set", "wait", "notify", "notify_all", "is_alive",
     "append", "appendleft", "extend", "popleft", "pop", "discard", "add",

@@ -643,8 +643,9 @@ _TIME_BARE = _TIME_FNS - {"time"}
 # mutations are host side effects — traced code calling them records
 # once at trace time and never again (the tracer-leak failure mode,
 # with a clock attached).
-_OBS_FNS = {"record_span", "mark_grad", "mark_committed", "terminal_shed",
-            "new_trace_id", "record_event", "latency_block"}
+_OBS_FNS = {"record_span", "mark_grad", "mark_committed", "mark_through",
+            "terminal_shed", "shed_dropped", "new_trace_id", "record_event",
+            "latency_block"}
 _OBS_METHODS = {"inc", "observe"}
 _OBS_RECV_HINTS = ("registry", "counter", "gauge", "histogram", "metric",
                    "recorder", "tracer")

@@ -13,10 +13,12 @@ extension; frames without the extension decode unchanged forever, npz
 frames are never traced). The receiver records a span timestamp at
 each stage the frame passes:
 
-    send ──> admission ──> decode ──> stage ──> merge ──> commit ──> grad
-                 │             │                  │
-                 └── shed ─────┴──── shed ────────┘   (terminal: counted,
-                                                       never leaked)
+    send ──> admission ──> decode ──> stage ──> merge ──> commit
+                 │             │                  │            │
+                 └── shed ─────┴──── shed ────────┘            │
+                                                               v
+                        done <── grad <── land <── h2d <───────┘
+                                                   (fused path only)
 
 - ``admission``  — the frame entered an ingest shard's deque
   (``ReplayService.add_payload``; zero-decode for v2 frames).
@@ -26,22 +28,33 @@ each stage the frame passes:
 - ``merge``      — the commit thread popped the ticket in global order.
 - ``commit``     — rows landed in replay state (buffer insert /
   direct-stage accounting settled). On the FUSED path "replay state" is
-  the HOST staging ring (``FusedDeviceReplay.add``): the rows reach the
-  device ring one ``fused.stage_block`` and one ``fused.commit_staged``
-  later, i.e. one to two chunks after this stamp.
-- ``grad``       — first learner consumption after commit: the fused
-  loop marks it right after each chunk dispatch
-  (``learner/loop.FusedLoop.run``), the fleet harness's consumer lane
-  marks it after each concurrent ``sample()``. The stamp is a DISPATCH,
-  not a completion, and it is not gated on the block that carries the
-  rows. On the fused path ``wire_to_grad`` is therefore short by one to
-  two chunks (the staged block's wait for its commit) plus one chunk's
-  run time on the device; the host-sampled paths are short by the
-  dispatch-to-completion time only. The repair (gate ``grad`` on the
-  ``block=`` that ``fused.commit_staged`` lands, stamp at the chunk's
-  completion) is entered in PERF.md section 7 against the
-  ``humanoid-mlp.learn-fleet-tcp`` cell, the first that would read it;
-  the fleet harnesses and two lint families depend on the present stamps.
+  the HOST staging ring (``FusedDeviceReplay.add``), and the service
+  keeps with the trace the POSITION of the group's last row in the
+  stream of rows pushed into host staging (``mark_committed(tids,
+  through=...)``): the same position the program's spans say
+  (``ingest.host_stage`` ``through``, ``fused.stage_block`` ``first`` /
+  ``through``, ``learner.dispatch`` ``landed``), so a sampled stamp and
+  a traced run follow one definition of the row's journey.
+- ``h2d``        — fused path: the ``fused.stage_block`` whose block
+  carries that position started its ``device_put``.
+- ``land``       — fused path: that block's ``fused.commit_staged``
+  dispatched the ring write + tree insert.
+- ``grad``       — the DISPATCH of the first learner consumption that can
+  see the rows. On the fused path ``FusedLoop`` calls
+  ``mark_grad(landed=...)`` after each chunk dispatch and only traces
+  whose position has landed are stamped: the first chunk that can sample
+  the rows. The host-sampled loops, the dealt loop and the fleet
+  harnesses call the bare ``mark_grad()`` after a ``sample()`` /
+  dispatch, which stamps everything committed (their rows are in replay
+  state at ``commit``).
+- ``done``       — fused path: the END on the device of the chunk that
+  stamped ``grad``, taken by one daemon thread that blocks on a small
+  output of that chunk. ``wire_to_done`` is the headline there;
+  ``wire_to_grad`` is send -> the dispatch and is short of it by the
+  wait behind the chunk already queued plus the chunk itself.
+
+A traced frame whose rows the host staging ring dropped before any block
+carried them gets a terminal ``shed`` (``shed_dropped``).
 
 A shed/tombstoned/undecodable frame gets a terminal ``shed`` span so
 every admitted trace terminates — the zero-orphan invariant the K-shard
@@ -70,6 +83,7 @@ import threading
 import time
 from collections import OrderedDict, deque
 
+from d4pg_tpu.obs.containment import contained_crash
 from d4pg_tpu.obs.registry import percentile_summary
 
 # The default sampling rate the --trace_sample knobs document: dense
@@ -85,7 +99,7 @@ DEFAULT_SAMPLE = 0.02
 # terminates a trace, so a dealt block lost to a learner kill can never
 # orphan the accounting.
 STAGES = ("send", "admission", "decode", "stage", "merge", "commit", "deal",
-          "grad")
+          "h2d", "land", "grad", "done")
 TERMINALS = ("commit", "grad", "shed")
 
 # Stage pairs the latency block reports (label, from, to).
@@ -98,8 +112,13 @@ _PAIRS = (
     ("commit_to_deal", "commit", "deal"),
     ("deal_to_grad", "deal", "grad"),
     ("commit_to_grad", "commit", "grad"),
+    ("commit_to_h2d", "commit", "h2d"),
+    ("h2d_to_land", "h2d", "land"),
+    ("land_to_grad", "land", "grad"),
+    ("grad_to_done", "grad", "done"),
     ("wire_to_commit", "send", "commit"),
     ("wire_to_grad", "send", "grad"),
+    ("wire_to_done", "send", "done"),
 )
 
 _tid_counter = itertools.count(1)  # next() is GIL-atomic in CPython
@@ -123,7 +142,16 @@ class TraceRecorder:
         self._mu = threading.Lock()
         self.max_traces = int(max_traces)
         self._spans: OrderedDict[int, dict] = OrderedDict()
+        # (tid, position of the trace's last row in host staging or None),
+        # in commit order: positions ascend
         self._await_grad: deque = deque()
+        # stage -> (highest position stamped, when): a trace committed just
+        # after its block moved on takes the block's own stamp
+        self._reached: dict[str, tuple[int, float]] = {}
+        # (output of a dispatched chunk, tids it stamped ``grad``) and the
+        # thread that blocks on them, alive only while there is one
+        self._watching: deque = deque()
+        self._watcher: threading.Thread | None = None
         self.enabled = False
         self.sample_rate = 0.0
         self.overflow = 0
@@ -142,6 +170,8 @@ class TraceRecorder:
         with self._mu:
             self._spans.clear()
             self._await_grad.clear()
+            self._reached.clear()
+            self._watching.clear()
             self.overflow = 0
 
     # -- span recording (hot path) ------------------------------------------
@@ -192,9 +222,11 @@ class TraceRecorder:
                 spans = self._spans[tid] = {}
             spans.setdefault("shed", t)
 
-    def mark_committed(self, tids) -> None:
+    def mark_committed(self, tids, through: int | None = None) -> None:
         """Commit spans for a merged group + queue them for the next
-        grad-consumption mark."""
+        grad-consumption mark. ``through``: the position of the group's
+        last row in the buffer's host staging stream (``None`` for a
+        buffer without one: the rows are in replay state already)."""
         if not self.enabled:
             return
         t = time.monotonic()
@@ -203,26 +235,106 @@ class TraceRecorder:
                 spans = self._spans.get(tid)
                 if spans is not None and "commit" not in spans:
                     spans["commit"] = t
-                    self._await_grad.append(tid)
+                    self._await_grad.append((tid, through))
+                    if through is not None:
+                        for stage, (pos, at) in self._reached.items():
+                            if through <= pos:
+                                spans[stage] = at
 
-    def mark_grad(self, ts: float | None = None) -> int:
-        """Stamp every commit-pending trace with grad-consumption time.
-        Called by the learner right after a fused-chunk dispatch (and by
-        the fleet harness's consumer lane after each concurrent sample).
+    def mark_through(self, stage: str, through: int) -> None:
+        """Stamp ``stage`` (``h2d``, ``land``) on every commit-pending
+        trace whose position is at most ``through``: the block that
+        carries its rows has reached that stage. Near-free when nothing
+        is pending."""
+        if not self._await_grad:
+            return
+        t = time.monotonic()
+        with self._mu:
+            self._reached[stage] = (through, t)
+            for tid, pos in self._await_grad:
+                if pos is None:
+                    continue
+                if pos > through:
+                    break
+                spans = self._spans.get(tid)
+                if spans is not None and stage not in spans:
+                    spans[stage] = t
+
+    def shed_dropped(self, through: int) -> int:
+        """Terminal ``shed`` for every commit-pending trace at a position
+        up to ``through`` that no block carried (no ``h2d``): the host
+        staging ring dropped its rows to admit newer ones."""
+        if not self._await_grad:
+            return 0
+        t = time.monotonic()
+        n = 0
+        with self._mu:
+            kept = deque()
+            for tid, pos in self._await_grad:
+                spans = self._spans.get(tid)
+                if (pos is not None and pos <= through and spans is not None
+                        and "h2d" not in spans):
+                    spans.setdefault("shed", t)
+                    n += 1
+                else:
+                    kept.append((tid, pos))
+            self._await_grad = kept
+        return n
+
+    def mark_grad(self, ts: float | None = None, landed: int | None = None,
+                  done=None) -> int:
+        """Stamp commit-pending traces with grad-consumption time. Bare
+        (the host-sampled loops, the dealt loop, the fleet harness's
+        consumer lane after a ``sample()``): every pending trace.
+        ``landed=`` (the fused loop, right after a chunk dispatch): only
+        traces whose position has landed on the device, i.e. the first
+        chunk that can sample their rows. ``done``: a small output of that
+        chunk; the watcher thread blocks on it and stamps ``done``.
         Near-free when nothing is pending (one unlocked emptiness probe,
         benign race under the GIL)."""
         if not self._await_grad:
             return 0
         t = time.monotonic() if ts is None else ts
-        n = 0
+        stamped = []
         with self._mu:
             while self._await_grad:
-                tid = self._await_grad.popleft()
+                tid, pos = self._await_grad[0]
+                if landed is not None and pos is not None and pos > landed:
+                    break
+                self._await_grad.popleft()
                 spans = self._spans.get(tid)
                 if spans is not None and "grad" not in spans:
                     spans["grad"] = t
-                    n += 1
-        return n
+                    stamped.append(tid)
+            if stamped and done is not None and self.enabled:
+                self._watching.append((done, stamped))
+                if self._watcher is None:
+                    self._watcher = threading.Thread(
+                        target=self._watch, daemon=True, name="trace-done")
+                    self._watcher.start()
+        return len(stamped)
+
+    def _watch(self) -> None:
+        """Block on each chunk's output in turn and stamp ``done`` at its
+        end; leaves when nothing is left to wait for."""
+        try:
+            while True:
+                with self._mu:
+                    if not self._watching:
+                        self._watcher = None
+                        return
+                    done, tids = self._watching.popleft()
+                done.block_until_ready()
+                t = time.monotonic()
+                with self._mu:
+                    for tid in tids:
+                        spans = self._spans.get(tid)
+                        if spans is not None:
+                            spans.setdefault("done", t)
+        except Exception as e:
+            with self._mu:
+                self._watcher = None
+            contained_crash("trace.done_watcher", e)
 
     # -- analysis (cold path) -----------------------------------------------
     def span_table(self) -> dict[int, dict]:
@@ -240,7 +352,8 @@ class TraceRecorder:
 
     def latency_block(self) -> dict:
         """The artifact block: per-stage latency percentiles (ms) plus
-        end-to-end wire-to-commit / wire-to-grad, the sample rate, and
+        end-to-end wire-to-commit / wire-to-grad / wire-to-done (the
+        fused path's headline), the sample rate, and
         the trace accounting (completed / shed / orphaned / overflow)."""
         table = self.span_table()
         stages: dict[str, list[float]] = {label: [] for label, _, _ in _PAIRS}
@@ -266,6 +379,7 @@ class TraceRecorder:
             "stages": {label: percentile_summary(vals)
                        for label, vals in stages.items()},
             "wire_to_grad": percentile_summary(stages["wire_to_grad"]),
+            "wire_to_done": percentile_summary(stages["wire_to_done"]),
             "n_traces": len(table),
             "completed": completed,
             "shed": shed,

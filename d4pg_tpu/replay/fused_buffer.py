@@ -49,6 +49,7 @@ import numpy as np
 from d4pg_tpu.obs.flight import record_event
 from d4pg_tpu.obs import trace as obs_trace
 from d4pg_tpu.obs.registry import REGISTRY
+from d4pg_tpu.obs.trace import RECORDER as _tracer
 from d4pg_tpu.replay import device_per as dper
 from d4pg_tpu.replay.device_ring import (DeviceStore, block_write,
                                          ring_program)
@@ -71,8 +72,12 @@ class HostStagingRing:
     counted: ``fused.rows_dropped`` in the registry, a ``staging_drop``
     event in the flight ring.
 
-    Each push notes ``(rows written so far, perf_counter)`` so the stager
-    can say how long the oldest row of a frame waited (``oldest_push``).
+    A row's POSITION is its 1-based index in the stream of rows pushed
+    (``written`` after its push); ``consumed`` is the position of the last
+    row popped or dropped, so the next frame holds positions ``consumed +
+    1 .. consumed + n``. Each push notes ``(written, time.monotonic())``
+    so the stager can say how long the oldest row of a frame waited
+    (``oldest_push``).
 
     Reuse discipline: a popped frame's rows are rewritten only after the
     write pointer laps the ring (≥ ``(n_blocks - 1) * block_rows`` newer
@@ -91,21 +96,33 @@ class HostStagingRing:
         self._r = 0  # absolute rows consumed
         self._w = 0  # absolute rows written
         self._pushed: deque = deque()  # (self._w after the push, its time)
+        self.dropped = 0  # rows dropped so far
 
     def __len__(self) -> int:
         return self._w - self._r
 
+    @property
+    def written(self) -> int:
+        """Position of the newest row pushed."""
+        return self._w
+
+    @property
+    def consumed(self) -> int:
+        """Position of the last row popped or dropped."""
+        return self._r
+
     def oldest_push(self) -> float | None:
-        """``perf_counter`` of the push that wrote the oldest pending row
+        """``time.monotonic()`` of the push that wrote the oldest pending row
         (``None`` when empty)."""
         pushed = self._pushed
         while pushed and pushed[0][0] <= self._r:
             pushed.popleft()
         return pushed[0][1] if pushed else None
 
-    def push(self, batch: TransitionBatch, at: float | None = None) -> None:
+    def push(self, batch: TransitionBatch, at: float | None = None) -> int:
         """``at``: when the rows were first staged, for a push that only
-        moves them on (the multi-ring merge); now otherwise."""
+        moves them on (the multi-ring merge); now otherwise. Returns the
+        rows dropped to admit these."""
         n = batch.obs.shape[0]
         dropped = max(0, n - self.size)
         if dropped:  # keep only the newest ring-full
@@ -125,10 +142,12 @@ class HostStagingRing:
             self._r = self._w - self.size  # drop oldest
         self.oldest_push()  # forget the pushes whose rows are all gone
         self._pushed.append(
-            (self._w, time.perf_counter() if at is None else at))
+            (self._w, time.monotonic() if at is None else at))
         if dropped:
+            self.dropped += dropped
             REGISTRY.counter("fused.rows_dropped").inc(dropped)
             record_event("staging_drop", rows=dropped)
+        return dropped
 
     def frame(self) -> tuple[TransitionBatch, int]:
         """Next pending frame as fixed-shape [block_rows] views + its
@@ -324,10 +343,15 @@ class FusedDeviceReplay:
         else:
             self._staging = HostStagingRing(specs, self.block_rows, n_blocks)
         # the frame on its way to the device: (frame, rows, block id,
-        # perf_counter at stage_block). The id is what a trace follows a
-        # block by, from ``fused.stage_block`` to ``fused.commit_staged``.
-        self._inflight: tuple[TransitionBatch, int, int, float] | None = None
+        # position of its last row, time.monotonic() at stage_block). The
+        # id and the position are what a trace follows a block by, from
+        # ``fused.stage_block`` to ``fused.commit_staged``.
+        self._inflight: tuple[TransitionBatch, int, int, int, float] | None \
+            = None
         self.blocks_staged = 0
+        # the highest position whose commit has been dispatched: what the
+        # next chunk can sample (``learner.dispatch`` says it)
+        self.landed = 0
         self._commit_fn = make_commit(
             self.capacity, self.block_rows, self.alpha, self._store.formats,
             prioritized=self.prioritized, gen_tracked=self.gen_tracked)
@@ -383,8 +407,22 @@ class FusedDeviceReplay:
         if self.ingest_shards > 1:
             self._staging.push(batch, shard=0)
         else:
-            self._staging.push(batch)
+            self._push(batch)
         return None
+
+    def _push(self, batch: TransitionBatch) -> None:
+        """Into the one staging ring; traced frames whose rows it dropped to
+        admit these end their journey here."""
+        if self._staging.push(batch):
+            _tracer.shed_dropped(self._staging.consumed)
+
+    def staged_position(self) -> tuple[int, int]:
+        """``(through, dropped)``: the position of the newest row pushed
+        into host staging (through the multi-ring: where the merged stream
+        will stand once it holds every row pushed so far) and the rows
+        staging has dropped so far. Under the service's buffer lock, like
+        ``add``."""
+        return self._staging.written, self._staging.dropped
 
     def add_sharded(self, batch: TransitionBatch, shard: int,
                     ticket: int | None = None) -> None:
@@ -399,7 +437,7 @@ class FusedDeviceReplay:
         if self.ingest_shards > 1:
             self._staging.push(batch, shard=shard, ticket=ticket)
         else:
-            self._staging.push(batch)
+            self._push(batch)
 
     def __len__(self) -> int:
         # staged + in-flight rows count toward warmup gates — they WILL be
@@ -433,11 +471,18 @@ class FusedDeviceReplay:
 
         # how long the frame's oldest row sat in host staging: measured
         # here, where the row waits, once per block
-        now = time.perf_counter()
+        now = time.monotonic()
         wait_ms = 1e3 * (now - (self._staging.oldest_push() or now))
         block = self.blocks_staged
+        first = self._staging.consumed + 1
+        through = first + n - 1
+        # through the multi-ring a row's place in the merged stream is
+        # fixed only now: the block says the tickets it carries
+        tickets = getattr(self._staging, "tickets_through", None)
+        stats = tickets(through) if tickets is not None else {}
         with obs_trace.span("fused.stage_block", block=block, rows=n,
-                            wait_ms=wait_ms):
+                            wait_ms=wait_ms, first=first, through=through,
+                            **stats):
             with obs_trace.span("fused.h2d"):
                 if self._host_aliased:
                     views = TransitionBatch(*[np.array(v) for v in views])
@@ -445,10 +490,9 @@ class FusedDeviceReplay:
                          if self._device is not None
                          else jax.device_put(views))
             self._staging.pop(n)
-            self._inflight = (frame, n, block, now)
+            self._inflight = (frame, n, block, through, now)
             self.blocks_staged = block + 1
-        # one registry observation per BLOCK (never per row)
-        REGISTRY.histogram("fused.staging_wait_ms").observe(wait_ms)
+        _tracer.mark_through("h2d", through)
         return n
 
     def commit_staged(self) -> int:  # jaxlint: guarded-by=_buffer_lock
@@ -457,9 +501,9 @@ class FusedDeviceReplay:
         donated). Learner thread only. Returns rows committed."""
         if self._inflight is None:
             return 0
-        frame, n, block, staged_at = self._inflight
+        frame, n, block, through, staged_at = self._inflight
         self._inflight = None
-        inflight_ms = 1e3 * (time.perf_counter() - staged_at)
+        inflight_ms = 1e3 * (time.monotonic() - staged_at)
         start = np.int32(self.head)
         if self.gen_tracked:
             # host-f64 pow, f32 cast: the trees only see host-rounded
@@ -479,8 +523,10 @@ class FusedDeviceReplay:
                              abstract_args(args))
             self._commit_tabled = True
         with obs_trace.span("fused.commit_staged", block=block, rows=n,
-                  inflight_ms=inflight_ms):
+                            inflight_ms=inflight_ms, through=through):
             out = self._commit(*args)
+        self.landed = through
+        _tracer.mark_through("land", through)
         if self.gen_tracked:
             storage, self.trees, self.gen = out
         elif self.trees is not None:
@@ -490,7 +536,6 @@ class FusedDeviceReplay:
         self._store.swap_arrays(storage)
         self.head = int((self.head + n) % self.capacity)
         self.size = int(min(self.size + n, self.capacity))
-        REGISTRY.histogram("fused.inflight_ms").observe(inflight_ms)
         return n
 
     # priority write-back for the dealt plane: reached from the device
@@ -525,6 +570,7 @@ class FusedDeviceReplay:
             if n == 0:
                 break
             self._staging.pop(n)
+            self.landed = self._staging.consumed
             for i in range(int(n)):
                 idx = np.array([self.head], np.int32)
                 row = TransitionBatch(*[np.asarray(v)[i:i + 1]

@@ -88,6 +88,10 @@ class ShardedPerTrees(NamedTuple):
 class ShardedFusedReplay:
     """Device-sharded ring + trees for the mesh fused learner path."""
 
+    # no host staging stream, so no positions (fused_buffer.py): rows are in
+    # the ring when ``add`` returns, and ``learner.dispatch`` says 0
+    landed = 0
+
     def __init__(
         self,
         capacity: int,

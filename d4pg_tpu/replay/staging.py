@@ -49,6 +49,13 @@ class MultiRingStaging:
     bitwise K=1↔K=2 equivalence bar); rows still being decoded on a
     straggler shard can be overtaken mid-flight — the merge never
     blocks the learner's stage call on a slow shard.
+
+    Positions (``HostStagingRing``) are those of the MERGED stream, fixed
+    only at ``_refill``: ``consumed`` is the merge ring's, ``written`` is
+    where the merged stream will stand once it holds every row pushed so
+    far (an upper bound on the position of any of them, exact when the
+    shards are quiescent), and ``tickets_through`` says which tickets a
+    block carries, which is what ties a row to its block here.
     """
 
     def __init__(self, specs, block_rows: int, n_blocks: int,
@@ -60,10 +67,16 @@ class MultiRingStaging:
         self._rings = [HostStagingRing(specs, block_rows, n_blocks)
                        for _ in range(self.shards)]
         self._ring_locks = [TieredLock("ring") for _ in range(self.shards)]
-        # per-ring (ticket, rows) records, ticket-ascending
+        # per-ring (ticket, rows, the caller's ticket or None) records,
+        # ticket-ascending; the third says what a block's span may report: a
+        # ticket of this object's own making orders the merge and means
+        # nothing to a reader
         self._records: list[deque] = [deque() for _ in range(self.shards)]
         self._merge = HostStagingRing(specs, block_rows, 2)
         self._ticket = itertools.count()
+        # (merged position of a caller's ticket's last row, the ticket),
+        # until a block has carried it; learner thread only
+        self._merged: deque = deque()
 
     def __len__(self) -> int:
         n = len(self._merge)
@@ -71,6 +84,18 @@ class MultiRingStaging:
             with self._ring_locks[i]:
                 n += len(self._rings[i])
         return n
+
+    @property
+    def written(self) -> int:
+        return self._merge.written + len(self) - len(self._merge)
+
+    @property
+    def consumed(self) -> int:
+        return self._merge.consumed
+
+    @property
+    def dropped(self) -> int:
+        return sum(ring.dropped for ring in self._rings)
 
     # -- producer side (one worker per shard) ------------------------------
     def push(self, batch, shard: int = 0, ticket: int | None = None) -> None:
@@ -85,14 +110,14 @@ class MultiRingStaging:
             # same rows off the oldest records so tickets stay aligned
             # with ring contents
             while overflow and records:
-                t0, n0 = records[0]
+                t0, n0, seq0 = records[0]
                 if n0 <= overflow:
                     records.popleft()
                     overflow -= n0
                 else:
-                    records[0] = (t0, n0 - overflow)
+                    records[0] = (t0, n0 - overflow, seq0)
                     overflow = 0
-            records.append((t, n))
+            records.append((t, n, ticket))
 
     # -- consumer side (learner thread) ------------------------------------
     def _refill(self) -> None:
@@ -112,18 +137,30 @@ class MultiRingStaging:
             with self._ring_locks[i]:
                 if not self._records[i] or self._records[i][0][0] != _t:
                     continue  # a push overflowed the head away; re-scan
-                _t, n = self._records[i].popleft()
+                _t, n, seq = self._records[i].popleft()
                 room = self._merge.size - len(self._merge)
                 if n > room:
                     # only part of the record fits this pass: keep the
                     # remainder (same ticket) at the head for the next
-                    self._records[i].appendleft((_t, n - room))
-                    n = room
+                    self._records[i].appendleft((_t, n - room, seq))
+                    n, seq = room, None
                 # the rows keep the time they were first staged, so the
                 # merged frame's wait is its oldest shard row's
                 at = self._rings[i].oldest_push()
                 for piece in self._rings[i].take(n):
                     self._merge.push(piece, at)
+            if seq is not None:  # the caller's ticket's last row has its place
+                self._merged.append((self._merge.written, seq))
+
+    def tickets_through(self, through: int) -> dict:
+        """``seq_lo`` / ``seq_hi`` of the tickets whose last row lies in
+        the merged stream up to ``through`` and in no earlier block: what
+        the block that ends there says of itself (nothing for rows pushed
+        under tickets of this object's own)."""
+        seqs = []
+        while self._merged and self._merged[0][0] <= through:
+            seqs.append(self._merged.popleft()[1])
+        return {"seq_lo": min(seqs), "seq_hi": max(seqs)} if seqs else {}
 
     # -- crash-recovery cut -------------------------------------------------
     def snapshot(self) -> dict:

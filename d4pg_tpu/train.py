@@ -905,11 +905,10 @@ def train(cfg: ExperimentConfig) -> dict:
 
     # Wire-to-grad tracing (docs/architecture.md "Observability plane"):
     # arm the receiver-side span recorder; frames sampled by raw-codec
-    # remote actors get their grad-consumption span stamped right after
-    # each fused dispatch (FusedLoop.run calls mark_grad — the host-side
-    # proxy for "a grad step consumed these rows"; the device runs async
-    # and observing the kernel would cost the sync the plane exists to
-    # avoid).
+    # remote actors are followed by their rows' position in host staging.
+    # FusedLoop stamps ``grad`` at the dispatch of the first chunk that
+    # can sample them and a watcher thread stamps ``done`` when that chunk
+    # ends on the device (``wire_to_done``, the headline on this path).
     from d4pg_tpu.obs.trace import RECORDER as trace_recorder
 
     if cfg.trace_sample > 0:
@@ -1521,9 +1520,10 @@ def train(cfg: ExperimentConfig) -> dict:
                 # wire-to-grad headline onto the metrics bus: the p95 of
                 # the end-to-end span over the recent trace window
                 lat = trace_recorder.latency_block()
-                if lat["wire_to_grad"]["n"]:
-                    last_metrics["wire_to_grad_p95_ms"] = \
-                        lat["wire_to_grad"]["p95"]
+                for headline in ("wire_to_grad", "wire_to_done"):
+                    if lat[headline]["n"]:
+                        last_metrics[headline + "_p95_ms"] = \
+                            lat[headline]["p95"]
             last_metrics["cycle_time_s"] = round(time.monotonic() - cycle_t0, 4)
             # Failure detection/recovery (SURVEY.md §5): stale heartbeats
             # reach the metrics bus (not just stdout); dead spawned actor

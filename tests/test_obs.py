@@ -346,6 +346,174 @@ def test_trace_shed_frames_get_terminal_spans(rng):
         svc._pending = 0  # frozen plane: skip close()'s flush deadline
 
 
+# --------------------- the fused path: positions, h2d, land, grad, done ----
+
+def test_mark_grad_landed_stamps_only_what_has_landed():
+    rec = obs_trace.TraceRecorder()
+    rec.enable(1.0)
+    for tid, through in ((1, 10), (2, 20), (3, None)):
+        rec.begin(tid, time.monotonic())
+        rec.record_span(tid, "admission")
+        rec.mark_committed([tid], through=through)
+    assert rec.mark_grad(landed=5) == 0  # nothing has landed
+    rec.mark_through("h2d", 10)
+    rec.mark_through("land", 10)
+    assert rec.mark_grad(landed=10) == 1
+    table = rec.span_table()
+    assert {"h2d", "land", "grad"} <= set(table[1])
+    assert not {"h2d", "land", "grad"} & set(table[2])
+    # a trace with no position (a buffer that keeps none) waits for nothing,
+    # but keeps its place in line behind the one whose rows are in flight
+    assert rec.mark_grad(landed=10) == 0 and "grad" not in table[3]
+    assert rec.mark_grad(landed=20) == 2
+    table = rec.span_table()
+    assert "grad" in table[2] and "grad" in table[3]
+    assert "land" not in table[2]  # its block was never said to land
+    # the bare call keeps its meaning: everything pending
+    rec.begin(4, time.monotonic())
+    rec.mark_committed([4], through=99)
+    assert rec.mark_grad() == 1
+    # a trace committed after its block moved on takes the block's stamps
+    rec.begin(5, time.monotonic())
+    rec.mark_committed([5], through=7)
+    late = rec.span_table()[5]
+    assert late["h2d"] == table[1]["h2d"] and late["land"] == table[1]["land"]
+    block = rec.latency_block()
+    assert block["stages"]["h2d_to_land"]["n"] == 2
+    assert block["stages"]["land_to_grad"]["n"] == 1
+    assert block["wire_to_done"]["n"] == 0 and rec._watcher is None
+
+
+@pytest.fixture
+def fused_plane(rng):
+    """``ReplayService`` -> ``FusedDeviceReplay`` -> ``FusedLoop`` at tiny
+    sizes, compiled, with the process-wide recorder armed."""
+    from test_program_spans import BLOCK, build_plane, rows
+
+    loop, service, buf, state = build_plane(rng, fill=2 * BLOCK)
+    state, _m = loop.run(state, 2)
+    obs_trace.RECORDER.reset()
+    obs_trace.RECORDER.enable(1.0)
+
+    def traced_add(n=BLOCK):
+        from d4pg_tpu.distributed.transport import encode_raw
+
+        tid = obs_trace.new_trace_id(9)
+        frame = encode_raw("lane-0", rows(rng, n),
+                           trace=(tid, time.monotonic()))[8:]
+        assert service.add_payload(frame, shard=0, codec="raw")
+        service.flush(timeout=10.0)
+        return tid
+
+    yield loop, service, buf, state, traced_add
+    obs_trace.RECORDER.disable()
+    obs_trace.RECORDER.reset()
+    loop.close()
+    service.close()
+
+
+def _watchers():
+    return [t for t in threading.enumerate() if t.name == "trace-done"]
+
+
+def test_fused_grad_waits_for_the_block_that_carries_the_rows(fused_plane):
+    """Rows that arrive while chunk 0 runs are staged after chunk 1's
+    dispatch and committed before chunk 2's: chunk 1 cannot sample them and
+    must not stamp ``grad`` (it did before PR 36); chunk 2 does, and the
+    chunk's end on the device is ``done``."""
+    import jax
+
+    loop, _service, buf, state, traced_add = fused_plane
+    tids, seen, own = [], [], []
+
+    def on_chunk(st, _k):
+        if not tids:
+            tids.append(traced_add())
+        seen.append(dict(obs_trace.RECORDER.span_table()[tids[0]]))
+        jax.block_until_ready(st)  # the test's own stamp of the chunk's end
+        own.append(time.monotonic())
+
+    state, _m = loop.run(state, 8, on_chunk=on_chunk)
+    assert {"commit"} <= set(seen[0]) and "h2d" not in seen[0]
+    # chunk 1: the block is on its way (h2d), not landed: no grad
+    assert "h2d" in seen[1] and not {"land", "grad"} & set(seen[1])
+    # chunk 2: landed by its ingest.commit, consumed by its dispatch
+    assert {"land", "grad"} <= set(seen[2])
+    for _ in range(200):  # the watcher stamps `done` on its own thread
+        spans = obs_trace.RECORDER.span_table()[tids[0]]
+        if "done" in spans:
+            break
+        time.sleep(0.01)
+    order = [spans[s] for s in ("send", "admission", "decode", "stage",
+                                "merge", "commit", "h2d", "land", "grad",
+                                "done")]
+    assert order == sorted(order)
+    # `done` is the end of chunk 2: beside the test's own block_until_ready
+    # stamp of that chunk, give or take the time a thread takes to wake on a
+    # machine that runs six test workers (on the chip: 0.19 ms, PERF.md)
+    assert spans["grad"] <= spans["done"] and abs(spans["done"] - own[2]) < 0.1
+    assert buf.landed == buf.staged_position()[0]
+    block = obs_trace.RECORDER.latency_block()
+    for pair in ("commit_to_h2d", "h2d_to_land", "land_to_grad",
+                 "grad_to_done", "wire_to_grad", "wire_to_done"):
+        assert block["stages"][pair]["n"] == 1, pair
+    assert block["wire_to_done"]["p95"] >= block["wire_to_grad"]["p95"]
+    assert block["orphans"] == 0
+    for _ in range(200):  # nothing pending: the watcher has left
+        if not _watchers():
+            break
+        time.sleep(0.01)
+    assert not _watchers() and obs_trace.RECORDER._watcher is None
+
+
+def test_no_watcher_thread_while_the_recorder_is_disabled(fused_plane):
+    loop, service, _buf, state, _traced_add = fused_plane
+    from test_program_spans import BLOCK, rows
+
+    obs_trace.RECORDER.disable()
+    fed = []
+
+    def on_chunk(_st, _k):
+        assert not _watchers()
+        if len(fed) < 2:
+            fed.append(service.add(rows(np.random.default_rng(1), BLOCK)))
+            service.flush()
+
+    state, _m = loop.run(state, 8, on_chunk=on_chunk)
+    assert fed == [True, True] and not _watchers()
+    assert obs_trace.RECORDER._watcher is None
+    assert not obs_trace.RECORDER._await_grad  # nothing was ever pending
+    # armed, but with no traced frame pending: still no thread
+    obs_trace.RECORDER.enable(1.0)
+    state, _m = loop.run(state, 4, on_chunk=lambda s, k: None)
+    assert not _watchers() and obs_trace.RECORDER.span_table() == {}
+
+
+def test_zero_orphans_after_a_staging_drop(fused_plane):
+    """Three traced blocks into a two-block staging ring before the learner
+    stages any: the first frame's rows are dropped, its trace ends in a
+    terminal ``shed`` and is never stamped ``grad``; the others complete."""
+    loop, service, _buf, state, traced_add = fused_plane
+    tids = [traced_add() for _ in range(3)]
+    table = obs_trace.RECORDER.span_table()
+    assert "shed" in table[tids[0]] and "commit" in table[tids[0]]
+    assert all("shed" not in table[t] for t in tids[1:])
+    state, _m = loop.run(state, 6)
+    for _ in range(200):
+        table = obs_trace.RECORDER.span_table()
+        if all("done" in table[t] for t in tids[1:]):
+            break
+        time.sleep(0.01)
+    assert not {"h2d", "land", "grad", "done"} & set(table[tids[0]])
+    for t in tids[1:]:
+        assert {"h2d", "land", "grad", "done"} <= set(table[t])
+        assert table[t]["land"] <= table[t]["grad"] <= table[t]["done"]
+    block = obs_trace.RECORDER.latency_block()
+    assert block["orphans"] == 0 and block["shed"] == 1
+    assert block["wire_to_done"]["n"] == 2
+    assert service.ingest_stats()["rows_dropped"] >= 16
+
+
 # ----------------------------------------------------- flight recorder ----
 
 @pytest.mark.failflow

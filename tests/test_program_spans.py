@@ -68,8 +68,11 @@ def rows(rng, n, first=0):
         discount=np.full(n, 0.99, np.float32))
 
 
-@pytest.fixture
-def loop_and_service(rng):
+def build_plane(rng, fill=CAP):
+    """``ReplayService`` -> ``FusedDeviceReplay`` (a two-block staging ring,
+    ``fill`` seeded rows drained to the device) -> ``FusedLoop``, and a fresh
+    state: ``(loop, service, buffer, state)``. Close the loop, then the
+    service."""
     import jax
 
     from d4pg_tpu.distributed.replay_service import ReplayService
@@ -81,11 +84,17 @@ def loop_and_service(rng):
                         n_atoms=11, hidden=(16, 16))
     buf = FusedDeviceReplay(CAP, OBS, ACT, alpha=0.6, block_rows=BLOCK,
                             staging_blocks=2)
-    buf.add(rows(rng, CAP))
+    buf.add(rows(rng, fill))
     buf.drain()
     service = ReplayService(buf)
     loop = FusedLoop(config, buf, k=2, batch_size=8, service=service)
-    yield loop, service, init_state(config, jax.random.key(0))
+    return loop, service, buf, init_state(config, jax.random.key(0))
+
+
+@pytest.fixture
+def loop_and_service(rng):
+    loop, service, _buf, state = build_plane(rng)
+    yield loop, service, state
     loop.close()
     service.close()
 
@@ -170,11 +179,14 @@ def test_a_block_keeps_its_id_from_stage_to_the_next_chunks_commit(
     (admit,) = by_name("ingest.admit")
     assert admit.stats["rows"] == BLOCK  # opened by the adding thread
     (host,) = by_name("ingest.host_stage")  # the commit thread's own
-    assert host.stats == {"rows": BLOCK, "batches": 1}
+    seq = admit.stats["seq"]  # the ticket the add was given
+    assert host.stats == {"rows": BLOCK, "batches": 1, "seq_lo": seq,
+                          "seq_hi": seq, "through": stage.stats["through"],
+                          "dropped": 0}
     assert host.parent is None
-    # and the waits went to the registry, one observation a block
-    for name in ("fused.staging_wait_ms", "fused.inflight_ms"):
-        assert REGISTRY.histogram(name).snapshot_dict()["n"] >= 1
+    # the block says the positions it carries, stage and commit alike
+    assert stage.stats["through"] - stage.stats["first"] + 1 == BLOCK
+    assert commit.stats["through"] == stage.stats["through"]
 
 
 def _specs():
@@ -233,7 +245,7 @@ def test_oldest_push_is_the_time_of_the_oldest_pending_row(rng):
 
     ring = HostStagingRing(_specs(), BLOCK, 2)
     assert ring.oldest_push() is None
-    ring.push(rows(rng, 4), at=1.0)
+    ring.push(rows(rng, 4), at=1.0)  # a time.monotonic() reading
     ring.push(rows(rng, 4), at=2.0)
     assert ring.oldest_push() == 1.0
     ring.pop(3)
