@@ -16,17 +16,29 @@ semantics *inside* the scan).
 
 Layout matches the host trees (``replay/segment_tree.py``): one flat
 array of ``2 * capacity`` (power of two) nodes, root at 1, leaf ``i`` at
-``capacity + i``. All ops are batched:
+``capacity + i``. Unlike the host trees only the levels a reader reads
+are maintained (:func:`kept_levels`): the leaves, every seventh level
+above them, and the root.
 
-  - ``set_leaves``: scatter the B leaves, then repair their ancestors.
-    Only the lowest levels, where B paths touch a vanishing share of the
-    nodes, are repaired by a gather and a scatter each (every touched
-    parent recomputed from its already-written children, so duplicate
-    parents among the B paths write identical values and need no dedup);
-    from the level that is narrow enough up to the root every level is
-    recomputed WHOLE from the one below, one pairwise reduction a level.
-    Which level that is follows from the static capacity and B
-    (``_scatter_levels``);
+THE INVARIANT. A kept node is the float32 sum (sum tree) or min (min
+tree) of the 128 kept nodes seven levels under it (under the root, of
+the ``2 ** first`` nodes of the first kept level), *taken as seven (or
+``first``) rounds of adjacent pairs*: the value a tree that stored every
+level as the ``+`` / ``min`` of its two children would hold there, to
+the bit. A node of no kept level (and node 0) is NEVER written and keeps
+what :func:`init` gave it (0 / inf), so two buffers that reached the
+same leaves by different batch shapes hold equal arrays.
+
+All ops are batched:
+
+  - ``set_leaves``: scatter the B leaves, then repair the kept levels
+    above them, each from the kept level below it, in one of two forms
+    chosen per step from the static capacity and B (:func:`repair_plan`):
+    by the touched rows (gather the B rows of 128 nodes under the touched
+    parents, total each by rounds of pairs, write B values; duplicates
+    among the B write identical values and need no dedup), or whole
+    (the level above recomputed from all of the one below, which is
+    what a batch that touches most rows must get);
   - ``sample``: B stratified inverse-CDF queries descend in lock-step,
     seven levels at a gather: the 128 nodes seven levels below node ``n``
     are row ``n`` of the tree read as ``[2N / 128, 128]``, and the six
@@ -83,29 +95,51 @@ def init(capacity: int) -> PerTrees:
     )
 
 
-# Where the repair turns dense, measured on the v5e (PERF.md, PR 29). A
-# scattered level costs its B indices one after another (~80 ns each on
-# a 4M-node tree, ~20 ns under 2^19 nodes); a dense level costs its bytes
-# (12 a parent: two children read, one parent written; ~0.02 ns a
-# parent); either costs ~1.7 us at least. A level at most
-# ``_DENSE_NODES_PER_LEAF * B`` nodes wide is therefore cheaper whole.
-# Under ``_DENSE_MIN_BATCH`` leaves the fixed cost is all a scatter pays,
-# and the dense width stops shrinking with B.
-_DENSE_NODES_PER_LEAF = 4096
-_DENSE_MIN_BATCH = 16
-_LANES = 128  # a dense level is kept as rows of lanes, [width / 128, 128]
+_LANES = 128  # a row of the tree read as [2 * capacity / 128, 128]
+_ROW_LEVELS = _LANES.bit_length() - 1  # 7: binary levels a row spans
+
+# Which form a step of the repair takes, from what each costs on the v5e
+# (PERF.md, PR 37). By rows a step costs its B indices: a gather of B rows
+# of 128 nodes, their totals and a scatter of B scalars into the level's
+# own slice, ~40 ns an index for both trees and ~10 us at least. Whole it
+# costs the bytes of the level below: a window a round over it, ~0.024 ns
+# a node for both trees (50 us over 2^21 leaves) and ~1 us a window at
+# least. A level more than ``_WHOLE_NODES_PER_LEAF * B`` nodes wide is
+# therefore repaired by its touched rows, a narrower one whole.
+_WHOLE_NODES_PER_LEAF = 4096
 
 
-def _scatter_levels(capacity: int, batch: int) -> int:
-    """Levels above the leaves that :func:`set_leaves` repairs by scatter
-    before the dense reduction takes over: those wider than
-    ``_DENSE_NODES_PER_LEAF * max(batch, _DENSE_MIN_BATCH)`` nodes. Static
-    shape arithmetic, so one compiled program per (capacity, B)."""
-    dense_width = _DENSE_NODES_PER_LEAF * max(batch, _DENSE_MIN_BATCH)
-    kept, width = 0, capacity // 2
-    while width > dense_width:
-        kept, width = kept + 1, width // 2
-    return kept
+def kept_levels(capacity: int) -> tuple[int, ...]:
+    """The levels of a ``capacity``-leaf tree (root 0, leaves
+    ``log2(capacity)``) whose nodes are maintained, from the root down:
+    the root, then every seventh level counted from the leaves. They are
+    what :func:`descend` reads and what :func:`set_leaves` writes; the
+    one definition keeps the reader and the writer from drifting."""
+    levels = _levels(capacity)
+    first = levels % _ROW_LEVELS or min(levels, _ROW_LEVELS)
+    return (0,) * (first > 0) + tuple(range(first, levels + 1, _ROW_LEVELS))
+
+
+def repair_plan(capacity: int, batch: int) -> tuple[tuple[int, int, str], ...]:
+    """The steps of :func:`set_leaves` for ``batch`` leaves, from the
+    leaves up: ``(kept level already right, kept level repaired from it,
+    "rows" | "whole")``. Static shape arithmetic, so one compiled program
+    a (capacity, B). The step under the root is one static slice of at
+    most 128 nodes and always whole; once a step is whole so is every
+    step above it (the levels only narrow)."""
+    kept = kept_levels(capacity)
+    return tuple(
+        (below, above,
+         "rows" if above and _WHOLE_NODES_PER_LEAF * batch < 1 << below
+         else "whole")
+        for above, below in zip(kept[-2::-1], kept[::-1]))
+
+
+def plan_text(capacity: int, batch: int) -> str:
+    """:func:`repair_plan` as ``train``'s ``plan:`` line prints it:
+    ``21>14 rows,14>7 whole,7>root whole``."""
+    return ",".join(f"{below}>{above or 'root'} {form}"
+                    for below, above, form in repair_plan(capacity, batch))
 
 
 def _parents(s: Array, m: Array) -> tuple[Array, Array]:
@@ -113,92 +147,146 @@ def _parents(s: Array, m: Array) -> tuple[Array, Array]:
     float32 ``+`` (sum tree) and ``min`` (min tree) of each adjacent pair,
     one operation a parent (the identities a window starts from change no
     bit of a priority). A (1, 2) window along the lanes is the one form of
-    this the TPU runs near memory speed; ``reshape(-1, 2).sum(-1)`` may be
-    merged with the next level's into one reduction of four, which rounds
-    differently, and costs 40 times as much; ``x[0::2] + x[1::2]`` 300
-    times (v5e; PERF.md, PR 29). Both trees share one window: half the
-    operations in the chunk's loop body."""
+    this the TPU runs near memory speed over a whole level;
+    ``reshape(-1, 2).sum(-1)`` may be merged with the next level's into
+    one reduction of four, which rounds differently, and costs 40 times
+    as much; ``x[0::2] + x[1::2]`` 300 times (v5e; PERF.md, PR 29). Both
+    trees share one window."""
     return jax.lax.reduce_window(
         (s, m), (jnp.float32(0), jnp.float32(jnp.inf)),
         lambda a, b: (a[0] + b[0], jnp.minimum(a[1], b[1])),
         (1, 2), (1, 2), "VALID")
 
 
-def _repair_dense(s: Array, m: Array, width: int) -> tuple[Array, Array]:
-    """Recompute every level above the ``width``-wide one (already right)
-    of both trees, each from the level below it, and write nodes
-    ``[0, width)`` back as ONE static slice a tree (one write a level
-    costs the chunk a quarter more; PERF.md, PR 28). Node 0, which no
-    level owns, keeps its value."""
-    if width == 1:
-        return s, m
-    lanes = min(width, _LANES)
-    level = tuple(t[width:2 * width].reshape(width // lanes, lanes)
-                  for t in (s, m))
-    wide, narrow = [], []
-    while level[0].shape[0] > 1:  # levels of whole rows
-        rows = level[0].shape[0] // 2
-        level = tuple(x.reshape(rows, lanes) for x in _parents(*level))
-        wide.append(level)
-    while level[0].shape[1] > 1:  # the levels inside the first row
-        level = _parents(*level)
-        narrow.append(level)
+def _pair_rounds(row: Array, op=jnp.add):
+    """The levels above a row of ``w`` nodes ([rows, w]), rebuilt in place
+    on its lanes by log2(w) rounds of adjacent pairs, bottom up: yields
+    ``(left, is_right, under)`` a round, where lane ``j`` holds for its
+    ancestor at that level the ``op`` under the ancestor's LEFT child,
+    whether ``j`` lies under the right one, and the ``op`` under the
+    ancestor itself: ``op(left child, right child)``, one float32
+    operation on two children, never a longer reduction (which XLA may
+    merge with the next level's and round differently; PR 29). After the
+    last round every lane of ``under`` holds the row's total: the node
+    log2(w) levels above the row, as the invariant defines it."""
+    width = row.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    under, span = row, 1
+    while span < width:
+        is_right = (lane & span) != 0  # lane j's ancestor at this level
+        left = jnp.where(is_right, jnp.roll(under, span, axis=-1), under)
+        right = jnp.where(is_right, under, jnp.roll(under, -span, axis=-1))
+        under, span = op(left, right), 2 * span
+        yield left, is_right, under
 
-    def write(tree: Array, i: int) -> Array:
-        head = jnp.concatenate(
-            [tree[:1]] + [lv[i][0] for lv in reversed(narrow)])
-        block = jnp.concatenate(
-            [head[None]] + [lv[i] for lv in reversed(wide)])
-        return jax.lax.dynamic_update_slice(tree, block.reshape(-1), (0,))
 
-    return write(s, 0), write(m, 1)
+def _row_total(row: Array, op=jnp.add) -> Array:
+    """``[rows, w] -> [rows]``: each row's total by rounds of adjacent
+    pairs in place on its lanes (:func:`_pair_rounds`), one fusion over a
+    gathered ``[B, 128]``."""
+    for _left, _is_right, under in _pair_rounds(row, op):
+        pass
+    return under[:, 0]
+
+
+def _level_totals(s: Array, m: Array) -> tuple[Array, Array]:
+    """``[r, w] -> [r]`` for a whole level of both trees, ``w`` nodes a
+    row: every row's total by log2(w) rounds of adjacent pairs, a window a
+    round (:func:`_parents`), re-tiled to rows of ``w`` lanes while there
+    is more than one; none of the levels in between is written. Measured
+    against the rounds in place on the lanes (:func:`_row_total`, what a
+    gathered ``[B, 128]`` takes): 176 us against 381 over the 2^21 leaves
+    of both trees, and 4 us less over levels 14 and 7 (v5e; PERF.md,
+    PR 37)."""
+    width = s.shape[1]
+    for _ in range(width.bit_length() - 1):
+        s, m = _parents(s, m)
+        if s.shape[0] > 1:
+            s, m = s.reshape(-1, width), m.reshape(-1, width)
+    return s.reshape(-1), m.reshape(-1)
 
 
 def set_leaves(trees: PerTrees, idx: Array, p_alpha: Array) -> PerTrees:
     """Write ``p_alpha`` ([B], already ``priority ** alpha``) at leaves
-    ``idx`` ([B] int) and repair both trees' ancestors: the lowest
-    ``_scatter_levels(capacity, B)`` levels path by path, the rest whole.
-    Every node ends as the float32 ``+`` / ``min`` of its two children,
-    as a repair of the touched paths alone would leave it.
+    ``idx`` ([B] int) and repair the kept levels of both trees above
+    them (:func:`kept_levels`), each from the kept level below it, by
+    the steps of :func:`repair_plan`:
 
-    PRECONDITION: the trees are consistent on entry (every internal node
-    already is that ``+`` / ``min`` of its children). A dense level is
-    recomputed from all its children, touched or not, so an inconsistent
-    node elsewhere in the tree would be silently rewritten. ``init``,
-    ``FusedDeviceReplay.load_state_dict`` / ``restore`` (``init`` then
-    this function) and ``ShardedFusedReplay.load_state_dict`` (a float32
-    pairwise rebuild on the host) all hand over consistent trees.
+    - **rows**: the B rows of 128 nodes under the touched parents are
+      gathered (after the write below, so duplicates read identical
+      rows), each totalled by rounds of adjacent pairs, and the B totals
+      scattered into the level above; duplicates write identical values
+      and need no dedup;
+    - **whole**: the level above is recomputed from all of the level
+      below, a window a round, and written as one static slice.
 
-    Entries with ``idx >= capacity`` are PADS and are dropped entirely —
-    their scatter node is parked out of bounds through every scattered
-    level (``mode='drop'`` discards the writes; the paired gathers clamp
-    but only feed dropped writes), and the dense levels read only the
-    tree. Callers bucket batch sizes with such pads for compile-count
-    control; a pad-only call changes nothing."""
+    Either way a kept node ends as the invariant defines it (module
+    docstring), to the bit; a node of no kept level is never written.
+
+    PRECONDITION: the kept levels are consistent on entry. A whole step
+    recomputes a level from all the nodes below, touched or not, so an
+    inconsistent kept node elsewhere would be silently rewritten.
+    ``init``, ``FusedDeviceReplay.load_state_dict`` / ``restore``
+    (``init`` then this function) and ``ShardedFusedReplay
+    .load_state_dict`` (the same rounds on the host) all hand over
+    consistent trees.
+
+    Entries with ``idx >= capacity`` are PADS and are dropped entirely:
+    their node is parked out of bounds through every step by rows
+    (``mode='drop'`` discards the writes; the paired gathers clamp but
+    only feed dropped writes), and a whole step reads only the tree.
+    Callers bucket batch sizes with such pads for compile-count control;
+    a pad-only call changes nothing."""
     cap = trees.capacity
     idx32 = idx.astype(jnp.int32)
     valid = idx32 < cap
     # pads park at 2*cap (one past the array): writes there are dropped;
     # re-parked after every shift so they never alias a real node. (A
     # shifted-high sentinel like (2*cap) << levels would overflow int32
-    # at realistic capacities — 2*cap^2 >= 2^41 for a 1M ring.)
+    # at realistic capacities: 2*cap^2 >= 2^41 for a 1M ring.)
     node = jnp.where(valid, idx32 + cap, 2 * cap)
-    s = trees.sum_tree.at[node].set(p_alpha.astype(jnp.float32),
-                                    mode="drop")
-    # XLA leaves the winner among duplicate scatter indices unspecified, so
-    # the min tree copies the sum tree's POST-scatter leaf values — both
-    # trees then agree on the same winner by construction (two independent
-    # scatters could record different priorities for the same slot, making
-    # min_tree report a phantom minimum).
-    m = trees.min_tree.at[node].set(s[jnp.minimum(node, 2 * cap - 1)],
-                                    mode="drop")
-    kept = _scatter_levels(cap, idx32.size)
-    for _ in range(kept):
-        node = jnp.where(valid, node >> 1, 2 * cap)
-        left = jnp.minimum(node << 1, 2 * cap - 2)
-        s = s.at[node].set(s[left] + s[left | 1], mode="drop")
-        m = m.at[node].set(jnp.minimum(m[left], m[left | 1]), mode="drop")
-    s, m = _repair_dense(s, m, cap >> kept)
+    with jax.named_scope("writeback.leaves"):
+        s = trees.sum_tree.at[node].set(p_alpha.astype(jnp.float32),
+                                        mode="drop")
+        # XLA leaves the winner among duplicate scatter indices
+        # unspecified, so the min tree copies the sum tree's POST-scatter
+        # leaf values: both trees then agree on the same winner by
+        # construction (two independent scatters could record different
+        # priorities for the same slot, making min_tree report a phantom
+        # minimum).
+        m = trees.min_tree.at[node].set(s[jnp.minimum(node, 2 * cap - 1)],
+                                        mode="drop")
+    # every step makes the whole kept level above as an array of its own,
+    # writes it as one static slice and hands it to the step above: a
+    # whole step then reads what the step below made, not the tree again
+    level = None
+    for below, above, form in repair_plan(cap, idx32.size):
+        width = 1 << above
+        if form == "whole":
+            with jax.named_scope("writeback.whole"):
+                if level is None:
+                    level = tuple(t[1 << below:2 << below] for t in (s, m))
+                lanes = min(1 << below, _LANES)  # under the root: one row
+                level = _level_totals(*(x.reshape(-1, lanes)
+                                        for x in level))
+        else:
+            with jax.named_scope("writeback.rows"):
+                # the node _ROW_LEVELS up is the index of the row under it
+                node = jnp.where(valid, node >> _ROW_LEVELS, 2 * cap)
+                row = jnp.minimum(node, 2 * cap // _LANES - 1)
+                totals = (_row_total(s.reshape(-1, _LANES)[row]),
+                          _row_total(m.reshape(-1, _LANES)[row],
+                                     jnp.minimum))
+                # B scalars cost ~86 ns an index scattered into the 16 MB
+                # tree where it lies in HBM and ~6 into the level's own
+                # slice, which the compiler keeps in fast memory (24.6
+                # against 1.6 us a tree at level 14; v5e, PERF.md, PR 37)
+                at = jnp.where(valid, node - width, width)
+                level = tuple(
+                    t[width:2 * width].at[at].set(x, mode="drop")
+                    for t, x in zip((s, m), totals))
+        s, m = (jax.lax.dynamic_update_slice(t, x, (width,))
+                for t, x in zip((s, m), level))
     return PerTrees(s, m, trees.max_priority)
 
 
@@ -235,9 +323,6 @@ def strata_mass(u: Array, total: Array) -> Array:
     return (jnp.arange(b) + u) * (total / b)
 
 
-_ROW_LEVELS = _LANES.bit_length() - 1  # 7: binary levels a row spans
-
-
 def _walk_row(row: Array, p: Array) -> tuple[Array, Array]:
     """The binary decisions below a node whose ``w`` descendants log2(w)
     levels down hold ``row`` ([B, w], or [1, w] where every query stands
@@ -250,9 +335,9 @@ def _walk_row(row: Array, p: Array) -> tuple[Array, Array]:
     - the levels between node and row are rebuilt from the row, in place
       on its lanes: ``left`` holds, in lane ``j``, the sum under the LEFT
       child of ``j``'s ancestor at that level, and ``left + right`` the
-      sum under the ancestor: one float32 ``+`` of two children, the add
-      ``set_leaves`` made (its invariant), never a longer sum (a reduction
-      XLA may merge with the next level's rounds differently; PR 29);
+      sum under the ancestor (:func:`_pair_rounds`: the rounds of pairs
+      the invariant is stated in, so the rebuilt node is the value a
+      stored level would hold, to the bit);
     - from the top down each lane makes the walk's own compare and
       subtract against its ancestor's left child. Lanes under one node
       hold one mass, so they decide alike, and the half whose own bit is
@@ -268,13 +353,8 @@ def _walk_row(row: Array, p: Array) -> tuple[Array, Array]:
     ``take_along_axis``, a lane gather (3 times the walk's time)."""
     width = row.shape[-1]
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
-    steps, under, span = [], row, 1
-    while span < width:
-        is_right = (lane & span) != 0  # lane j's ancestor at this level
-        left = jnp.where(is_right, jnp.roll(under, span, axis=-1), under)
-        right = jnp.where(is_right, under, jnp.roll(under, -span, axis=-1))
-        steps.insert(0, (left, is_right))
-        under, span = left + right, 2 * span
+    steps = [(left, is_right)
+             for left, is_right, _under in _pair_rounds(row)][::-1]
     mass = jnp.broadcast_to(p[:, None], (p.shape[0], width))
     on_path = jnp.ones(mass.shape, bool)
     for left, is_right in steps:  # from the top down
@@ -304,26 +384,27 @@ def descend(sum_tree: Array, mass: Array) -> Array:
     of ``_LANES`` nodes every ``_ROW_LEVELS`` levels: in the flat heap the
     128 descendants seven levels below node ``n`` are nodes ``128 n ..
     128 n + 127``, row ``n`` of ``sum_tree.reshape(-1, 128)`` (the same
-    bytes: no copy), and ``set_leaves``'s invariant lets :func:`_walk_row`
-    rebuild the six levels between from the row. The levels left over
-    when log2(capacity) is no multiple of seven come first, from ONE
-    static slice under the root (a tree of under 128 leaves is that slice
-    and nothing else); the step count follows the static capacity, so
-    there is one compiled walk a (capacity, B). Every decision is the
-    float32 compare and subtract the level-by-level walk makes, on the
-    same values in the same order, so the slots are that walk's to the
-    bit (tests/test_device_per.py keeps it as the oracle)."""
+    bytes: no copy), and the invariant lets :func:`_walk_row` rebuild the
+    six levels between from the row: the rows read are the kept levels
+    (:func:`kept_levels`), the only ones ``set_leaves`` maintains. The
+    levels left over when log2(capacity) is no multiple of seven come
+    first, from ONE static slice under the root (a tree of under 128
+    leaves is that slice and nothing else); the step count follows the
+    static capacity, so there is one compiled walk a (capacity, B). Every
+    decision is the float32 compare and subtract the level-by-level walk
+    makes, on the same values in the same order, so the slots are that
+    walk's to the bit (tests/test_device_per.py keeps it as the oracle)."""
     cap = sum_tree.shape[0] // 2
-    levels = _levels(cap)
-    first = min(levels, levels % _ROW_LEVELS or _ROW_LEVELS)
+    kept = kept_levels(cap)
     p = mass.reshape(-1)
     node = jnp.ones(p.shape, jnp.int32)
-    if first:
-        p, pos = _walk_row(sum_tree[1 << first:2 << first][None], p)
-        node = (1 << first) + pos
-    for _ in range((levels - first) // _ROW_LEVELS):
-        p, pos = _walk_row(sum_tree.reshape(-1, _LANES)[node], p)
-        node = node * _LANES + pos
+    for above, below in zip(kept, kept[1:]):
+        if above == 0:  # every query stands at the root
+            p, pos = _walk_row(sum_tree[1 << below:2 << below][None], p)
+            node = (1 << below) + pos
+        else:
+            p, pos = _walk_row(sum_tree.reshape(-1, _LANES)[node], p)
+            node = node * _LANES + pos
     return (node - cap).reshape(mass.shape)
 
 

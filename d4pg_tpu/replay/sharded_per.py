@@ -453,16 +453,20 @@ class ShardedFusedReplay:
                 sz = int(self._size[sh])
                 sum_tree[sh, c:c + sz] = leaves[sh, :sz]
                 min_tree[sh, c:c + sz] = leaves[sh, :sz]
-            # rebuild internal nodes level by level, vectorized across
-            # shards (a per-node Python loop would be ~1M iterations at
-            # production capacities)
-            lo = c
-            while lo > 1:
-                lo //= 2
-                kids_s = sum_tree[:, 2 * lo:4 * lo].reshape(n, -1, 2)
-                kids_m = min_tree[:, 2 * lo:4 * lo].reshape(n, -1, 2)
-                sum_tree[:, lo:2 * lo] = kids_s.sum(-1)
-                min_tree[:, lo:2 * lo] = kids_m.min(-1)
+            # rebuild the kept levels as device_per.set_leaves leaves them
+            # (its invariant: rounds of adjacent pairs from the kept level
+            # below, float32; no other node is written), vectorized across
+            # shards, so a restored tree equals a live one array for array
+            from d4pg_tpu.replay.device_per import repair_plan
+
+            for below, above, _form in repair_plan(c, c):
+                lvl_s = sum_tree[:, 1 << below:2 << below]
+                lvl_m = min_tree[:, 1 << below:2 << below]
+                for _ in range(below - above):
+                    lvl_s = lvl_s[:, 0::2] + lvl_s[:, 1::2]
+                    lvl_m = np.minimum(lvl_m[:, 0::2], lvl_m[:, 1::2])
+                sum_tree[:, 1 << above:2 << above] = lvl_s
+                min_tree[:, 1 << above:2 << above] = lvl_m
             self.trees = ShardedPerTrees(
                 sum_tree=to_global(sum_tree),
                 min_tree=to_global(min_tree),

@@ -65,6 +65,7 @@ from d4pg_tpu.parallel import (
 )
 from d4pg_tpu.parallel.mesh import DATA_AXIS
 from d4pg_tpu.replay import LinearSchedule, PrioritizedReplayBuffer, ReplayBuffer
+from d4pg_tpu.replay import device_per as dper
 from d4pg_tpu.replay.uniform import TransitionBatch
 
 
@@ -475,6 +476,13 @@ def train(cfg: ExperimentConfig) -> dict:
         plan["ring_on"] = _platforms(buffer.storage.obs)
         if hasattr(buffer, "formats"):  # the one-device ring
             plan["ring_layout"] = _layouts(buffer.formats)
+        if hasattr(buffer, "block_rows") and buffer.trees is not None:
+            # how set_leaves repairs the trees at the chunk's and the
+            # commit's batch (static per shape; replay/device_per.py)
+            cap = buffer.trees.capacity
+            plan["tree_repair"] = (
+                f"chunk:{dper.plan_text(cap, cfg.batch_size)};"
+                f"commit:{dper.plan_text(cap, buffer.block_rows)}")
     if isinstance(buffer, PrioritizedReplayBuffer):
         # the only buffer with a host tree (the fused path has none):
         # name the backend that loaded — the C++ library is built on

@@ -139,12 +139,17 @@ _NEW_JIT = jax.jit(dper.set_leaves)
 
 
 def _seeded_trees(cap, rng):
-    """A consistent tree with history: three quarters of the ring written
-    (the rest still 0 / inf), max_priority off its initial 1."""
+    """A consistent tree with history, as ``set_leaves`` and as the oracle
+    leave it: three quarters of the ring written in one call (the rest
+    still 0 / inf), max_priority off its initial 1."""
     n = max(1, 3 * cap // 4)
-    trees = _ORACLE_JIT(dper.init(cap), jnp.arange(n),
-                        jnp.asarray(rng.uniform(0.01, 5.0, n), jnp.float32))
-    return trees._replace(max_priority=jnp.float32(3.25))
+    p = jnp.asarray(rng.uniform(0.01, 5.0, n), jnp.float32)
+    trees, oracle = (
+        fn(dper.init(cap), jnp.arange(n), p)._replace(
+            max_priority=jnp.float32(3.25))
+        for fn in (_NEW_JIT, _ORACLE_JIT))
+    _assert_same_trees(trees, oracle)
+    return trees, oracle
 
 
 def _batch(kind, cap, rng):
@@ -170,11 +175,26 @@ def _batch(kind, cap, rng):
     return jnp.asarray(idx, jnp.int32), jnp.asarray(value[idx])
 
 
+def _kept(cap):
+    """Mask over the ``2 * cap`` nodes: those of a kept level."""
+    mask = np.zeros(2 * cap, bool)
+    for level in dper.kept_levels(cap):
+        mask[1 << level:2 << level] = True
+    return mask
+
+
 def _assert_same_trees(got, want):
-    """Every node of both trees and the running max, to the bit."""
+    """The kept levels of both trees (node 1 and the leaves among them)
+    and the running max are ``want``'s to the bit, ``want`` an oracle's
+    trees with every level written; every other node of ``got`` still
+    holds what ``init`` gave it: it was never written."""
+    kept, fresh = _kept(got.capacity), dper.init(got.capacity)
+    assert kept[1] and kept[got.capacity:].all() and not kept[0]
     for name in ("sum_tree", "min_tree", "max_priority"):
         g, w = np.asarray(getattr(got, name)), np.asarray(getattr(want, name))
         assert g.dtype == w.dtype and g.shape == w.shape, name
+        if g.ndim:  # a tree: every other node is as `init` left it
+            w = np.where(kept, w, np.asarray(getattr(fresh, name)))
         np.testing.assert_array_equal(g.view(np.uint32), w.view(np.uint32),
                                       err_msg=name)
 
@@ -187,50 +207,84 @@ BATCH_KINDS = ("one", "random256", "wrapping_block", "with_pads",
 @pytest.mark.parametrize("kind", BATCH_KINDS)
 @pytest.mark.parametrize("cap", (16, 256, 4096, 65536))
 def test_set_leaves_is_bitwise_the_level_by_level_repair(cap, kind, jit, rng):
-    trees = _seeded_trees(cap, rng)
+    trees, oracle_trees = _seeded_trees(cap, rng)
     idx, p = _batch(kind, cap, rng)
     new, oracle = ((_NEW_JIT, _ORACLE_JIT) if jit
                    else (dper.set_leaves, _level_by_level))
-    got, want = new(trees, idx, p), oracle(trees, idx, p)
+    got, want = new(trees, idx, p), oracle(oracle_trees, idx, p)
     _assert_same_trees(got, want)
     if kind == "pads_only":  # changes nothing
-        _assert_same_trees(got, trees)
+        _assert_same_trees(got, oracle_trees)
 
 
-@pytest.mark.parametrize("batch", (1, 48))
-@pytest.mark.parametrize("levels", (16, 17, 18, 19, 20))
-def test_set_leaves_at_the_split(levels, batch, rng):
-    """Trees a level shorter and longer than where the repair turns dense:
-    at B <= 16 a level of 65,536 nodes is the widest recomputed whole
-    (capacity 2^17: every level dense; 2^18: one level scattered below
-    it); at B = 48, 196,608 (2^18: all dense; 2^19 and 2^20: one and
-    two)."""
-    cap = 1 << levels
-    dense_width = dper._DENSE_NODES_PER_LEAF * max(batch,
-                                                   dper._DENSE_MIN_BATCH)
-    kept = dper._scatter_levels(cap, batch)
-    assert kept == max(0, levels - 1 - int(math.log2(dense_width)))
-    trees = _seeded_trees(cap, rng)
-    for _ in range(2):  # the second call starts from the first's trees
-        idx = jnp.asarray(rng.integers(0, cap, batch), jnp.int32)
-        p = jnp.asarray(rng.uniform(0.01, 5.0, batch), jnp.float32)
-        got, want = _NEW_JIT(trees, idx, p), _ORACLE_JIT(trees, idx, p)
-        _assert_same_trees(got, want)
-        trees = got
+def _rows_above(batch):
+    """The narrowest level a step by rows starts from at ``batch`` leaves,
+    said apart from ``repair_plan``: the first more than
+    ``_WHOLE_NODES_PER_LEAF * batch`` nodes wide."""
+    return (dper._WHOLE_NODES_PER_LEAF * batch).bit_length()
 
 
-@pytest.mark.parametrize("ratio", (1, 8, 64))
-def test_set_leaves_joins_scattered_and_dense_levels_anywhere(
-        ratio, rng, monkeypatch):
-    """With the constants turned down a small tree has the join in its
-    middle (capacity 4,096, B 4: 9, 6 and 3 levels scattered), where a
-    sequence of inserts, duplicate-laden updates and padded batches must
-    still leave the oracle's trees."""
-    monkeypatch.setattr(dper, "_DENSE_NODES_PER_LEAF", ratio)
-    monkeypatch.setattr(dper, "_DENSE_MIN_BATCH", 1)
+@pytest.mark.parametrize("batch", (1, 4, 48, 256, 512, 4096, "capacity"))
+def test_repair_plan_either_side_of_each_change_of_form(batch, rng):
+    """A step is by rows where the level below is more than 4,096 nodes a
+    leaf wide, whole where it is narrower, and whole under the root: at
+    the capacity where the leaves' step changes form and a level either
+    side of it (B 1: 2^12 whole, 2^13 rows; 4: 2^15; 48: 2^18; 256:
+    2^21, the MLP cells' chunk; 512: 2^22; 4,096: 2^25; a batch of every
+    leaf: never), every step above follows the same rule, and up to 2^18
+    leaves the trees are the oracle's through two calls."""
+    turns = 22 if batch == "capacity" else _rows_above(batch)
+    for levels in (turns - 1, turns, turns + 1):
+        cap = 1 << levels
+        b = cap if batch == "capacity" else batch
+        plan = dper.repair_plan(cap, b)
+        kept = dper.kept_levels(cap)
+        assert [s[0] for s in plan] == list(kept[:0:-1])
+        assert [s[1] for s in plan] == list(kept[-2::-1])
+        for below, above, form in plan:
+            rows = above > 0 and below >= _rows_above(b)
+            assert form == ("rows" if rows else "whole"), (cap, below)
+        assert (plan[0][2] == "rows") == (batch != "capacity"
+                                          and levels >= turns)
+        assert plan[-1][1:] == (0, "whole")
+        if levels > 18:
+            continue
+        trees, oracle_trees = _seeded_trees(cap, rng)
+        for _ in range(2):  # the second call starts from the first's trees
+            idx = jnp.asarray(rng.integers(0, cap, b), jnp.int32)
+            p = jnp.asarray(rng.uniform(0.01, 5.0, 2 * cap), jnp.float32)[idx]
+            trees = _NEW_JIT(trees, idx, p)
+            oracle_trees = _ORACLE_JIT(oracle_trees, idx, p)
+            _assert_same_trees(trees, oracle_trees)
+
+
+@pytest.mark.parametrize("levels, kept", [
+    (0, (0,)), (1, (0, 1)), (6, (0, 6)), (7, (0, 7)), (8, (0, 1, 8)),
+    (14, (0, 7, 14)), (15, (0, 1, 8, 15)), (16, (0, 2, 9, 16)),
+    (21, (0, 7, 14, 21)), (22, (0, 1, 8, 15, 22))])
+def test_kept_levels_are_every_seventh_from_the_leaves_and_the_root(levels,
+                                                                    kept):
+    assert dper.kept_levels(1 << levels) == kept
+
+
+# which steps go by rows in a 4,096-leaf tree (kept levels 0, 5, 12) at
+# B = 4 / 256 / 2,048 under each value of the rule's constant
+FORCED = {"rows": 0, "whole": 1 << 30, "rows_under_256": 16}
+
+
+@pytest.mark.parametrize("form", FORCED)
+def test_set_leaves_rows_and_whole_forced_in_turn(form, rng, monkeypatch):
+    """With the rule's constant turned a 4,096-leaf tree takes its 12 > 5
+    step by rows at every batch, whole at every batch, and by rows under
+    256 leaves only; through a sequence of inserts, duplicate-laden
+    updates and padded batches each must leave the oracle's trees."""
+    monkeypatch.setattr(dper, "_WHOLE_NODES_PER_LEAF", FORCED[form])
     cap = 4096
-    assert dper._scatter_levels(cap, 4) == {1: 9, 8: 6, 64: 3}[ratio]
-    new = jax.jit(dper.set_leaves)  # traced under these constants
+    assert [dper.repair_plan(cap, b)[0][2] for b in (4, 256, 2048)] == {
+        "rows": ["rows"] * 3, "whole": ["whole"] * 3,
+        "rows_under_256": ["rows", "whole", "whole"]}[form]
+    assert dper.repair_plan(cap, 4)[1] == (5, 0, "whole")
+    new = jax.jit(dper.set_leaves)  # traced under this constant
     got = want = dper.init(cap)
     for step in range(6):
         kind = ("wrapping_block", "random256", "with_pads")[step % 3]
@@ -240,21 +294,32 @@ def test_set_leaves_joins_scattered_and_dense_levels_anywhere(
         _assert_same_trees(got, want)
 
 
+def _pairwise(level, op, rounds):
+    """numpy: ``rounds`` rounds of adjacent pairs over a level."""
+    for _ in range(rounds):
+        level = op(level[0::2], level[1::2])
+    return level
+
+
 def test_set_leaves_duplicates_agree_between_the_trees(rng):
     """Duplicates with DIFFERENT values: whichever write wins, the min
-    tree's leaf is the sum tree's, and every node of both trees is the
-    float32 op of its two children."""
+    tree's leaf is the sum tree's, and every kept level of both trees is
+    the numpy rebuild of the kept level below it by rounds of adjacent
+    float32 pairs (the invariant)."""
     cap = 1024
     idx = jnp.asarray(rng.integers(0, 64, 256), jnp.int32)
     p = jnp.asarray(rng.uniform(0.01, 5.0, 256), jnp.float32)
-    trees = _NEW_JIT(_seeded_trees(cap, rng), idx, p)
+    trees = _NEW_JIT(_seeded_trees(cap, rng)[0], idx, p)
     s, m = np.asarray(trees.sum_tree), np.asarray(trees.min_tree)
     touched = cap + np.unique(np.asarray(idx))
     np.testing.assert_array_equal(s[touched], m[touched])
-    kids_s, kids_m = s[2:].reshape(-1, 2), m[2:].reshape(-1, 2)
-    np.testing.assert_array_equal(s[1:cap], kids_s[:, 0] + kids_s[:, 1])
-    np.testing.assert_array_equal(m[1:cap],
-                                  np.minimum(kids_m[:, 0], kids_m[:, 1]))
+    kept = dper.kept_levels(cap)
+    assert kept == (0, 3, 10)
+    for above, below in zip(kept, kept[1:]):
+        for tree, op in ((s, np.add), (m, np.minimum)):
+            np.testing.assert_array_equal(
+                tree[1 << above:2 << above],
+                _pairwise(tree[1 << below:2 << below], op, below - above))
     assert s[0] == 0.0 and m[0] == np.inf  # node 0 belongs to no level
 
 
@@ -265,43 +330,118 @@ def _abstract_trees(cap):
         jax.ShapeDtypeStruct((), jnp.float32))
 
 
-def _lowered_counts(cap, batch):
+def _lowered(cap, batch):
+    """What ``set_leaves`` lowers to (trace and lower only): ``(scatters,
+    gathers of 128-node rows, [nodes a tree each reduce_window reads])``,
+    both trees in every window."""
+    import re
+
     text = jax.jit(dper.set_leaves).lower(
         _abstract_trees(cap), jax.ShapeDtypeStruct((batch,), jnp.int32),
         jax.ShapeDtypeStruct((batch,), jnp.float32)).as_text()
-    return (text.count('"stablehlo.scatter"('),
-            text.count('"stablehlo.reduce_window"('))
+    windows = [int(r) * int(w) for r, w in re.findall(
+        r"\}\) : \(tensor<(\d+)x(\d+)xf32>, tensor<\d+x\d+xf32>, "
+        r"tensor<f32>, tensor<f32>\) ->", text)]
+    assert len(windows) == text.count('"stablehlo.reduce_window"(')
+    gathers = [ln for ln in text.splitlines() if '"stablehlo.gather"(' in ln]
+    rows = [ln for ln in gathers
+            if "slice_sizes = array<i64: 1, 128>" in ln]
+    assert all(f"(tensor<{2 * cap // 128}x128xf32>," in ln for ln in rows)
+    return text.count('"stablehlo.scatter"('), len(rows), windows
+
+
+def _windows_of(plan):
+    """The nodes a tree each ``reduce_window`` of a plan reads: a window a
+    round of every whole step."""
+    return sorted(((1 << below) >> i for below, above, form in plan
+                   if form == "whole" for i in range(below - above)),
+                  reverse=True)
 
 
 @pytest.mark.parametrize("batch", (256, 4096))
-def test_set_leaves_scatter_count_at_production_capacity(batch):
-    """The structure of the repair, read off the lowered module (trace and
-    lower only): 2 x (levels kept) + 2 scatters, the 2 being the leaf
-    writes, where the level-by-level repair had 2 x 21 + 2 = 44; one
-    window a dense level, both trees in it. At the benchmark's shapes
-    (2,097,152 leaves; the chunk's B = 256, the commit's 4,096) no level
-    is kept, and a ring twice or four times the size adds at most a
-    level each, not two scatters a level of the whole tree."""
-    levels = 21
+def test_set_leaves_structure_at_production_capacity(batch):
+    """The structure of the repair at the MLP cells' 2,097,152 leaves,
+    read off the lowered module. The chunk's B = 256: the two leaf
+    scatters, two gathers of 256 rows of 128 leaves, two scatters of their
+    totals (into level 14's own slice), and fourteen windows of which the
+    widest reads level 14 (16,384 nodes, 64 KB a tree) and none the
+    leaves, where the parent had 21, the widest over all 2,097,152 leaves
+    of both trees. The commit's 4,096: the two leaf scatters and
+    twenty-one windows, none of which reads more than one kept level's
+    span, and three levels written in place of twenty-one; a ring twice
+    or four times the size adds no scatter and no gather to either."""
     for grow in (0, 1, 2):
-        cap = 1 << (levels + grow)
-        kept = dper._scatter_levels(cap, batch)
-        scatters, windows = _lowered_counts(cap, batch)
-        assert scatters == 2 * kept + 2
-        assert windows == levels + grow - kept
-        if grow == 0:
-            assert kept == 0
-        assert kept <= grow
+        cap = 1 << (21 + grow)
+        scatters, rows, windows = _lowered(cap, batch)
+        plan = dper.repair_plan(cap, batch)
+        by_rows = [form for _b, _a, form in plan].count("rows")
+        assert by_rows == (1 if batch == 256 else 0)
+        assert (scatters, rows) == (2 + 2 * by_rows, 2 * by_rows)
+        assert sorted(windows, reverse=True) == _windows_of(plan)
+        assert len(windows) == 21 + grow - 7 * by_rows
+        assert max(windows) == cap >> 7 * by_rows
 
 
-def test_set_leaves_scatter_count_follows_the_batch():
-    """Few leaves on a large tree keep the lowest levels path by path: a
-    per-row insert (``drain_per_row``) into the 2M-leaf ring scatters 4
-    levels and reduces 17."""
-    assert _lowered_counts(1 << 21, 1) == (2 * 4 + 2, 17)
-    assert _lowered_counts(1 << 21, 64) == (2 * 2 + 2, 19)
-    assert _lowered_counts(1 << 16, 512) == (2, 16)  # the pixel cell
-    assert _lowered_counts(1 << 15, 4) == (2, 15)  # cell 4
+def test_set_leaves_structure_follows_the_batch():
+    """Few leaves on a large tree go by rows further up: a per-row insert
+    (``drain_per_row``) into the 2M-leaf ring gathers a row a tree at two
+    steps and runs the seven windows under the root only; the pixel
+    cell's 512 leaves on 65,536 and a batch of every leaf are whole at
+    every step."""
+    assert _lowered(1 << 21, 1) == (2 + 4, 4, [128 >> i for i in range(7)])
+    assert _lowered(1 << 21, 64)[:2] == (2 + 2, 2)
+    assert _lowered(1 << 16, 512) == (2, 0, [1 << 16 >> i
+                                             for i in range(16)])
+    assert _lowered(1 << 15, 4)[:2] == (2 + 2, 2)  # cells 4 and 6
+    assert _lowered(1 << 21, 1 << 21)[:2] == (2, 0)
+
+
+# the benchmark's six cells: what the chunk's write-back and the commit's
+# insert do to the trees at the shapes of each cell's configuration file
+CELL_PLANS = {
+    "humanoid-mlp.learn-static": (
+        "21>14 rows,14>7 whole,7>root whole",
+        "21>14 whole,14>7 whole,7>root whole"),
+    "dmc-pixels-drq.learn-static": (
+        "16>9 whole,9>2 whole,2>root whole",
+        "16>9 whole,9>2 whole,2>root whole"),
+    "humanoid-mlp.learn-ingest": (
+        "21>14 rows,14>7 whole,7>root whole",
+        "21>14 whole,14>7 whole,7>root whole"),
+    "humanoid-mellum2-ep4.learn-static": (
+        "15>8 rows,8>1 whole,1>root whole",
+        "15>8 whole,8>1 whole,1>root whole"),
+    "humanoid-keye2-ep8.learn-static": (
+        "14>7 rows,7>root whole", "14>7 whole,7>root whole"),
+    "humanoid-lfm2-ep4.learn-static": (
+        "15>8 rows,8>1 whole,1>root whole",
+        "15>8 whole,8>1 whole,1>root whole"),
+}
+
+
+@pytest.mark.parametrize("cell", CELL_PLANS)
+def test_repair_plan_at_the_benchmarks_cells(cell):
+    """``repair_plan`` (as ``plan_text`` spells it for ``train``'s
+    ``plan:`` line) at each cell's ``(capacity, B)``: the chunk's batch
+    and the commit's block. Only the MLP cells' 2^21-leaf tree is wide
+    enough for 256 leaves to go by rows; the torso cells' batches of 2
+    and 4 do on their small trees; every commit is whole."""
+    import json
+    import os
+
+    from d4pg_tpu.replay.segment_tree import next_pow2
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        cells = {w["name"]: w["config"] for w in json.load(f)["workloads"]}
+    assert sorted(cells) == sorted(CELL_PLANS)
+    with open(os.path.join(root, "benchmark/configs",
+                           cells[cell] + ".json")) as f:
+        cfg = json.load(f)
+    cap = next_pow2(cfg["replay"]["capacity"])
+    chunk, commit = CELL_PLANS[cell]
+    assert dper.plan_text(cap, cfg["learner"]["batch_size"]) == chunk
+    assert dper.plan_text(cap, cfg["replay"]["block_rows"]) == commit
 
 
 def _walk_level_by_level(sum_tree, mass):
@@ -334,8 +474,14 @@ def _priorities(cap, rng, zero_share=0.1):
 
 
 def _full_trees(vals):
-    return _NEW_JIT(dper.init(vals.size), jnp.arange(vals.size),
-                    jnp.asarray(vals))
+    """``(trees, oracle)`` over the leaves ``vals``: as ``set_leaves``
+    leaves them (kept levels only: what ``descend`` gets) and as the
+    level-by-level repair does (every level: what the level-by-level
+    walk needs)."""
+    trees, oracle = (fn(dper.init(vals.size), jnp.arange(vals.size),
+                        jnp.asarray(vals)) for fn in (_NEW_JIT, _ORACLE_JIT))
+    _assert_same_trees(trees, oracle)
+    return trees, oracle
 
 
 def _masses(sum_tree, shape, rng):
@@ -361,7 +507,7 @@ def test_descend_is_bitwise_the_level_by_level_walk(cap, blocks, jit, rng):
     from d4pg_tpu.replay.sampler import ShardSlicePerTrees
 
     vals = _priorities(cap, rng)
-    trees = _full_trees(vals)
+    trees, every_level = _full_trees(vals)
     batch = min(64, 2 * cap)
     mass = _masses(trees.sum_tree,
                    (batch,) if blocks is None else (blocks, batch), rng)
@@ -369,7 +515,7 @@ def test_descend_is_bitwise_the_level_by_level_walk(cap, blocks, jit, rng):
                    else (dper.descend, _walk_level_by_level))
     got = new(trees.sum_tree, mass)
     assert got.dtype == jnp.int32
-    _assert_same_slots(got, oracle(trees.sum_tree, mass))
+    _assert_same_slots(got, oracle(every_level.sum_tree, mass))
     twin = ShardSlicePerTrees(cap, 1, dtype=np.float32)
     twin.set(np.arange(cap), vals)
     assert np.float32(twin.total()) == np.asarray(trees.sum_tree[1])
@@ -381,11 +527,12 @@ def test_descend_is_bitwise_the_level_by_level_walk(cap, blocks, jit, rng):
 def test_descend_under_vmap_over_a_shard_axis(cap, rng):
     """The sharded chunk's shape: trees stacked on a leading shard axis,
     each shard's queries through its own tree."""
-    shards = [_full_trees(_priorities(cap, rng)).sum_tree for _ in range(3)]
-    stacked = jnp.stack(shards)
-    mass = jnp.stack([_masses(t, (32,), rng) for t in shards])
+    shards = [_full_trees(_priorities(cap, rng)) for _ in range(3)]
+    stacked = jnp.stack([trees.sum_tree for trees, _oracle in shards])
+    mass = jnp.stack([_masses(t, (32,), rng) for t in stacked])
     got = jax.jit(jax.vmap(dper.descend))(stacked, mass)
-    want = jnp.stack([_WALK_ORACLE_JIT(t, m) for t, m in zip(shards, mass)])
+    want = jnp.stack([_WALK_ORACLE_JIT(oracle.sum_tree, m)
+                      for (_trees, oracle), m in zip(shards, mass)])
     _assert_same_slots(got, want)
 
 
@@ -399,20 +546,20 @@ def test_descend_goes_right_on_a_tie_at_every_level(depth, rng):
     random small integers every prefix sum is a tie somewhere, and lands
     on the next leaf that has any mass."""
     cap = 1 << 14
-    ones = _full_trees(np.ones(cap, np.float32)).sum_tree
+    ones = _full_trees(np.ones(cap, np.float32))[0].sum_tree
     tie = jnp.full((8,), float(cap >> depth), jnp.float32)
     _assert_same_slots(_DESCEND_JIT(ones, tie),
                        np.full(8, cap >> depth, np.int32))
     vals = rng.integers(0, 4, cap).astype(np.float32)
     vals[-1] = 1.0
-    tree = _full_trees(vals).sum_tree
+    tree, every_level = (x.sum_tree for x in _full_trees(vals))
     prefix = np.cumsum(vals, dtype=np.float64)
     # ties whose deciding node is `depth` levels down: the prefix sums
     # at the right edge of each left subtree at that depth
     edges = np.arange(cap >> depth, cap, cap >> (depth - 1))[:64] - 1
     mass = jnp.asarray(prefix[edges], jnp.float32)
     got = _DESCEND_JIT(tree, mass)
-    _assert_same_slots(got, _WALK_ORACLE_JIT(tree, mass))
+    _assert_same_slots(got, _WALK_ORACLE_JIT(every_level, mass))
     _assert_same_slots(got, np.searchsorted(prefix, prefix[edges],
                                             side="right").astype(np.int32))
     assert (np.asarray(got) > edges).all()  # never the left subtree
@@ -423,11 +570,11 @@ def test_descend_past_the_total_lands_on_the_last_leaf(cap, rng):
     """``mass >= total`` goes right at every node, onto the last leaf;
     ``sample_from_uniforms`` then clips it onto the written ones."""
     vals = rng.integers(1, 4, cap).astype(np.float32)
-    trees = _full_trees(vals)
+    trees, every_level = _full_trees(vals)
     total = trees.sum_tree[1]
     mass = jnp.stack([total, 2 * total, jnp.float32(jnp.inf)])
     got = _DESCEND_JIT(trees.sum_tree, mass)
-    _assert_same_slots(got, _WALK_ORACLE_JIT(trees.sum_tree, mass))
+    _assert_same_slots(got, _WALK_ORACLE_JIT(every_level.sum_tree, mass))
     _assert_same_slots(got, np.full(3, cap - 1, np.int32))
     limit = jnp.int32(cap // 2)
     u = jnp.full((8,), np.nextafter(np.float32(1), np.float32(0)))
@@ -435,18 +582,19 @@ def test_descend_past_the_total_lands_on_the_last_leaf(cap, rng):
     assert idx.max() == cap // 2 - 1 and idx.min() >= 0
 
 
-@pytest.mark.parametrize("ratio", (1, 64, None),
-                         ids=("9_scattered", "3_scattered", "all_dense"))
-def test_descend_after_fifty_set_leaves_and_inserts(ratio, rng, monkeypatch):
+@pytest.mark.parametrize("form", ("rows", "whole", None),
+                         ids=("rows", "whole", "the_rules_own"))
+def test_descend_after_fifty_set_leaves_and_inserts(form, rng, monkeypatch):
     """The pairwise rebuild inside a row relies on ``set_leaves``'s
     invariant; it holds after fifty random ``set_leaves`` / ``insert``
-    calls through the scattered and the dense repair alike (constants
-    turned down as in the join test above; ``None`` leaves them)."""
+    calls through the repair by rows and the whole one alike (the rule's
+    constant turned as in the forced test above; ``None`` leaves it, and
+    a 4,096-leaf tree then goes whole)."""
     cap = 4096
-    if ratio is not None:
-        monkeypatch.setattr(dper, "_DENSE_NODES_PER_LEAF", ratio)
-        monkeypatch.setattr(dper, "_DENSE_MIN_BATCH", 1)
-    set_leaves = jax.jit(dper.set_leaves)  # traced under these constants
+    if form is not None:
+        monkeypatch.setattr(dper, "_WHOLE_NODES_PER_LEAF", FORCED[form])
+    assert dper.repair_plan(cap, 4)[0] == (12, 5, form or "whole")
+    set_leaves = jax.jit(dper.set_leaves)  # traced under this constant
     insert = jax.jit(dper.insert, static_argnames=("alpha",))
     trees = dper.init(cap)
     for call in range(50):
@@ -459,8 +607,12 @@ def test_descend_after_fifty_set_leaves_and_inserts(ratio, rng, monkeypatch):
             trees = trees._replace(max_priority=jnp.float32(1 + call))
         if call % 7 == 0 or call == 49:
             mass = _masses(trees.sum_tree, (64,), rng)
+            # the level-by-level walk's tree: every level, over the leaves
+            # the fifty calls have left
+            every_level = _ORACLE_JIT(dper.init(cap), jnp.arange(cap),
+                                      trees.sum_tree[cap:])
             _assert_same_slots(_DESCEND_JIT(trees.sum_tree, mass),
-                               _WALK_ORACLE_JIT(trees.sum_tree, mass))
+                               _WALK_ORACLE_JIT(every_level.sum_tree, mass))
 
 
 def _sample_and_weigh(descend, batch):
@@ -533,9 +685,11 @@ def test_the_scan_body_never_copies_the_tree_for_its_row_view(levels, batch):
     the CPU; ``tests/test_torso_v5e_compile.py`` asks the chip's compiler)
     inside a scan over donated, loop-carried trees that samples, weighs
     and writes back, the program makes a whole tree only where the
-    write-back does: no copy, transpose or convert of one, and no more
-    whole-tree instructions than the same loop over the level-by-level
-    walk has."""
+    write-back does: no transpose or convert of one, and no more
+    whole-tree instructions (copies among them: the CPU's compiler
+    answers the sum tree's two scatters of a step by rows with one; the
+    chip's does not) than the same loop over the level-by-level walk
+    has."""
     import re
 
     cap = 1 << levels
@@ -560,8 +714,9 @@ def test_the_scan_body_never_copies_the_tree_for_its_row_view(levels, batch):
             "parameter", "get-tuple-element", "bitcast", "reshape"))
 
     new = whole_tree_outputs(dper.descend)
-    assert new and not {"copy", "transpose", "convert"} & set(new)
-    assert len(new) <= len(whole_tree_outputs(_walk_level_by_level))
+    old = whole_tree_outputs(_walk_level_by_level)
+    assert new and not {"transpose", "convert"} & set(new)
+    assert len(new) <= len(old) and new.count("copy") <= old.count("copy")
 
 
 def test_commit_program_compiles_once_across_block_shapes(rng):
@@ -661,17 +816,9 @@ def test_device_trees_match_host_under_random_op_sequences(rng):
     # final: a batch of prefix queries descends to the same leaves
     mass = rng.uniform(0, s_host.sum() * 0.999, size=64)
     host_leaves = s_host.find_prefixsum(mass)
-    # replicate via the device descent on the same masses
-    p = jnp.asarray(mass, jnp.float32)
-    node = jnp.ones(64, jnp.int32)
-    import math
-    for _ in range(int(math.log2(CAP))):
-        left = node << 1
-        ls = trees.sum_tree[left]
-        go = p >= ls
-        p = jnp.where(go, p - ls, p)
-        node = jnp.where(go, left | 1, left)
-    dev_leaves = np.asarray(node) - CAP
+    # the device descent on the same masses
+    dev_leaves = np.asarray(dper.descend(trees.sum_tree,
+                                         jnp.asarray(mass, jnp.float32)))
     # f32 vs f64 partial sums can disagree exactly at a leaf boundary;
     # allow off-by-one-leaf there
     assert (np.abs(dev_leaves - host_leaves) <= 1).all()
@@ -823,9 +970,11 @@ def test_train_fused_uniform_async(tmp_path):
     assert np.isfinite(metrics["critic_loss"])
 
 
-def test_train_fused_her_goal_env(tmp_path):
+def test_train_fused_her_goal_env(tmp_path, capsys):
     """HER relabels stream through the fused device buffer like ordinary
-    rows (goal-conditioned obs, success-based dones)."""
+    rows (goal-conditioned obs, success-based dones). ``train``'s
+    ``plan:`` line says how the trees are repaired at the chunk's batch
+    and at the commit's block."""
     from d4pg_tpu.config import ExperimentConfig
     from d4pg_tpu.train import train
 
@@ -839,6 +988,11 @@ def test_train_fused_her_goal_env(tmp_path):
     metrics = train(cfg)
     assert np.isfinite(metrics["critic_loss"])
     assert "success_rate" in metrics
+    want = "chunk:11>4 whole,4>root whole;commit:11>4 whole,4>root whole"
+    assert metrics["plan"]["tree_repair"] == want
+    plan_line, = [ln for ln in capsys.readouterr().out.splitlines()
+                  if ln.startswith("plan: ")]
+    assert f" tree_repair={want}" in plan_line
 
 
 def test_fused_buffer_stage_drain(rng):

@@ -187,6 +187,59 @@ def test_sharded_state_dict_roundtrip(rng):
                                   np.asarray(src.storage.reward))
 
 
+@pytest.mark.parametrize("kind", ("fused", "sharded"))
+def test_restored_trees_equal_the_live_ones_array_for_array(kind, rng):
+    """A buffer restored from a state dict saved after a commit, a chunk's
+    write-backs and another commit holds both trees bit for bit: the kept
+    levels (``device_per.kept_levels``) as ``set_leaves`` left them step
+    by step (rebuilt in one ``set_leaves`` by ``FusedDeviceReplay``, on
+    the host by ``ShardedFusedReplay``), every other node what ``init``
+    gave it."""
+    from d4pg_tpu.learner.fused import make_fused_chunk
+    from d4pg_tpu.replay import device_per as dper
+    from d4pg_tpu.replay.fused_buffer import FusedDeviceReplay
+
+    config = D4PGConfig(obs_dim=4, act_dim=2, v_min=-10, v_max=10,
+                        n_atoms=11, hidden=(16, 16, 16))
+    state = init_state(config, jax.random.key(2))
+    if kind == "fused":
+        make = lambda: FusedDeviceReplay(1024, 4, 2, alpha=0.6,  # noqa: E731
+                                         block_rows=64)
+        fn = make_fused_chunk(config, k=3, batch_size=16, alpha=0.6,
+                              donate=False)
+    else:
+        mesh = _mesh(4)
+        make = lambda: ShardedFusedReplay(1024, 4, 2, mesh,  # noqa: E731
+                                          alpha=0.6)
+        fn = make_sharded_fused_chunk(config, mesh, k=3, batch_size=16,
+                                      alpha=0.6, donate=False)
+    src = make()
+    src.add(_batch(rng, 700))
+    src.drain()
+    _, src.trees, _ = fn(state, src.trees, src.storage, src.size)
+    src.add(_batch(rng, 100))
+    src.drain()
+    dst = make()
+    dst.load_state_dict(src.state_dict())
+    cap = src.trees.sum_tree.shape[-1] // 2
+    kept = np.zeros(2 * cap, bool)
+    for level in dper.kept_levels(cap):
+        kept[1 << level:2 << level] = True
+    assert len(dper.kept_levels(cap)) >= 3 and not kept.all()
+    fresh = dper.init(cap)
+    for name in ("sum_tree", "min_tree", "max_priority"):
+        live = np.asarray(getattr(src.trees, name))
+        np.testing.assert_array_equal(np.asarray(getattr(dst.trees, name)),
+                                      live, err_msg=name)
+        if name != "max_priority":
+            assert (live[..., kept] != np.asarray(
+                getattr(fresh, name))[kept]).any(), name
+            np.testing.assert_array_equal(
+                live[..., ~kept], np.broadcast_to(
+                    np.asarray(getattr(fresh, name))[~kept],
+                    live[..., ~kept].shape), err_msg=name)
+
+
 def test_sharded_checkpoint_rejected_by_flat_buffers(rng):
     """A sharded replay checkpoint restored into a non-sharded buffer must
     raise, not silently resume with an empty ring."""

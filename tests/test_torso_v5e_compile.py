@@ -224,15 +224,28 @@ def test_each_kind_of_lfm2_layer_and_its_backward_compile_at_real_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
 
 
+def _loop_body(text):
+    """The lines of the (one) ``while`` loop's body in compiled text."""
+    import re
+
+    body = re.search(r"\bwhile\(.*?body=%?([\w.\-]+)", text).group(1)
+    return re.search(r"^%?" + re.escape(body) + r" [^\n]*\{\n(.*?)^\}", text,
+                     re.S | re.M).group(1).splitlines()
+
+
 @pytest.mark.parametrize("batch", [256, 4096], ids=["chunk", "commit"])
-def test_the_dense_tree_repair_updates_the_trees_in_place_on_the_chip(
+def test_the_tree_repair_reads_rows_and_kept_levels_only_on_the_chip(
         one_chip, batch):
     """``set_leaves`` at the humanoid-mlp cells' shapes (2,097,152 leaves;
     the chunk's B = 256 and the commit's 4,096) inside a scan over donated,
-    loop-carried trees, compiled for the v5e: the block of recomputed
-    ancestors is a static slice update, which the chip's compiler must not
-    answer with a copy of a 16 MB tree a step; and every dense level is
-    one window over both trees (PR 29)."""
+    loop-carried trees, compiled for the v5e. The chunk's body (21 > 14 by
+    rows, then whole) holds no ``copy`` of a 16 MB tree and fourteen
+    ``reduce-window``s, the widest over level 14 (``[128, 128]``, 64 KB a
+    tree, both trees in each), and leaves both trees in HBM: nothing moves
+    one into ``S(1)`` and out again a step, as the parent's whole-level
+    windows made the compiler do (PR 35, PR 37). The commit's (whole at
+    every step) holds twenty-one, the widest over the leaves: one kept
+    level's span."""
     import re
 
     cap = 1 << 21
@@ -243,17 +256,25 @@ def test_the_dense_tree_repair_updates_the_trees_in_place_on_the_chip(
         return jax.lax.scan(body, trees, (idx, td))[0]
 
     trees = on(one_chip, jax.eval_shape(lambda: dper.init(cap)))
-    text = jax.jit(loop, donate_argnums=(0,)).lower(
+    lines = _loop_body(jax.jit(loop, donate_argnums=(0,)).lower(
         trees,
         jax.ShapeDtypeStruct((4, batch), jnp.int32, sharding=one_chip),
         jax.ShapeDtypeStruct((4, batch), jnp.float32, sharding=one_chip),
-    ).compile().as_text()
-    body = re.search(r"\bwhile\(.*?body=%?([\w.\-]+)", text).group(1)
-    lines = re.search(r"^%?" + re.escape(body) + r" [^\n]*\{\n(.*?)^\}", text,
-                      re.S | re.M).group(1).splitlines()
+    ).compile().as_text())
     whole_tree = re.compile(r"= f32\[%d\]\S* copy\(" % (2 * cap))
     assert not [ln for ln in lines if whole_tree.search(ln)][:2]
-    assert sum(" reduce-window(" in ln for ln in lines) == 21
+    windows = [tuple(map(int, m.groups())) for ln in lines for m in [
+        re.search(r"= \(f32\[(\d+),(\d+)\]\S*, f32\[\d+,\d+\]\S*\) "
+                  r"reduce-window\(", ln)] if m]
+    assert len(windows) == sum(" reduce-window(" in ln for ln in lines)
+    if batch == 256:
+        assert len(windows) == 14 and max(windows) == (128, 64)
+        in_fast_memory = re.compile(r"= f32\[%d\]\{[^}]*S\(1\)" % (2 * cap))
+        assert not [ln for ln in lines if in_fast_memory.search(ln)][:2]
+        assert not [ln for ln in lines if " copy-done(" in ln
+                    and "f32[%d]" % (2 * cap) in ln.split(" copy-done(")[0]]
+    else:
+        assert len(windows) == 21 and max(windows) == (cap >> 7, 64)
 
 
 @pytest.mark.parametrize("levels, batch", [(21, 256), (16, 512)],
@@ -265,11 +286,21 @@ def test_the_descent_reads_rows_of_the_tree_in_place_on_the_chip(
     compiler must answer the view with a bitcast, not with a copy of the
     tree a step. Sample, weights and write-back in a scan over donated,
     loop-carried trees at the cells' shapes: nothing in the loop body makes
-    a whole tree but the write-back's two scatters and two slice updates
-    (and the moves in and out of ``S(1)``: ``copy-done`` and a
-    ``ConcatBitcast`` of four parts, none of them a ``copy``), and
-    sampling gathers from the tree twice by the row and once by the leaf,
-    where the level-by-level walk gathered ``levels + 1`` times (PR 35)."""
+    a whole tree but the write-back (PR 37), each in place:
+
+    - MLP cells (21 > 14 by rows, then whole): eight fusions, a tree's leaf
+      scatter and three slice updates (level 14, scattered into as a 64 KB
+      slice of its own; level 7; node 1);
+    - pixel cell (whole at every step: 16 > 9 > 2 > root): eight, a tree's
+      leaf scatter and three slice updates (levels 9 and 2, node 1);
+
+    and where the compiler moves a tree through ``S(1)`` (the pixel
+    cell's windows) the moves are ``copy-done`` and a ``ConcatBitcast``
+    of parts, none of them a ``copy``. Sampling gathers from the tree
+    twice by the row and once by the leaf, where the level-by-level walk
+    gathered ``levels + 1`` times (PR 35); the write-back gathers once by
+    the leaf (the min tree's leaves read the sum tree's after its
+    scatter) and, by rows, once a tree by the row."""
     import re
 
     cap = 1 << levels
@@ -284,29 +315,26 @@ def test_the_descent_reads_rows_of_the_tree_in_place_on_the_chip(
         return jax.lax.scan(body, (trees, key), None, length=4)
 
     trees = on(one_chip, jax.eval_shape(lambda: dper.init(cap)))
-    text = jax.jit(loop, donate_argnums=(0,)).lower(
+    lines = _loop_body(jax.jit(loop, donate_argnums=(0,)).lower(
         trees,
         jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=one_chip),
         jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
-    ).compile().as_text()
-    body = re.search(r"\bwhile\(.*?body=%?([\w.\-]+)", text).group(1)
-    lines = re.search(r"^%?" + re.escape(body) + r" [^\n]*\{\n(.*?)^\}", text,
-                      re.S | re.M).group(1).splitlines()
+    ).compile().as_text())
     made = re.compile(r"= f32\[(?:%d|%d,128)\]\S* ([\w\-]+)\("
                       % (2 * cap, 2 * cap // 128))
     whole = [(m.group(1), ln) for ln in lines for m in [made.search(ln)] if m]
     ops = [op for op, _ln in whole]
-    assert ops.count("fusion") == 4 and "copy" not in ops
+    assert ops.count("fusion") == 8 and "copy" not in ops
     assert set(ops) <= {"fusion", "get-tuple-element", "bitcast",
                         "copy-done", "custom-call"}
     assert all("ConcatBitcast" in ln for op, ln in whole
-               if op == "custom-call")  # a move into S(1), in four parts
+               if op == "custom-call")  # a move into S(1), in parts
     gathers = [ln.split(" fusion(")[0] for ln in lines
                if re.search(r'kind=kCustom.*op_name="[^"]*/gather"', ln)]
-    # the fourth is the write-back's own: the min tree's leaves read the
-    # sum tree's after its scatter
+    by_rows = dper.repair_plan(cap, batch)[0][2] == "rows"
+    assert by_rows == (levels == 21)
     assert sorted(g.split("= ")[1].split("{")[0] for g in gathers) == [
-        "f32[%d,128]" % batch] * 2 + ["f32[%d]" % batch] * 2
+        "f32[%d,128]" % batch] * (2 + 2 * by_rows) + ["f32[%d]" % batch] * 2
 
 
 # configuration: the wide ring field's type as the compiled text prints it
