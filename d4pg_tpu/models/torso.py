@@ -8,10 +8,12 @@ transformer layers and pools them into one latent per row; D4PG's own heads
 state vector. ``D4PGConfig.torso`` names one (``TORSOS``) with its sizes
 (``TorsoSpec``, made from a configuration file's ``model.torso`` block).
 
-Three models share the one layer path, told apart by the data in the spec
+Four models share the one layer path, told apart by the data in the spec
 (``layer_types``, ``qk_norm``, ``sa_config``, ``num_dense_layers``,
-``router_scores``, ``use_expert_bias``), not by code of their own. Every
-layer is ``x + Op(RMSNorm(x))`` then ``x + FF(RMSNorm(x))``:
+``router_scores``, ``use_expert_bias``, ``attn_output_gate``,
+``partial_rotary_factor``, ``shared_expert_intermediate_size``, the
+``linear_*`` sizes), not by code of their own. Every layer is ``x +
+Op(RMSNorm(x))`` then ``x + FF(RMSNorm(x))``:
 
 - ``mellum2``, the Mellum2-12B-A2.5B layer: RMSNorm, grouped-query
   attention with rotary embeddings (default on ``sliding_attention``
@@ -35,14 +37,37 @@ layer is ``x + Op(RMSNorm(x))`` then ``x + FF(RMSNorm(x))``:
   layers' feed-forward is a dense SwiGLU of ``intermediate_size``, the
   others' the expert layer under a ``sigmoid`` router with a
   load-balancing bias.
+- ``qwen3next``, Qwen3-Next-80B-A3B's layers: ``linear_attention`` layers
+  whose operator is a Gated DeltaNet (``_delta``: ``[q, k, v, z] = h
+  W_qkvz``, ``[b, a] = h W_ba``, a depthwise causal convolution of
+  ``linear_conv_kernel_dim`` taps and a SiLU over ``q, k, v``, l2-normed
+  ``q`` and ``k`` a head, the gated delta rule of ``ops/delta_rule.py``
+  with write strength ``sigmoid(b)`` and log-decay ``-exp(A_log) softplus(a
+  + dt_bias)`` a value head, an RMSNorm a head gated by ``silu(z)``,
+  ``out_proj``; a state carried along the sequence, no rotary embedding)
+  round ``full_attention`` layers of 256-wide heads whose ``q`` projection
+  is twice as wide (``attn_output_gate``: a head's query, then the logits
+  of a sigmoid gate on its output) and whose rotary embedding turns the
+  first ``partial_rotary_factor`` of a head; every layer's feed-forward is
+  the expert layer with a shared expert added whole under a scalar sigmoid
+  gate (``shared_expert_intermediate_size``). ``aux["delta_kept"]`` is the
+  mean of ``exp(g)`` a DeltaNet layer, ``aux["shared_gate"]`` the mean of
+  the shared expert's gate a layer.
 
 Leaves. A layer has only the leaves its kind has: the operator's are
 ``attn_norm``, ``q``, ``k``, ``v``, ``o`` (with ``qk_norm`` also ``q_norm``,
 ``k_norm``; a sparse layer's indexer beside them) or ``conv_norm``,
-``in_proj``, ``conv`` (the taps, ``[D, conv_L_cache]``), ``out_proj``; the
-feed-forward's ``mlp_norm``, ``w1``, ``w3``, ``w2`` or ``moe_norm``,
-``router``, ``gate``, ``up``, ``down``. ``init`` draws eight keys a layer
-whatever its kind, so a layer's draws do not depend on its neighbours'.
+``in_proj``, ``conv`` (the taps, ``[D, conv_L_cache]``), ``out_proj`` or
+``linear_norm``, ``in_proj_qkvz``, ``in_proj_ba``, ``conv`` (``[2 Wk + Wv,
+linear_conv_kernel_dim]``), ``A_log``, ``dt_bias``, ``out_norm``,
+``out_proj`` (with ``attn_output_gate`` an attention layer's ``q`` is ``[D,
+2 H Dh]``); the feed-forward's ``mlp_norm``, ``w1``, ``w3``, ``w2`` or
+``moe_norm``, ``router``, ``gate``, ``up``, ``down`` (with a shared expert
+also ``shared_gate``, ``shared_up``, ``shared_down``,
+``shared_expert_gate``). ``init`` draws eight keys a layer whatever its
+kind, so a layer's draws do not depend on its neighbours'; the decay's and
+the shared expert's draws come from keys folded off the torso's own, so
+the older models' trees are bit for bit what they were.
 
 The expert layer: a float32 router over all ``num_experts`` experts,
 SwiGLU experts. ``softmax`` scores are a softmax over the experts with the
@@ -83,7 +108,16 @@ times that. At ``lfm2``'s 8,192 tokens a layer boundary is 67 MB a
 sequence; inside a sequence the largest arrays are ``in_proj``'s ``[8192,
 6144]`` in the compute dtype (the gates and taps are one fused pass over
 it) and the dense layer's two ``[8192, 7168]`` float32 products; the
-expert layer takes the sequence in two parts of 16,384 assignments.
+expert layer takes the sequence in two parts of 16,384 assignments. At
+``qwen3next``'s 16,384 tokens a DeltaNet sequence's largest arrays are
+``in_proj_qkvz``'s ``[16384, 12288]`` in the compute dtype (403 MB) and the
+float32 ``q, k, v`` behind the taps (``[16384, 8192]``, 537 MB): those are
+made again in the backward pass (``_delta``'s ``front``), so the scan's own
+backward runs beside its inputs alone; the scan keeps one ``[32, 128, 128]``
+state a group of 4 chunks (64 x 2 MB a sequence, where a state a chunk
+would be 537 MB) and a group's intermediates for that group alone
+(``ops/delta_rule.py``); the expert layer takes the sequence in four parts
+of 40,960 assignments, of which about 1,280 land on the 16 experts held.
 """
 
 from __future__ import annotations
@@ -98,6 +132,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from d4pg_tpu.ops import attention as attn_ops
+from d4pg_tpu.ops import delta_rule as delta_ops
 from d4pg_tpu.ops import grouped as grouped_ops
 from d4pg_tpu.ops import short_conv as conv_ops
 from d4pg_tpu.ops import sparse_attention as sparse_ops
@@ -112,7 +147,7 @@ EXPERT_BUFFER = 1.5
 # tokens, 131,072 rows, takes 4.5 GB that the chip does not have
 EXPERT_TOKENS = 4096
 LAYER_TYPES = ("sliding_attention", "full_attention", "sparse_attention",
-               "conv")
+               "conv", "linear_attention")
 ROUTER_SCORES = ("softmax", "sigmoid")
 SA_KEYS = ("indexer_head_dim", "indexer_num_heads", "indexer_num_kv_heads",
            "kv_chunk_size", "q_chunk_size", "topk")
@@ -157,6 +192,15 @@ class TorsoSpec:
     use_expert_bias: bool = False  # a bias a layer that enters the selection
     routed_scaling_factor: float = 1.0
     bias_update_rate: float = 0.0  # gamma of the load-balancing rule
+    # 'linear_attention' layers (Gated DeltaNet): heads, their widths, taps
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 0
+    partial_rotary_factor: float = 1.0  # the share of a head RoPE turns
+    attn_output_gate: bool = False  # q twice as wide: sigmoid(gate) * attn
+    shared_expert_intermediate_size: int = 0  # 0: no shared expert
 
     @classmethod
     def from_dict(cls, d: dict) -> "TorsoSpec":
@@ -197,6 +241,23 @@ class TorsoSpec:
                                   sa["kv_chunk_size"])
         if "conv" in self.layer_types and self.conv_L_cache < 1:
             raise ValueError("conv layers need conv_L_cache taps")
+        if "linear_attention" in self.layer_types:
+            sizes = (self.linear_num_key_heads, self.linear_num_value_heads,
+                     self.linear_key_head_dim, self.linear_value_head_dim,
+                     self.linear_conv_kernel_dim)
+            if min(sizes) < 1:
+                raise ValueError("linear_attention layers need the five "
+                                 "linear_* sizes")
+            if self.linear_num_value_heads % self.linear_num_key_heads:
+                raise ValueError("value heads do not divide into key heads")
+        rotary = self.head_dim * self.partial_rotary_factor
+        if not (0 < rotary <= self.head_dim and rotary == int(rotary)
+                and int(rotary) % 2 == 0):
+            raise ValueError(f"partial_rotary_factor "
+                             f"{self.partial_rotary_factor} does not turn a "
+                             f"whole even share of {self.head_dim}")
+        if self.attn_output_gate and "sparse_attention" in self.layer_types:
+            raise ValueError("sparse_attention layers have no output gate")
         if not 0 <= self.num_dense_layers < len(self.layer_types):
             raise ValueError(f"num_dense_layers {self.num_dense_layers} "
                              f"leaves no expert layer of "
@@ -222,6 +283,11 @@ class TorsoSpec:
     def expert_layers(self) -> tuple:
         """Indices of the layers whose feed-forward is the expert layer."""
         return tuple(range(self.num_dense_layers, len(self.layer_types)))
+
+    @property
+    def rotary_dim(self) -> int:
+        """How many of a head's ``head_dim`` RoPE turns (the first)."""
+        return int(self.head_dim * self.partial_rotary_factor)
 
     def rope_for(self, layer_type: str) -> dict:
         return dict(dict(self.rope_parameters)[layer_type])
@@ -406,7 +472,13 @@ def expert_share(spec: TorsoSpec, p: dict, h, dtype, grouped: str = "ragged"):
     (``T * k`` rows) when it must. When the ``n`` landing here fit
     ``even_load_rows`` (the usual case) the same computation runs on that
     many rows instead (``lax.cond``: both are compiled, one runs): gathers
-    and elementwise work follow the buffer, not the ``n`` rows in it."""
+    and elementwise work follow the buffer, not the ``n`` rows in it.
+
+    With ``shared_expert_intermediate_size`` the shared expert (Qwen3-Next's:
+    one SwiGLU every token goes through, under a scalar sigmoid gate
+    ``h w_s``) is added whole: under expert parallelism every chip computes
+    it alike for its own tokens. ``stats["shared_gate"]`` is the gate summed
+    over the tokens."""
     lo, hi = spec.experts_held
     k, n_exp = spec.num_experts_per_tok, spec.num_experts
     t_len = h.shape[0]
@@ -454,8 +526,21 @@ def expert_share(spec: TorsoSpec, p: dict, h, dtype, grouped: str = "ragged"):
 
     every, usual = t_len * k, even_load_rows(spec, t_len)
     if usual >= every:
-        return on(every)(h, w), stats
-    return jax.lax.cond(n_held <= usual, on(usual), on(every), h, w), stats
+        out = on(every)(h, w)
+    else:
+        out = jax.lax.cond(n_held <= usual, on(usual), on(every), h, w)
+    if spec.shared_expert_intermediate_size:
+        with jax.named_scope("torso.shared_expert"):
+            hs = h.astype(dtype)
+            proj = lambda name: jnp.dot(  # noqa: E731
+                hs, p[name]["kernel"], preferred_element_type=jnp.float32)
+            open_ = jax.nn.sigmoid(proj("shared_expert_gate"))  # [T, 1]
+            mid = (jax.nn.silu(proj("shared_gate"))
+                   * proj("shared_up")).astype(dtype)
+            out = out + open_ * jnp.dot(mid, p["shared_down"]["kernel"],
+                                        preferred_element_type=jnp.float32)
+            stats["shared_gate"] = jnp.sum(open_)
+    return out, stats
 
 
 # -- the torso ----------------------------------------------------------------
@@ -517,7 +602,27 @@ class SequenceTorso:
             # not depend on the kinds of the layers before it
             k_q, k_k, k_v, k_o, k_router, k_gate, k_up, k_down = (
                 next(keys) for _ in range(8))
-            if layer_type == "conv":
+            if layer_type == "linear_attention":
+                hv, tap = s.linear_num_value_heads, s.linear_conv_kernel_dim
+                wk = s.linear_num_key_heads * s.linear_key_head_dim
+                wv = hv * s.linear_value_head_dim
+                # keys of their own, as the indexer's below; Mamba-2's and
+                # the published Gated DeltaNet's draw: A ~ U(0, 16), dt
+                # log-uniform on [1e-3, 1e-1] behind an inverse softplus
+                k_a, k_dt = jax.random.split(
+                    jax.random.fold_in(key, i + 1), 2)
+                dt = jnp.exp(jax.random.uniform(
+                    k_dt, (hv,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+                op = {"linear_norm": gain(),
+                      "in_proj_qkvz": normal(k_q, (d, 2 * wk + 2 * wv), d),
+                      "in_proj_ba": normal(k_k, (d, 2 * hv), d),
+                      "conv": normal(k_v, (2 * wk + wv, tap), tap),
+                      "A_log": {"value": jnp.log(jax.random.uniform(
+                          k_a, (hv,), jnp.float32, 1e-6, 16.0))},
+                      "dt_bias": {"value": dt + jnp.log(-jnp.expm1(-dt))},
+                      "out_norm": gain(s.linear_value_head_dim),
+                      "out_proj": normal(k_o, (wv, d), wv)}
+            elif layer_type == "conv":
                 op = {"conv_norm": gain(),
                       "in_proj": normal(k_q, (d, 3 * d), d),
                       "conv": normal(k_k, (d, s.conv_L_cache),
@@ -525,7 +630,8 @@ class SequenceTorso:
                       "out_proj": normal(k_o, (d, d), d)}
             else:
                 op = {"attn_norm": gain(),
-                      "q": normal(k_q, (d, hq), d),
+                      "q": normal(k_q, (
+                          d, 2 * hq if s.attn_output_gate else hq), d),
                       "k": normal(k_k, (d, hkv), d),
                       "v": normal(k_v, (d, hkv), d),
                       "o": normal(k_o, (hq, d), hq)}
@@ -558,30 +664,52 @@ class SequenceTorso:
                       "gate": normal(k_gate, (n, d, f), d),
                       "up": normal(k_up, (n, d, f), d),
                       "down": normal(k_down, (n, f, d), f)}
+                if s.shared_expert_intermediate_size:
+                    fs = s.shared_expert_intermediate_size
+                    ks = jax.random.split(jax.random.fold_in(
+                        key, len(s.layer_types) + 1 + i), 4)
+                    ff.update(
+                        shared_gate=normal(ks[0], (d, fs), d),
+                        shared_up=normal(ks[1], (d, fs), d),
+                        shared_down=normal(ks[2], (fs, d), fs),
+                        shared_expert_gate=normal(ks[3], (d, 1), d))
             params[f"layer_{i}"] = {**op, **ff}
         return params
 
     def _qkv(self, p: dict, h, layer_type: str):
         """``q [Hkv, G, T, D]`` (scaled), ``k``, ``v [Hkv, T, D]`` of the
-        normed ``h [T, D]``: query head i reads key/value head i // G."""
+        normed ``h [T, D]``: query head i reads key/value head i // G; and
+        the output gate's logits ``[T, H * D]`` float32, ``None`` without
+        ``attn_output_gate`` (with it a head's ``2 D`` outputs of ``q`` are
+        its query, then its gate). RoPE turns the first ``rotary_dim`` of a
+        head and passes the rest."""
         s, dtype = self.spec, self.dtype
         t_len = h.shape[0]
         hkv, dh = s.num_key_value_heads, s.head_dim
         group = s.num_attention_heads // hkv
         proj = lambda name, out: jnp.dot(  # noqa: E731
             h, p[name]["kernel"], preferred_element_type=out)
-        cos, sin = rope_tables(s.rope_for(layer_type), dh, t_len)
-        q = proj("q", jnp.float32).reshape(t_len, hkv, group, dh)
+        cos, sin = rope_tables(s.rope_for(layer_type), s.rotary_dim, t_len)
+        if s.rotary_dim == dh:
+            turn = lambda x: apply_rope(x, cos, sin)  # noqa: E731
+        else:
+            turn = lambda x: jnp.concatenate([  # noqa: E731
+                apply_rope(x[..., :s.rotary_dim], cos, sin),
+                x[..., s.rotary_dim:]], axis=-1)
+        q, gate = proj("q", jnp.float32), None
+        if s.attn_output_gate:
+            q = q.reshape(t_len, hkv * group, 2 * dh)
+            q, gate = q[..., :dh], q[..., dh:].reshape(t_len, -1)
+        q = q.reshape(t_len, hkv, group, dh)
         if s.qk_norm:
             q = rms_norm(q, p["q_norm"]["scale"], s.rms_norm_eps)
-        q = (apply_rope(q.transpose(1, 2, 0, 3), cos, sin)
-             / math.sqrt(dh)).astype(dtype)
+        q = (turn(q.transpose(1, 2, 0, 3)) / math.sqrt(dh)).astype(dtype)
         k = proj("k", jnp.float32).reshape(t_len, hkv, dh)
         if s.qk_norm:
             k = rms_norm(k, p["k_norm"]["scale"], s.rms_norm_eps)
-        k = apply_rope(k.transpose(1, 0, 2), cos, sin).astype(dtype)
+        k = turn(k.transpose(1, 0, 2)).astype(dtype)
         v = proj("v", dtype).reshape(t_len, hkv, dh).transpose(1, 0, 2)
-        return q, k, v
+        return q, k, v, gate
 
     def _attend(self, p: dict, x, layer_type: str):
         """Attention of one sequence ``x [T, D]`` added to it."""
@@ -591,12 +719,15 @@ class SequenceTorso:
                              else "torso.attn_window"):
             h = rms_norm(x, p["attn_norm"]["scale"], s.rms_norm_eps).astype(
                 self.dtype)
-            q, k, v = self._qkv(p, h, layer_type)
+            q, k, v, gate = self._qkv(p, h, layer_type)
             a = attn_ops.causal_attention(
                 q[None], k[None], v[None],
                 window=None if full else s.sliding_window,
                 impl=self.attention_impl())[0]
             a = a.transpose(2, 0, 1, 3).reshape(x.shape[0], -1)
+            if gate is not None:
+                a = (a.astype(jnp.float32)
+                     * jax.nn.sigmoid(gate)).astype(self.dtype)
             return x + jnp.dot(a, p["o"]["kernel"],
                                preferred_element_type=jnp.float32)
 
@@ -612,7 +743,7 @@ class SequenceTorso:
         with jax.named_scope("torso.attn_sparse"):
             h = rms_norm(x, p["attn_norm"]["scale"], s.rms_norm_eps).astype(
                 dtype)
-            q, k, v = self._qkv(p, h, "sparse_attention")
+            q, k, v, _gate = self._qkv(p, h, "sparse_attention")
         with jax.named_scope("torso.indexer"):
             hx = jax.lax.stop_gradient(h)
             proj = lambda name: jnp.dot(  # noqa: E731
@@ -637,6 +768,59 @@ class SequenceTorso:
             x = x + jnp.dot(a, p["o"]["kernel"],
                             preferred_element_type=jnp.float32)
         return x, (counts, loss)
+
+    def _delta(self, p: dict, x):
+        """Qwen3-Next's Gated DeltaNet operator of one sequence ``x [T, D]``
+        added to it: ``(x, kept)``, ``kept`` the mean of ``exp(g)`` over
+        heads and tokens. ``[q, k, v, z] = h W_qkvz`` in that order
+        (``linear_num_key_heads`` heads of ``q`` and ``k``,
+        ``linear_num_value_heads`` of ``v`` and ``z``), ``[b, a] = h W_ba``;
+        ``q, k, v`` go through a depthwise causal convolution and a SiLU,
+        ``q`` and ``k`` are l2-normalised a head, key head ``i`` serves
+        value heads ``2 i, 2 i + 1``; the recurrence is
+        ``ops/delta_rule.py``'s, in float32; its output is RMS-normalised a
+        head, gated by ``silu(z)`` and projected."""
+        s, dtype = self.spec, self.dtype
+        t_len = x.shape[0]
+        hk, hv = s.linear_num_key_heads, s.linear_num_value_heads
+        dk, dv = s.linear_key_head_dim, s.linear_value_head_dim
+        wk, wv = hk * dk, hv * dv
+        with jax.named_scope("torso.deltanet"):
+            h = rms_norm(x, p["linear_norm"]["scale"], s.rms_norm_eps).astype(
+                dtype)
+            qkvz = jnp.dot(h, p["in_proj_qkvz"]["kernel"],
+                           preferred_element_type=dtype)
+            ba = jnp.dot(h, p["in_proj_ba"]["kernel"],
+                         preferred_element_type=jnp.float32)
+            z = qkvz[:, 2 * wk + wv:].reshape(t_len, hv, dv)
+
+        # the float32 q, k, v behind the taps are the sequence's largest
+        # arrays ([T, 8192], 537 MB each way): made again in the backward
+        # pass, so the scan's own backward runs beside its inputs alone
+        @jax.checkpoint
+        def front(qkv, taps):
+            with jax.named_scope("torso.deltanet"):
+                qkv = jax.nn.silu(conv_ops.depthwise_causal(qkv, taps))
+            with jax.named_scope("torso.delta_scan"):
+                unit = lambda a: a * jax.lax.rsqrt(  # noqa: E731
+                    jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+                q = unit(qkv[:, :wk].reshape(t_len, hk, dk)) / math.sqrt(dk)
+                k = unit(qkv[:, wk:2 * wk].reshape(t_len, hk, dk))
+                return q, k, qkv[:, 2 * wk:].reshape(t_len, hv, dv)
+
+        q, k, v = front(qkvz[:, :2 * wk + wv], p["conv"]["kernel"])
+        with jax.named_scope("torso.delta_scan"):
+            beta = jax.nn.sigmoid(ba[:, :hv])
+            g = -jnp.exp(p["A_log"]["value"]) * jax.nn.softplus(
+                ba[:, hv:] + p["dt_bias"]["value"])
+            o = delta_ops.gated_delta_rule(q, k, v, g, beta)
+            kept = jnp.mean(jnp.exp(g))
+        with jax.named_scope("torso.deltanet"):
+            y = rms_norm(o, p["out_norm"]["scale"], s.rms_norm_eps) \
+                * jax.nn.silu(z.astype(jnp.float32))
+            return x + jnp.dot(y.astype(dtype).reshape(t_len, wv),
+                               p["out_proj"]["kernel"],
+                               preferred_element_type=jnp.float32), kept
 
     def _conv(self, p: dict, x):
         """LFM2's gated short convolution of one sequence ``x [T, D]``
@@ -665,21 +849,25 @@ class SequenceTorso:
     def _sequence(self, p: dict, x, layer_type: str, dense: bool,
                   train: bool):
         """One layer on one sequence: ``x [T, D] -> (x, stats, selected)``;
-        ``stats`` is the router's (``{}`` of a dense layer), ``selected``
+        ``stats`` is the router's (none of a dense layer) with a
+        ``linear_attention`` layer's ``delta_kept`` beside it, ``selected``
         ``()`` but for a sparse layer."""
-        selected = ()
+        selected, op_stats = (), {}
         if layer_type == "conv":
             x = self._conv(p, x)
+        elif layer_type == "linear_attention":
+            x, kept = self._delta(p, x)
+            op_stats = {"delta_kept": kept}
         elif layer_type == "sparse_attention":
             x, selected = self._attend_sparse(p, x, train)
         else:
             x = self._attend(p, x, layer_type)
         if dense:
-            return x + self._mlp(p, x), {}, selected
+            return x + self._mlp(p, x), op_stats, selected
         with jax.named_scope("torso.route"):
             h = rms_norm(x, p["moe_norm"]["scale"], self.spec.rms_norm_eps)
         out, stats = self._experts(p, h)
-        return x + out, stats, selected
+        return x + out, {**stats, **op_stats}, selected
 
     def _experts(self, p: dict, h):
         """``expert_share`` of one sequence, ``EXPERT_TOKENS`` at a time
@@ -713,12 +901,14 @@ class SequenceTorso:
         with jax.named_scope("torso.embed"):
             tokens = tokenise(s, obs)
             x = params["embed"]["kernel"][tokens]
-        stats, selected = [], []
+        stats, selected, kept = [], [], []
         for i, layer_type in enumerate(s.layer_types):
             layer = jax.checkpoint(
                 lambda p, x, lt=layer_type, dense=i < s.num_dense_layers:
                 self._layer(p, x, lt, dense, train))
             x, st, sel = layer(params[f"layer_{i}"], x)
+            if "delta_kept" in st:  # a mean a sequence, summed over them
+                kept.append(st.pop("delta_kept") / obs.shape[0])
             if st:
                 stats.append(st)
             if sel:
@@ -728,6 +918,10 @@ class SequenceTorso:
             latent = jnp.mean(x, axis=1)
         aux = {name: jnp.stack([st[name] for st in stats])
                for name in stats[0]}
+        if kept:
+            aux["delta_kept"] = jnp.stack(kept)
+        if "shared_gate" in aux:  # summed over tokens and sequences
+            aux["shared_gate"] = aux["shared_gate"] / (obs.shape[0] * s.tokens)
         if selected and train:
             with jax.named_scope("torso.indexer"):
                 aux["select_counts"] = jnp.stack(
@@ -802,7 +996,7 @@ class TorsoCritic:
 
 
 TORSOS = {"mellum2": SequenceTorso, "keye2": SequenceTorso,
-          "lfm2": SequenceTorso}
+          "lfm2": SequenceTorso, "qwen3next": SequenceTorso}
 
 
 def build_torso(spec: TorsoSpec, dtype=jnp.float32):

@@ -30,11 +30,16 @@ def shifted(g, back: int):
     return jnp.pad(g, ((back, 0), (0, 0)))[:g.shape[0]]
 
 
+def depthwise_causal(g, taps):
+    """``s[t] = sum_j taps[:, j] * g[t - (L - 1 - j)]`` in float32 on ``g [T,
+    C]`` of any dtype: the convolution alone (Qwen3-Next's Gated DeltaNet
+    puts a SiLU behind it and no gates round it)."""
+    g, taps = g.astype(jnp.float32), taps.astype(jnp.float32)
+    n_taps = taps.shape[1]
+    return sum(taps[:, j] * shifted(g, n_taps - 1 - j) for j in range(n_taps))
+
+
 def gated_short_conv(bcu, taps):
     """``[T, C]`` in ``bcu``'s dtype (module docstring)."""
     b, c, u = (x.astype(jnp.float32) for x in jnp.split(bcu, 3, axis=-1))
-    taps = taps.astype(jnp.float32)
-    n_taps = taps.shape[1]
-    g = b * u
-    s = sum(taps[:, j] * shifted(g, n_taps - 1 - j) for j in range(n_taps))
-    return (c * s).astype(bcu.dtype)
+    return (c * depthwise_causal(b * u, taps)).astype(bcu.dtype)

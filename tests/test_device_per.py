@@ -396,7 +396,7 @@ def test_set_leaves_structure_follows_the_batch():
     assert _lowered(1 << 21, 1 << 21)[:2] == (2, 0)
 
 
-# the benchmark's six cells: what the chunk's write-back and the commit's
+# the benchmark's seven cells: what the chunk's write-back and the commit's
 # insert do to the trees at the shapes of each cell's configuration file
 CELL_PLANS = {
     "humanoid-mlp.learn-static": (
@@ -416,6 +416,8 @@ CELL_PLANS = {
     "humanoid-lfm2-ep4.learn-static": (
         "15>8 rows,8>1 whole,1>root whole",
         "15>8 whole,8>1 whole,1>root whole"),
+    "humanoid-qwen3next-ep32.learn-static": (
+        "14>7 rows,7>root whole", "14>7 whole,7>root whole"),
 }
 
 

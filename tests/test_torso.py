@@ -243,21 +243,21 @@ def test_fused_chunk_reports_the_load_counter_per_step_and_layer():
 
 
 # -- the share ----------------------------------------------------------------
-def _layer_inputs(bias=None):
-    """One expert layer's whole parameters (8 experts) and a normed input;
-    ``bias`` leans the router towards the first two experts."""
+def _layer_inputs(bias=None, n=8):
+    """One expert layer's whole parameters (``n`` experts) and a normed
+    input; ``bias`` leans the router towards the first two experts."""
     k = jax.random.split(jax.random.key(11), 5)
     fan = lambda key, shape, n: jax.random.normal(key, shape) / math.sqrt(n)  # noqa
-    p = {"router": {"kernel": fan(k[0], (64, 8), 64)},
-         "gate": {"kernel": fan(k[1], (8, 64, 32), 64)},
-         "up": {"kernel": fan(k[2], (8, 64, 32), 64)},
-         "down": {"kernel": fan(k[3], (8, 32, 64), 32)}}
+    p = {"router": {"kernel": fan(k[0], (64, n), 64)},
+         "gate": {"kernel": fan(k[1], (n, 64, 32), 64)},
+         "up": {"kernel": fan(k[2], (n, 64, 32), 64)},
+         "down": {"kernel": fan(k[3], (n, 32, 64), 32)}}
     h = jax.random.normal(k[4], (32, 64))
     if bias is not None:
         # every token's largest two logits are experts 0 and 1: h gets a
         # constant column the router reads with weight `bias` for those two
         h = h.at[:, 0].set(1.0)
-        lean = jnp.zeros((64, 8)).at[0, :2].set(bias)
+        lean = jnp.zeros((64, n)).at[0, :2].set(bias)
         p["router"] = {"kernel": p["router"]["kernel"].at[0].set(0.0) + lean}
     return p, h
 
@@ -268,8 +268,16 @@ LFM2 = dict(router_scores="sigmoid", use_expert_bias=True,
             bias_update_rate=1e-3, rms_norm_eps=1e-5)
 
 
-@pytest.mark.parametrize("router", ["softmax", "sigmoid"])
-@pytest.mark.parametrize("shares", [4, 8], ids=["four", "eight"])
+# the linear-attention model's expert layer: 32 experts, three a token, and
+# what every chip of it computes alike, a shared expert under a sigmoid gate
+QWEN3NEXT = dict(num_experts=32, num_experts_per_tok=3,
+                 shared_expert_intermediate_size=32, rms_norm_eps=1e-6)
+
+
+@pytest.mark.parametrize("shares, router", [
+    (4, "softmax"), (8, "softmax"), (4, "sigmoid"), (8, "sigmoid"),
+    (32, "shared")], ids=lambda v: {4: "four", 8: "eight",
+                                    32: "thirtytwo"}.get(v, v))
 @pytest.mark.parametrize("bias", [None, 50.0], ids=["seeded", "biased"])
 def test_the_four_shares_add_up_to_the_uncut_layer(bias, shares, router):
     """Each share routes over all 8 experts and computes its own (2 of four
@@ -278,8 +286,12 @@ def test_the_four_shares_add_up_to_the_uncut_layer(bias, shares, router):
     whole layer, and the assignments the shares computed are all of them,
     even when two experts get every token. ``sigmoid``: LFM2's router with
     its selection bias, and the dense feed-forward that every chip computes
-    alike counted once."""
-    p, h = _layer_inputs(bias)
+    alike counted once. ``shared``: 1 of 32 experts a share, three a token,
+    as ``humanoid-qwen3next-ep32`` stands for, and the gated shared expert
+    that ``expert_share`` adds on every chip counted once."""
+    n, top = (32, 3) if router == "shared" else (8, 2)
+    p, h = _layer_inputs(bias, n)
+    extra = {}  # leaves every share holds whole
     if router == "sigmoid":
         from benchmark import reference_hybrid as rh
 
@@ -294,16 +306,28 @@ def test_the_four_shares_add_up_to_the_uncut_layer(bias, shares, router):
         over, block = LFM2, {**SMALL, **LFM2}
         w, e, counts, swapped = rh.route(block, h, p["router"])
         alike = rh.dense_ff(rh.EXACT_OPS, dense, h)
+    elif router == "shared":
+        from benchmark import reference_linear as rl
+
+        k = jax.random.split(jax.random.key(13), 4)
+        extra = {name: {"kernel": jax.random.normal(k[i], shape) / 8}
+                 for i, (name, shape) in enumerate((
+                     ("shared_gate", (64, 32)), ("shared_up", (64, 32)),
+                     ("shared_down", (32, 64)),
+                     ("shared_expert_gate", (64, 1))))}
+        over, block = QWEN3NEXT, {**SMALL, **QWEN3NEXT}
+        w, e, counts = rl.route(block, h, p["router"]["kernel"])
+        alike, gate = rl.shared_expert(rl.EXACT_OPS, extra, h)
     else:
         over, block = {}, SMALL
         w, e, counts = rt.route(SMALL, h, p["router"]["kernel"])
         alike = jnp.zeros_like(h)
-    whole = alike + rt.experts(rt.EXACT_OPS, block, p, h, w, e, held=(0, 8))
+    whole = alike + rt.experts(rt.EXACT_OPS, block, p, h, w, e, held=(0, n))
     total, computed = alike, 0  # counted once, not once a share
     for index in range(shares):
-        lo, hi = partition.expert_share(8, shares, index)
+        lo, hi = partition.expert_share(n, shares, index)
         spec = small_config(experts_held=[lo, hi], **over).torso
-        mine = {"router": p["router"], **{
+        mine = {"router": p["router"], **extra, **{
             name: {"kernel": p[name]["kernel"][lo:hi]}
             for name in ("gate", "up", "down")}}
         out, stats = torso_lib.expert_share(spec, mine, h, jnp.float32)
@@ -314,15 +338,20 @@ def test_the_four_shares_add_up_to_the_uncut_layer(bias, shares, router):
             assert int(stats["bias_swapped"]) == int(swapped)
         # and gives what the reference gives for its experts alone
         part = rt.experts(rt.EXACT_OPS, block, mine, h, w, e, held=(lo, hi))
+        if router == "shared":  # with the shared expert, on every share
+            part = part + alike
+            assert float(stats["shared_gate"]) == pytest.approx(
+                32 * float(gate), rel=1e-5)
         np.testing.assert_allclose(np.asarray(out), np.asarray(part),
                                    rtol=1e-4, atol=1e-5)
-        total = total + out
+        total = total + (out - alike if router == "shared" else out)
         computed += int(np.asarray(seen)[lo:hi].sum())
     np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
                                rtol=1e-4, atol=1e-5)
-    assert computed == 32 * 2  # N x k: no assignment lost
-    if bias is not None:
-        assert np.asarray(counts).tolist() == [32, 32, 0, 0, 0, 0, 0, 0]
+    assert computed == 32 * top  # N x k: no assignment lost
+    if bias is not None:  # every token's first two choices; a third is free
+        assert np.asarray(counts)[:2].tolist() == [32, 32]
+        assert int(np.asarray(counts).sum()) == 32 * top
 
 
 def test_expert_share_names_a_contiguous_range():

@@ -103,7 +103,7 @@ def test_spec_takes_the_layer_type_and_its_sizes_as_data():
     with pytest.raises(ValueError, match="sa_config"):
         small_config(sa_config={**SA, "window": 3})
     with pytest.raises(ValueError, match="unknown layer types"):
-        small_config(layer_types=["linear_attention"])
+        small_config(layer_types=["latent_attention"])
     with pytest.raises(ValueError, match="do not divide"):
         small_config(sa_config={**SA, "q_chunk_size": 24})
     # a mellum layer beside a sparse one is one torso
@@ -412,7 +412,7 @@ def test_with_no_key_to_discard_the_layer_is_full_causal_attention():
     x = jax.random.normal(jax.random.key(1), (16, 64))
     got, (counts, _loss) = torso._attend_sparse(p, x, True)
     h = torso_lib.rms_norm(x, p["attn_norm"]["scale"], 1e-6)
-    q, k, v = torso._qkv(p, h, "sparse_attention")
+    q, k, v, _gate = torso._qkv(p, h, "sparse_attention")
     a = attn_ops.causal_attention(q[None], k[None], v[None], window=None,
                                   impl="blockwise")[0]
     want = x + jnp.dot(a.transpose(2, 0, 1, 3).reshape(16, -1),

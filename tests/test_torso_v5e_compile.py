@@ -224,6 +224,125 @@ def test_each_kind_of_lfm2_layer_and_its_backward_compile_at_real_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
 
 
+def test_the_delta_rule_scan_and_its_backward_compile_at_real_widths(
+        one_chip):
+    """``ops/delta_rule.gated_delta_rule`` on one 16,384-token sequence of
+    ``humanoid-qwen3next-ep32`` (16 key heads serving 32 value heads of
+    128), differentiated in all five inputs: 256 chunks in 64 rematerialised
+    groups, so two nested loops forward and again backward, the solve inside
+    a chunk products like the rest (no ``triangular-solve`` custom call);
+    its temporaries are a group's, not the sequence's (every chunk's state
+    kept would alone be 537 MB)."""
+    from d4pg_tpu.ops import delta_rule
+
+    f32 = lambda *s: jax.ShapeDtypeStruct(  # noqa: E731
+        s, jnp.float32, sharding=one_chip)
+    t_len, hk, hv, d = 16384, 16, 32, 128
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(delta_rule.gated_delta_rule(q, k, v, g, beta))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        f32(t_len, hk, d), f32(t_len, hk, d), f32(t_len, hv, d),
+        f32(t_len, hv), f32(t_len, hv)).compile()
+    text = compiled.as_text()
+    assert text.count(" while(") >= 4 and "triangular" not in text
+    assert "tpu_custom_call" not in text  # plain XLA: no kernel yet
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def test_splash_attention_compiles_at_256_wide_heads_and_two_kv_heads(
+        one_chip):
+    """The gated attention of ``humanoid-qwen3next-ep32``: 8 query heads on
+    each of 2 key/value heads of 256 at 16,384 positions, full causal, the
+    first heads here wider than 128: forward and backward kernels."""
+    q = jax.ShapeDtypeStruct((1, 2, 8, 16384, 256), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 2, 16384, 256), jnp.bfloat16,
+                              sharding=one_chip)
+    assert attn_ops.splash_fits(16384, 256)
+
+    def loss(q, k, v):
+        out = attn_ops.causal_attention(q, k, v, window=None, impl="splash")
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
+@pytest.mark.parametrize("index, layer_type, kernels, temp", [
+    (0, "linear_attention", ("gmm",), 6.5e9),
+    (3, "full_attention", ("splash", "gmm"), 4.6e9)],
+    ids=["deltanet-experts", "gated-attention-experts"])
+def test_each_kind_of_qwen3next_layer_and_its_backward_compile_at_real_widths(
+        one_chip, monkeypatch, index, layer_type, kernels, temp):
+    """One layer of ``humanoid-qwen3next-ep32`` on one 16,384-token
+    sequence, differentiated: the Gated DeltaNet operator (projections of
+    12,288 and 64 outputs, four taps on 8,192 channels, the scan, the gated
+    output norm) or the output-gated attention (the splash kernel at 2
+    key/value heads of 256 with 8 queries each, a quarter of a head
+    rotated), then the router's 512 outputs and ten choices a token, the
+    grouped products at 2048 x 512 over 16 held experts 4,096 tokens at a
+    time, and the shared expert."""
+    with open(os.path.join(
+            REPO, "benchmark/configs/humanoid-qwen3next-ep32.json")) as f:
+        model = json.load(f)["model"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    config = D4PGConfig(**model)
+    torso = config.build_critic().torso
+    assert torso.attention_impl() == "splash" \
+        and torso.grouped_impl() == "megablox"
+    params = jax.eval_shape(lambda: torso.init(jax.random.key(0)))[
+        f"layer_{index}"]
+    x = jax.ShapeDtypeStruct((1, 16384, 2048), jnp.float32)
+
+    def loss(p, x):
+        out, _stats, _selected = torso._layer(p, x, layer_type, False, True)
+        return jnp.sum(out)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        *on(one_chip, (params, x))).compile()
+    text = compiled.as_text()
+    for kernel in ("splash", "gmm"):
+        assert (kernel in text) == (kernel in kernels), kernel
+    assert "ragged-dot" not in text
+    # a 4,096-token part's every-assignment buffer: ten rows a token
+    assert "[40960,2048]" in text and "[163840,2048]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < temp
+
+
+def test_the_fused_chunk_of_the_linear_cell_compiles_for_the_chip(
+        one_chip, monkeypatch):
+    """The whole chunk of ``humanoid-qwen3next-ep32`` at the cell's sizes (2
+    sequences of 16,384 tokens, K=1, a 16,384-row ring): the compiler
+    refuses a program that does not fit the chip, and takes this one. Its
+    own count of arguments and temporaries together (8.34 + 8.74 GB) is
+    over ``HBM_BYTES`` as the chunks of ``humanoid-lfm2-ep4`` (10.30 + 7.01)
+    and ``humanoid-keye2-ep8`` (8.99 + 9.99) are, which run: the buffer
+    assignment packs tighter than the sum."""
+    with open(os.path.join(
+            REPO, "benchmark/configs/humanoid-qwen3next-ep32.json")) as f:
+        cfg = json.load(f)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    config = D4PGConfig(**cfg["model"])
+    cap = cfg["replay"]["capacity"]
+    row = lambda *s: jax.ShapeDtypeStruct(  # noqa: E731
+        (cap,) + s, jnp.float32, sharding=one_chip)
+    storage = TransitionBatch(
+        obs=row(config.obs_dim), action=row(config.act_dim), reward=row(),
+        next_obs=row(config.obs_dim), done=row(), discount=row())
+    compiled = _chunk(cfg, config, storage, one_chip)
+    m = compiled.memory_analysis()
+    # the state (6.19 GB of parameters, moments and targets) in place
+    assert m.alias_size_in_bytes > 6.1e9
+    assert m.argument_size_in_bytes < 8.5e9 and m.temp_size_in_bytes < 9.2e9
+    text = compiled.as_text()
+    assert "gmm" in text and "splash" in text
+    assert "ragged-dot" not in text and "triangular" not in text
+
+
 def _loop_body(text):
     """The lines of the (one) ``while`` loop's body in compiled text."""
     import re
