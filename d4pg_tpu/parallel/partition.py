@@ -118,7 +118,8 @@ def replica_spec() -> PS:
 
 
 def per_tree_spec() -> PS:
-    """[2·cap] device PER sum/min trees (``replay/device_per.PerTrees``):
+    """Device PER trees (``replay/device_per.PerTrees``: the sum tree
+    [2·cap], the min tree [2·cap/128], which has no leaves):
     REPLICATED. The stratified descent is a root-to-leaf pointer chase —
     every query touches every level, so splitting the tree over any mesh
     axis would turn each of the log2(cap) gathers into a collective.

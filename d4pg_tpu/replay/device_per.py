@@ -14,31 +14,50 @@ writes priorities once per step, ``ddpg.py:252-255``; the host-pipelined
 chunk path bounds staleness by (depth+1)K; this path restores exact per-step
 semantics *inside* the scan).
 
-Layout matches the host trees (``replay/segment_tree.py``): one flat
-array of ``2 * capacity`` (power of two) nodes, root at 1, leaf ``i`` at
-``capacity + i``. Unlike the host trees only the levels a reader reads
-are maintained (:func:`kept_levels`): the leaves, every seventh level
-above them, and the root.
+Layout matches the host trees (``replay/segment_tree.py``): the sum tree
+is one flat array of ``2 * capacity`` (power of two) nodes, root at 1,
+leaf ``i`` at ``capacity + i``. Unlike the host trees only the levels a
+reader reads are maintained (:func:`kept_levels`): the leaves, every
+seventh level above them, and the root. The min tree has NO LEAVES: its
+only reader reads its root, so it holds the sum tree's kept levels above
+the leaves (:func:`min_kept_levels`) under the sum tree's node numbers,
+and the array is cut below the lowest of them (:func:`min_tree_nodes`:
+``2 * capacity / 128`` nodes, 128 KB where the sum tree is 16 MB; two
+nodes for a tree of 128 leaves or fewer, which keeps the root alone).
+Its lowest kept level is taken from the SUM tree's leaves, so the
+leaves are scattered once and both trees agree on the winner among
+duplicates by construction.
 
 THE INVARIANT. A kept node is the float32 sum (sum tree) or min (min
 tree) of the 128 kept nodes seven levels under it (under the root, of
 the ``2 ** first`` nodes of the first kept level), *taken as seven (or
 ``first``) rounds of adjacent pairs*: the value a tree that stored every
 level as the ``+`` / ``min`` of its two children would hold there, to
-the bit. A node of no kept level (and node 0) is NEVER written and keeps
-what :func:`init` gave it (0 / inf), so two buffers that reached the
-same leaves by different batch shapes hold equal arrays.
+the bit; under the min tree's lowest kept level the nodes are
+``where(leaf > 0, leaf, inf)`` of the sum tree's leaves. A node of no
+kept level (and node 0) is NEVER written and keeps what :func:`init`
+gave it (0 / inf), so two buffers that reached the same leaves by
+different batch shapes hold equal arrays.
+
+THE EMPTY SLOT. A sum-tree leaf of exactly 0 is an empty slot for both
+trees: it adds no mass, cannot be sampled and is left out of the minimum
+(what :func:`init` means by 0 / inf, made a rule). No caller writes 0:
+``update_from_td`` writes ``(|td| + eps) ** alpha``, ``insert`` and the
+commit programs ``max_priority ** alpha >= 1``. A written 0 (or a
+negative or NaN leaf) is ignored by the minimum, where a min tree with
+leaves of its own would have turned every importance weight 0 or NaN.
 
 All ops are batched:
 
-  - ``set_leaves``: scatter the B leaves, then repair the kept levels
-    above them, each from the kept level below it, in one of two forms
-    chosen per step from the static capacity and B (:func:`repair_plan`):
-    by the touched rows (gather the B rows of 128 nodes under the touched
-    parents, total each by rounds of pairs, write B values; duplicates
-    among the B write identical values and need no dedup), or whole
-    (the level above recomputed from all of the one below, which is
-    what a batch that touches most rows must get);
+  - ``set_leaves``: scatter the B leaves (of the sum tree: the one leaf
+    scatter), then repair the kept levels above them, each from the kept
+    level below it, in one of two forms chosen per step from the static
+    capacity and B (:func:`repair_plan`): by the touched rows (gather
+    the B rows of 128 nodes under the touched parents, total each by
+    rounds of pairs, write B values; duplicates among the B write
+    identical values and need no dedup), or whole (the level above
+    recomputed from all of the one below, which is what a batch that
+    touches most rows must get);
   - ``sample``: B stratified inverse-CDF queries descend in lock-step,
     seven levels at a gather: the 128 nodes seven levels below node ``n``
     are row ``n`` of the tree read as ``[2N / 128, 128]``, and the six
@@ -52,7 +71,8 @@ All ops are batched:
 
 Duplicate sampled indices within a batch: ``set_leaves`` keeps one
 write-back winner per slot (scatter set), matching the reference's
-last-write-wins sequential loop up to ordering.
+last-write-wins sequential loop up to ordering; XLA leaves the winner
+unspecified, and the min tree reads whichever the sum tree kept.
 """
 
 from __future__ import annotations
@@ -71,7 +91,7 @@ class PerTrees(NamedTuple):
     """Device PER state; a pure pytree (donate/checkpoint-able)."""
 
     sum_tree: Array  # [2 * capacity] float32, node 1 is the root
-    min_tree: Array  # [2 * capacity] float32
+    min_tree: Array  # [min_tree_nodes(capacity)] float32, no leaves
     max_priority: Array  # [] float32, running max of RAW priorities
 
     @property
@@ -90,7 +110,7 @@ def init(capacity: int) -> PerTrees:
     cap = next_pow2(int(capacity))
     return PerTrees(
         sum_tree=jnp.zeros(2 * cap, jnp.float32),
-        min_tree=jnp.full(2 * cap, jnp.inf, jnp.float32),
+        min_tree=jnp.full(min_tree_nodes(cap), jnp.inf, jnp.float32),
         max_priority=jnp.ones((), jnp.float32),
     )
 
@@ -120,6 +140,19 @@ def kept_levels(capacity: int) -> tuple[int, ...]:
     return (0,) * (first > 0) + tuple(range(first, levels + 1, _ROW_LEVELS))
 
 
+def min_kept_levels(capacity: int) -> tuple[int, ...]:
+    """The levels the MIN tree keeps: the sum tree's without the leaves
+    (only its root is ever read, and its lowest kept level is made from
+    the sum tree's leaves); the root alone for 128 leaves or fewer."""
+    return kept_levels(capacity)[:-1] or (0,)
+
+
+def min_tree_nodes(capacity: int) -> int:
+    """The length of ``PerTrees.min_tree``: heap order under the sum
+    tree's node numbers, cut below its lowest kept level."""
+    return 2 << min_kept_levels(capacity)[-1]
+
+
 def repair_plan(capacity: int, batch: int) -> tuple[tuple[int, int, str], ...]:
     """The steps of :func:`set_leaves` for ``batch`` leaves, from the
     leaves up: ``(kept level already right, kept level repaired from it,
@@ -137,9 +170,14 @@ def repair_plan(capacity: int, batch: int) -> tuple[tuple[int, int, str], ...]:
 
 def plan_text(capacity: int, batch: int) -> str:
     """:func:`repair_plan` as ``train``'s ``plan:`` line prints it:
-    ``21>14 rows,14>7 whole,7>root whole``."""
-    return ",".join(f"{below}>{above or 'root'} {form}"
-                    for below, above, form in repair_plan(capacity, batch))
+    ``21>14 rows(min from sum),14>7 whole,7>root whole``. The first step
+    says where the min tree's side of it comes from: the sum tree's
+    leaves, the one leaf level there is."""
+    steps = [f"{below}>{above or 'root'} {form}"
+             for below, above, form in repair_plan(capacity, batch)]
+    if steps:
+        steps[0] += "(min from sum)"
+    return ",".join(steps)
 
 
 def _parents(s: Array, m: Array) -> tuple[Array, Array]:
@@ -151,11 +189,19 @@ def _parents(s: Array, m: Array) -> tuple[Array, Array]:
     ``reshape(-1, 2).sum(-1)`` may be merged with the next level's into
     one reduction of four, which rounds differently, and costs 40 times
     as much; ``x[0::2] + x[1::2]`` 300 times (v5e; PERF.md, PR 29). Both
-    trees share one window."""
+    trees share one window; over the leaves ``m`` is the sum tree's own
+    level with its empty slots at ``inf`` (:func:`_min_side`), an
+    elementwise producer of the window and no array of its own."""
     return jax.lax.reduce_window(
         (s, m), (jnp.float32(0), jnp.float32(jnp.inf)),
         lambda a, b: (a[0] + b[0], jnp.minimum(a[1], b[1])),
         (1, 2), (1, 2), "VALID")
+
+
+def _min_side(leaves: Array) -> Array:
+    """What the min tree reads of the sum tree's leaves: an empty slot
+    (0; and what no priority can be, negative or NaN) is ``inf``."""
+    return jnp.where(leaves > 0, leaves, jnp.float32(jnp.inf))
 
 
 def _pair_rounds(row: Array, op=jnp.add):
@@ -208,9 +254,9 @@ def _level_totals(s: Array, m: Array) -> tuple[Array, Array]:
 
 def set_leaves(trees: PerTrees, idx: Array, p_alpha: Array) -> PerTrees:
     """Write ``p_alpha`` ([B], already ``priority ** alpha``) at leaves
-    ``idx`` ([B] int) and repair the kept levels of both trees above
-    them (:func:`kept_levels`), each from the kept level below it, by
-    the steps of :func:`repair_plan`:
+    ``idx`` ([B] int) of the sum tree and repair the kept levels of both
+    trees above them (:func:`kept_levels`, :func:`min_kept_levels`), each
+    from the kept level below it, by the steps of :func:`repair_plan`:
 
     - **rows**: the B rows of 128 nodes under the touched parents are
       gathered (after the write below, so duplicates read identical
@@ -219,6 +265,13 @@ def set_leaves(trees: PerTrees, idx: Array, p_alpha: Array) -> PerTrees:
       and need no dedup;
     - **whole**: the level above is recomputed from all of the level
       below, a window a round, and written as one static slice.
+
+    The first step reads the SUM tree's leaves for both trees (by rows:
+    one gather of the B rows; whole: one stream of windows over the
+    leaves), totalled with ``+`` for the sum tree and with ``min`` over
+    :func:`_min_side` of them for the min tree, which has no leaves of
+    its own: one leaf scatter a call, and both trees see one winner
+    among duplicates. Every step above reads each tree's own level.
 
     Either way a kept node ends as the invariant defines it (module
     docstring), to the bit; a node of no kept level is never written.
@@ -248,24 +301,21 @@ def set_leaves(trees: PerTrees, idx: Array, p_alpha: Array) -> PerTrees:
     with jax.named_scope("writeback.leaves"):
         s = trees.sum_tree.at[node].set(p_alpha.astype(jnp.float32),
                                         mode="drop")
-        # XLA leaves the winner among duplicate scatter indices
-        # unspecified, so the min tree copies the sum tree's POST-scatter
-        # leaf values: both trees then agree on the same winner by
-        # construction (two independent scatters could record different
-        # priorities for the same slot, making min_tree report a phantom
-        # minimum).
-        m = trees.min_tree.at[node].set(s[jnp.minimum(node, 2 * cap - 1)],
-                                        mode="drop")
+    m = trees.min_tree
+    plan = repair_plan(cap, idx32.size)
+    if not plan:  # one leaf: it is the root
+        m = m.at[1].set(_min_side(s[1]))
     # every step makes the whole kept level above as an array of its own,
     # writes it as one static slice and hands it to the step above: a
     # whole step then reads what the step below made, not the tree again
     level = None
-    for below, above, form in repair_plan(cap, idx32.size):
+    for below, above, form in plan:
         width = 1 << above
+        leaves = level is None  # the first step: both trees read `s`'s
         if form == "whole":
             with jax.named_scope("writeback.whole"):
-                if level is None:
-                    level = tuple(t[1 << below:2 << below] for t in (s, m))
+                if leaves:
+                    level = s[cap:], _min_side(s[cap:])
                 lanes = min(1 << below, _LANES)  # under the root: one row
                 level = _level_totals(*(x.reshape(-1, lanes)
                                         for x in level))
@@ -273,10 +323,11 @@ def set_leaves(trees: PerTrees, idx: Array, p_alpha: Array) -> PerTrees:
             with jax.named_scope("writeback.rows"):
                 # the node _ROW_LEVELS up is the index of the row under it
                 node = jnp.where(valid, node >> _ROW_LEVELS, 2 * cap)
-                row = jnp.minimum(node, 2 * cap // _LANES - 1)
-                totals = (_row_total(s.reshape(-1, _LANES)[row]),
-                          _row_total(m.reshape(-1, _LANES)[row],
-                                     jnp.minimum))
+                row_s = s.reshape(-1, _LANES)[
+                    jnp.minimum(node, 2 * cap // _LANES - 1)]
+                row_m = _min_side(row_s) if leaves else m.reshape(
+                    -1, _LANES)[jnp.minimum(node, m.size // _LANES - 1)]
+                totals = _row_total(row_s), _row_total(row_m, jnp.minimum)
                 # B scalars cost ~86 ns an index scattered into the 16 MB
                 # tree where it lies in HBM and ~6 into the level's own
                 # slice, which the compiler keeps in fast memory (24.6

@@ -77,7 +77,7 @@ class ShardedPerTrees(NamedTuple):
     """Per-shard tree pair, leading axis = shard (sharded over ``data``)."""
 
     sum_tree: "jax.Array"  # [n_shards, 2 * cap_shard]
-    min_tree: "jax.Array"  # [n_shards, 2 * cap_shard]
+    min_tree: "jax.Array"  # [n_shards, min_tree_nodes(cap_shard)]
     max_priority: "jax.Array"  # [n_shards] per-shard running max
 
     @property
@@ -107,6 +107,7 @@ class ShardedFusedReplay:
 
         from d4pg_tpu.parallel import partition
         from d4pg_tpu.parallel.mesh import DATA_AXIS
+        from d4pg_tpu.replay.device_per import min_tree_nodes
 
         self.mesh = mesh
         self.n_shards = int(mesh.shape[DATA_AXIS])
@@ -156,7 +157,8 @@ class ShardedFusedReplay:
         def _zero_trees():
             return ShardedPerTrees(
                 sum_tree=jnp.zeros((n, 2 * c), jnp.float32),
-                min_tree=jnp.full((n, 2 * c), jnp.inf, jnp.float32),
+                min_tree=jnp.full((n, min_tree_nodes(c)), jnp.inf,
+                                  jnp.float32),
                 max_priority=jnp.ones((n,), jnp.float32),
             )
 
@@ -446,22 +448,26 @@ class ShardedFusedReplay:
         self._size_global = None
         self._rr = int(s["rr"])
         if self.trees is not None:
+            from d4pg_tpu.replay.device_per import (min_tree_nodes,
+                                                    repair_plan)
+
             leaves = np.asarray(s["leaf_priorities"], np.float32)
             sum_tree = np.zeros((n, 2 * c), np.float32)
-            min_tree = np.full((n, 2 * c), np.inf, np.float32)
+            min_tree = np.full((n, min_tree_nodes(c)), np.inf, np.float32)
             for sh in range(n):
                 sz = int(self._size[sh])
                 sum_tree[sh, c:c + sz] = leaves[sh, :sz]
-                min_tree[sh, c:c + sz] = leaves[sh, :sz]
             # rebuild the kept levels as device_per.set_leaves leaves them
             # (its invariant: rounds of adjacent pairs from the kept level
-            # below, float32; no other node is written), vectorized across
-            # shards, so a restored tree equals a live one array for array
-            from d4pg_tpu.replay.device_per import repair_plan
-
+            # below, float32; the min tree has no leaves and reads the sum
+            # tree's, an empty slot at inf; no other node is written),
+            # vectorized across shards, so a restored tree equals a live
+            # one array for array
+            lvl_s = sum_tree[:, c:]
+            lvl_m = np.where(lvl_s > 0, lvl_s, np.float32(np.inf))
+            if c == 1:  # one leaf: it is the root
+                min_tree[:, 1:] = lvl_m
             for below, above, _form in repair_plan(c, c):
-                lvl_s = sum_tree[:, 1 << below:2 << below]
-                lvl_m = min_tree[:, 1 << below:2 << below]
                 for _ in range(below - above):
                     lvl_s = lvl_s[:, 0::2] + lvl_s[:, 1::2]
                     lvl_m = np.minimum(lvl_m[:, 0::2], lvl_m[:, 1::2])

@@ -113,18 +113,28 @@ def test_set_leaves_traces_at_production_capacity():
     assert out.sum_tree.shape == (2 * cap,)
 
 
+def _oracle_init(cap):
+    """The oracle's trees: both of ``2 * cap`` nodes, every level and the
+    min tree's leaves among them."""
+    return dper.init(cap)._replace(
+        min_tree=jnp.full(2 * cap, jnp.inf, jnp.float32))
+
+
 def _level_by_level(trees, idx, p_alpha):
     """The ORACLE: the repair ``set_leaves`` did before the upper levels
-    went dense (PR 29), kept here verbatim. After the leaf scatter every
-    level is a gather of two children and a scatter of the touched
-    parents, so only the B paths are ever written."""
+    went dense (PR 29), kept here verbatim but for the empty slot (a min
+    tree with leaves of its own, each the sum tree's or, where that is no
+    priority, ``inf``). After the leaf scatter every level is a gather of
+    two children and a scatter of the touched parents, so only the B
+    paths are ever written."""
     cap = trees.capacity
     idx32 = idx.astype(jnp.int32)
     valid = idx32 < cap
     node = jnp.where(valid, idx32 + cap, 2 * cap)
     s = trees.sum_tree.at[node].set(p_alpha.astype(jnp.float32),
                                     mode="drop")
-    m = trees.min_tree.at[node].set(s[jnp.minimum(node, 2 * cap - 1)],
+    leaf = s[jnp.minimum(node, 2 * cap - 1)]
+    m = trees.min_tree.at[node].set(jnp.where(leaf > 0, leaf, jnp.inf),
                                     mode="drop")
     for _ in range(int(math.log2(cap))):
         node = jnp.where(valid, node >> 1, 2 * cap)
@@ -145,9 +155,9 @@ def _seeded_trees(cap, rng):
     n = max(1, 3 * cap // 4)
     p = jnp.asarray(rng.uniform(0.01, 5.0, n), jnp.float32)
     trees, oracle = (
-        fn(dper.init(cap), jnp.arange(n), p)._replace(
+        fn(init(cap), jnp.arange(n), p)._replace(
             max_priority=jnp.float32(3.25))
-        for fn in (_NEW_JIT, _ORACLE_JIT))
+        for fn, init in ((_NEW_JIT, dper.init), (_ORACLE_JIT, _oracle_init)))
     _assert_same_trees(trees, oracle)
     return trees, oracle
 
@@ -175,26 +185,32 @@ def _batch(kind, cap, rng):
     return jnp.asarray(idx, jnp.int32), jnp.asarray(value[idx])
 
 
-def _kept(cap):
-    """Mask over the ``2 * cap`` nodes: those of a kept level."""
-    mask = np.zeros(2 * cap, bool)
-    for level in dper.kept_levels(cap):
+def _kept(levels, nodes):
+    """Mask over a tree's ``nodes`` nodes: those of the kept ``levels``."""
+    mask = np.zeros(nodes, bool)
+    for level in levels:
         mask[1 << level:2 << level] = True
     return mask
 
 
 def _assert_same_trees(got, want):
-    """The kept levels of both trees (node 1 and the leaves among them)
-    and the running max are ``want``'s to the bit, ``want`` an oracle's
-    trees with every level written; every other node of ``got`` still
-    holds what ``init`` gave it: it was never written."""
-    kept, fresh = _kept(got.capacity), dper.init(got.capacity)
-    assert kept[1] and kept[got.capacity:].all() and not kept[0]
-    for name in ("sum_tree", "min_tree", "max_priority"):
+    """The kept levels of both trees (node 1 among them; the sum tree's
+    leaves; the min tree has none and ends above them) and the running
+    max are ``want``'s to the bit, ``want`` an oracle's trees of
+    ``2 * cap`` nodes with every level written; every other node of
+    ``got`` still holds what ``init`` gave it: it was never written."""
+    cap, fresh = got.capacity, dper.init(got.capacity)
+    assert got.min_tree.shape == (dper.min_tree_nodes(cap),)
+    assert want.min_tree.shape == want.sum_tree.shape == (2 * cap,)
+    for name, levels in (("sum_tree", dper.kept_levels(cap)),
+                         ("min_tree", dper.min_kept_levels(cap)),
+                         ("max_priority", None)):
         g, w = np.asarray(getattr(got, name)), np.asarray(getattr(want, name))
-        assert g.dtype == w.dtype and g.shape == w.shape, name
+        assert g.dtype == w.dtype, name
         if g.ndim:  # a tree: every other node is as `init` left it
-            w = np.where(kept, w, np.asarray(getattr(fresh, name)))
+            kept = _kept(levels, g.size)
+            assert kept[1] and not kept[0] and levels[0] == 0
+            w = np.where(kept, w[:g.size], np.asarray(getattr(fresh, name)))
         np.testing.assert_array_equal(g.view(np.uint32), w.view(np.uint32),
                                       err_msg=name)
 
@@ -285,7 +301,7 @@ def test_set_leaves_rows_and_whole_forced_in_turn(form, rng, monkeypatch):
         "rows_under_256": ["rows", "whole", "whole"]}[form]
     assert dper.repair_plan(cap, 4)[1] == (5, 0, "whole")
     new = jax.jit(dper.set_leaves)  # traced under this constant
-    got = want = dper.init(cap)
+    got, want = dper.init(cap), _oracle_init(cap)
     for step in range(6):
         kind = ("wrapping_block", "random256", "with_pads")[step % 3]
         idx, p = _batch(kind, cap, rng)
@@ -301,39 +317,137 @@ def _pairwise(level, op, rounds):
     return level
 
 
-def test_set_leaves_duplicates_agree_between_the_trees(rng):
-    """Duplicates with DIFFERENT values: whichever write wins, the min
-    tree's leaf is the sum tree's, and every kept level of both trees is
-    the numpy rebuild of the kept level below it by rounds of adjacent
-    float32 pairs (the invariant)."""
-    cap = 1024
-    idx = jnp.asarray(rng.integers(0, 64, 256), jnp.int32)
-    p = jnp.asarray(rng.uniform(0.01, 5.0, 256), jnp.float32)
-    trees = _NEW_JIT(_seeded_trees(cap, rng)[0], idx, p)
+def _assert_the_invariant(trees):
+    """THE INVARIANT, rebuilt in numpy from the sum tree's leaves alone:
+    every kept level of the sum tree is the kept level below it totalled
+    by rounds of adjacent float32 pairs; every kept level of the min tree
+    (which has no leaves) is the rounds of adjacent pairs over
+    ``where(leaf > 0, leaf, inf)`` of the SUM tree's leaves; every other
+    node of both is as ``init`` left it."""
+    cap = trees.capacity
     s, m = np.asarray(trees.sum_tree), np.asarray(trees.min_tree)
-    touched = cap + np.unique(np.asarray(idx))
-    np.testing.assert_array_equal(s[touched], m[touched])
+    levels = int(math.log2(cap))
+    want_s = np.zeros(2 * cap, np.float32)
+    want_s[cap:] = s[cap:]
     kept = dper.kept_levels(cap)
-    assert kept == (0, 3, 10)
-    for above, below in zip(kept, kept[1:]):
-        for tree, op in ((s, np.add), (m, np.minimum)):
-            np.testing.assert_array_equal(
-                tree[1 << above:2 << above],
-                _pairwise(tree[1 << below:2 << below], op, below - above))
-    assert s[0] == 0.0 and m[0] == np.inf  # node 0 belongs to no level
+    for above, below in zip(kept[-2::-1], kept[::-1]):
+        want_s[1 << above:2 << above] = _pairwise(
+            want_s[1 << below:2 << below], np.add, below - above)
+    side = np.where(s[cap:] > 0, s[cap:], np.float32(np.inf))
+    want_m = np.full(dper.min_tree_nodes(cap), np.inf, np.float32)
+    for level in dper.min_kept_levels(cap):
+        want_m[1 << level:2 << level] = _pairwise(side, np.minimum,
+                                                  levels - level)
+    for got, want in ((s, want_s), (m, want_m)):
+        np.testing.assert_array_equal(got.view(np.uint32),
+                                      want.view(np.uint32))
+
+
+MIN_TREE_CAPS = (8, 16, 128, 1024, 65536)
+
+
+@pytest.mark.parametrize("form", ("rows", "whole"))
+@pytest.mark.parametrize("batch", (1, 256, 4096, "capacity"))
+@pytest.mark.parametrize("cap", MIN_TREE_CAPS)
+def test_min_root_is_the_host_min_trees_over_the_written_leaves(
+        cap, batch, form, rng, monkeypatch):
+    """The min tree has no leaves, and its root is still the host
+    ``MinTree``'s over the leaves ever written, to the bit, through a
+    sequence of ``insert`` (a wrapping block), ``set_leaves`` (duplicates
+    with DIFFERENT values: whichever write wins, both trees see that
+    winner, the one leaf there is) and ``update_from_td`` (pads among the
+    indices) on a ring three quarters in use, at B of 1 / 256 / 4,096 /
+    every leaf, with every step that can go by rows forced by rows and
+    then whole (the rule's constant turned, as ``FORCED`` does); after
+    every call both trees are the invariant's over the sum tree's
+    leaves."""
+    monkeypatch.setattr(dper, "_WHOLE_NODES_PER_LEAF", FORCED[form])
+    b = cap if batch == "capacity" else batch
+    if cap > 128:
+        assert dper.repair_plan(cap, b)[0][2] == form
+    # fresh function objects: traced under this constant, not an earlier
+    set_leaves = jax.jit(lambda t, i, p: dper.set_leaves(t, i, p))
+    insert = jax.jit(lambda t, i: dper.insert(t, i, 0.6))
+    update = jax.jit(lambda t, i, td: dper.update_from_td(t, i, td, 0.6))
+    live = max(1, 3 * cap // 4)
+    trees, host, written = dper.init(cap), MinTree(cap), np.zeros(cap, bool)
+    assert float(trees.min_tree[1]) == host.min() == np.inf
+    for call in range(6):
+        if call % 3 == 0:
+            idx = (live - b // 3 + np.arange(b)) % live
+            trees = insert(trees, jnp.asarray(idx, jnp.int32))
+        elif call % 3 == 1:
+            idx = rng.integers(0, min(live, 64), b)
+            p = rng.uniform(0.01, 5.0, b).astype(np.float32)
+            trees = set_leaves(trees, jnp.asarray(idx, jnp.int32),
+                               jnp.asarray(p))
+        else:
+            idx = rng.integers(0, live, b)
+            if b > 1:
+                idx[rng.random(b) < 0.3] = cap  # pads
+            td = rng.normal(0.0, 3.0, b).astype(np.float32)
+            trees = update(trees, jnp.asarray(idx, jnp.int32),
+                           jnp.asarray(td))
+        written[idx[idx < cap]] = True
+        slots = np.flatnonzero(written)
+        leaves = np.asarray(trees.sum_tree[cap:])
+        assert (leaves[slots] > 0).all() and not leaves[~written].any()
+        host.set(slots, leaves[slots])
+        root = np.asarray(trees.min_tree[1])
+        assert root.dtype == np.float32 and float(root) == host.min()
+        _assert_the_invariant(trees)
+
+
+@pytest.mark.parametrize("cap, batch, form", [
+    (1, 1, None), (8, 4, None), (128, 4, None), (4096, 4, "rows"),
+    (4096, 4, "whole"), (4096, 4096, None), (1 << 15, 4, None)])
+def test_a_written_zero_is_an_empty_slot_in_both_trees(cap, batch, form,
+                                                       rng, monkeypatch):
+    """A sum-tree leaf of exactly 0 adds no mass, cannot be sampled and is
+    left out of the minimum, written or never written alike (``init``'s
+    0 / inf made a rule): zeros written over the smallest priorities leave
+    the min root at the smallest POSITIVE leaf and the importance weights
+    finite, where a min tree with leaves of its own read 0 and made every
+    weight 0 or NaN; a ring of nothing but zeros is a fresh one."""
+    if form is not None:
+        monkeypatch.setattr(dper, "_WHOLE_NODES_PER_LEAF", FORCED[form])
+    set_leaves = jax.jit(lambda t, i, p: dper.set_leaves(t, i, p))
+    vals = rng.uniform(0.5, 5.0, cap).astype(np.float32)
+    trees = set_leaves(dper.init(cap), jnp.arange(cap), jnp.asarray(vals))
+    assert float(trees.min_tree[1]) == vals.min()
+    zeroed = np.argsort(vals)[:min(batch, cap // 2)]  # the smallest
+    idx = np.resize(zeroed, batch) if zeroed.size else np.full(batch, cap)
+    trees = set_leaves(trees, jnp.asarray(idx, jnp.int32),
+                       jnp.zeros(batch, jnp.float32))
+    vals[zeroed] = 0.0
+    np.testing.assert_array_equal(np.asarray(trees.sum_tree[cap:]), vals)
+    _assert_the_invariant(trees)
+    assert float(trees.min_tree[1]) == vals[vals > 0].min()
+    got = np.asarray(dper.sample(trees, jax.random.key(0), 64,
+                                 jnp.int32(cap)))
+    assert (vals[got] > 0).all()
+    w = np.asarray(dper.is_weights(trees, jnp.asarray(got),
+                                   jnp.float32(0.5), jnp.int32(cap)))
+    assert np.isfinite(w).all() and (w > 0).all() and w.max() <= 1.0
+    emptied = set_leaves(trees, jnp.arange(cap), jnp.zeros(cap, jnp.float32))
+    fresh = dper.init(cap)
+    for name in ("sum_tree", "min_tree"):
+        np.testing.assert_array_equal(np.asarray(getattr(emptied, name)),
+                                      np.asarray(getattr(fresh, name)))
 
 
 def _abstract_trees(cap):
-    return dper.PerTrees(
-        jax.ShapeDtypeStruct((2 * cap,), jnp.float32),
-        jax.ShapeDtypeStruct((2 * cap,), jnp.float32),
-        jax.ShapeDtypeStruct((), jnp.float32))
+    return jax.eval_shape(lambda: dper.init(cap))
 
 
 def _lowered(cap, batch):
     """What ``set_leaves`` lowers to (trace and lower only): ``(scatters,
     gathers of 128-node rows, [nodes a tree each reduce_window reads])``,
-    both trees in every window."""
+    both trees in every window. ONE scatter goes into a ``2 * cap``
+    operand, the sum tree's leaf scatter; every other goes into a kept
+    level's own slice. A row gather reads the sum tree, or the (smaller)
+    min tree at a step by rows above the first; none reads the sum tree
+    twice at a step."""
     import re
 
     text = jax.jit(dper.set_leaves).lower(
@@ -343,11 +457,22 @@ def _lowered(cap, batch):
         r"\}\) : \(tensor<(\d+)x(\d+)xf32>, tensor<\d+x\d+xf32>, "
         r"tensor<f32>, tensor<f32>\) ->", text)]
     assert len(windows) == text.count('"stablehlo.reduce_window"(')
+    into = [int(n) for n in re.findall(
+        r'"stablehlo\.scatter"\(.*?\}\) : \(tensor<(\d+)xf32>', text, re.S)]
+    assert len(into) == text.count('"stablehlo.scatter"(')
+    assert into.count(2 * cap) == 1 and max(into) == 2 * cap
     gathers = [ln for ln in text.splitlines() if '"stablehlo.gather"(' in ln]
     rows = [ln for ln in gathers
             if "slice_sizes = array<i64: 1, 128>" in ln]
-    assert all(f"(tensor<{2 * cap // 128}x128xf32>," in ln for ln in rows)
-    return text.count('"stablehlo.scatter"('), len(rows), windows
+    assert len(rows) == len(gathers)  # no leaf is read back by the scalar
+    of_sum = [f"(tensor<{2 * cap // 128}x128xf32>," in ln for ln in rows]
+    of_min = [f"(tensor<{dper.min_tree_nodes(cap) // 128}x128xf32>," in ln
+              for ln in rows]
+    assert all(s or m for s, m in zip(of_sum, of_min))
+    by_rows = [form for _b, _a, form
+               in dper.repair_plan(cap, batch)].count("rows")
+    assert of_sum.count(True) == by_rows
+    return len(into), len(rows), windows
 
 
 def _windows_of(plan):
@@ -358,66 +483,78 @@ def _windows_of(plan):
                   reverse=True)
 
 
+@pytest.mark.parametrize("grow", (0, 1, 2), ids=("2M", "4M", "8M"))
 @pytest.mark.parametrize("batch", (256, 4096))
-def test_set_leaves_structure_at_production_capacity(batch):
-    """The structure of the repair at the MLP cells' 2,097,152 leaves,
-    read off the lowered module. The chunk's B = 256: the two leaf
-    scatters, two gathers of 256 rows of 128 leaves, two scatters of their
-    totals (into level 14's own slice), and fourteen windows of which the
-    widest reads level 14 (16,384 nodes, 64 KB a tree) and none the
-    leaves, where the parent had 21, the widest over all 2,097,152 leaves
-    of both trees. The commit's 4,096: the two leaf scatters and
-    twenty-one windows, none of which reads more than one kept level's
-    span, and three levels written in place of twenty-one; a ring twice
-    or four times the size adds no scatter and no gather to either."""
-    for grow in (0, 1, 2):
-        cap = 1 << (21 + grow)
-        scatters, rows, windows = _lowered(cap, batch)
-        plan = dper.repair_plan(cap, batch)
-        by_rows = [form for _b, _a, form in plan].count("rows")
-        assert by_rows == (1 if batch == 256 else 0)
-        assert (scatters, rows) == (2 + 2 * by_rows, 2 * by_rows)
-        assert sorted(windows, reverse=True) == _windows_of(plan)
-        assert len(windows) == 21 + grow - 7 * by_rows
-        assert max(windows) == cap >> 7 * by_rows
+def test_set_leaves_structure_at_production_capacity(batch, grow):
+    """The structure of the repair at the MLP cells' 2,097,152 leaves and
+    at rings twice and four times the size, read off the lowered module.
+    The chunk's B = 256: ONE scatter into a tree-sized operand (the sum
+    tree's leaves; the parent had a second, of the same values at the
+    same nodes of the min tree, and a gather of the leaves between them),
+    ONE gather of 256 rows of 128 leaves that both trees total (the
+    parent had one a tree), two scatters of the totals (into the level's
+    own slice), and fourteen windows (2^21) of which the widest reads the
+    first kept level above the leaves and none the leaves. The commit's
+    4,096: the one leaf scatter and a window a level, none of which reads
+    more than one kept level's span; the stream over the leaves reads the
+    sum tree's alone."""
+    cap = 1 << (21 + grow)
+    scatters, rows, windows = _lowered(cap, batch)
+    plan = dper.repair_plan(cap, batch)
+    by_rows = [form for _b, _a, form in plan].count("rows")
+    assert by_rows == (1 if batch == 256 else 0)
+    assert (scatters, rows) == (1 + 2 * by_rows, by_rows)
+    assert sorted(windows, reverse=True) == _windows_of(plan)
+    assert len(windows) == 21 + grow - 7 * by_rows
+    assert max(windows) == cap >> 7 * by_rows
 
 
-def test_set_leaves_structure_follows_the_batch():
+@pytest.mark.parametrize("levels, batch, scatters, rows, windows", [
+    (21, 1, 1 + 4, 3, [128 >> i for i in range(7)]),
+    (21, 64, 1 + 2, 1, None),
+    (16, 512, 1, 0, [1 << 16 >> i for i in range(16)]),
+    (15, 4, 1 + 2, 1, None),  # cells 4 and 6
+    (21, 1 << 21, 1, 0, None),
+], ids=("per_row_insert", "64_on_2M", "pixel_cell", "torso_cells",
+        "every_leaf"))
+def test_set_leaves_structure_follows_the_batch(levels, batch, scatters,
+                                                rows, windows):
     """Few leaves on a large tree go by rows further up: a per-row insert
-    (``drain_per_row``) into the 2M-leaf ring gathers a row a tree at two
-    steps and runs the seven windows under the root only; the pixel
+    (``drain_per_row``) into the 2M-leaf ring gathers a row of the sum
+    tree at the first step (both trees total it) and a row a tree at the
+    second (three gathers, a scatter a tree and step into the levels' own
+    slices) and runs the seven windows under the root only; the pixel
     cell's 512 leaves on 65,536 and a batch of every leaf are whole at
-    every step."""
-    assert _lowered(1 << 21, 1) == (2 + 4, 4, [128 >> i for i in range(7)])
-    assert _lowered(1 << 21, 64)[:2] == (2 + 2, 2)
-    assert _lowered(1 << 16, 512) == (2, 0, [1 << 16 >> i
-                                             for i in range(16)])
-    assert _lowered(1 << 15, 4)[:2] == (2 + 2, 2)  # cells 4 and 6
-    assert _lowered(1 << 21, 1 << 21)[:2] == (2, 0)
+    every step: the leaf scatter and nothing else."""
+    got = _lowered(1 << levels, batch)
+    assert got[:2] == (scatters, rows)
+    assert windows is None or got[2] == windows
 
 
 # the benchmark's seven cells: what the chunk's write-back and the commit's
 # insert do to the trees at the shapes of each cell's configuration file
 CELL_PLANS = {
     "humanoid-mlp.learn-static": (
-        "21>14 rows,14>7 whole,7>root whole",
-        "21>14 whole,14>7 whole,7>root whole"),
+        "21>14 rows(min from sum),14>7 whole,7>root whole",
+        "21>14 whole(min from sum),14>7 whole,7>root whole"),
     "dmc-pixels-drq.learn-static": (
-        "16>9 whole,9>2 whole,2>root whole",
-        "16>9 whole,9>2 whole,2>root whole"),
+        "16>9 whole(min from sum),9>2 whole,2>root whole",
+        "16>9 whole(min from sum),9>2 whole,2>root whole"),
     "humanoid-mlp.learn-ingest": (
-        "21>14 rows,14>7 whole,7>root whole",
-        "21>14 whole,14>7 whole,7>root whole"),
+        "21>14 rows(min from sum),14>7 whole,7>root whole",
+        "21>14 whole(min from sum),14>7 whole,7>root whole"),
     "humanoid-mellum2-ep4.learn-static": (
-        "15>8 rows,8>1 whole,1>root whole",
-        "15>8 whole,8>1 whole,1>root whole"),
+        "15>8 rows(min from sum),8>1 whole,1>root whole",
+        "15>8 whole(min from sum),8>1 whole,1>root whole"),
     "humanoid-keye2-ep8.learn-static": (
-        "14>7 rows,7>root whole", "14>7 whole,7>root whole"),
+        "14>7 rows(min from sum),7>root whole",
+        "14>7 whole(min from sum),7>root whole"),
     "humanoid-lfm2-ep4.learn-static": (
-        "15>8 rows,8>1 whole,1>root whole",
-        "15>8 whole,8>1 whole,1>root whole"),
+        "15>8 rows(min from sum),8>1 whole,1>root whole",
+        "15>8 whole(min from sum),8>1 whole,1>root whole"),
     "humanoid-qwen3next-ep32.learn-static": (
-        "14>7 rows,7>root whole", "14>7 whole,7>root whole"),
+        "14>7 rows(min from sum),7>root whole",
+        "14>7 whole(min from sum),7>root whole"),
 }
 
 
@@ -480,8 +617,10 @@ def _full_trees(vals):
     leaves them (kept levels only: what ``descend`` gets) and as the
     level-by-level repair does (every level: what the level-by-level
     walk needs)."""
-    trees, oracle = (fn(dper.init(vals.size), jnp.arange(vals.size),
-                        jnp.asarray(vals)) for fn in (_NEW_JIT, _ORACLE_JIT))
+    trees, oracle = (fn(init(vals.size), jnp.arange(vals.size),
+                        jnp.asarray(vals))
+                     for fn, init in ((_NEW_JIT, dper.init),
+                                      (_ORACLE_JIT, _oracle_init)))
     _assert_same_trees(trees, oracle)
     return trees, oracle
 
@@ -611,7 +750,7 @@ def test_descend_after_fifty_set_leaves_and_inserts(form, rng, monkeypatch):
             mass = _masses(trees.sum_tree, (64,), rng)
             # the level-by-level walk's tree: every level, over the leaves
             # the fifty calls have left
-            every_level = _ORACLE_JIT(dper.init(cap), jnp.arange(cap),
+            every_level = _ORACLE_JIT(_oracle_init(cap), jnp.arange(cap),
                                       trees.sum_tree[cap:])
             _assert_same_slots(_DESCEND_JIT(trees.sum_tree, mass),
                                _WALK_ORACLE_JIT(every_level.sum_tree, mass))
@@ -755,7 +894,7 @@ def test_commit_program_compiles_once_across_block_shapes(rng):
         assert buf.drain() == block
     assert buf.head < block and buf.size == cap
     assert sentinel.compilations == 0
-    want = _ORACLE_JIT(dper.init(cap), jnp.arange(cap),
+    want = _ORACLE_JIT(_oracle_init(cap), jnp.arange(cap),
                        jnp.ones(cap, jnp.float32))
     _assert_same_trees(buf.trees, want)
 
@@ -976,7 +1115,8 @@ def test_train_fused_her_goal_env(tmp_path, capsys):
     """HER relabels stream through the fused device buffer like ordinary
     rows (goal-conditioned obs, success-based dones). ``train``'s
     ``plan:`` line says how the trees are repaired at the chunk's batch
-    and at the commit's block."""
+    and at the commit's block, and that the min tree's side of the first
+    step comes from the sum tree's leaves."""
     from d4pg_tpu.config import ExperimentConfig
     from d4pg_tpu.train import train
 
@@ -990,7 +1130,8 @@ def test_train_fused_her_goal_env(tmp_path, capsys):
     metrics = train(cfg)
     assert np.isfinite(metrics["critic_loss"])
     assert "success_rate" in metrics
-    want = "chunk:11>4 whole,4>root whole;commit:11>4 whole,4>root whole"
+    want = ("chunk:11>4 whole(min from sum),4>root whole;"
+            "commit:11>4 whole(min from sum),4>root whole")
     assert metrics["plan"]["tree_repair"] == want
     plan_line, = [ln for ln in capsys.readouterr().out.splitlines()
                   if ln.startswith("plan: ")]

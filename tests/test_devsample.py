@@ -288,6 +288,13 @@ def test_deal_dispatch_sentinels(rng):
                  buf.trees.min_tree, buf.gen, u, np.int32(buf.size))
     resh.assert_clean("device deal dispatch")
     assert resh.steady_state_reshards == 0
+    # the deal hands on the min tree's root, all it reads of that tree
+    # (which has no leaves): the smallest live leaf of the sum tree
+    cap = buf.trees.capacity
+    out = dealer.deal_fn(buf.storage, buf.trees.sum_tree,
+                         buf.trees.min_tree, buf.gen, u, np.int32(buf.size))
+    assert float(out[-1]) == float(
+        np.asarray(buf.trees.sum_tree[cap:cap + buf.size]).min()) > 0
 
 
 # ----------------------------------------------- chaos smoke (device)

@@ -53,6 +53,10 @@ def test_block_drain_bitwise_equals_per_row(rng):
                                   np.asarray(b.trees.sum_tree))
     np.testing.assert_array_equal(np.asarray(a.trees.min_tree),
                                   np.asarray(b.trees.min_tree))
+    # the min tree has no leaves: both made it from the sum tree's
+    assert a.trees.min_tree.shape == (2,) and a.trees.capacity == 128
+    assert float(a.trees.min_tree[1]) == float(
+        np.asarray(a.trees.sum_tree[128:128 + a.size]).min()) > 0
 
 
 def test_block_drain_wraparound_at_capacity_boundary(rng):

@@ -187,14 +187,17 @@ def test_sharded_state_dict_roundtrip(rng):
                                   np.asarray(src.storage.reward))
 
 
-@pytest.mark.parametrize("kind", ("fused", "sharded"))
+@pytest.mark.parametrize("kind", ("fused", "fused_snapshot", "sharded"))
 def test_restored_trees_equal_the_live_ones_array_for_array(kind, rng):
-    """A buffer restored from a state dict saved after a commit, a chunk's
-    write-backs and another commit holds both trees bit for bit: the kept
-    levels (``device_per.kept_levels``) as ``set_leaves`` left them step
-    by step (rebuilt in one ``set_leaves`` by ``FusedDeviceReplay``, on
-    the host by ``ShardedFusedReplay``), every other node what ``init``
-    gave it."""
+    """A buffer restored from a state dict (``load_state_dict``) or a
+    snapshot (``restore``) saved after a commit, a chunk's write-backs
+    and another commit holds both trees bit for bit: the kept levels
+    (``device_per.kept_levels``; the min tree's, ``min_kept_levels``: it
+    has no leaves and is made of the sum tree's) as ``set_leaves`` left
+    them step by step (rebuilt in one ``set_leaves`` by
+    ``FusedDeviceReplay``, on the host by ``ShardedFusedReplay``), every
+    other node what ``init`` gave it; the min root, all a reader reads of
+    the min tree, is the smallest leaf saved."""
     from d4pg_tpu.learner.fused import make_fused_chunk
     from d4pg_tpu.replay import device_per as dper
     from d4pg_tpu.replay.fused_buffer import FusedDeviceReplay
@@ -202,7 +205,7 @@ def test_restored_trees_equal_the_live_ones_array_for_array(kind, rng):
     config = D4PGConfig(obs_dim=4, act_dim=2, v_min=-10, v_max=10,
                         n_atoms=11, hidden=(16, 16, 16))
     state = init_state(config, jax.random.key(2))
-    if kind == "fused":
+    if kind != "sharded":
         make = lambda: FusedDeviceReplay(1024, 4, 2, alpha=0.6,  # noqa: E731
                                          block_rows=64)
         fn = make_fused_chunk(config, k=3, batch_size=16, alpha=0.6,
@@ -220,18 +223,32 @@ def test_restored_trees_equal_the_live_ones_array_for_array(kind, rng):
     src.add(_batch(rng, 100))
     src.drain()
     dst = make()
-    dst.load_state_dict(src.state_dict())
+    if kind == "fused_snapshot":
+        saved = src.snapshot()
+        dst.restore(saved)
+    else:
+        saved = src.state_dict()
+        dst.load_state_dict(saved)
     cap = src.trees.sum_tree.shape[-1] // 2
-    kept = np.zeros(2 * cap, bool)
-    for level in dper.kept_levels(cap):
-        kept[1 << level:2 << level] = True
-    assert len(dper.kept_levels(cap)) >= 3 and not kept.all()
+    assert len(dper.kept_levels(cap)) >= 3
     fresh = dper.init(cap)
-    for name in ("sum_tree", "min_tree", "max_priority"):
+    assert src.trees.min_tree.shape[-1] == dper.min_tree_nodes(cap) < cap
+    saved_leaves = np.asarray(
+        (saved.get("sharded") or saved)["leaf_priorities"], np.float32)
+    np.testing.assert_array_equal(  # over the shards, where there are any
+        np.asarray(dst.trees.min_tree)[..., 1].min(),
+        saved_leaves[saved_leaves > 0].min())
+    for name, levels in (("sum_tree", dper.kept_levels(cap)),
+                         ("min_tree", dper.min_kept_levels(cap)),
+                         ("max_priority", None)):
         live = np.asarray(getattr(src.trees, name))
         np.testing.assert_array_equal(np.asarray(getattr(dst.trees, name)),
                                       live, err_msg=name)
-        if name != "max_priority":
+        if levels is not None:
+            kept = np.zeros(live.shape[-1], bool)
+            for level in levels:
+                kept[1 << level:2 << level] = True
+            assert not kept.all()
             assert (live[..., kept] != np.asarray(
                 getattr(fresh, name))[kept]).any(), name
             np.testing.assert_array_equal(
