@@ -33,9 +33,15 @@ from jax import Array
 
 from d4pg_tpu.core import mog as mog_ops
 from d4pg_tpu.core.distribution import categorical_projection
-from d4pg_tpu.core.losses import categorical_td_loss, expected_q
+from d4pg_tpu.core.losses import (
+    categorical_td_loss,
+    cross_entropy_per_sample,
+    expected_q,
+    weighted_mean,
+)
 from d4pg_tpu.core.updates import soft_update, tie_encoder
 from d4pg_tpu.learner.state import D4PGConfig, D4PGState
+from d4pg_tpu.models.torso import exit_distribution
 from d4pg_tpu.replay.uniform import TransitionBatch
 
 
@@ -214,6 +220,28 @@ def update_step(
     return new_state, metrics
 
 
+def _expected_exit_loss(critic, params, proj, action, is_weights, beta,
+                        latents, logits):
+    """A looped torso's critic loss (Ouro's entropy-regularised objective
+    with a uniform prior, arXiv:2510.25741): ``l[t, i]`` the categorical TD
+    loss of the one critic head on pass ``t``'s latent of row ``i``, ``p``
+    the row's exit distribution (``torso.exit_distribution``); the loss is
+    the weighted mean over rows of ``sum_t p l`` less ``beta`` times the mean
+    entropy of ``p``. Returns ``(total, (first term, l of the last pass,
+    counters))``; ``exit_dist [R]`` is the mean over rows of ``p``,
+    ``loss_by_pass [R]`` the weighted mean of ``l`` a pass."""
+    td = jax.vmap(lambda z: cross_entropy_per_sample(
+        proj, critic.of_latent(params, z, action)))(latents)
+    p, entropy = exit_distribution(logits)
+    with jax.named_scope("torso.exit"):
+        loss = weighted_mean(jnp.sum(p * td, axis=0), is_weights)
+        counters = {
+            "exit_dist": jnp.mean(p, axis=1),
+            "loss_by_pass": jax.vmap(
+                lambda l: weighted_mean(l, is_weights))(td)}
+        return loss - beta * jnp.mean(entropy), (loss, td[-1], counters)
+
+
 def _torso_update_step(
     config: D4PGConfig,
     state: D4PGState,
@@ -244,7 +272,17 @@ def _torso_update_step(
     tokens / kv_chunk_size]`` int32 (pass 2's selections by block of keys)
     and ``index_loss``; with a routing bias ``bias_swapped`` ``[layers with
     experts]`` int32, pass 2's assignments that the bias changed.
-    ``critic_loss`` stays the TD loss alone."""
+    ``critic_loss`` stays the TD loss alone.
+
+    A looped torso (``total_ut_steps`` > 1) runs all its passes in each of
+    the three; passes 1 and 3 read the last pass's latent, pass 2 every
+    pass's and the exit gate's logits (``_expected_exit_loss``: the
+    expectation of the TD loss over the exit distribution less an entropy
+    bonus; the gradient runs through all passes into the shared leaves and
+    the gate). ``critic_loss`` is the expectation alone, ``td_error`` the
+    last pass's (the network actors and targets use), and the metrics gain
+    ``exit_dist [R]`` and ``loss_by_pass [R]``. A torso without experts
+    reports no ``route_counts``."""
     key, _sub = jax.random.split(state.key)
     actor, critic = config.build_actor(), config.build_critic()
 
@@ -259,6 +297,11 @@ def _torso_update_step(
 
         def critic_loss_fn(p):
             z, aux = critic.latent(p, batch.obs, train=True)
+            if "exit_logits" in aux:  # a looped torso: every pass's latent
+                return _expected_exit_loss(
+                    critic, p, proj, batch.action, is_weights,
+                    config.torso.exit_entropy_beta, aux["pass_latents"],
+                    aux["exit_logits"])
             loss, td = categorical_td_loss(
                 proj, critic.of_latent(p, z, batch.action),
                 weights=is_weights)
@@ -277,7 +320,8 @@ def _torso_update_step(
         # loss trains (its gradient is exactly zero: it enters a top-k
         # only) and no optimizer steps; this pass's own load counter moves
         # it, after the step, and the target's follows by soft_update
-        critic_params = critic.balance(critic_params, aux["route_counts"])
+        critic_params = critic.balance(critic_params,
+                                       aux.get("route_counts"))
 
     with jax.named_scope("update.actor"):
         z = jax.lax.stop_gradient(critic.latent(critic_params, batch.obs)[0])
@@ -314,7 +358,8 @@ def _torso_update_step(
         "actor_loss": actor_loss,
         "q_mean": -actor_loss,
         "td_error": td_error,
-        **aux,  # route_counts; the sparse and the biased layers' counters
+        **aux,  # route_counts; the sparse and the biased layers' counters;
+        # a looped torso's exit_dist and loss_by_pass
     }
     return new_state, metrics
 

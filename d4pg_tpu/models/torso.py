@@ -8,12 +8,14 @@ transformer layers and pools them into one latent per row; D4PG's own heads
 state vector. ``D4PGConfig.torso`` names one (``TORSOS``) with its sizes
 (``TorsoSpec``, made from a configuration file's ``model.torso`` block).
 
-Four models share the one layer path, told apart by the data in the spec
+Five models share the one layer path, told apart by the data in the spec
 (``layer_types``, ``qk_norm``, ``sa_config``, ``num_dense_layers``,
 ``router_scores``, ``use_expert_bias``, ``attn_output_gate``,
 ``partial_rotary_factor``, ``shared_expert_intermediate_size``, the
-``linear_*`` sizes), not by code of their own. Every layer is ``x +
-Op(RMSNorm(x))`` then ``x + FF(RMSNorm(x))``:
+``linear_*`` sizes, ``num_experts`` 0, ``sandwich_norm``,
+``total_ut_steps``), not by code of their own. Every layer is ``x +
+Op(RMSNorm(x))`` then ``x + FF(RMSNorm(x))``, with ``sandwich_norm`` ``x +
+RMSNorm(Op(RMSNorm(x)))`` then ``x + RMSNorm(FF(RMSNorm(x)))``:
 
 - ``mellum2``, the Mellum2-12B-A2.5B layer: RMSNorm, grouped-query
   attention with rotary embeddings (default on ``sliding_attention``
@@ -53,6 +55,26 @@ Op(RMSNorm(x))`` then ``x + FF(RMSNorm(x))``:
   gate (``shared_expert_intermediate_size``). ``aux["delta_kept"]`` is the
   mean of ``exp(g)`` a DeltaNet layer, ``aux["shared_gate"]`` the mean of
   the shared expert's gate a layer.
+- ``ouro``, Ouro-2.6B's looped (depth-recurrent) dense stack: ungrouped
+  ``full_attention`` layers (as many key/value heads as query heads) with a
+  dense SwiGLU in EVERY layer (``num_experts`` 0, ``num_dense_layers`` all of
+  them, ``experts_held`` ``[0, 0]``: no router, no ``route_counts``,
+  ``balance`` a no-op, ``grouped_impl`` never asked) and ``sandwich_norm``: a
+  second RMSNorm with its own gain behind the operator and behind the
+  feed-forward. ``total_ut_steps`` R > 1 runs the whole stack R times on its
+  own output with the SAME leaves (``_passes``): ``x_t =
+  final_norm(Layers(x_{t-1}))``, the one final norm behind every pass, its
+  output what the next pass starts from and what is pooled (``u_t``, pass
+  ``t``'s latent). ``apply`` returns ``u_R`` (``early_exit_threshold`` 1: no
+  pass is ever skipped; another threshold is refused); with ``train=True``
+  ``aux`` holds every pass's latent (``pass_latents [R, B, D]``) and the exit
+  gate's logits (``exit_logits [R, B]``, ``exit_gate``: a ``[D, 1]`` kernel
+  and a bias on the pooled latent, float32), from which
+  ``exit_distribution`` makes a row's distribution over the passes and
+  ``learner/update._expected_exit_loss`` the critic loss (the expectation of
+  the TD loss over it less ``exit_entropy_beta`` times its entropy). A looped
+  torso's layers are attention or conv with a dense feed-forward: it hands
+  up no counters.
 
 Leaves. A layer has only the leaves its kind has: the operator's are
 ``attn_norm``, ``q``, ``k``, ``v``, ``o`` (with ``qk_norm`` also ``q_norm``,
@@ -64,10 +86,13 @@ linear_conv_kernel_dim]``), ``A_log``, ``dt_bias``, ``out_norm``,
 2 H Dh]``); the feed-forward's ``mlp_norm``, ``w1``, ``w3``, ``w2`` or
 ``moe_norm``, ``router``, ``gate``, ``up``, ``down`` (with a shared expert
 also ``shared_gate``, ``shared_up``, ``shared_down``,
-``shared_expert_gate``). ``init`` draws eight keys a layer whatever its
-kind, so a layer's draws do not depend on its neighbours'; the decay's and
-the shared expert's draws come from keys folded off the torso's own, so
-the older models' trees are bit for bit what they were.
+``shared_expert_gate``); with ``sandwich_norm`` also ``op_post_norm`` and
+``ff_post_norm``, the gains behind the two branches. A looped torso has
+``exit_gate`` beside ``embed`` and ``final_norm``. ``init`` draws eight keys
+a layer whatever its kind, so a layer's draws do not depend on its
+neighbours'; the decay's, the shared expert's and the exit gate's draws come
+from keys folded off the torso's own (gains draw nothing), so the older
+models' trees are bit for bit what they were.
 
 The expert layer: a float32 router over all ``num_experts`` experts,
 SwiGLU experts. ``softmax`` scores are a softmax over the experts with the
@@ -118,6 +143,20 @@ state a group of 4 chunks (64 x 2 MB a sequence, where a state a chunk
 would be 537 MB) and a group's intermediates for that group alone
 (``ops/delta_rule.py``); the expert layer takes the sequence in four parts
 of 40,960 assignments, of which about 1,280 land on the 16 experts held.
+
+A loop (``ouro``: 4 passes of 8 layers on 2 sequences of 4,096 tokens) is a
+``lax.scan`` over the passes with the leaves closed over: one pass is
+traced and compiled, and the scan's transpose sums each leaf's float32
+gradient over its R uses. Inside a pass the scheme is the one above, so what
+is kept for the backward pass is one layer boundary a layer AND a pass, ``R
+x L`` = 32 of 67 MB (2.1 GB), stacked by the scan; nothing inside a layer
+outlives a sequence. The compute-dtype copies of a layer's matrices are made
+inside the layer's checkpoint, once a use (R x L times a torso pass, and
+again in the backward pass), 103 MB at a time, never all 822 MB at once, and
+each use's weight gradient is cast back to float32 before the passes are
+summed. PERF.md (PR 41) has the forms that lost: a checkpoint round a pass
+(``R + L`` boundaries, one more forward), the casts made once and held, and
+four traced copies of the stack.
 """
 
 from __future__ import annotations
@@ -201,6 +240,15 @@ class TorsoSpec:
     partial_rotary_factor: float = 1.0  # the share of a head RoPE turns
     attn_output_gate: bool = False  # q twice as wide: sigmoid(gate) * attn
     shared_expert_intermediate_size: int = 0  # 0: no shared expert
+    # a looped (depth-recurrent) torso: the whole stack run this many times
+    # on its own output with the same leaves, ``final_norm`` behind every
+    # pass; from 2 on the torso has an exit gate (``apply``)
+    total_ut_steps: int = 1
+    early_exit_threshold: float = 1.0  # 1: no pass is ever skipped
+    exit_entropy_beta: float = 0.0  # weight of the exit entropy in the loss
+    # a norm with its own gain behind each operator and feed-forward too:
+    # x + Norm(Op(Norm(x))), then x + Norm(FF(Norm(x)))
+    sandwich_norm: bool = False
 
     @classmethod
     def from_dict(cls, d: dict) -> "TorsoSpec":
@@ -215,7 +263,14 @@ class TorsoSpec:
             raise ValueError(f"unknown torso {self.name!r}; one of "
                              f"{sorted(TORSOS)}")
         lo, hi = self.experts_held
-        if not 0 <= lo < hi <= self.num_experts:
+        dense_only = self.num_experts == 0  # no router, no experts
+        if dense_only:
+            if (lo, hi) != (0, 0) \
+                    or self.num_dense_layers != len(self.layer_types):
+                raise ValueError("a torso without experts holds none "
+                                 "(experts_held [0, 0]) and every layer of "
+                                 "it is dense (num_dense_layers)")
+        elif not 0 <= lo < hi <= self.num_experts:
             raise ValueError(f"experts_held {self.experts_held} is not a "
                              f"range of the {self.num_experts} experts")
         if self.bins > self.vocab_rows:
@@ -258,7 +313,8 @@ class TorsoSpec:
                              f"whole even share of {self.head_dim}")
         if self.attn_output_gate and "sparse_attention" in self.layer_types:
             raise ValueError("sparse_attention layers have no output gate")
-        if not 0 <= self.num_dense_layers < len(self.layer_types):
+        if not dense_only and not 0 <= self.num_dense_layers < len(
+                self.layer_types):
             raise ValueError(f"num_dense_layers {self.num_dense_layers} "
                              f"leaves no expert layer of "
                              f"{len(self.layer_types)}")
@@ -270,6 +326,20 @@ class TorsoSpec:
                              f"{ROUTER_SCORES}")
         if self.use_expert_bias and self.router_scores != "sigmoid":
             raise ValueError("use_expert_bias is the sigmoid router's")
+        if self.total_ut_steps < 1:
+            raise ValueError("total_ut_steps counts the passes: at least 1")
+        if self.total_ut_steps > 1 and (
+                self.num_experts or set(self.layer_types)
+                & {"sparse_attention", "linear_attention"}):
+            raise ValueError("a looped torso hands up one latent a pass and "
+                             "no counters: its layers are attention or conv "
+                             "with a dense feed-forward")
+        if self.early_exit_threshold != 1.0:
+            raise ValueError("every pass runs for every row "
+                             "(early_exit_threshold 1): an exit before the "
+                             "last pass is not implemented")
+        if self.exit_entropy_beta < 0.0:
+            raise ValueError("exit_entropy_beta weighs an entropy bonus")
 
     @property
     def n_held(self) -> int:
@@ -673,8 +743,23 @@ class SequenceTorso:
                         shared_up=normal(ks[1], (d, fs), d),
                         shared_down=normal(ks[2], (fs, d), fs),
                         shared_expert_gate=normal(ks[3], (d, 1), d))
+            if s.sandwich_norm:  # gains draw nothing
+                ff.update(op_post_norm=gain(), ff_post_norm=gain())
             params[f"layer_{i}"] = {**op, **ff}
+        if s.total_ut_steps > 1:
+            # a key of its own, behind the decays' and the shared experts'
+            params["exit_gate"] = {
+                **normal(jax.random.fold_in(
+                    key, 2 * len(s.layer_types) + 1), (d, 1), d),
+                "bias": jnp.zeros((1,), jnp.float32)}
         return params
+
+    def _post(self, p: dict, out, norm: str):
+        """A branch's output on its way to the residual stream: with
+        ``sandwich_norm`` through its own RMSNorm (``p[norm]``) first."""
+        if self.spec.sandwich_norm:
+            return rms_norm(out, p[norm]["scale"], self.spec.rms_norm_eps)
+        return out
 
     def _qkv(self, p: dict, h, layer_type: str):
         """``q [Hkv, G, T, D]`` (scaled), ``k``, ``v [Hkv, T, D]`` of the
@@ -728,8 +813,9 @@ class SequenceTorso:
             if gate is not None:
                 a = (a.astype(jnp.float32)
                      * jax.nn.sigmoid(gate)).astype(self.dtype)
-            return x + jnp.dot(a, p["o"]["kernel"],
-                               preferred_element_type=jnp.float32)
+            return x + self._post(p, jnp.dot(
+                a, p["o"]["kernel"], preferred_element_type=jnp.float32),
+                "op_post_norm")
 
     def _attend_sparse(self, p: dict, x, train: bool):
         """``_attend`` over the keys the indexer selects: ``(x, (counts
@@ -765,8 +851,9 @@ class SequenceTorso:
             a = sparse_ops.masked_attention(
                 q, k, v, keep, impl=self.sparse_impl(), **chunks)
             a = a.transpose(2, 0, 1, 3).reshape(t_len, -1)
-            x = x + jnp.dot(a, p["o"]["kernel"],
-                            preferred_element_type=jnp.float32)
+            x = x + self._post(p, jnp.dot(
+                a, p["o"]["kernel"], preferred_element_type=jnp.float32),
+                "op_post_norm")
         return x, (counts, loss)
 
     def _delta(self, p: dict, x):
@@ -818,9 +905,9 @@ class SequenceTorso:
         with jax.named_scope("torso.deltanet"):
             y = rms_norm(o, p["out_norm"]["scale"], s.rms_norm_eps) \
                 * jax.nn.silu(z.astype(jnp.float32))
-            return x + jnp.dot(y.astype(dtype).reshape(t_len, wv),
-                               p["out_proj"]["kernel"],
-                               preferred_element_type=jnp.float32), kept
+            return x + self._post(p, jnp.dot(
+                y.astype(dtype).reshape(t_len, wv), p["out_proj"]["kernel"],
+                preferred_element_type=jnp.float32), "op_post_norm"), kept
 
     def _conv(self, p: dict, x):
         """LFM2's gated short convolution of one sequence ``x [T, D]``
@@ -832,8 +919,9 @@ class SequenceTorso:
             bcu = jnp.dot(h, p["in_proj"]["kernel"],
                           preferred_element_type=dtype)
             y = conv_ops.gated_short_conv(bcu, p["conv"]["kernel"])
-            return x + jnp.dot(y, p["out_proj"]["kernel"],
-                               preferred_element_type=jnp.float32)
+            return x + self._post(p, jnp.dot(
+                y, p["out_proj"]["kernel"],
+                preferred_element_type=jnp.float32), "op_post_norm")
 
     def _mlp(self, p: dict, x):
         """The dense SwiGLU feed-forward of one sequence."""
@@ -843,8 +931,9 @@ class SequenceTorso:
             proj = lambda name: jnp.dot(  # noqa: E731
                 h, p[name]["kernel"], preferred_element_type=jnp.float32)
             mid = (jax.nn.silu(proj("w1")) * proj("w3")).astype(self.dtype)
-            return jnp.dot(mid, p["w2"]["kernel"],
-                           preferred_element_type=jnp.float32)
+            return self._post(p, jnp.dot(
+                mid, p["w2"]["kernel"], preferred_element_type=jnp.float32),
+                "ff_post_norm")
 
     def _sequence(self, p: dict, x, layer_type: str, dense: bool,
                   train: bool):
@@ -867,6 +956,8 @@ class SequenceTorso:
         with jax.named_scope("torso.route"):
             h = rms_norm(x, p["moe_norm"]["scale"], self.spec.rms_norm_eps)
         out, stats = self._experts(p, h)
+        with jax.named_scope("torso.route"):
+            out = self._post(p, out, "ff_post_norm")
         return x + out, {**stats, **op_stats}, selected
 
     def _experts(self, p: dict, h):
@@ -896,11 +987,10 @@ class SequenceTorso:
         x, stats, selected = jax.lax.map(per_seq, x)
         return x, _summed(stats), selected
 
-    def apply(self, params: dict, obs, train: bool = False):
+    def _stack(self, params: dict, x, train: bool):
+        """Every layer once, in order, on the batch ``x [B, T, D]``: ``(x,
+        stats, selected, kept)``, one entry a layer that has any."""
         s = self.spec
-        with jax.named_scope("torso.embed"):
-            tokens = tokenise(s, obs)
-            x = params["embed"]["kernel"][tokens]
         stats, selected, kept = [], [], []
         for i, layer_type in enumerate(s.layer_types):
             layer = jax.checkpoint(
@@ -908,16 +998,49 @@ class SequenceTorso:
                 self._layer(p, x, lt, dense, train))
             x, st, sel = layer(params[f"layer_{i}"], x)
             if "delta_kept" in st:  # a mean a sequence, summed over them
-                kept.append(st.pop("delta_kept") / obs.shape[0])
+                kept.append(st.pop("delta_kept") / x.shape[0])
             if st:
                 stats.append(st)
             if sel:
                 selected.append(sel)
+        return x, stats, selected, kept
+
+    def _passes(self, params: dict, x):
+        """The looped torso: ``total_ut_steps`` passes of ``_stack`` over its
+        own output with the same leaves (a ``lax.scan`` with the leaves
+        closed over: one pass is compiled, and every leaf's gradient is the
+        sum over its uses), ``final_norm`` behind every pass: the normed
+        state is what the next pass starts from and what is pooled.
+        ``(latents [R, B, D], gate logits [R, B])``."""
+        s = self.spec
+
+        def one(x, _):
+            x = self._stack(params, x, False)[0]
+            with jax.named_scope("torso.exit"):
+                x = rms_norm(x, params["final_norm"]["scale"], s.rms_norm_eps)
+                return x, jnp.mean(x, axis=1)
+
+        _, latents = jax.lax.scan(one, x, None, length=s.total_ut_steps)
+        with jax.named_scope("torso.exit"):
+            gate = params["exit_gate"]
+            logits = jnp.dot(latents, gate["kernel"], precision=HI)[..., 0]
+            return latents, logits + gate["bias"]
+
+    def apply(self, params: dict, obs, train: bool = False):
+        s = self.spec
+        with jax.named_scope("torso.embed"):
+            tokens = tokenise(s, obs)
+            x = params["embed"]["kernel"][tokens]
+        if s.total_ut_steps > 1:
+            latents, logits = self._passes(params, x)
+            return latents[-1], ({"pass_latents": latents,
+                                  "exit_logits": logits} if train else {})
+        x, stats, selected, kept = self._stack(params, x, train)
         with jax.named_scope("torso.pool"):
             x = rms_norm(x, params["final_norm"]["scale"], s.rms_norm_eps)
             latent = jnp.mean(x, axis=1)
         aux = {name: jnp.stack([st[name] for st in stats])
-               for name in stats[0]}
+               for name in (stats[0] if stats else ())}
         if kept:
             aux["delta_kept"] = jnp.stack(kept)
         if "shared_gate" in aux:  # summed over tokens and sequences
@@ -949,6 +1072,29 @@ class SequenceTorso:
             out[f"layer_{i}"] = {**layer, "router": {
                 **router, "bias": router["bias"] + move[row]}}
         return out
+
+
+def exit_distribution(logits):
+    """A looped torso's exit distribution a row from its gate logits ``[R,
+    B]``, ``lambda_t = sigmoid(logit_t)``: ``p_t = lambda_t prod_{j<t} (1 -
+    lambda_j)`` for ``t < R`` and ``p_R = prod_{j<R} (1 - lambda_j)``, what
+    is left (``lambda_R`` is not read); and its entropy ``-sum_t p_t log
+    p_t``. ``(p [R, B], entropy [B])``. ``p`` is the products themselves, so
+    that it sums to 1 to float32's last bits (the chip's ``exp`` of a summed
+    ``log`` is good to five digits); ``log p`` is the sum of log-sigmoids,
+    finite where a gate saturates and ``p`` underflows."""
+    with jax.named_scope("torso.exit"):
+        lam = jax.nn.sigmoid(logits[:-1])
+        stay = jnp.cumprod(1.0 - lam, axis=0)
+        p = jnp.concatenate(
+            [lam * jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]]),
+             stay[-1:]])
+        log_stay = jnp.cumsum(jax.nn.log_sigmoid(-logits[:-1]), axis=0)
+        log_p = jnp.concatenate(
+            [jax.nn.log_sigmoid(logits[:-1]) + jnp.concatenate(
+                [jnp.zeros_like(log_stay[:1]), log_stay[:-1]]),
+             log_stay[-1:]])
+        return p, -jnp.sum(p * log_p, axis=0)
 
 
 def _summed(stats: dict) -> dict:
@@ -996,7 +1142,8 @@ class TorsoCritic:
 
 
 TORSOS = {"mellum2": SequenceTorso, "keye2": SequenceTorso,
-          "lfm2": SequenceTorso, "qwen3next": SequenceTorso}
+          "lfm2": SequenceTorso, "qwen3next": SequenceTorso,
+          "ouro": SequenceTorso}
 
 
 def build_torso(spec: TorsoSpec, dtype=jnp.float32):

@@ -555,6 +555,9 @@ CELL_PLANS = {
     "humanoid-qwen3next-ep32.learn-static": (
         "14>7 rows(min from sum),7>root whole",
         "14>7 whole(min from sum),7>root whole"),
+    "humanoid-ouro-ut4.learn-static": (
+        "15>8 rows(min from sum),8>1 whole,1>root whole",
+        "15>8 whole(min from sum),8>1 whole,1>root whole"),
 }
 
 

@@ -605,3 +605,69 @@ def test_the_pixel_chunk_fits_the_chip_at_70256_rows(one_chip):
     held = m.argument_size_in_bytes + m.temp_size_in_bytes \
         + m.generated_code_size_in_bytes
     assert 14e9 < held < HBM_BYTES, held
+
+
+def _ouro(monkeypatch):
+    with open(os.path.join(
+            REPO, "benchmark/configs/humanoid-ouro-ut4.json")) as f:
+        cfg = json.load(f)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return cfg, D4PGConfig(**cfg["model"])
+
+
+def test_an_ouro_layer_and_its_backward_compile_at_real_widths(
+        one_chip, monkeypatch):
+    """One layer of ``humanoid-ouro-ut4`` on one 4,096-token sequence,
+    differentiated: the first ungrouped attention (16 query heads on 16
+    key/value heads of 128) through the splash kernel, the dense SwiGLU of
+    5,632 and the four norms of a layer, with no grouped product anywhere
+    (a torso without experts never asks for ``grouped_impl``)."""
+    _cfg, config = _ouro(monkeypatch)
+    torso = config.build_critic().torso
+    assert attn_ops.splash_fits(4096, 128)
+    assert torso.attention_impl() == "splash"
+    params = jax.eval_shape(lambda: torso.init(jax.random.key(0)))
+    assert set(params["layer_0"]) >= {"op_post_norm", "ff_post_norm"}
+    x = jax.ShapeDtypeStruct((1, 4096, 2048), jnp.float32)
+
+    def loss(p, x):
+        out, stats, _selected = torso._layer(p, x, "full_attention", True,
+                                             True)
+        assert stats == {}
+        return jnp.sum(out)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        *on(one_chip, (params["layer_0"], x))).compile()
+    text = compiled.as_text()
+    assert "splash" in text and text.count("tpu_custom_call") >= 2
+    assert "gmm" not in text and "ragged-dot" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
+def test_the_fused_chunk_of_the_loop_cell_compiles_for_the_chip(
+        one_chip, monkeypatch):
+    """The whole chunk of ``humanoid-ouro-ut4`` at the cell's sizes (2
+    sequences of 4,096 tokens, K=1, a 32,768-row ring, 4 passes of 8
+    layers): the compiler refuses a program that does not fit the chip, and
+    takes this one. One pass is compiled, not four: the loop over passes is
+    a ``while`` of its own in each of the three torso passes and in the
+    backward pass, beside the loops over sequences."""
+    cfg, config = _ouro(monkeypatch)
+    cap = cfg["replay"]["capacity"]
+    row = lambda *s: jax.ShapeDtypeStruct(  # noqa: E731
+        (cap,) + s, jnp.float32, sharding=one_chip)
+    storage = TransitionBatch(
+        obs=row(config.obs_dim), action=row(config.act_dim), reward=row(),
+        next_obs=row(config.obs_dim), done=row(), discount=row())
+    compiled = _chunk(cfg, config, storage, one_chip)
+    m = compiled.memory_analysis()
+    # the state (8.21 GB of parameters, moments and targets) in place
+    assert m.alias_size_in_bytes > 8.2e9
+    assert m.argument_size_in_bytes < 9.4e9 and m.temp_size_in_bytes < 10.5e9
+    text = compiled.as_text()
+    assert "splash" in text and "gmm" not in text
+    assert "ragged-dot" not in text
+    assert "torso.exit" in text and "torso.mlp" in text
+    # eight layers a pass, forward three times and once more with its
+    # backward: a pass traced four times would hold four times as many
+    assert 32 <= text.count("tpu_custom_call") <= 64
