@@ -30,7 +30,9 @@ RMSNorm(Op(RMSNorm(x)))`` then ``x + RMSNorm(FF(RMSNorm(x)))``:
   gradient; the indexer's own parameters (``index_q``, ``index_k``,
   ``index_k_norm``, ``index_w``) are trained by an alignment loss that
   ``apply(..., train=True)`` hands up beside the counters (``index_loss``,
-  ``select_counts``) and that reaches nothing else.
+  ``select_counts``) and that reaches nothing else; its target is read
+  off the main attention's own forward call (that call's log-sum-exp),
+  not a second one.
 - ``lfm2``, LFM2-8B-A1B's layers: ``conv`` layers whose operator is a gated
   short convolution with no attention at all (``ops/short_conv.py``: ``[b,
   c, u] = h W_in``, a depthwise causal convolution of ``conv_L_cache`` taps
@@ -822,7 +824,9 @@ class SequenceTorso:
         [T / kv_chunk_size], loss))``, the loss summed over positions and 0
         unless ``train``. The indexer reads the normed input through a
         stop-gradient and the selection is a bool: the main attention's
-        gradient cannot reach the indexer, nor the loss anything else."""
+        gradient cannot reach the indexer, nor the loss anything else. With
+        ``train`` the attention's one forward call also hands the loss the
+        heads' log-sum-exp (a constant) its target is made from."""
         s, dtype, sa = self.spec, self.dtype, self.spec.sa
         t_len = x.shape[0]
         hi, di = sa["indexer_num_heads"], sa["indexer_head_dim"]
@@ -844,12 +848,18 @@ class SequenceTorso:
                           kv_chunk=sa["kv_chunk_size"])
             keep, counts = sparse_ops.select_keys(qi, ki, wi,
                                                   topk=sa["topk"], **chunks)
-            loss = (sparse_ops.alignment_loss(
-                qi, ki, wi, keep, q, k, v, impl=self.sparse_impl(), **chunks)
-                if train else jnp.zeros((), jnp.float32))
+        attn = dict(impl=self.sparse_impl(), **chunks)
+        if train:  # one forward call serves the output and the loss
+            with jax.named_scope("torso.attn_sparse"):
+                a, lse = sparse_ops.attention_and_lse(q, k, v, keep, **attn)
+            with jax.named_scope("torso.indexer"):
+                loss = sparse_ops.alignment_loss(qi, ki, wi, keep, q, k, lse,
+                                                 **chunks)
+        else:
+            with jax.named_scope("torso.attn_sparse"):
+                a = sparse_ops.masked_attention(q, k, v, keep, **attn)
+            loss = jnp.zeros((), jnp.float32)
         with jax.named_scope("torso.attn_sparse"):
-            a = sparse_ops.masked_attention(
-                q, k, v, keep, impl=self.sparse_impl(), **chunks)
             a = a.transpose(2, 0, 1, 3).reshape(t_len, -1)
             x = x + self._post(p, jnp.dot(
                 a, p["o"]["kernel"], preferred_element_type=jnp.float32),

@@ -13,12 +13,16 @@ queries at a time so that no ``[heads, T, T]`` array is ever made (16,384 x
    there are fewer; ties to the lower position) as a ``[T, T]`` bool mask,
    and how many selections fell on each ``kv_chunk`` keys. The selection
    carries no gradient.
-2. ``masked_attention``: softmax attention under that mask.
+2. ``masked_attention``: softmax attention under that mask. In the
+   differentiated pass ``attention_and_lse``: the same output from the same
+   forward call, and with it every head's log-sum-exp over ``S_t``, which
+   that call makes anyway as its backward's residual.
 3. ``alignment_loss`` (the differentiated pass only): the loss the indexer
    is trained by, ``sum_t KL(p_t || softmax_{S_t} I[t, .])``, ``p_t`` the
    main attention's probabilities over ``S_t`` summed over its heads and
-   L1-normalised, a constant. The index scores are made again here, with
-   their gradient.
+   L1-normalised, a constant: ``exp(q . k - lse)`` under the log-sum-exp
+   step 2 hands over, no pass of the main attention of its own. The index
+   scores are made again here, with their gradient.
 
 The selection is a threshold, not a sort: ``lax.top_k`` at ``k`` 2,048 of
 16,384 is a full sort on the TPU (291 ms a sequence on the v5e against 19
@@ -45,7 +49,11 @@ or ``GROUPS``.
   kernel's own (dq and dkv apart). TPU only: blocks are multiples of 128.
   A sequence on the v5e: 34 ms forward, 110 forward and backward (blocks
   of 512; 256: 65 / 214; 1,024 does not fit VMEM; a static causal mask
-  over the same pairs: 17 forward).
+  over the same pairs: 17 forward). jax's ``custom_vjp`` keeps the
+  log-sum-exp to itself, so ``splash_attention_and_lse`` is a ``custom_vjp``
+  of this module round the same forward and backward functions that
+  returns it beside the output (until PR 42 the loss ran the forward a
+  second time for it, 34 ms of a sequence and layer's every evaluation).
 - ``blockwise``: plain ``jax.numpy``, a block of queries against the keys
   its group sees, rematerialised in the backward pass. Runs anywhere (441
   ms forward there).
@@ -196,7 +204,8 @@ def head_mean_probs_kernel(q, k, lse, keep, *, interpret: bool = False):
     k - lse)`` summed in the output tile while it stays in VMEM, so no
     ``[heads, bq, S]`` array reaches HBM (on the v5e a 16,384-token
     sequence's loss takes 462 ms in the ``jnp`` form, 50 ms in this one, 35
-    of them the forward pass that gives the lse; my chip run, PR 32)."""
+    of them a forward pass for the lse, which since PR 42 is the main
+    attention's own; my chip run, PR 32)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -265,24 +274,20 @@ def _align_block(_start, qi, wi, keep, q, lse, ki, k, *, extent, interpret):
     return jnp.zeros((qi.shape[0], 0), jnp.float32), loss
 
 
-def alignment_loss(qi, ki, wi, keep, q, k, v, *, impl: str, q_chunk: int,
+def alignment_loss(qi, ki, wi, keep, q, k, lse, *, q_chunk: int,
                    kv_chunk: int, interpret: bool = False):
     """The indexer's loss summed over the queries of a sequence: the KL
     divergence from the main attention's probabilities over the selection
     (the mean over heads, a constant) to ``softmax_{S_t} I[t, .]``.
-    Gradient reaches ``qi``, ``ki``, ``wi`` and nothing else. ``impl``
-    ``splash`` takes every head's log-sum-exp from the splash kernel (a
-    forward pass that keeps its residuals) and sums the heads in
-    ``head_mean_probs_kernel``; ``blockwise`` is plain ``jnp``."""
-    if impl not in IMPLS:
-        raise ValueError(f"unknown attention impl {impl!r}; one of {IMPLS}")
-    q, k, v = jax.lax.stop_gradient((q, k, v))
+    Gradient reaches ``qi``, ``ki``, ``wi`` and nothing else. ``lse [Hkv,
+    G, T]`` is every head's log-sum-exp over its selection as the main
+    attention's own forward pass made it (``attention_and_lse``): the
+    heads are then summed in ``head_mean_probs_kernel``; with ``None``
+    the target is plain ``jnp``."""
+    q, k, lse = jax.lax.stop_gradient((q, k, lse))
     plan = block_plan(qi.shape[0], q_chunk, kv_chunk)
     per_query = (qi, wi, keep, jnp.moveaxis(q, 2, 0))
-    if impl == "splash":
-        _out, (lse,) = _splash_kernel(keep, q.shape[1], q.shape[2],
-                                      residuals=True, interpret=interpret)(
-            q, k, v)
+    if lse is not None:
         fn = functools.partial(_align_block, interpret=interpret)
         per_query += (jnp.moveaxis(lse, 2, 0),)
     else:
@@ -322,7 +327,8 @@ def splash_fits(t_len: int, head_dim: int, kv_chunk: int) -> bool:
 
 def _shared_mask_info(keep, heads: int, block: int, dkv: bool):
     """``keep``'s ``MaskInfo`` for ``heads`` query heads that share it: the
-    mask's blocks laid out once, every head's tables pointing at them."""
+    mask's blocks laid out once (as the kernels take them: ``[blocks,
+    block, block]``), every head's tables pointing at them."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_mask_info as mask_info)
 
@@ -331,16 +337,17 @@ def _shared_mask_info(keep, heads: int, block: int, dkv: bool):
     info, _ = process(keep[None], (block, block), downcast_smem_data=True,
                       head_shards=1, q_seq_shards=1)
     every = lambda a: jnp.broadcast_to(a, (heads,) + a.shape[1:])  # noqa
-    return info._replace(data_next=every(info.data_next),
-                         mask_next=every(info.mask_next),
-                         block_mask=every(info.block_mask))
+    return info._replace(
+        data_next=every(info.data_next), mask_next=every(info.mask_next),
+        block_mask=every(info.block_mask),
+        partial_mask_blocks=info.partial_mask_blocks.reshape(
+            -1, block, block))
 
 
-def _splash_kernel(keep, group: int, t_len: int, *, residuals: bool,
-                   interpret: bool):
-    """The kernel for one key/value head and its ``group`` query heads
-    under ``keep``; ``residuals``: the forward pass alone, returning
-    ``(out, (logsumexp [G, T],))`` (not differentiable)."""
+def _splash_layout(keep, group: int, t_len: int):
+    """``(block sizes, keep's MaskInfo by query, by key)``: what the
+    kernels of one key/value head and its ``group`` query heads take (by
+    query: forward and dq; by key: dkv)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sa)
 
@@ -350,21 +357,90 @@ def _splash_kernel(keep, group: int, t_len: int, *, residuals: bool,
     sizes = sa.BlockSizes(
         block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b,
         block_kv_dkv=b, block_kv_dkv_compute=b, block_q_dq=b, block_kv_dq=b)
-    by_query = _shared_mask_info(keep, group, b, False)
-    by_key = None if residuals else _shared_mask_info(keep, group, b, True)
-    kernel = sa.SplashAttentionKernel(
-        by_query, None if residuals else by_query, by_key,
-        block_sizes=sizes, is_mqa=True, save_residuals=residuals,
-        mask_value=sa.DEFAULT_MASK_VALUE, attn_logits_soft_cap=None,
-        residual_checkpoint_name=None, mask_function=None,
-        interpret=interpret)
-    return jax.vmap(kernel)
+    return (sizes, _shared_mask_info(keep, group, b, False),
+            _shared_mask_info(keep, group, b, True))
 
 
 def splash_masked_attention(q, k, v, keep, *, interpret: bool = False):
-    _hkv, group, t_len, _d = q.shape
-    return _splash_kernel(keep, group, t_len, residuals=False,
-                          interpret=interpret)(q, k, v)
+    """The kernel's output alone, each key/value head with its ``G`` query
+    heads under ``keep``; differentiable by the kernel's own rule."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sa)
+
+    sizes, by_query, by_key = _splash_layout(keep, q.shape[1], q.shape[2])
+    kernel = sa.SplashAttentionKernel(
+        by_query, by_query, by_key, block_sizes=sizes, is_mqa=True,
+        save_residuals=False, mask_value=sa.DEFAULT_MASK_VALUE,
+        attn_logits_soft_cap=None, residual_checkpoint_name=None,
+        mask_function=None, interpret=interpret)
+    return jax.vmap(kernel)(q, k, v)
+
+
+# One forward call that hands out what jax's own rule keeps to itself: the
+# output, and the log-sum-exp that is both the backward's residual and the
+# alignment target's. Forward and backward are the kernel module's (private
+# names of jax 0.9.0; ``tests/test_torso_v5e_compile.py`` compiles them).
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _splash_out_and_lse(sizes, interpret, by_query, by_key, q, k, v):
+    # by_key only rides to the backward rule, as in jax's own
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sa)
+
+    def head(q, k, v):
+        out, (lse,) = sa._splash_attention_forward(
+            by_query, q, k, v, None, None, mask_value=sa.DEFAULT_MASK_VALUE,
+            is_mqa=True, block_sizes=sizes, residual_checkpoint_name=None,
+            save_residuals=True, mask_function=None,
+            attn_logits_soft_cap=None, interpret=interpret)
+        return out, lse
+
+    return jax.vmap(head)(q, k, v)
+
+
+def _splash_out_and_lse_fwd(sizes, interpret, *primals):
+    from jax.custom_derivatives import CustomVJPPrimal
+
+    by_query, by_key, q, k, v = jax.tree_util.tree_map(
+        lambda p: p.value, primals,
+        is_leaf=lambda p: isinstance(p, CustomVJPPrimal))
+    out, lse = _splash_out_and_lse(sizes, interpret, by_query, by_key, q, k,
+                                   v)
+    return (out, lse), (q, k, v, out, lse, by_query, by_key)
+
+
+def _splash_out_and_lse_bwd(sizes, interpret, res, cts):
+    from jax.custom_derivatives import SymbolicZero
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sa)
+
+    q, k, v, out, lse, by_query, by_key = res
+    d_out, d_lse = cts
+    if not isinstance(d_lse, SymbolicZero):
+        raise TypeError("the log-sum-exp is handed out as a constant: its "
+                        "backward is not the kernel's (stop_gradient it)")
+
+    def head(q, k, v, out, lse, d_out):
+        return sa._splash_attention_bwd(
+            False, sa.DEFAULT_MASK_VALUE, True, sizes, None, None, None,
+            interpret, (q, k, v, None, None, out, lse, by_query, by_key),
+            d_out)[3:6]
+
+    return (None, None) + tuple(jax.vmap(head)(q, k, v, out, lse, d_out))
+
+
+_splash_out_and_lse.defvjp(_splash_out_and_lse_fwd, _splash_out_and_lse_bwd,
+                           symbolic_zeros=True)
+
+
+def splash_attention_and_lse(q, k, v, keep, *, interpret: bool = False):
+    """``splash_masked_attention``'s output and gradients, and ``lse [Hkv,
+    G, T]`` float32, every query head's log-sum-exp over its selection,
+    from the one forward call (a constant: the backward rule refuses a
+    cotangent on it)."""
+    sizes, by_query, by_key = _splash_layout(keep, q.shape[1], q.shape[2])
+    out, lse = _splash_out_and_lse(sizes, interpret, by_query, by_key, q, k,
+                                   v)
+    return out, jax.lax.stop_gradient(lse)
 
 
 def masked_attention(q, k, v, keep, *, impl: str, q_chunk: int,
@@ -377,3 +453,14 @@ def masked_attention(q, k, v, keep, *, impl: str, q_chunk: int,
         return splash_masked_attention(q, k, v, keep)
     return blockwise_masked_attention(q, k, v, keep, q_chunk=q_chunk,
                                       kv_chunk=kv_chunk)
+
+
+def attention_and_lse(q, k, v, keep, *, impl: str, q_chunk: int,
+                      kv_chunk: int):
+    """``masked_attention`` for the pass that also trains the indexer:
+    ``(out, lse)``, ``lse`` what ``alignment_loss`` takes (``None`` from
+    ``blockwise``, whose loss makes its own target)."""
+    if impl == "splash":
+        return splash_attention_and_lse(q, k, v, keep)
+    return masked_attention(q, k, v, keep, impl=impl, q_chunk=q_chunk,
+                            kv_chunk=kv_chunk), None

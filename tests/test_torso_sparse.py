@@ -255,33 +255,192 @@ def test_the_kernels_dynamic_mask_form_matches_the_blockwise_form():
 
 
 def test_the_alignment_loss_through_the_kernels_matches_the_jnp_form():
-    """``impl="splash"`` in interpret mode: every head's log-sum-exp from
-    the splash kernel's forward pass, the heads' probabilities summed in
-    ``head_mean_probs_kernel``; value and gradient against plain ``jnp``,
-    at two block sizes (one key tile a block, and several)."""
+    """The kernel form in interpret mode: every head's log-sum-exp from the
+    main attention's own forward call, the heads' probabilities summed in
+    ``head_mean_probs_kernel``; value and gradient against plain ``jnp``
+    (``lse`` ``None``), at two block sizes (one key tile a block, and
+    several)."""
     q, k, v, _ = attention_inputs(256, seed=4, d=128)
     qi, ki, wi = index_inputs(256, seed=4)
     keep, _ = sparse.select_keys(qi, ki, wi, topk=64, q_chunk=64,
                                  kv_chunk=64)
+    _out, lse = sparse.splash_attention_and_lse(q, k, v, keep,
+                                                interpret=True)
 
-    def loss(impl, q_chunk, **kw):
+    def loss(lse, q_chunk, **kw):
         return jax.value_and_grad(lambda qi, ki, wi: sparse.alignment_loss(
-            qi, ki, wi, keep, q, k, v, impl=impl, q_chunk=q_chunk,
-            kv_chunk=128, **kw), argnums=(0, 1, 2))(qi, ki, wi)
+            qi, ki, wi, keep, q, k, lse, q_chunk=q_chunk, kv_chunk=128,
+            **kw), argnums=(0, 1, 2))(qi, ki, wi)
 
-    want, want_grads = loss("blockwise", 64)
+    want, want_grads = loss(None, 64)
     assert float(want) > 1.0
     for q_chunk in (128, 16):
-        got, got_grads = loss("splash", q_chunk, interpret=True)
+        got, got_grads = loss(lse, q_chunk, interpret=True)
         assert float(got) == pytest.approx(float(want), rel=1e-4)
         for g, w in zip(got_grads, want_grads):
             np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                        rtol=1e-3, atol=1e-5)
     # the main attention's operands get nothing from it
-    zero = jax.grad(lambda q, k, v: sparse.alignment_loss(
-        qi, ki, wi, keep, q, k, v, impl="blockwise", q_chunk=64,
-        kv_chunk=64), argnums=(0, 1, 2))(q, k, v)
+    zero = jax.grad(lambda q, k: sparse.alignment_loss(
+        qi, ki, wi, keep, q, k, None, q_chunk=64, kv_chunk=64),
+        argnums=(0, 1))(q, k)
     assert all(float(jnp.max(jnp.abs(z))) == 0.0 for z in zero)
+
+
+# -- one forward call for the output and the loss -----------------------------
+def residual_forward(q, k, v, keep):
+    """Until PR 42 the loss's own pass: jax's kernel object asked to keep
+    its residuals, ``(out, lse)``, not differentiable."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sa)
+
+    sizes, by_query, _ = sparse._splash_layout(keep, q.shape[1], q.shape[2])
+    kernel = sa.SplashAttentionKernel(
+        by_query, None, None, block_sizes=sizes, is_mqa=True,
+        save_residuals=True,
+        mask_value=sa.DEFAULT_MASK_VALUE, attn_logits_soft_cap=None,
+        residual_checkpoint_name=None, mask_function=None, interpret=True)
+    out, (lse,) = jax.vmap(kernel)(q, k, v)
+    return out, lse
+
+
+@pytest.fixture(scope="module")
+def selected():
+    """256 tokens, two key/value heads of 128 with two query heads each,
+    the top 64: ``(q, k, v, ct)``, the indexer's ``(qi, ki, wi)``, ``keep``
+    and the two forms' ``(out, lse)``."""
+    q, k, v, ct = attention_inputs(256, seed=4, d=128)
+    index = index_inputs(256, seed=4)
+    keep, _ = sparse.select_keys(*index, topk=64, q_chunk=64, kv_chunk=64)
+    return ((q, k, v, ct), index, keep,
+            sparse.splash_attention_and_lse(q, k, v, keep, interpret=True),
+            residual_forward(q, k, v, keep))
+
+
+def same_bits(a, b):
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert a.dtype == b.dtype and a.shape == b.shape
+
+
+@pytest.mark.parametrize("part", ["out", "dq", "dk", "dv"])
+def test_the_one_call_gives_the_kernels_own_output_and_gradients(selected,
+                                                                 part):
+    """``splash_attention_and_lse`` against ``splash_masked_attention``
+    (jax's own ``custom_vjp``): bit for bit."""
+    (q, k, v, ct), _index, keep, _new, _old = selected
+    got = with_gradients(lambda q, k, v: sparse.splash_attention_and_lse(
+        q, k, v, keep, interpret=True)[0], q, k, v, ct)
+    want = with_gradients(lambda q, k, v: sparse.splash_masked_attention(
+        q, k, v, keep, interpret=True), q, k, v, ct)
+    i = ["out", "dq", "dk", "dv"].index(part)
+    same_bits(got[i], want[i])
+    assert float(jnp.max(jnp.abs(got[i]))) > 0
+
+
+def test_the_one_calls_lse_is_the_residual_keeping_forwards(selected):
+    (q, k, _v, _ct), _index, keep, (out, lse), (old_out, old_lse) = selected
+    same_bits(lse, old_lse)
+    same_bits(out, old_out)
+    assert lse.shape == (2, 2, 256) and lse.dtype == jnp.float32
+    s = jnp.einsum("hgqd,hkd->hgqk", q, k, precision=reference.HI)
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(jax.nn.logsumexp(
+            jnp.where(keep, s, -jnp.inf), axis=-1)), rtol=2e-3, atol=2e-3)
+    # the attention that is not differentiated keeps no residual, same bits
+    same_bits(sparse.splash_masked_attention(q, k, _v, keep, interpret=True),
+              out)
+
+
+@pytest.mark.parametrize("q_chunk", [128, 16])
+def test_the_alignment_loss_given_that_lse_is_the_loss_of_its_own_pass(
+        selected, q_chunk):
+    """The loss and its three gradients with the log-sum-exp of the
+    attention's own call against the same with the residual-keeping
+    forward's (what ``alignment_loss`` fetched for itself until PR 42): bit
+    for bit; ``q``, ``k`` and the ``lse`` get exact zeros."""
+    (q, k, _v, _ct), (qi, ki, wi), keep, (_, lse), (_, old_lse) = selected
+
+    def loss(lse):
+        return jax.value_and_grad(
+            lambda qi, ki, wi, q, k, lse: sparse.alignment_loss(
+                qi, ki, wi, keep, q, k, lse, q_chunk=q_chunk, kv_chunk=128,
+                interpret=True), argnums=(0, 1, 2, 3, 4, 5))(
+                    qi, ki, wi, q, k, lse)
+
+    got, got_grads = loss(lse)
+    want, want_grads = loss(old_lse)
+    assert float(got) == float(want) > 1.0
+    for g, w in zip(got_grads, want_grads):
+        same_bits(g, w)
+    assert all(float(jnp.max(jnp.abs(g))) > 0 for g in got_grads[:3])
+    assert all(float(jnp.max(jnp.abs(g))) == 0.0 for g in got_grads[3:])
+
+
+def both_losses(keep, ct, constant=True):
+    """The attention's output against ``ct`` plus the alignment loss, as
+    ``_attend_sparse`` puts them together."""
+    def fn(q, k, v, qi, ki, wi):
+        if constant:
+            out, lse = sparse.splash_attention_and_lse(q, k, v, keep,
+                                                       interpret=True)
+        else:  # the custom_vjp itself, with nothing between it and a reader
+            sizes, *infos = sparse._splash_layout(keep, 2, q.shape[2])
+            out, lse = sparse._splash_out_and_lse(sizes, True, *infos, q, k,
+                                                  v)
+        return (jnp.sum(out * ct) + jnp.sum(lse) * (not constant)
+                + sparse.alignment_loss(qi, ki, wi, keep, q, k, lse,
+                                        q_chunk=128, kv_chunk=128,
+                                        interpret=True))
+    return fn
+
+
+def test_the_two_losses_through_one_call_are_the_two_losses_apart(selected):
+    """Differentiated together through the one forward call (under a
+    ``jax.checkpoint``, as the layer is): ``q, k, v`` get the attention's
+    gradient alone and the indexer the loss's alone, bit for bit; the
+    backward rule ran, so the cotangent that reached it on ``lse`` was a
+    symbolic zero (it refuses any other)."""
+    (q, k, v, ct), (qi, ki, wi), keep, (_, lse), _old = selected
+    got = jax.grad(jax.checkpoint(both_losses(keep, ct)),
+                   argnums=tuple(range(6)))(q, k, v, qi, ki, wi)
+    attn = with_gradients(lambda q, k, v: sparse.splash_masked_attention(
+        q, k, v, keep, interpret=True), q, k, v, ct)[1:]
+    index = jax.grad(lambda qi, ki, wi: sparse.alignment_loss(
+        qi, ki, wi, keep, q, k, lse, q_chunk=128, kv_chunk=128,
+        interpret=True), argnums=(0, 1, 2))(qi, ki, wi)
+    for g, w in zip(got, attn + index):
+        same_bits(g, w)
+
+
+def test_a_cotangent_on_the_lse_is_refused(selected):
+    """Read without the stop-gradient ``splash_attention_and_lse`` puts on
+    it, the log-sum-exp would need a backward the kernel does not have: the
+    rule raises rather than drop it."""
+    (q, k, v, ct), index, keep, _new, _old = selected
+    with pytest.raises(TypeError, match="log-sum-exp is handed out as a "
+                                        "constant"):
+        jax.grad(both_losses(keep, ct, constant=False))(q, k, v, *index)
+    # and through the public function nothing of it is asked for
+    out, back = jax.vjp(lambda q, k, v: sparse.splash_attention_and_lse(
+        q, k, v, keep, interpret=True), q, k, v)
+    zero, one = jnp.zeros_like(out[1]), jnp.ones_like(out[1])
+    for g, w in zip(back((ct, one)), back((ct, zero))):
+        same_bits(g, w)
+
+
+def test_attention_and_lse_is_the_plain_attention_where_no_kernel_runs():
+    """``blockwise``: the output of ``masked_attention`` and no log-sum-exp
+    (the loss then makes its own target in ``jnp``)."""
+    q, k, v, _ = attention_inputs(64)
+    keep, _ = sparse.select_keys(*index_inputs(), topk=16, q_chunk=8,
+                                 kv_chunk=8)
+    kw = dict(impl="blockwise", q_chunk=8, kv_chunk=16)
+    out, lse = sparse.attention_and_lse(q, k, v, keep, **kw)
+    assert lse is None
+    same_bits(out, sparse.masked_attention(q, k, v, keep, **kw))
+    with pytest.raises(ValueError, match="unknown attention impl"):
+        sparse.attention_and_lse(q, k, v, keep, impl="dense", q_chunk=8,
+                                 kv_chunk=16)
 
 
 # -- the layer against the reference ------------------------------------------

@@ -6,6 +6,7 @@ one file (one process may hold the TPU library)."""
 
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +51,14 @@ def config(monkeypatch):
 def on(sharding, tree):
     return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
         x.shape, x.dtype, sharding=sharding), tree)
+
+
+def kernel_calls(text, name):
+    """The compiled program's call sites of the Pallas kernels whose names
+    start with ``name`` (the compiler names the call after the kernel)."""
+    return len(re.findall(
+        rf"^\s*%{name}[\w.]* = .*custom_call_target=\"tpu_custom_call\"",
+        text, re.M))
 
 
 @pytest.mark.parametrize("window", [1024, None], ids=["window", "full"])
@@ -180,7 +189,17 @@ def test_a_sparse_attention_layer_and_its_backward_compile_at_real_widths(
     assert "gmm" in text and "splash" in text and "ragged-dot" not in text
     # the every-assignment buffer is a 4,096-token part's, not a sequence's
     assert "[131072,2048]" not in text and "[32768,2048]" in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 7e9
+    # the dynamic-mask kernel runs forward ONCE a forward evaluation: that
+    # call's output, its backward's residual and the alignment target's
+    # log-sum-exp are one call's. Nothing reads the values of the pass going
+    # up here, so the program holds the rematerialised evaluation alone: 1
+    # (2 until PR 42, when the loss made a pass of its own; the cell's
+    # chunk, which also holds passes 1 and 3, 6 -> 4 a layer)
+    assert kernel_calls(text, "splash_mqa_fwd") == 1
+    assert kernel_calls(text, "splash_mqa_dkv") == 1
+    assert kernel_calls(text, "splash_mqa_dq") == 1
+    # 5.98 GB (6.48 with the second pass)
+    assert compiled.memory_analysis().temp_size_in_bytes < 6.3e9
 
 
 @pytest.mark.parametrize("index, layer_type, kernels", [
