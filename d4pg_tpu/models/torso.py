@@ -641,7 +641,7 @@ class SequenceTorso:
                 else "blockwise")
 
     def sparse_impl(self) -> str:
-        """As ``attention_impl``, for the kernel's dynamic-mask form."""
+        """As ``attention_impl``, for the kernels under a run-time mask."""
         fits = sparse_ops.splash_fits(self.spec.tokens, self.spec.head_dim,
                                       self.spec.sa["kv_chunk_size"])
         return ("splash" if fits and jax.default_backend() == "tpu"

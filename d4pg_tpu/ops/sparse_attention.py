@@ -16,7 +16,7 @@ queries at a time so that no ``[heads, T, T]`` array is ever made (16,384 x
 2. ``masked_attention``: softmax attention under that mask. In the
    differentiated pass ``attention_and_lse``: the same output from the same
    forward call, and with it every head's log-sum-exp over ``S_t``, which
-   that call makes anyway as its backward's residual.
+   that call makes as its backward's residual.
 3. ``alignment_loss`` (the differentiated pass only): the loss the indexer
    is trained by, ``sum_t KL(p_t || softmax_{S_t} I[t, .])``, ``p_t`` the
    main attention's probabilities over ``S_t`` summed over its heads and
@@ -40,20 +40,32 @@ or ``GROUPS``.
 
 ``masked_attention`` implementations:
 
-- ``splash``: the Pallas splash-attention kernel jax ships, in its
-  dynamic-mask form. ``process_dynamic_mask`` would lay the mask out once a
-  query head; the selection is one for all heads, so the mask's blocks are
-  laid out once and every head's block table points at them. The kernel
-  skips the blocks the causal order empties and masks inside the others:
-  it visits every causal block whatever was selected. Backward is the
-  kernel's own (dq and dkv apart). TPU only: blocks are multiples of 128.
-  A sequence on the v5e: 34 ms forward, 110 forward and backward (blocks
-  of 512; 256: 65 / 214; 1,024 does not fit VMEM; a static causal mask
-  over the same pairs: 17 forward). jax's ``custom_vjp`` keeps the
-  log-sum-exp to itself, so ``splash_attention_and_lse`` is a ``custom_vjp``
-  of this module round the same forward and backward functions that
-  returns it beside the output (until PR 42 the loss ran the forward a
-  second time for it, 34 ms of a sequence and layer's every evaluation).
+- ``splash``: Pallas kernels, TPU only (blocks of 512). The forward pass
+  is this repo's (``group_masked_forward``, since PR 43): a grid step holds
+  the ``G`` query heads of a key/value head (eight in ``humanoid-keye2-
+  ep8``), one tile of keys, one of values and ONE int8 tile of the
+  selection for all of them, read from ``keep`` as it is (a byte a pair).
+  It hands back the output and every head's log-sum-exp, which is both the
+  backward's residual and the alignment target's, so one call serves the
+  attention, its gradient and the loss, and the passes that need neither
+  drop it. The backward is the splash-attention kernels jax ships (0.9.0;
+  dq and dkv apart, reached through the private
+  ``_splash_attention_bwd``), in their dynamic-mask form:
+  ``process_dynamic_mask`` would lay the mask out once a query head; the
+  selection is one for all heads, so its blocks are laid out once, by
+  query and by key, as int32, and every head's block table points at them.
+  Both halves skip the blocks that keep no pair (above the diagonal, all of
+  them) and mask inside the others: they visit every causal block whatever
+  was selected. What is assumed of ``keep``: a bool ``[T, T]`` in which
+  every query keeps at least one key; not that it is causal. A sequence
+  alone on the v5e (my chip runs, PR 43): forward 16.5 ms of which the
+  kernel 14.6-15.2, the int8 copy 1.6 and the block table 1.0 (jax's
+  dynamic-mask forward: 35.0 with its layout, 33.9 without; it steps a head
+  at a time and reads the mask as int32, 17.7 GB a call; a static causal
+  mask over the same pairs: 17.3); forward and backward 93.8 (110 with
+  jax's forward). Blocks of 1,024 x 512, 512 x 1,024 and 1,024 x 1,024
+  read within 0.3 ms of 512 x 512; 256 x 512 is 1.3 ms slower; one int8
+  tile a head in place of a group 21.2-27.1 ms.
 - ``blockwise``: plain ``jax.numpy``, a block of queries against the keys
   its group sees, rematerialised in the backward pass. Runs anywhere (441
   ms forward there).
@@ -74,6 +86,12 @@ import jax.numpy as jnp
 IMPLS = ("splash", "blockwise")
 GROUPS = 8
 SPLASH_BLOCK = 512
+# the forward kernel's name in a compiled program and a trace, and the fast
+# memory it may take: a group's accumulators (6 MB at 8 heads and blocks of
+# 512) and the unrolled heads' score tiles are refused under the compiler's
+# default of 16 MiB and fit in 32 (the v5e has 128)
+FORWARD_KERNEL = "group_masked_fwd"
+VMEM_LIMIT = 64 * 2 ** 20
 
 
 def block_plan(t_len: int, q_chunk: int, kv_chunk: int) -> list:
@@ -325,6 +343,132 @@ def splash_fits(t_len: int, head_dim: int, kv_chunk: int) -> bool:
             and kv_chunk % 128 == 0)
 
 
+def _block_table(keep, bq: int, bkv: int):
+    """``[T / bq, T / bkv]`` int32, a block of keys for every grid step of
+    ``group_masked_forward`` to hold: the step's own where the block keeps
+    a pair (the step runs), else the last one before it that does (the
+    first, before any), which the step before holds already, so nothing is
+    fetched and nothing run."""
+    t_len = keep.shape[0]
+    # down the rows first, then along them: 0.96 ms on the v5e at 16,384
+    # positions where both axes at once take 1.93 (my chip run, PR 43)
+    some = jnp.any(keep.reshape(t_len // bq, bq, t_len), axis=1)
+    some = jnp.any(some.reshape(t_len // bq, t_len // bkv, bkv), axis=2)
+    j = jnp.arange(t_len // bkv, dtype=jnp.int32)
+    last = jax.lax.cummax(jnp.where(some, j, -1), axis=1)
+    first = jnp.argmax(some, axis=1).astype(jnp.int32)
+    return jnp.where(last >= 0, last, first[:, None])
+
+
+def group_masked_forward(q, k, v, keep, *, block_q: int | None = None,
+                         block_kv: int | None = None,
+                         interpret: bool = False):
+    """``(out [Hkv, G, T, D], lse [Hkv, G, T] float32)``: softmax attention
+    under ``keep [T, T]`` and every query head's log-sum-exp over its
+    selection, the forward pass as a Pallas kernel of this repo. One grid
+    step holds a key/value head's ``G`` tiles of queries, one tile of keys,
+    one of values and ONE tile of ``keep`` as int8 for all ``G`` heads,
+    which are taken in a loop (jax 0.9.0's kernel steps a query head at a
+    time and reads the mask as int32: eight fetches of 1 MB where this
+    makes one of 256 KB). Blocks that keep no pair are neither fetched nor
+    run (``_block_table``); ``keep`` is any bool matrix in which every
+    query keeps a key, causal or not. The arithmetic is jax's kernel's,
+    step for step: products of the inputs' dtype summed in float32, the
+    softmax in float32 under a running maximum, ``DEFAULT_MASK_VALUE`` for
+    what is not kept, ``lse = m + log(l)``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sa)
+
+    hkv, group, t_len, d = q.shape
+    bq = min(block_q or SPLASH_BLOCK, t_len)
+    bkv = min(block_kv or SPLASH_BLOCK, t_len)
+    lanes = min(128, bkv)
+    steps = t_len // bkv
+    mask_value = sa.DEFAULT_MASK_VALUE
+
+    def wide(a, n):  # a row's value on ``lanes`` lanes, on ``n`` of them
+        return a if n == lanes else jnp.tile(a, (1, n // lanes))
+
+    # (the steps take the refs they write as arguments: a Pallas kernel
+    # hands its outputs and scratch back through them)
+    def start(m_ref, l_ref, acc_ref):
+        m_ref[...] = jnp.full_like(m_ref, mask_value)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def visit(q_ref, k_ref, v_ref, keep_ref, m_ref, l_ref, acc_ref):
+        kept = keep_ref[...].astype(jnp.int32) != 0
+        keys, values = k_ref[0], v_ref[0]
+        for g in range(group):
+            s = jax.lax.dot_general(
+                q_ref[0, g], keys, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            s = jnp.where(kept, s, mask_value)
+            m_prev, l_prev = m_ref[g], l_ref[g]
+            m_next = jnp.maximum(m_prev, s.max(axis=-1)[:, None])
+            p = jnp.exp(s - wide(m_next, bkv))
+            alpha = jnp.exp(m_prev - m_next)
+            m_ref[g] = m_next
+            l_ref[g] = alpha * l_prev + jax.lax.broadcast_in_dim(
+                p.sum(axis=-1), l_prev.shape, (0,))
+            acc_ref[g] = wide(alpha, d) * acc_ref[g] + jax.lax.dot_general(
+                p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    def finish(out_ref, lse_ref, m_ref, l_ref, acc_ref):
+        for g in range(group):
+            l = l_ref[g]
+            out_ref[0, g] = (acc_ref[g] * wide(1.0 / l, d)).astype(
+                out_ref.dtype)
+            # a query's value fills its row of lanes: one column of it,
+            # with the queries along the lanes
+            lse_ref[0, pl.ds(g, 1), :] = jnp.transpose(
+                jnp.log(l) + m_ref[g])[:1]
+
+    def kernel(table_ref, q_ref, k_ref, v_ref, keep_ref, out_ref, lse_ref,
+               *scratch):
+        i, j = pl.program_id(1), pl.program_id(2)
+        pl.when(j == 0)(lambda: start(*scratch))
+        pl.when(table_ref[i, j] == j)(
+            lambda: visit(q_ref, k_ref, v_ref, keep_ref, *scratch))
+        pl.when(j == steps - 1)(lambda: finish(out_ref, lse_ref, *scratch))
+
+    # (the scope keeps the call's name in a compiled program the kernel's
+    # own under a transformation too, as jax's kernels keep theirs)
+    with jax.named_scope(FORWARD_KERNEL):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(hkv, t_len // bq, steps),
+                in_specs=[
+                    pl.BlockSpec((1, group, bq, d),
+                                 lambda h, i, j, t: (h, 0, i, 0)),
+                    pl.BlockSpec((1, bkv, d),
+                                 lambda h, i, j, t: (h, t[i, j], 0)),
+                    pl.BlockSpec((1, bkv, d),
+                                 lambda h, i, j, t: (h, t[i, j], 0)),
+                    pl.BlockSpec((bq, bkv),
+                                 lambda h, i, j, t: (i, t[i, j]))],
+                out_specs=[
+                    pl.BlockSpec((1, group, bq, d),
+                                 lambda h, i, j, t: (h, 0, i, 0)),
+                    pl.BlockSpec((1, group, bq),
+                                 lambda h, i, j, t: (h, 0, i))],
+                scratch_shapes=[
+                    pltpu.VMEM((group, bq, lanes), jnp.float32),
+                    pltpu.VMEM((group, bq, lanes), jnp.float32),
+                    pltpu.VMEM((group, bq, d), jnp.float32)]),
+            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                       jax.ShapeDtypeStruct(q.shape[:3], jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+                vmem_limit_bytes=VMEM_LIMIT),
+            interpret=interpret, name=FORWARD_KERNEL)(
+                _block_table(keep, bq, bkv), q, k, v, keep.astype(jnp.int8))
+
+
 def _shared_mask_info(keep, heads: int, block: int, dkv: bool):
     """``keep``'s ``MaskInfo`` for ``heads`` query heads that share it: the
     mask's blocks laid out once (as the kernels take them: ``[blocks,
@@ -344,10 +488,10 @@ def _shared_mask_info(keep, heads: int, block: int, dkv: bool):
             -1, block, block))
 
 
-def _splash_layout(keep, group: int, t_len: int):
-    """``(block sizes, keep's MaskInfo by query, by key)``: what the
-    kernels of one key/value head and its ``group`` query heads take (by
-    query: forward and dq; by key: dkv)."""
+def _backward_layout(keep, group: int, t_len: int):
+    """``(block sizes, keep's MaskInfo by query, by key)``: what jax's
+    backward kernels of one key/value head and its ``group`` query heads
+    take (by query: dq; by key: dkv)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sa)
 
@@ -361,50 +505,27 @@ def _splash_layout(keep, group: int, t_len: int):
             _shared_mask_info(keep, group, b, True))
 
 
-def splash_masked_attention(q, k, v, keep, *, interpret: bool = False):
-    """The kernel's output alone, each key/value head with its ``G`` query
-    heads under ``keep``; differentiable by the kernel's own rule."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as sa)
-
-    sizes, by_query, by_key = _splash_layout(keep, q.shape[1], q.shape[2])
-    kernel = sa.SplashAttentionKernel(
-        by_query, by_query, by_key, block_sizes=sizes, is_mqa=True,
-        save_residuals=False, mask_value=sa.DEFAULT_MASK_VALUE,
-        attn_logits_soft_cap=None, residual_checkpoint_name=None,
-        mask_function=None, interpret=interpret)
-    return jax.vmap(kernel)(q, k, v)
-
-
-# One forward call that hands out what jax's own rule keeps to itself: the
-# output, and the log-sum-exp that is both the backward's residual and the
-# alignment target's. Forward and backward are the kernel module's (private
-# names of jax 0.9.0; ``tests/test_torso_v5e_compile.py`` compiles them).
+# The forward pass is this module's kernel (``group_masked_forward``); the
+# backward is jax 0.9.0's: ``_splash_attention_bwd``, a private name of its
+# splash-attention module, runs its dq and dkv kernels on ``(q, k, v, out,
+# lse)`` and the two int32 layouts of ``keep`` that ``_backward_layout``
+# makes (``tests/test_torso_v5e_compile.py`` compiles both halves). The
+# layouts only ride to the backward rule: a program that is not
+# differentiated never makes them. ``keep`` is any bool ``[T, T]`` in which
+# every query keeps a key; neither half assumes the causal order.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _splash_out_and_lse(sizes, interpret, by_query, by_key, q, k, v):
-    # by_key only rides to the backward rule, as in jax's own
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as sa)
-
-    def head(q, k, v):
-        out, (lse,) = sa._splash_attention_forward(
-            by_query, q, k, v, None, None, mask_value=sa.DEFAULT_MASK_VALUE,
-            is_mqa=True, block_sizes=sizes, residual_checkpoint_name=None,
-            save_residuals=True, mask_function=None,
-            attn_logits_soft_cap=None, interpret=interpret)
-        return out, lse
-
-    return jax.vmap(head)(q, k, v)
+def _splash_out_and_lse(sizes, interpret, keep, by_query, by_key, q, k, v):
+    return group_masked_forward(q, k, v, keep, interpret=interpret)
 
 
 def _splash_out_and_lse_fwd(sizes, interpret, *primals):
     from jax.custom_derivatives import CustomVJPPrimal
 
-    by_query, by_key, q, k, v = jax.tree_util.tree_map(
+    keep, by_query, by_key, q, k, v = jax.tree_util.tree_map(
         lambda p: p.value, primals,
         is_leaf=lambda p: isinstance(p, CustomVJPPrimal))
-    out, lse = _splash_out_and_lse(sizes, interpret, by_query, by_key, q, k,
-                                   v)
+    out, lse = _splash_out_and_lse(sizes, interpret, keep, by_query, by_key,
+                                   q, k, v)
     return (out, lse), (q, k, v, out, lse, by_query, by_key)
 
 
@@ -425,7 +546,8 @@ def _splash_out_and_lse_bwd(sizes, interpret, res, cts):
             interpret, (q, k, v, None, None, out, lse, by_query, by_key),
             d_out)[3:6]
 
-    return (None, None) + tuple(jax.vmap(head)(q, k, v, out, lse, d_out))
+    return (None, None, None) + tuple(
+        jax.vmap(head)(q, k, v, out, lse, d_out))
 
 
 _splash_out_and_lse.defvjp(_splash_out_and_lse_fwd, _splash_out_and_lse_bwd,
@@ -433,14 +555,21 @@ _splash_out_and_lse.defvjp(_splash_out_and_lse_fwd, _splash_out_and_lse_bwd,
 
 
 def splash_attention_and_lse(q, k, v, keep, *, interpret: bool = False):
-    """``splash_masked_attention``'s output and gradients, and ``lse [Hkv,
-    G, T]`` float32, every query head's log-sum-exp over its selection,
-    from the one forward call (a constant: the backward rule refuses a
-    cotangent on it)."""
-    sizes, by_query, by_key = _splash_layout(keep, q.shape[1], q.shape[2])
-    out, lse = _splash_out_and_lse(sizes, interpret, by_query, by_key, q, k,
-                                   v)
+    """Attention under ``keep`` by the kernels, each key/value head with
+    its ``G`` query heads: the output, differentiable (this module's
+    forward, jax's dq and dkv), and ``lse [Hkv, G, T]`` float32, every
+    query head's log-sum-exp over its selection, from the one forward call
+    (a constant: the backward rule refuses a cotangent on it)."""
+    sizes, by_query, by_key = _backward_layout(keep, q.shape[1], q.shape[2])
+    out, lse = _splash_out_and_lse(sizes, interpret, keep, by_query, by_key,
+                                   q, k, v)
     return out, jax.lax.stop_gradient(lse)
+
+
+def splash_masked_attention(q, k, v, keep, *, interpret: bool = False):
+    """``splash_attention_and_lse``'s output alone: the same forward call
+    with its log-sum-exp dropped (the passes that keep no residual)."""
+    return splash_attention_and_lse(q, k, v, keep, interpret=interpret)[0]
 
 
 def masked_attention(q, k, v, keep, *, impl: str, q_chunk: int,

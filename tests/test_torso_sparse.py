@@ -9,6 +9,7 @@ kernel's dynamic-mask form in interpret mode; the normal path through
 2 index heads of 8, top 16 of 64 tokens (four times ``topk``), 8 experts
 top-2 of width 32, two layers."""
 
+import functools
 import json
 import math
 import os
@@ -294,7 +295,8 @@ def residual_forward(q, k, v, keep):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sa)
 
-    sizes, by_query, _ = sparse._splash_layout(keep, q.shape[1], q.shape[2])
+    sizes, by_query, _ = sparse._backward_layout(keep, q.shape[1],
+                                                 q.shape[2])
     kernel = sa.SplashAttentionKernel(
         by_query, None, None, block_sizes=sizes, is_mqa=True,
         save_residuals=True,
@@ -384,9 +386,9 @@ def both_losses(keep, ct, constant=True):
             out, lse = sparse.splash_attention_and_lse(q, k, v, keep,
                                                        interpret=True)
         else:  # the custom_vjp itself, with nothing between it and a reader
-            sizes, *infos = sparse._splash_layout(keep, 2, q.shape[2])
-            out, lse = sparse._splash_out_and_lse(sizes, True, *infos, q, k,
-                                                  v)
+            sizes, *infos = sparse._backward_layout(keep, 2, q.shape[2])
+            out, lse = sparse._splash_out_and_lse(sizes, True, keep, *infos,
+                                                  q, k, v)
         return (jnp.sum(out * ct) + jnp.sum(lse) * (not constant)
                 + sparse.alignment_loss(qi, ki, wi, keep, q, k, lse,
                                         q_chunk=128, kv_chunk=128,
@@ -444,6 +446,141 @@ def test_attention_and_lse_is_the_plain_attention_where_no_kernel_runs():
 
 
 # -- the layer against the reference ------------------------------------------
+# -- the forward kernel: one mask tile for the heads of a group ---------------
+KINDS = ("topk", "empty_block", "full_block", "not_causal")
+T_KERNEL = 256
+
+
+@functools.lru_cache(maxsize=None)
+def selection(kind):
+    """``keep [256, 256]``: the top 64 of seeded index scores; the same with
+    queries 128.. keeping none of keys ..127 (blocks of 128: one below the
+    diagonal is empty; every query keeps itself); with those queries keeping
+    all of them (that block is kept whole, as is the first 64 queries'
+    causal triangle); and a seeded third of ALL pairs, above the diagonal
+    too."""
+    rows = jnp.arange(T_KERNEL)[:, None]
+    cols = jnp.arange(T_KERNEL)[None, :]
+    if kind == "not_causal":
+        return jax.random.bernoulli(jax.random.key(9), 0.3,
+                                    (T_KERNEL, T_KERNEL)) | (rows == cols)
+    keep, _ = sparse.select_keys(*index_inputs(T_KERNEL, seed=6), topk=64,
+                                 q_chunk=64, kv_chunk=64)
+    corner = (rows >= 128) & (cols < 128)
+    if kind == "empty_block":
+        return (keep & ~corner) | (rows == cols)
+    return keep | corner if kind == "full_block" else keep
+
+
+@functools.lru_cache(maxsize=None)
+def grouped_inputs(group):
+    k = jax.random.split(jax.random.key(10 + group), 4)
+    heads, keys = (2, group, T_KERNEL, 128), (2, T_KERNEL, 128)
+    return (jax.random.normal(k[0], heads) / math.sqrt(128),
+            jax.random.normal(k[1], keys), jax.random.normal(k[2], keys),
+            jax.random.normal(k[3], heads))
+
+
+@functools.lru_cache(maxsize=None)
+def references(group, kind):
+    """``(out, lse)`` by the plain mask, and by jax's own forward kernel
+    (``residual_forward`` in blocks of 128: the mask laid out as int32 by
+    query) in interpret mode."""
+    q, k, v, _ = grouped_inputs(group)
+    keep = selection(kind)
+    s = jnp.where(keep, jnp.einsum("hgqd,hkd->hgqk", q, k,
+                                   precision=reference.HI), -jnp.inf)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(sparse, "SPLASH_BLOCK", 128)
+        by_jax = residual_forward(q, k, v, keep)
+    return ((naive_masked(q, k, v, keep), jax.nn.logsumexp(s, axis=-1)),
+            by_jax)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("block_q, block_kv", [(128, 128), (256, 128),
+                                               (128, 256), (64, 128)])
+@pytest.mark.parametrize("group", [1, 2, 4])
+def test_the_forward_kernel_is_the_masked_attention_and_its_lse(
+        group, block_q, block_kv, kind):
+    """``group_masked_forward`` in interpret mode, the ``group`` heads of a
+    key/value head over one int8 tile of ``keep``: ``out`` and ``lse``
+    against the plain mask, against ``blockwise_masked_attention`` (which
+    reads the causal extent only) and against jax's forward kernel on the
+    same operands, bit for bit where the blocks are jax's; at blocks that
+    are square, and that cut the diagonal into unequal parts; with a block
+    that keeps nothing (skipped: never fetched, never run), one kept
+    whole, and a selection that is not causal."""
+    q, k, v, _ = grouped_inputs(group)
+    keep = selection(kind)
+    table = np.asarray(sparse._block_table(keep, block_q, block_kv))
+    visited = table == np.arange(table.shape[1])
+    some = np.asarray(keep).reshape(T_KERNEL // block_q, block_q,
+                                    T_KERNEL // block_kv, block_kv).any(
+                                        axis=(1, 3))
+    np.testing.assert_array_equal(visited, some)
+    if kind == "empty_block" and block_kv == 128 and block_q <= 128:
+        assert not visited[-1, 0] and table[-1, 0] == 1
+    out, lse = sparse.group_masked_forward(
+        q, k, v, keep, block_q=block_q, block_kv=block_kv, interpret=True)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    assert lse.shape == q.shape[:3] and lse.dtype == jnp.float32
+    (want, want_lse), (jax_out, jax_lse) = references(group, kind)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-3,
+                               atol=2e-3)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                               rtol=2e-3, atol=2e-3)
+    if (block_q, block_kv) == (128, 128):
+        same_bits(out, jax_out)
+        same_bits(lse, jax_lse)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(jax_out),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(jax_lse),
+                               rtol=1e-5, atol=1e-6)
+    if kind != "not_causal":
+        blockwise = sparse.blockwise_masked_attention(
+            q, k, v, keep, q_chunk=64, kv_chunk=64)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(blockwise),
+                                   rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("kind", KINDS[:3])
+@pytest.mark.parametrize("group", [1, 2, 4])
+def test_the_new_forward_under_jaxs_backward_gives_the_blockwise_gradients(
+        group, kind, monkeypatch):
+    """``splash_attention_and_lse`` differentiated (this module's forward,
+    jax's dq and dkv kernels on its ``out`` and ``lse``), in blocks of 128
+    so that there are four, against the blockwise form's gradients."""
+    monkeypatch.setattr(sparse, "SPLASH_BLOCK", 128)
+    q, k, v, ct = grouped_inputs(group)
+    keep = selection(kind)
+    got = with_gradients(lambda q, k, v: sparse.splash_attention_and_lse(
+        q, k, v, keep, interpret=True)[0], q, k, v, ct)
+    want = with_gradients(lambda q, k, v: sparse.blockwise_masked_attention(
+        q, k, v, keep, q_chunk=64, kv_chunk=64), q, k, v, ct)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-3,
+                                   atol=2e-3)
+        assert float(jnp.max(jnp.abs(g))) > 0
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_the_pass_that_drops_the_lse_is_the_same_forward_call(kind):
+    """``splash_masked_attention`` (what ``masked_attention`` calls for
+    ``impl="splash"``) is ``splash_attention_and_lse``'s output, bit for
+    bit, whose ``lse`` is the plain mask's (that the undifferentiated
+    program lays out no int32 mask is pinned on the compiled program,
+    ``tests/test_torso_v5e_compile.py``)."""
+    q, k, v, _ = grouped_inputs(2)
+    keep = selection(kind)
+    out, lse = sparse.splash_attention_and_lse(q, k, v, keep, interpret=True)
+    same_bits(sparse.splash_masked_attention(q, k, v, keep, interpret=True),
+              out)
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(references(2, kind)[0][1]), rtol=2e-3,
+        atol=2e-3)
+
+
 def test_forward_pass_selections_and_index_loss_match_the_reference():
     config = small_config()
     params = seeded_state(config, 3).critic_params

@@ -53,12 +53,18 @@ def on(sharding, tree):
         x.shape, x.dtype, sharding=sharding), tree)
 
 
+def call_sites(text, name):
+    """The compiled text's lines that call the Pallas kernels whose names
+    start with ``name``."""
+    return re.findall(
+        rf"^\s*%{name}[\w.]* = .*custom_call_target=\"tpu_custom_call\".*$",
+        text, re.M)
+
+
 def kernel_calls(text, name):
     """The compiled program's call sites of the Pallas kernels whose names
     start with ``name`` (the compiler names the call after the kernel)."""
-    return len(re.findall(
-        rf"^\s*%{name}[\w.]* = .*custom_call_target=\"tpu_custom_call\"",
-        text, re.M))
+    return len(call_sites(text, name))
 
 
 @pytest.mark.parametrize("window", [1024, None], ids=["window", "full"])
@@ -133,30 +139,68 @@ def test_the_fused_chunk_of_the_benchmark_cell_fits_the_chip(one_chip,
     assert "gmm" in text and "splash" in text and "ragged-dot" not in text
 
 
-def test_the_kernels_dynamic_mask_form_compiles_at_real_widths(one_chip):
-    """Splash attention under a mask that is an argument (``humanoid-keye2-
-    ep8``: 16,384 positions, 8 query heads a key/value head): forward, dq
-    and dkv kernels, the mask's blocks laid out once for the eight heads
-    (two layouts, by query and by key: not one a head)."""
+MASK_LAYOUT = "s32[1024,512,512]"
+
+
+def _masked(differentiated):
+    def out(q, k, v, keep):
+        return sparse_ops.masked_attention(q, k, v, keep, impl="splash",
+                                           q_chunk=512, kv_chunk=512)
+
+    def loss(q, k, v, keep):
+        return jnp.sum(out(q, k, v, keep).astype(jnp.float32))
+
+    return jax.grad(loss, argnums=(0, 1, 2)) if differentiated else out
+
+
+@pytest.fixture(scope="module")
+def masked_operands(one_chip):
+    """``humanoid-keye2-ep8``'s attention: 16,384 positions, 8 query heads
+    a key/value head, the selection an argument."""
     q = jax.ShapeDtypeStruct((4, 8, 16384, 128), jnp.bfloat16,
                              sharding=one_chip)
     kv = jax.ShapeDtypeStruct((4, 16384, 128), jnp.bfloat16,
                               sharding=one_chip)
     keep = jax.ShapeDtypeStruct((16384, 16384), jnp.bool_, sharding=one_chip)
+    return q, kv, kv, keep
 
-    def loss(q, k, v, keep):
-        out = sparse_ops.masked_attention(q, k, v, keep, impl="splash",
-                                          q_chunk=512, kv_chunk=512)
-        return jnp.sum(out.astype(jnp.float32))
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        q, kv, kv, keep).compile()
+def test_the_kernels_dynamic_mask_form_compiles_at_real_widths(
+        masked_operands):
+    """Attention under a mask that is an argument, differentiated: this
+    repo's forward kernel (one int8 tile of the mask for the eight heads)
+    and jax's dq and dkv kernels, the mask's blocks laid out once for the
+    eight heads (two layouts, by query and by key: not one a head)."""
+    compiled = jax.jit(_masked(True)).lower(*masked_operands).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3
-    # the kernels read the mask as int32 blocks: 1 GiB a layout, two
-    # layouts; a layout a head would be eight times that
-    assert text.count("s32[1024,512,512]") >= 2
+    # jax's backward kernels read the mask as int32 blocks: 1 GiB a layout,
+    # two layouts; a layout a head would be eight times that
+    assert text.count(MASK_LAYOUT) >= 2
     assert compiled.memory_analysis().temp_size_in_bytes < 4.5e9
+    # the forward reads the mask as it is, a byte a pair, and no layout
+    (forward,) = call_sites(text, "group_masked_fwd")
+    assert "s8[16384,16384]" in forward and MASK_LAYOUT not in forward
+    assert kernel_calls(text, "splash_mqa_fwd") == 0
+    for name in ("splash_mqa_dq", "splash_mqa_dkv"):
+        (backward,) = call_sites(text, name)
+        assert MASK_LAYOUT in backward
+
+
+def test_the_forward_alone_lays_out_no_int32_mask(masked_operands):
+    """The passes that are not differentiated (1 and 3 of a step, every
+    ``train=False`` call): one call of the forward kernel on the int8 mask
+    and a 32 x 32 block table; the backward's two 1 GiB layouts are never
+    made (until PR 43 the forward read one of them: 1 MB a block and head
+    where this reads 256 KB a block and group)."""
+    compiled = jax.jit(_masked(False)).lower(*masked_operands).compile()
+    text = compiled.as_text()
+    assert kernel_calls(text, "group_masked_fwd") == 1
+    assert text.count("tpu_custom_call") == 1
+    assert "s32[1024" not in text and "s8[16384,16384]" in text
+    assert "s32[32,32]" in text
+    # the int8 copy of the mask (268 MB) and little else
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
 
 
 def test_a_sparse_attention_layer_and_its_backward_compile_at_real_widths(
@@ -189,15 +233,22 @@ def test_a_sparse_attention_layer_and_its_backward_compile_at_real_widths(
     assert "gmm" in text and "splash" in text and "ragged-dot" not in text
     # the every-assignment buffer is a 4,096-token part's, not a sequence's
     assert "[131072,2048]" not in text and "[32768,2048]" in text
-    # the dynamic-mask kernel runs forward ONCE a forward evaluation: that
-    # call's output, its backward's residual and the alignment target's
+    # the attention's forward runs ONCE a forward evaluation: that call's
+    # output, its backward's residual and the alignment target's
     # log-sum-exp are one call's. Nothing reads the values of the pass going
     # up here, so the program holds the rematerialised evaluation alone: 1
     # (2 until PR 42, when the loss made a pass of its own; the cell's
-    # chunk, which also holds passes 1 and 3, 6 -> 4 a layer)
-    assert kernel_calls(text, "splash_mqa_fwd") == 1
+    # chunk, which also holds passes 1 and 3, 6 -> 4 a layer). Since PR 43
+    # it is this repo's kernel on the int8 mask; the backward is jax's, on
+    # the mask's two int32 layouts
+    (forward,) = call_sites(text, "group_masked_fwd")
+    assert "s8[16384,16384]" in forward and MASK_LAYOUT not in forward
+    assert kernel_calls(text, "splash_mqa_fwd") == 0
     assert kernel_calls(text, "splash_mqa_dkv") == 1
     assert kernel_calls(text, "splash_mqa_dq") == 1
+    assert all(MASK_LAYOUT in line for name in ("splash_mqa_dq",
+                                                "splash_mqa_dkv")
+               for line in call_sites(text, name))
     # 5.98 GB (6.48 with the second pass)
     assert compiled.memory_analysis().temp_size_in_bytes < 6.3e9
 
