@@ -41,7 +41,8 @@ class FusedLoop:
     """Drives fused replay+learn chunks against a device-resident buffer.
 
     ``buffer`` is a ``FusedDeviceReplay``/``ShardedFusedReplay`` (needs
-    ``.storage``, ``.size`` and — prioritized — ``.trees``). ``service``
+    ``.storage``, ``.size`` and ``.trees``, ``None`` under uniform
+    replay). ``service``
     is the owning ``ReplayService`` when actor rows stream in between
     chunks (the loop claims the service's single ingest-dispatch slot
     via ``IngestOverlap``); ``None`` runs the loop against a statically
@@ -66,7 +67,12 @@ class FusedLoop:
         self._buffer = buffer
         self.k = max(1, int(k))
         self._batch_size = int(batch_size)
-        self._prioritized = bool(prioritized)
+        # the buffer says whether replay is prioritized (its ``trees``);
+        # the argument is kept for its callers and held to that
+        if bool(prioritized) != (buffer.trees is not None):
+            raise ValueError(
+                f"prioritized={prioritized} against a buffer "
+                f"{'with' if buffer.trees is not None else 'without'} trees")
         self._alpha = float(alpha)
         self._beta0 = float(beta0)
         self._beta_steps = int(beta_steps)
@@ -81,20 +87,13 @@ class FusedLoop:
     def fused_for(self, k: int):
         """The jitted fused-chunk fn for chunk length ``k`` (cached)."""
         if k not in self._fns:
-            from d4pg_tpu.learner.fused import (
-                make_fused_chunk,
-                make_sharded_fused_chunk,
-            )
+            from d4pg_tpu.learner.fused import make_fused_chunk
 
-            kwargs = dict(
-                k=k, batch_size=self._batch_size,
-                prioritized=self._prioritized, alpha=self._alpha,
-                beta0=self._beta0, beta_steps=self._beta_steps,
+            self._fns[k] = make_fused_chunk(
+                self._config, k=k, batch_size=self._batch_size,
+                alpha=self._alpha, beta0=self._beta0,
+                beta_steps=self._beta_steps, mesh=self._mesh,
                 donate=self._donate)
-            self._fns[k] = (
-                make_sharded_fused_chunk(self._config, self._mesh, **kwargs)
-                if self._mesh is not None
-                else make_fused_chunk(self._config, **kwargs))
         return self._fns[k]
 
     def run(
@@ -141,9 +140,7 @@ class FusedLoop:
             with obs_trace.span("learner.chunk", chunk=chunk, k=k):
                 if self.ingest is not None:
                     self.ingest.commit()
-                args = ((state, buffer.trees, buffer.storage, buffer.size)
-                        if self._prioritized
-                        else (state, buffer.storage, buffer.size))
+                args = (state, buffer.trees, buffer.storage, buffer.size)
                 if not self._tabled:  # first dispatch: enter the table
                     obs_trace.register_program("learner.chunk", fn,
                                      abstract_args(args))
@@ -157,12 +154,8 @@ class FusedLoop:
                 landed = buffer.landed
                 with obs_trace.span("learner.dispatch", chunk=chunk,
                                     landed=landed):
-                    out = fn(*args)
+                    state, buffer.trees, metrics = fn(*args)
                 del args  # state and trees were donated
-                if self._prioritized:
-                    state, buffer.trees, metrics = out
-                else:
-                    state, metrics = out
                 if self.ingest is not None:
                     self.ingest.stage()
                 # traces whose rows landed before this dispatch are now

@@ -48,12 +48,21 @@ import jax.numpy as jnp
 import numpy as np
 from jax import shard_map
 
-from d4pg_tpu.learner.fused import fused_chunk_step
+from d4pg_tpu.learner.fused import device_replay, fused_chunk_step
 from d4pg_tpu.learner.replica import PARAM_FIELDS
 from d4pg_tpu.learner.state import D4PGConfig, D4PGState
 from d4pg_tpu.parallel import partition, replica_mesh
 
 _tree_map = jax.tree_util.tree_map
+
+
+def _local(tree):
+    """A replica's own slice of a stacked tree, inside the ``shard_map``."""
+    return _tree_map(lambda x: x[0], tree)
+
+
+def _expand(tree):
+    return _tree_map(lambda x: x[None], tree)
 
 MODES = ("async", "sync")
 
@@ -119,7 +128,6 @@ class MeshReplicaGroup:
         store=None,
         extract: Optional[Callable[[Any], Any]] = None,
         norm_stats: Optional[Callable[[], tuple | None]] = None,
-        prioritized: bool = True,
         alpha: float = 0.6,
         beta0: float = 0.4,
         beta_steps: int = 100_000,
@@ -137,7 +145,6 @@ class MeshReplicaGroup:
         self._store = store
         self._extract = extract
         self._norm_stats = norm_stats
-        self._prioritized = bool(prioritized)
         self._alpha = float(alpha)
         self._beta0 = float(beta0)
         self._beta_steps = int(beta_steps)
@@ -185,22 +192,18 @@ class MeshReplicaGroup:
         rows are copied over ICI, never through the host."""
         buffer.drain()
         n = self.n
-        payload = (buffer.storage, buffer.trees) if self._prioritized \
-            else (buffer.storage,)
+        # no trees under uniform replay: ``None``, an empty pytree
+        payload = (buffer.storage, buffer.trees)
         out_sh = partition.replica_stack_shardings(self.mesh, payload)
         # the buffer's arrays are committed to its one device
         # (replay/device_ring.py): hand them to the mesh first
         payload = jax.device_put(payload, partition.replicated(self.mesh))
         # one-shot per load (startup / test fill): jit-with-out_shardings
         # is what materializes the broadcast on every replica's device
-        placed = jax.jit(  # jaxlint: disable=recompile-hazard
+        self._storage, self._trees = jax.jit(  # jaxlint: disable=recompile-hazard
             lambda t: _tree_map(
                 lambda x: jnp.broadcast_to(x[None], (n, *x.shape)), t),
             out_shardings=out_sh)(payload)
-        if self._prioritized:
-            self._storage, self._trees = placed
-        else:
-            (self._storage,) = placed
         self._sizes = jax.device_put(
             jnp.full((n,), int(buffer.size), jnp.int32),
             partition.replica_sharding(self.mesh))
@@ -211,41 +214,22 @@ class MeshReplicaGroup:
         FusedLoop jits, against its own shard of the stacked state."""
         if k in self._chunk_fns:
             return self._chunk_fns[k]
-        config, bsz = self._config, self._batch_size
-        alpha, beta0, beta_steps = self._alpha, self._beta0, self._beta_steps
+        config = self._config
+        sample, write_back = device_replay(
+            self._batch_size, self._alpha, self._beta0, self._beta_steps)
         R = partition.replica_spec()
 
-        def local(tree):
-            return _tree_map(lambda x: x[0], tree)
+        def body(state, trees, storage, size):
+            s, t, m = fused_chunk_step(
+                config, _local(state), _local(trees), _local(storage),
+                size[0], k=k, sample=sample, write_back=write_back)
+            return _expand(s), _expand(t), _expand(m)
 
-        def expand(tree):
-            return _tree_map(lambda x: x[None], tree)
-
-        if self._prioritized:
-            def body(state, trees, storage, size):
-                s, t, m = fused_chunk_step(
-                    config, local(state), local(trees), local(storage),
-                    size[0], k=k, batch_size=bsz, alpha=alpha,
-                    beta0=beta0, beta_steps=beta_steps)
-                return expand(s), expand(t), expand(m)
-
-            fn = shard_map(body, mesh=self.mesh,
-                           in_specs=(R, R, R, R), out_specs=(R, R, R),
-                           check_vma=False)
-            jitted = jax.jit(fn, donate_argnums=(0, 1))
-        else:
-            def body_u(state, storage, size):
-                s, _t, m = fused_chunk_step(
-                    config, local(state), None, local(storage), size[0],
-                    k=k, batch_size=bsz)
-                return expand(s), expand(m)
-
-            fn = shard_map(body_u, mesh=self.mesh,
-                           in_specs=(R, R, R), out_specs=(R, R),
-                           check_vma=False)
-            jitted = jax.jit(fn, donate_argnums=(0,))
-        self._chunk_fns[k] = jitted
-        return jitted
+        fn = shard_map(body, mesh=self.mesh,
+                       in_specs=(R, R, R, R), out_specs=(R, R, R),
+                       check_vma=False)
+        self._chunk_fns[k] = jax.jit(fn, donate_argnums=(0, 1))
+        return self._chunk_fns[k]
 
     def _fused_steps(self, n: int) -> None:
         if self._storage is None:
@@ -253,13 +237,8 @@ class MeshReplicaGroup:
         done = 0
         while done < n:
             k = min(self.k, n - done)
-            fn = self._chunk_for(k)
-            if self._prioritized:
-                self._state, self._trees, self.last_metrics = fn(
-                    self._state, self._trees, self._storage, self._sizes)
-            else:
-                self._state, self.last_metrics = fn(
-                    self._state, self._storage, self._sizes)
+            self._state, self._trees, self.last_metrics = self._chunk_for(k)(
+                self._state, self._trees, self._storage, self._sizes)
             done += k
         self.steps_done += done
 
@@ -275,36 +254,18 @@ class MeshReplicaGroup:
             config = self._config
             R = partition.replica_spec()
 
-            def local(tree):
-                return _tree_map(lambda x: x[0], tree)
+            def body(state, batches, w):  # ``w`` None: uniform replay
+                s, m = multi_update_step(
+                    config, _local(state), _local(batches), _local(w))
+                return _expand(s), _expand(m)
 
-            def expand(tree):
-                return _tree_map(lambda x: x[None], tree)
-
-            use_w = weights is not None
-            if use_w:
-                def body(state, batches, w):
-                    s, m = multi_update_step(
-                        config, local(state), local(batches), local(w))
-                    return expand(s), expand(m)
-                specs = (R, R, R)
-            else:
-                def body(state, batches):
-                    s, m = multi_update_step(
-                        config, local(state), local(batches))
-                    return expand(s), expand(m)
-                specs = (R, R)
-            fn = shard_map(body, mesh=self.mesh, in_specs=specs,
+            fn = shard_map(body, mesh=self.mesh, in_specs=(R, R, R),
                            out_specs=(R, R), check_vma=False)
             self._update_fn = jax.jit(fn, donate_argnums=(0,))
         stack_sh = partition.replica_sharding(self.mesh)
-        batches = jax.device_put(batches, stack_sh)
-        if weights is not None:
-            weights = jax.device_put(weights, stack_sh)
-            self._state, metrics = self._update_fn(
-                self._state, batches, weights)
-        else:
-            self._state, metrics = self._update_fn(self._state, batches)
+        self._state, metrics = self._update_fn(
+            self._state, jax.device_put(batches, stack_sh),
+            jax.device_put(weights, stack_sh))
         self.steps_done += int(batches[0].shape[1])  # [N, K, B, ...] → K
         self.last_metrics = metrics
         return metrics

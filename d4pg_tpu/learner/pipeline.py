@@ -160,7 +160,8 @@ class ChunkPipeline:
     """Drives ``multi_update`` over prefetched chunks.
 
     ``sample_fn() -> ((batches, weights), aux)``: host-side sample of one
-    [K, B, ...] chunk; ``weights``/``aux`` are None for uniform replay.
+    [K, B, ...] chunk; ``weights``/``aux`` are None for uniform replay
+    (``update_fn(state, batches, None)``).
     ``write_back(aux, td)``: PER priority update, td shaped [K, B].
     ``sharding``: optional NamedSharding for the staged chunk (mesh path).
     """
@@ -171,14 +172,12 @@ class ChunkPipeline:
         sample_fn: Callable[[], tuple],
         write_back: Optional[Callable[[Any, np.ndarray], None]] = None,
         sharding=None,
-        use_weights: bool = True,
         fetch_td: Optional[Callable] = None,
         put_fn: Optional[Callable] = None,
         depth: int = 2,
     ):
         self._update = update_fn
         self._write_back = write_back
-        self._use_weights = use_weights
         # How to pull td_error to the host. Default: full fetch. Multi-host
         # passes a local-shard extractor (a host can only read its own rows
         # of the globally-sharded [K, B] td_error).
@@ -216,10 +215,7 @@ class ChunkPipeline:
         for i in range(n_chunks):
             prefetch = final_prefetch or (i + 1 < n_chunks)
             (batches, w), aux = self._stager.next(prefetch=prefetch)
-            if self._use_weights:
-                state, metrics = self._update(state, batches, w)
-            else:
-                state, metrics = self._update(state, batches)
+            state, metrics = self._update(state, batches, w)
             td = metrics.get("td_error") if self._write_back else None
             if td is not None and getattr(td, "is_fully_addressable", False):
                 # start the D2H copy now; by flush time the bytes are

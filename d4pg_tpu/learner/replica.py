@@ -159,8 +159,7 @@ class LearnerReplica:
                 donate=donate)
         else:
             from d4pg_tpu.learner.update import make_multi_update
-            self._update = make_multi_update(
-                config, donate=donate, use_is_weights=prioritized)
+            self._update = make_multi_update(config, donate=donate)
         # control state ONLY under this lock (see module doc)
         self._replica_lock = TieredLock("replica")
         self._stop = threading.Event()
@@ -200,7 +199,8 @@ class LearnerReplica:
             else:
                 batches, _w, _idx, _gen = svc.sample_chunk(
                     k, self._batch_size)
-                self._state, metrics = self._update(self._state, batches)
+                self._state, metrics = self._update(self._state, batches,
+                                                    None)
             self.last_metrics = metrics
             done += k
         if done:

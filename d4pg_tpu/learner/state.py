@@ -95,7 +95,7 @@ class D4PGConfig:
     # vector of ``torso.tokens`` values; the torso's parameters live in the
     # critic's tree ONLY (stored once: parameter, gradient, two Adam
     # moments, target copy), the actor's tree is its head, and the torso is
-    # trained by the critic loss alone (``update._torso_update_step``).
+    # trained by the critic loss alone (``update._torso_critic_loss``).
     torso: Any = None
 
     def __post_init__(self):

@@ -24,7 +24,7 @@ deterministic update (SURVEY.md §5 race-detection note).
 from __future__ import annotations
 
 from functools import partial
-from typing import Any
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -107,6 +107,164 @@ def _actor_loss_fn(
     return -jnp.mean(expected_q(config.support, probs)) + penalty
 
 
+def _augment(config: D4PGConfig, batch: TransitionBatch, key: Array):
+    """The plain family's batch hook: DrQ random shift on the sampled rows
+    (ops/augment.py). Both losses see the same augmented view; obs and
+    next_obs get independent shifts (DrQ's convention: the target should
+    not share the online view's crop). Returns the batch and the key the
+    critic loss draws from."""
+    if config.augment != "shift":
+        return batch, key
+    key, k_obs, k_next = jax.random.split(key, 3)
+    from d4pg_tpu.ops.augment import random_shift
+
+    with jax.named_scope("update.augment"):
+        batch = batch._replace(
+            obs=random_shift(k_obs, batch.obs, config.augment_pad),
+            next_obs=random_shift(k_next, batch.next_obs,
+                                  config.augment_pad),
+        )
+    return batch, key
+
+
+def _plain_critic_loss(config, state, batch, is_weights, key):
+    def loss_fn(p):
+        loss, td = _critic_loss_fn(config, p, state, batch, is_weights, key)
+        return loss, (loss, td, {})
+
+    return loss_fn
+
+
+def _plain_actor_loss(config, critic_params, batch):
+    return lambda p: _actor_loss_fn(config, p, critic_params, batch)
+
+
+def _expected_exit_loss(critic, params, proj, action, is_weights, beta,
+                        latents, logits):
+    """A looped torso's critic loss (Ouro's entropy-regularised objective
+    with a uniform prior, arXiv:2510.25741): ``l[t, i]`` the categorical TD
+    loss of the one critic head on pass ``t``'s latent of row ``i``, ``p``
+    the row's exit distribution (``torso.exit_distribution``); the loss is
+    the weighted mean over rows of ``sum_t p l`` less ``beta`` times the mean
+    entropy of ``p``. Returns ``(total, (first term, l of the last pass,
+    counters))``; ``exit_dist [R]`` is the mean over rows of ``p``,
+    ``loss_by_pass [R]`` the weighted mean of ``l`` a pass."""
+    td = jax.vmap(lambda z: cross_entropy_per_sample(
+        proj, critic.of_latent(params, z, action)))(latents)
+    p, entropy = exit_distribution(logits)
+    with jax.named_scope("torso.exit"):
+        loss = weighted_mean(jnp.sum(p * td, axis=0), is_weights)
+        counters = {
+            "exit_dist": jnp.mean(p, axis=1),
+            "loss_by_pass": jax.vmap(
+                lambda l: weighted_mean(l, is_weights))(td)}
+        return loss - beta * jnp.mean(entropy), (loss, td[-1], counters)
+
+
+def _torso_critic_loss(config, state, batch, is_weights, key):
+    """Passes 1 and 2 of a model with a shared sequence torso
+    (``config.torso``, models/torso.py). The torso is stored once, in the
+    critic's tree, and run once per input per phase: three forward passes
+    and one backward a step.
+
+      1. the TARGET torso on ``next_obs``; target actor and target critic
+         heads read its latent (the Bellman target);
+      2. the torso on ``obs`` under the critic loss, differentiated: the
+         critic loss alone trains it (as the shared pixel encoder), but for
+         a sparse-attention layer's indexer: nothing of the critic loss
+         reaches it (the selection is not differentiable), so the torso's
+         ``index_loss`` is added to the critic loss with no coefficient;
+         the two losses' parameter sets are disjoint and both step at
+         ``lr_critic``;
+      3. (``_torso_actor_loss``) the torso just stepped on ``obs`` again,
+         through a stop-gradient: the actor head and the stepped critic
+         head read it for the actor loss, which trains the actor's head
+         only.
+
+    The aux handed up becomes metrics: ``route_counts`` ``[layers with
+    experts, num_experts]`` int32, how many of pass 2's assignments the
+    router gave each expert; with sparse-attention layers also
+    ``select_counts`` ``[sparse layers, tokens / kv_chunk_size]`` int32
+    (pass 2's selections by block of keys) and ``index_loss``; with a
+    routing bias ``bias_swapped`` ``[layers with experts]`` int32, pass 2's
+    assignments that the bias changed. ``critic_loss`` stays the TD loss
+    alone.
+
+    A looped torso (``total_ut_steps`` > 1) runs all its passes in each of
+    the three; passes 1 and 3 read the last pass's latent, pass 2 every
+    pass's and the exit gate's logits (``_expected_exit_loss``: the
+    expectation of the TD loss over the exit distribution less an entropy
+    bonus; the gradient runs through all passes into the shared leaves and
+    the gate). ``critic_loss`` is the expectation alone, ``td_error`` the
+    last pass's (the network actors and targets use), and the metrics gain
+    ``exit_dist [R]`` and ``loss_by_pass [R]``. A torso without experts
+    reports no ``route_counts``."""
+    del key  # no loss of this family draws
+    actor, critic = config.build_actor(), config.build_critic()
+    z_next, _ = critic.latent(state.target_critic_params, batch.next_obs)
+    next_action = actor.apply(state.target_actor_params, z_next)
+    target_probs = critic.of_latent(state.target_critic_params, z_next,
+                                    next_action)
+    proj = jax.lax.stop_gradient(
+        categorical_projection(config.support, target_probs,
+                               batch.reward, batch.discount))
+
+    def loss_fn(p):
+        z, aux = critic.latent(p, batch.obs, train=True)
+        if "exit_logits" in aux:  # a looped torso: every pass's latent
+            return _expected_exit_loss(
+                critic, p, proj, batch.action, is_weights,
+                config.torso.exit_entropy_beta, aux["pass_latents"],
+                aux["exit_logits"])
+        loss, td = categorical_td_loss(
+            proj, critic.of_latent(p, z, batch.action), weights=is_weights)
+        total = loss + aux["index_loss"] if "index_loss" in aux else loss
+        return total, (loss, td, aux)
+
+    return loss_fn
+
+
+def _torso_balance(config, critic_params, aux):
+    """A router's load-balancing bias: the first torso state that no loss
+    trains (its gradient is exactly zero: it enters a top-k only) and no
+    optimizer steps; this pass's own load counter moves it, after the
+    step, and the target's follows by soft_update."""
+    return config.build_critic().balance(critic_params,
+                                         aux.get("route_counts"))
+
+
+def _torso_actor_loss(config, critic_params, batch):
+    actor, critic = config.build_actor(), config.build_critic()
+    z = jax.lax.stop_gradient(critic.latent(critic_params, batch.obs)[0])
+
+    def loss_fn(p):
+        action = actor.apply(p, z)
+        probs = critic.of_latent(critic_params, z, action)
+        return (-jnp.mean(expected_q(config.support, probs))
+                + config.action_l2 * jnp.mean(jnp.square(action)))
+
+    return loss_fn
+
+
+class _Family(NamedTuple):
+    """How a family of models reads its networks in the one update step:
+    whole networks on the observation (plain), or heads on a latent a
+    shared torso made once (torso). ``critic_loss`` and ``actor_loss`` run
+    what is not differentiated and return the function of the parameters
+    that is: ``(total, (critic_loss, td_error, aux))`` and a scalar."""
+    batch_hook: Callable
+    critic_loss: Callable
+    post_critic: Callable
+    actor_loss: Callable
+
+
+_PLAIN = _Family(_augment, _plain_critic_loss,
+                 lambda config, critic_params, aux: critic_params,
+                 _plain_actor_loss)
+_TORSO = _Family(lambda config, batch, key: (batch, key), _torso_critic_loss,
+                 _torso_balance, _torso_actor_loss)
+
+
 def update_step(
     config: D4PGConfig,
     state: D4PGState,
@@ -116,36 +274,21 @@ def update_step(
     """One full D4PG update. Pure; jit with config static.
 
     Returns the new state and a metrics dict containing scalar ``critic_loss``
-    / ``actor_loss`` / ``q_mean`` and the per-sample ``td_error`` vector (the
-    PER priority signal, ``ddpg.py:252-255``).
+    / ``actor_loss`` / ``q_mean``, the per-sample ``td_error`` vector (the
+    PER priority signal, ``ddpg.py:252-255``) and what the family's critic
+    loss handed up (``_torso_critic_loss``; nothing for a plain model).
     """
-    if config.torso is not None:
-        return _torso_update_step(config, state, batch, is_weights)
+    family = _PLAIN if config.torso is None else _TORSO
     key, sub = jax.random.split(state.key)
+    batch, sub = family.batch_hook(config, batch, sub)
 
-    if config.augment == "shift":
-        # DrQ random shift on the sampled rows (ops/augment.py): both
-        # losses see the same augmented view; obs and next_obs get
-        # independent shifts (DrQ's convention — the target should not
-        # share the online view's crop)
-        sub, k_obs, k_next = jax.random.split(sub, 3)
-        from d4pg_tpu.ops.augment import random_shift
-
-        with jax.named_scope("update.augment"):
-            batch = batch._replace(
-                obs=random_shift(k_obs, batch.obs, config.augment_pad),
-                next_obs=random_shift(k_next, batch.next_obs,
-                                      config.augment_pad),
-            )
-
-    # --- critic step. The named scopes (update.augment above,
+    # --- critic step. The named scopes (update.augment in the hook,
     # update.critic, update.actor, update.optim) are metadata only; a
     # trace reader splits the fused chunk's ``learner.update`` phase by
     # them (PERF.md section 3) ----------------------------------------------
     with jax.named_scope("update.critic"):
-        (critic_loss, td_error), critic_grads = jax.value_and_grad(
-            lambda p: _critic_loss_fn(config, p, state, batch, is_weights,
-                                      sub),
+        (_, (critic_loss, td_error, aux)), critic_grads = jax.value_and_grad(
+            family.critic_loss(config, state, batch, is_weights, sub),
             has_aux=True,
         )(state.critic_params)
     with jax.named_scope("update.optim"):
@@ -154,6 +297,7 @@ def update_step(
             critic_grads, state.critic_opt_state, state.critic_params)
         critic_params = optax.apply_updates(state.critic_params,
                                             critic_updates)
+        critic_params = family.post_critic(config, critic_params, aux)
 
     # --- shared-encoder tie (SAC-AE/DrQ): the actor's encoder subtree IS
     # the critic's, refreshed right after the critic step. Done on the
@@ -181,7 +325,7 @@ def update_step(
     # ``learner/state.py:34-41``). -----------------------------------------
     with jax.named_scope("update.actor"):
         actor_loss, actor_grads = jax.value_and_grad(
-            lambda p: _actor_loss_fn(config, p, critic_params, batch)
+            family.actor_loss(config, critic_params, batch)
         )(actor_params_in)
     with jax.named_scope("update.optim"):
         actor_updates, actor_opt_state = config.optimizer(
@@ -216,166 +360,56 @@ def update_step(
         "actor_loss": actor_loss,
         "q_mean": -actor_loss,
         "td_error": td_error,
+        **aux,
     }
     return new_state, metrics
 
 
-def _expected_exit_loss(critic, params, proj, action, is_weights, beta,
-                        latents, logits):
-    """A looped torso's critic loss (Ouro's entropy-regularised objective
-    with a uniform prior, arXiv:2510.25741): ``l[t, i]`` the categorical TD
-    loss of the one critic head on pass ``t``'s latent of row ``i``, ``p``
-    the row's exit distribution (``torso.exit_distribution``); the loss is
-    the weighted mean over rows of ``sum_t p l`` less ``beta`` times the mean
-    entropy of ``p``. Returns ``(total, (first term, l of the last pass,
-    counters))``; ``exit_dist [R]`` is the mean over rows of ``p``,
-    ``loss_by_pass [R]`` the weighted mean of ``l`` a pass."""
-    td = jax.vmap(lambda z: cross_entropy_per_sample(
-        proj, critic.of_latent(params, z, action)))(latents)
-    p, entropy = exit_distribution(logits)
-    with jax.named_scope("torso.exit"):
-        loss = weighted_mean(jnp.sum(p * td, axis=0), is_weights)
-        counters = {
-            "exit_dist": jnp.mean(p, axis=1),
-            "loss_by_pass": jax.vmap(
-                lambda l: weighted_mean(l, is_weights))(td)}
-        return loss - beta * jnp.mean(entropy), (loss, td[-1], counters)
+def mesh_shardings(config: D4PGConfig, mesh, td_error) -> tuple[Any, dict]:
+    """The state's shardings (by partition rule) and the metrics' (scalars
+    replicated, ``td_error`` as given) for a program over ``mesh``: the one
+    place a mesh enters the learner's builders, so the one place it is
+    refused (``check_mesh_compatible``)."""
+    from d4pg_tpu.parallel import partition
+    from d4pg_tpu.parallel.data_parallel import check_mesh_compatible
+
+    check_mesh_compatible(config)
+    repl = partition.replicated(mesh)
+    return partition.state_shardings(config, mesh), {
+        "critic_loss": repl, "actor_loss": repl, "q_mean": repl,
+        "td_error": td_error}
 
 
-def _torso_update_step(
-    config: D4PGConfig,
-    state: D4PGState,
-    batch: TransitionBatch,
-    is_weights: Array | None,
-) -> tuple[D4PGState, dict[str, Array]]:
-    """``update_step`` for a model with a shared sequence torso
-    (``config.torso``, models/torso.py). The torso is stored once, in the
-    critic's tree, and run once per input per phase: three forward passes
-    and one backward a step.
+def _jit_update(fn, config: D4PGConfig, mesh, donate: bool, stacked: bool):
+    """jit ``fn(state, batch, weights)``; with a mesh, data-parallel over
+    it: batch and IS weights ([B, ...]; ``stacked`` [K, B, ...]) with B
+    split over ``data``, ``td_error`` like them (it flows back to the host
+    PER update, ``ddpg.py:252-255``). A sharding is a pytree prefix: one
+    covers a tree, and no leaf of a ``None``. The loss's mean over the
+    global batch becomes an all-reduce over ICI; every replica then applies
+    the same Adam update."""
+    over = {}
+    if mesh is not None:
+        from d4pg_tpu.parallel import partition
 
-      1. the TARGET torso on ``next_obs``; target actor and target critic
-         heads read its latent (the Bellman target);
-      2. the torso on ``obs`` under the critic loss, differentiated: the
-         critic loss alone trains it (as the shared pixel encoder), but for
-         a sparse-attention layer's indexer: nothing of the critic loss
-         reaches it (the selection is not differentiable), so the torso's
-         ``index_loss`` is added to the critic loss with no coefficient;
-         the two losses' parameter sets are disjoint and both step at
-         ``lr_critic``;
-      3. the torso just stepped on ``obs`` again, through a stop-gradient:
-         the actor head and the stepped critic head read it for the actor
-         loss, which trains the actor's head only.
-
-    Metrics gain ``route_counts`` ``[layers with experts, num_experts]``
-    int32: how many of pass 2's assignments the router gave each expert;
-    with sparse-attention layers also ``select_counts`` ``[sparse layers,
-    tokens / kv_chunk_size]`` int32 (pass 2's selections by block of keys)
-    and ``index_loss``; with a routing bias ``bias_swapped`` ``[layers with
-    experts]`` int32, pass 2's assignments that the bias changed.
-    ``critic_loss`` stays the TD loss alone.
-
-    A looped torso (``total_ut_steps`` > 1) runs all its passes in each of
-    the three; passes 1 and 3 read the last pass's latent, pass 2 every
-    pass's and the exit gate's logits (``_expected_exit_loss``: the
-    expectation of the TD loss over the exit distribution less an entropy
-    bonus; the gradient runs through all passes into the shared leaves and
-    the gate). ``critic_loss`` is the expectation alone, ``td_error`` the
-    last pass's (the network actors and targets use), and the metrics gain
-    ``exit_dist [R]`` and ``loss_by_pass [R]``. A torso without experts
-    reports no ``route_counts``."""
-    key, _sub = jax.random.split(state.key)
-    actor, critic = config.build_actor(), config.build_critic()
-
-    with jax.named_scope("update.critic"):
-        z_next, _ = critic.latent(state.target_critic_params, batch.next_obs)
-        next_action = actor.apply(state.target_actor_params, z_next)
-        target_probs = critic.of_latent(state.target_critic_params, z_next,
-                                        next_action)
-        proj = jax.lax.stop_gradient(
-            categorical_projection(config.support, target_probs,
-                                   batch.reward, batch.discount))
-
-        def critic_loss_fn(p):
-            z, aux = critic.latent(p, batch.obs, train=True)
-            if "exit_logits" in aux:  # a looped torso: every pass's latent
-                return _expected_exit_loss(
-                    critic, p, proj, batch.action, is_weights,
-                    config.torso.exit_entropy_beta, aux["pass_latents"],
-                    aux["exit_logits"])
-            loss, td = categorical_td_loss(
-                proj, critic.of_latent(p, z, batch.action),
-                weights=is_weights)
-            total = loss + aux["index_loss"] if "index_loss" in aux else loss
-            return total, (loss, td, aux)
-
-        (_, (critic_loss, td_error, aux)), critic_grads = jax.value_and_grad(
-            critic_loss_fn, has_aux=True)(state.critic_params)
-    with jax.named_scope("update.optim"):
-        critic_updates, critic_opt_state = config.optimizer(
-            config.lr_critic).update(
-            critic_grads, state.critic_opt_state, state.critic_params)
-        critic_params = optax.apply_updates(state.critic_params,
-                                            critic_updates)
-        # a router's load-balancing bias: the first torso state that no
-        # loss trains (its gradient is exactly zero: it enters a top-k
-        # only) and no optimizer steps; this pass's own load counter moves
-        # it, after the step, and the target's follows by soft_update
-        critic_params = critic.balance(critic_params,
-                                       aux.get("route_counts"))
-
-    with jax.named_scope("update.actor"):
-        z = jax.lax.stop_gradient(critic.latent(critic_params, batch.obs)[0])
-
-        def actor_loss_fn(p):
-            action = actor.apply(p, z)
-            probs = critic.of_latent(critic_params, z, action)
-            return (-jnp.mean(expected_q(config.support, probs))
-                    + config.action_l2 * jnp.mean(jnp.square(action)))
-
-        actor_loss, actor_grads = jax.value_and_grad(actor_loss_fn)(
-            state.actor_params)
-    with jax.named_scope("update.optim"):
-        actor_updates, actor_opt_state = config.optimizer(
-            config.lr_actor).update(
-            actor_grads, state.actor_opt_state, state.actor_params)
-        actor_params = optax.apply_updates(state.actor_params, actor_updates)
-        target_actor_params = soft_update(
-            state.target_actor_params, actor_params, config.tau)
-        target_critic_params = soft_update(
-            state.target_critic_params, critic_params, config.tau)
-    new_state = D4PGState(
-        actor_params=actor_params,
-        critic_params=critic_params,
-        target_actor_params=target_actor_params,
-        target_critic_params=target_critic_params,
-        actor_opt_state=actor_opt_state,
-        critic_opt_state=critic_opt_state,
-        key=key,
-        step=state.step + 1,
-    )
-    metrics = {
-        "critic_loss": critic_loss,
-        "actor_loss": actor_loss,
-        "q_mean": -actor_loss,
-        "td_error": td_error,
-        **aux,  # route_counts; the sparse and the biased layers' counters;
-        # a looped torso's exit_dist and loss_by_pass
-    }
-    return new_state, metrics
+        rows = (partition.stacked_sharding if stacked
+                else partition.batch_sharding)(mesh)
+        state_sh, metrics_sh = mesh_shardings(config, mesh, td_error=rows)
+        over = dict(in_shardings=(state_sh, rows, rows),
+                    out_shardings=(state_sh, metrics_sh))
+    return jax.jit(fn, donate_argnums=(0,) if donate else (), **over)
 
 
-def make_update(config: D4PGConfig, donate: bool = True, use_is_weights: bool = True):
-    """jit-compile the update with ``config`` closed over statically.
+def make_update(config: D4PGConfig, *, mesh=None, donate: bool = True):
+    """jit the update with ``config`` closed over statically:
+    ``fn(state, batch, is_weights) -> (state, metrics)``. Uniform replay
+    passes ``None`` for the weights: an empty pytree, no operand.
 
     ``donate=True`` donates the input state's buffers so XLA updates
-    parameters in place (HBM-frugal). ``use_is_weights=False`` compiles the
-    uniform-replay variant without the weights operand.
-    """
-    if use_is_weights:
-        fn = lambda state, batch, w: update_step(config, state, batch, w)
-    else:
-        fn = lambda state, batch: update_step(config, state, batch, None)
-    return jax.jit(fn, donate_argnums=(0,) if donate else ())
+    parameters in place (HBM-frugal)."""
+    return _jit_update(
+        lambda state, batch, w: update_step(config, state, batch, w),
+        config, mesh, donate, stacked=False)
 
 
 def multi_update_step(
@@ -385,42 +419,31 @@ def multi_update_step(
     weights: Array | None = None,
 ):
     """K sequential updates via ``lax.scan`` over stacked batches — the pure
-    function behind :func:`make_multi_update` and the mesh-sharded variant
-    (``parallel.data_parallel.make_sharded_multi_update``).
+    function behind :func:`make_multi_update`.
 
     Inputs carry a leading K axis: batch fields [K, B, ...], weights
     [K, B]. Returns ``(state, metrics)`` with metrics stacked along K
     (``td_error`` [K, B] feeds the batched priority write-back).
     """
-    def body(s, xs):
-        if weights is not None:
-            b, w = xs
-            return update_step(config, s, b, w)
-        return update_step(config, s, xs, None)
-
-    xs = (batches, weights) if weights is not None else batches
-    return jax.lax.scan(body, state, xs)
+    # ``weights=None`` scans as it is: an empty pytree, ``None`` every step
+    return jax.lax.scan(lambda s, xs: update_step(config, s, *xs), state,
+                        (batches, weights))
 
 
-def make_multi_update(
-    config: D4PGConfig, donate: bool = True, use_is_weights: bool = True
-):
-    """jit :func:`multi_update_step` (K updates per device dispatch).
-
-    The single-step update is dispatch-bound on TPU (measured ~4.2k
-    steps/sec single vs ~69k at K=16 on one v5e chip, batch 256): each
-    step's compute is ~15us while the Python->device round trip costs
-    ~240us. Scanning K steps amortizes the dispatch. Semantically identical
-    to K sequential ``update_step`` calls (the PRNG chain threads through
-    the carried state); for PER the K priority updates land after the scan,
-    i.e. with staleness < K (standard in high-throughput actor-learner
-    pipelines).
+def make_multi_update(config: D4PGConfig, *, mesh=None, donate: bool = True):
+    """jit :func:`multi_update_step`, K updates per device dispatch:
+    ``fn(state, batches, weights) -> (state, metrics)``, ``None`` weights
+    for uniform replay. Scanning K steps amortizes the dispatch of a step
+    whose compute is tens of microseconds; with a mesh the scan axis K
+    stays replicated. Semantically identical to K sequential
+    ``update_step`` calls (the PRNG chain threads through the carried
+    state); for PER the K priority updates land after the scan, i.e. with
+    staleness < K (standard in high-throughput actor-learner pipelines).
     """
-    if use_is_weights:
-        fn = lambda state, batches, w: multi_update_step(config, state, batches, w)
-    else:
-        fn = lambda state, batches: multi_update_step(config, state, batches)
-    return jax.jit(fn, donate_argnums=(0,) if donate else ())
+    return _jit_update(
+        lambda state, batches, w: multi_update_step(config, state, batches,
+                                                    w),
+        config, mesh, donate, stacked=True)
 
 
 def policy_params(config: D4PGConfig, state: D4PGState) -> Any:

@@ -13,8 +13,6 @@ the pixel-encoder config can shard activations later (SURVEY.md §2 mandate).
 from d4pg_tpu.parallel.mesh import MeshSpec, make_mesh, replica_mesh
 from d4pg_tpu.parallel import partition
 from d4pg_tpu.parallel.data_parallel import (
-    make_sharded_multi_update,
-    make_sharded_update,
     replicate_state,
     shard_batch,
     shard_stacked,
@@ -24,8 +22,6 @@ from d4pg_tpu.parallel.data_parallel import (
 __all__ = [
     "MeshSpec",
     "make_mesh",
-    "make_sharded_multi_update",
-    "make_sharded_update",
     "partition",
     "replica_mesh",
     "replicate_state",

@@ -48,8 +48,7 @@ def main(argv=None) -> None:
     multihost.initialize(ns.coordinator, ns.num_processes, ns.process_id)
     assert jax.process_count() == ns.num_processes
 
-    from d4pg_tpu.learner import D4PGConfig, init_state
-    from d4pg_tpu.parallel.data_parallel import make_sharded_update
+    from d4pg_tpu.learner import D4PGConfig, init_state, make_update
     from d4pg_tpu.replay.uniform import TransitionBatch
 
     mesh = multihost.global_mesh()
@@ -62,8 +61,7 @@ def main(argv=None) -> None:
     # identical seed on every process -> identical replicated state
     state = multihost.replicate_state_global(
         partial(init_state, config, jax.random.key(0)), mesh)
-    update = make_sharded_update(config, mesh, donate=True,
-                                 use_is_weights=False)
+    update = make_update(config, mesh=mesh, donate=True)
 
     # each process samples ITS shard of the global batch
     rng = np.random.default_rng(100 + ns.process_id)
@@ -82,15 +80,15 @@ def main(argv=None) -> None:
         # drains ITS rows into its local shards (collective insert), then
         # both run the fused chunk — sample + update + priority write-back
         # all inside one SPMD dispatch over the global mesh.
-        from d4pg_tpu.learner.fused import make_sharded_fused_chunk
+        from d4pg_tpu.learner.fused import make_fused_chunk
         from d4pg_tpu.replay.sharded_per import ShardedFusedReplay
 
         buf = ShardedFusedReplay(256, obs_dim, act_dim, mesh, alpha=0.6)
         for _ in range(4):
             buf.add(local)
             buf.drain()
-        fn = make_sharded_fused_chunk(config, mesh, k=2, batch_size=16,
-                                      alpha=0.6, donate=False)
+        fn = make_fused_chunk(config, mesh=mesh, k=2, batch_size=16,
+                              alpha=0.6, donate=False)
         trees = buf.trees
         for _ in range(2):
             state, trees, metrics = fn(state, trees, buf.storage, buf.size)
@@ -106,7 +104,7 @@ def main(argv=None) -> None:
     else:
         for _ in range(2):
             batch = multihost.make_global_batch(local, mesh)
-            state, metrics = update(state, batch)
+            state, metrics = update(state, batch, None)
             losses.append(float(jax.device_get(metrics["critic_loss"])))
         assert int(jax.device_get(state.step)) == 2
     assert all(np.isfinite(losses))

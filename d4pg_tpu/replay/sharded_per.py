@@ -6,7 +6,7 @@ The multi-chip extension of the fused replay path (``device_ring.py`` /
 the data axis owns a shard of the transition ring and its own PER
 sum/min tree pair; sampling, gathering and priority write-back run
 per-shard inside the sharded learner dispatch (``learner/fused.py``'s
-``make_sharded_fused_chunk``) — so the production configuration
+``make_fused_chunk(mesh=...)``) — so the production configuration
 (K-step scan x data parallelism) keeps ZERO per-chunk host round trips
 and the batch rows never cross devices (each shard contributes
 ``B / n_shards`` rows; only gradients ride the ICI collectives).

@@ -57,8 +57,6 @@ from d4pg_tpu.learner.pipeline import ChunkPipeline
 from d4pg_tpu.parallel import (
     MeshSpec,
     make_mesh,
-    make_sharded_multi_update,
-    make_sharded_update,
     replicate_state,
     shard_batch,
     stacked_sharding,
@@ -307,7 +305,7 @@ def train(cfg: ExperimentConfig) -> dict:
     obs_dim, act_dim, obs_dtype = infer_dims(cfg)
     config = cfg.learner_config(obs_dim, act_dim)
 
-    # --- learner state + update (single-device or sharded) ----------------
+    # --- learner state (placed three ways) + update (one builder) ---------
     mesh = None
     if multi_host:
         from functools import partial
@@ -320,19 +318,14 @@ def train(cfg: ExperimentConfig) -> dict:
         # other hosts' devices
         state = multihost.replicate_state_global(
             partial(init_state, config, jax.random.key(cfg.seed)), mesh)
-        update = make_sharded_update(config, mesh, donate=True,
-                                     use_is_weights=cfg.prioritized_replay)
     elif cfg.data_parallel > 1:
         mesh = make_mesh(MeshSpec(data_parallel=cfg.data_parallel),
                          devices=jax.devices()[:cfg.data_parallel])
         state = replicate_state(init_state(config, jax.random.key(cfg.seed)),
                                 mesh)
-        update = make_sharded_update(config, mesh, donate=True,
-                                     use_is_weights=cfg.prioritized_replay)
     else:
         state = init_state(config, jax.random.key(cfg.seed))
-        update = make_update(config, donate=True,
-                             use_is_weights=cfg.prioritized_replay)
+    update = make_update(config, mesh=mesh, donate=True)
 
     # --- replay + schedule ------------------------------------------------
     storage = cfg.replay_storage
@@ -880,16 +873,8 @@ def train(cfg: ExperimentConfig) -> dict:
     # data parallelism: batches are stacked [K, B, ...] with K replicated
     # (the scan axis) and B sharded over ``data``.
     K = max(1, cfg.updates_per_dispatch)
-    if K > 1 and not fused:
-        if mesh is not None:
-            multi_update = make_sharded_multi_update(
-                config, mesh, donate=True,
-                use_is_weights=cfg.prioritized_replay)
-        else:
-            multi_update = make_multi_update(
-                config, donate=True, use_is_weights=cfg.prioritized_replay)
-    else:
-        multi_update = None
+    multi_update = (make_multi_update(config, mesh=mesh, donate=True)
+                    if K > 1 and not fused else None)
     chunk_sharding = stacked_sharding(mesh) if mesh is not None else None
 
     # Fully-fused chunks (learner/fused.py): sample + gather + update +
@@ -994,7 +979,6 @@ def train(cfg: ExperimentConfig) -> dict:
             multi_update, _sample_chunk,
             write_back=_per_write_back if cfg.prioritized_replay else None,
             sharding=chunk_sharding,
-            use_weights=cfg.prioritized_replay,
             # multi-host: stage chunks by assembling the global [K, B, ...]
             # array from each process's local sample, and pull back only
             # this host's td_error rows for its PER write-back
@@ -1036,20 +1020,18 @@ def train(cfg: ExperimentConfig) -> dict:
             batch, w, idx, gen = service.sample(
                 cfg.batch_size, beta=beta.value(lstep),
                 weight_base=weight_base_cell["z"])
-            batch = _stage_single(batch)
             w = _stage_single(np.asarray(w, np.float32))
-            state, metrics = update(state, batch, w)
-            lstep += 1
+        else:
+            batch, w = service.sample(cfg.batch_size), None
+        state, metrics = update(state, _stage_single(batch), w)
+        lstep += 1
+        if cfg.prioritized_replay:
             # each host writes back only ITS rows of the (possibly
             # globally-sharded) td_error — they are the ones its local
             # buffer sampled
             td = (multihost.local_rows(metrics["td_error"], axis=0)
                   if multi_host else np.asarray(metrics["td_error"]))
             service.update_priorities(idx, np.abs(td) + 1e-6, generation=gen)
-        else:
-            batch = _stage_single(service.sample(cfg.batch_size))
-            state, metrics = update(state, batch)
-            lstep += 1
         return metrics
 
     def train_steps(n: int):
@@ -1170,8 +1152,7 @@ def train(cfg: ExperimentConfig) -> dict:
                 mode=cfg.agg_mode, clip=cfg.agg_clip, store=weights,
                 # actors pull acting params only, as with the aggregator
                 extract=lambda tree: tree["actor_params"],
-                norm_stats=_norm_snapshot,
-                prioritized=cfg.prioritized_replay, alpha=cfg.per_alpha,
+                norm_stats=_norm_snapshot, alpha=cfg.per_alpha,
                 beta0=cfg.per_beta0, beta_steps=cfg.per_beta_steps)
             print(f"learner plane: {n_learners} mesh-native replicas "
                   f"(collective merge), mode={cfg.agg_mode} "

@@ -995,8 +995,8 @@ def test_fused_chunk_per_step_and_priorities(rng):
     state = init_state(config, jax.random.key(0))
     storage = _fill_storage(rng, CAP, 4, 2)
     trees = dper.insert(dper.init(CAP), jnp.arange(CAP), 0.6)
-    fn = make_fused_chunk(config, k=1, batch_size=8, prioritized=True,
-                          alpha=0.6, donate=False)
+    fn = make_fused_chunk(config, k=1, batch_size=8, alpha=0.6,
+                          donate=False)
     state2, trees2, m = fn(state, trees, storage, CAP)
     assert int(state2.step) == int(state.step) + 1
     # with k=1 no resampling can overwrite: leaf at each sampled idx must
@@ -1048,9 +1048,9 @@ def test_fused_chunk_uniform_variant(rng):
                         hidden=(16, 16, 16))
     state = init_state(config, jax.random.key(0))
     storage = _fill_storage(rng, CAP, 4, 2)
-    fn = make_fused_chunk(config, k=3, batch_size=8, prioritized=False,
-                          donate=False)
-    state2, m = fn(state, storage, jnp.int32(CAP))
+    fn = make_fused_chunk(config, k=3, batch_size=8, donate=False)
+    state2, no_trees, m = fn(state, None, storage, jnp.int32(CAP))
+    assert no_trees is None
     assert int(state2.step) == 3
     idx = np.asarray(m["idx"])
     assert idx.min() >= 0 and idx.max() < CAP

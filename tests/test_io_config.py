@@ -74,7 +74,7 @@ def test_checkpoint_roundtrip_and_resume(tmp_path, rng):
     config = D4PGConfig(obs_dim=3, act_dim=1, v_min=-5, v_max=0, n_atoms=11,
                         hidden=(16, 16))
     state = init_state(config, jax.random.key(0))
-    update = make_update(config, donate=False, use_is_weights=False)
+    update = make_update(config, donate=False)
     done = np.zeros(8, np.float32)
     batch = TransitionBatch(
         obs=rng.standard_normal((8, 3)).astype(np.float32),
@@ -85,7 +85,7 @@ def test_checkpoint_roundtrip_and_resume(tmp_path, rng):
         discount=(0.99 * (1 - done)).astype(np.float32),
     )
     for _ in range(3):
-        state, _ = update(state, batch)
+        state, _ = update(state, batch, None)
 
     mgr = CheckpointManager(str(tmp_path / "ckpt"))
     mgr.save(state, extra={"env_steps": 123})
@@ -100,8 +100,8 @@ def test_checkpoint_roundtrip_and_resume(tmp_path, rng):
                     jax.tree_util.tree_leaves(restored.actor_params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     # continued training from the restore matches continued training live
-    s_live, _ = update(state, batch)
-    s_resumed, _ = update(restored, batch)
+    s_live, _ = update(state, batch, None)
+    s_resumed, _ = update(restored, batch, None)
     for a, b in zip(jax.tree_util.tree_leaves(s_live.critic_params),
                     jax.tree_util.tree_leaves(s_resumed.critic_params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

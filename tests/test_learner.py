@@ -77,11 +77,11 @@ def test_loss_decreases_on_fixed_task(rng):
     """On a fixed batch, repeated updates must reduce the critic loss."""
     config = _config(lr_actor=1e-3, lr_critic=1e-3)
     state = init_state(config, jax.random.key(1))
-    update = make_update(config, donate=False, use_is_weights=False)
+    update = make_update(config, donate=False)
     batch = _batch(rng)
     first = None
     for i in range(60):
-        state, metrics = update(state, batch)
+        state, metrics = update(state, batch, None)
         if first is None:
             first = float(metrics["critic_loss"])
     assert float(metrics["critic_loss"]) < first
@@ -95,9 +95,9 @@ def test_determinism_same_seed(rng):
     outs = []
     for _ in range(2):
         state = init_state(config, jax.random.key(7))
-        update = make_update(config, donate=False, use_is_weights=False)
+        update = make_update(config, donate=False)
         for _ in range(3):
-            state, _ = update(state, batch)
+            state, _ = update(state, batch, None)
         outs.append(state)
     for a, b in zip(
         jax.tree_util.tree_leaves(outs[0].actor_params),
@@ -130,11 +130,11 @@ def test_mog_family_end_to_end(rng):
     85-87), implemented for real: full update runs and improves."""
     config = _config(critic_family="mog", n_components=3, mog_samples=16)
     state = init_state(config, jax.random.key(3))
-    update = make_update(config, donate=False, use_is_weights=False)
+    update = make_update(config, donate=False)
     batch = _batch(rng)
     first = None
     for _ in range(40):
-        state, metrics = update(state, batch)
+        state, metrics = update(state, batch, None)
         if first is None:
             first = float(metrics["critic_loss"])
     assert np.isfinite(float(metrics["critic_loss"]))
@@ -207,11 +207,11 @@ def test_bfloat16_compute_dtype(rng):
     and the critic still improves on a fixed task."""
     config = _config(compute_dtype="bfloat16")
     state = init_state(config, jax.random.key(6))
-    update = make_update(config, donate=False, use_is_weights=False)
+    update = make_update(config, donate=False)
     batch = _batch(rng)
     first = None
     for _ in range(40):
-        state, metrics = update(state, batch)
+        state, metrics = update(state, batch, None)
         if first is None:
             first = float(metrics["critic_loss"])
     assert metrics["critic_loss"].dtype == jnp.float32
@@ -256,3 +256,161 @@ def test_bad_projection_rejected(projection):
     no longer be handed anything but the einsum."""
     with pytest.raises(ValueError, match="projection"):
         _config(projection=projection)
+
+
+# --- the one step's tail, for every family of model (PR 44) ----------------
+
+TORSO_FILES = {"mellum2": "test_torso", "keye2": "test_torso_sparse",
+               "lfm2": "test_torso_hybrid", "qwen3next": "test_torso_linear",
+               "ouro": "test_torso_loop"}
+
+
+def _case(name, rng):
+    """``(config, batch)`` of a small model of each family: a plain MLP, the
+    mixture-of-Gaussians critic, pixels with the shared encoder and the DrQ
+    shift, and the small torso of each name from its own test file."""
+    if name in TORSO_FILES:
+        import importlib
+
+        mod = importlib.import_module(TORSO_FILES[name])
+        assert mod.MODEL["torso"]["name"] == name
+        return D4PGConfig(**mod.MODEL), mod.small_batch()
+    if name == "pixels":
+        shape = (16, 16, 3)
+        config = D4PGConfig(
+            obs_dim=int(np.prod(shape)), act_dim=2, v_min=-20.0, v_max=0.0,
+            n_atoms=11, hidden=(32, 32), pixels=True, obs_shape=shape,
+            share_encoder=True, augment="shift", augment_pad=2)
+        n = 8
+        return config, TransitionBatch(
+            obs=rng.integers(0, 255, (n, *shape), dtype=np.uint8),
+            action=rng.uniform(-1, 1, (n, 2)).astype(np.float32),
+            reward=rng.standard_normal(n).astype(np.float32),
+            next_obs=rng.integers(0, 255, (n, *shape), dtype=np.uint8),
+            done=np.zeros(n, np.float32),
+            discount=np.full(n, 0.99, np.float32))
+    if name == "mog":
+        return _config(critic_family="mog", n_components=3,
+                       mog_samples=8), _batch(rng)
+    return _config(), _batch(rng)
+
+
+def _leaves(tree):
+    """Leaves by path, as numpy (a PRNG key as its data)."""
+    return {jax.tree_util.keystr(path): np.asarray(
+        jax.random.key_data(x) if jnp.issubdtype(x.dtype, jax.dtypes.prng_key)
+        else x) for path, x in jax.tree_util.tree_leaves_with_path(tree)}
+
+
+@pytest.mark.parametrize(
+    "name", ["mlp", "mog", "pixels", *sorted(TORSO_FILES)])
+def test_the_steps_tail_is_the_same_for_every_family(name, rng):
+    """What ``update_step`` does after the two losses, whatever reads the
+    networks: the step counts up, the key is the first half of the split,
+    both targets are ``soft_update(old target, new online, tau)`` bitwise
+    (the target actor's encoder tied after it where it is shared), and a
+    parameter that neither loss reaches and no optimizer moves stays as it
+    was, but for a router's balancing bias, which moves."""
+    from d4pg_tpu.core.updates import soft_update, tie_encoder
+    from d4pg_tpu.learner import update as update_lib
+
+    config, batch = _case(name, rng)
+    state = init_state(config, jax.random.key(3))
+    w = jnp.linspace(0.5, 1.0, batch.reward.shape[0])
+    new, metrics = make_update(config, donate=False)(state, batch, w)
+
+    assert int(new.step) == int(state.step) + 1
+    key, _sub = jax.random.split(state.key)
+    np.testing.assert_array_equal(jax.random.key_data(new.key),
+                                  jax.random.key_data(key))
+    assert set(metrics) >= {"critic_loss", "actor_loss", "q_mean",
+                            "td_error"}
+    assert float(metrics["q_mean"]) == -float(metrics["actor_loss"])
+
+    soft = jax.jit(lambda t, o: soft_update(t, o, config.tau))
+    want_critic = soft(state.target_critic_params, new.critic_params)
+    want_actor = soft(state.target_actor_params, new.actor_params)
+    if config.share_encoder:
+        want_actor = tie_encoder(want_actor, want_critic)
+    for got, want in ((new.target_critic_params, want_critic),
+                      (new.target_actor_params, want_actor)):
+        got, want = _leaves(got), _leaves(want)
+        assert got.keys() == want.keys()
+        for path in got:
+            np.testing.assert_array_equal(got[path], want[path], path)
+
+    # the critic's leaves the critic loss does not reach: from zero Adam
+    # moments the optimizer leaves them where they were
+    family = update_lib._PLAIN if config.torso is None else update_lib._TORSO
+    key, sub = jax.random.split(state.key)
+    hooked, sub = family.batch_hook(config, batch, sub)
+    loss_fn = family.critic_loss(config, state, hooked, w, sub)
+    grads = _leaves(jax.jit(jax.grad(lambda p: loss_fn(p)[0]))(
+        state.critic_params))
+    old, stepped = _leaves(state.critic_params), _leaves(new.critic_params)
+    unreached = [p for p, g in grads.items() if not g.any()]
+    moved = [p for p in unreached if not np.array_equal(old[p], stepped[p])]
+    biased = config.torso is not None and config.torso.use_expert_bias
+    assert all("router" in p and p.endswith("['bias']") for p in moved), moved
+    assert bool(moved) == biased, (moved, unreached)
+    # and what the loss reaches, the optimizer moved
+    reached = [p for p in grads if p not in unreached]
+    assert reached and all(
+        not np.array_equal(old[p], stepped[p]) for p in reached)
+
+
+def _uniform_program(builder, config, batch):
+    """``(the builder's jitted function, its operands with and without
+    weights or trees, the pure function jitted by hand)``."""
+    from d4pg_tpu.learner import make_multi_update
+    from d4pg_tpu.learner.fused import (device_replay, fused_chunk_step,
+                                        make_fused_chunk)
+    from d4pg_tpu.learner.update import multi_update_step, update_step
+    from d4pg_tpu.replay import device_per as dper
+
+    n = batch.reward.shape[0]
+    if builder == "update":
+        return (make_update(config, donate=False),
+                (batch, jnp.ones((n,), jnp.float32)), (batch, None),
+                jax.jit(lambda s, b: update_step(config, s, b, None)))
+    if builder == "multi_update":
+        stacked = jax.tree_util.tree_map(lambda x: np.stack([x, x[::-1]]),
+                                         batch)
+        return (make_multi_update(config, donate=False),
+                (stacked, jnp.ones((2, n), jnp.float32)), (stacked, None),
+                jax.jit(lambda s, b: multi_update_step(config, s, b, None)))
+    trees = dper.insert(dper.init(n), jnp.arange(n), 0.6)
+    sample, write_back = device_replay(8, 0.6, 0.4, 100_000)
+    return (make_fused_chunk(config, k=2, batch_size=8, donate=False),
+            (trees, batch, jnp.int32(n)), (None, batch, jnp.int32(n)),
+            jax.jit(lambda s, st, size: fused_chunk_step(
+                config, s, None, st, size, k=2, sample=sample,
+                write_back=write_back)))
+
+
+@pytest.mark.parametrize("builder", ["update", "multi_update", "fused_chunk"])
+def test_uniform_replay_is_none_and_one_operand_fewer(builder, rng):
+    """A caller with uniform replay passes ``None`` for the weights (the
+    trees): an empty pytree, so the program the one builder compiles for
+    that call has no such operand, and is bitwise ``jax.jit`` of the pure
+    function called by hand."""
+    config, batch = _config(), _batch(rng)
+    state = init_state(config, jax.random.key(5))
+    fn, per, uniform, by_hand = _uniform_program(builder, config, batch)
+
+    def operands(*args):
+        text = fn.lower(state, *args).as_text()
+        main = text[text.index("func.func public @main("):]
+        return main[:main.index("{\n")].count("%arg")
+
+    dropped = 3 if builder == "fused_chunk" else 1  # a PerTrees: 3 arrays
+    assert operands(*per) - operands(*uniform) == dropped
+
+    got = fn(state, *uniform)
+    want = by_hand(state, *[a for a in uniform if a is not None])
+    if builder == "fused_chunk":
+        assert got[1] is None and want[1] is None
+    got, want = _leaves(got), _leaves(want)
+    assert got.keys() == want.keys()
+    for path in got:
+        np.testing.assert_array_equal(got[path], want[path], path)

@@ -7,7 +7,7 @@ composition fault, caught only on the single-device path).
 Tiny shapes on the 8-virtual-CPU-device mesh: --share_encoder
 --frame_stack 3 --augment shift resolved through ExperimentConfig (the
 real flag path), uint8 pixel rows in the sharded device ring,
-one fused chunk through make_sharded_fused_chunk.
+one fused chunk through make_fused_chunk.
 
 Plus the real-shape EQUIVALENCE gate (ISSUE 14): the same 84x84xstack
 [K, B] pixel chunk through the rule-sharded {data, model} scanned
@@ -24,7 +24,7 @@ import pytest
 
 from d4pg_tpu.config import ExperimentConfig
 from d4pg_tpu.learner import init_state
-from d4pg_tpu.learner.fused import make_sharded_fused_chunk
+from d4pg_tpu.learner.fused import make_fused_chunk
 from d4pg_tpu.parallel import MeshSpec, make_mesh
 from d4pg_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from d4pg_tpu.replay.sharded_per import ShardedFusedReplay
@@ -82,7 +82,7 @@ def test_pixel_share_encoder_fused_chunk_on_data_model_mesh(rng):
     assert np.asarray(buf.storage.obs).dtype == np.uint8  # packed pixels
 
     state = init_state(config, jax.random.key(0))
-    fn = make_sharded_fused_chunk(config, mesh, k=2, batch_size=16,
+    fn = make_fused_chunk(config, mesh=mesh, k=2, batch_size=16,
                                   alpha=0.6, donate=False)
     s1, t1, m = fn(state, buf.trees, buf.storage, buf.size)
     assert int(jax.device_get(s1.step)) == 2
@@ -101,7 +101,6 @@ def test_pixel_mesh_chunk_matches_single_device_shapes(rng):
     """The data-parallel pixel chunk and the single-device fused chunk
     agree on metric/state structure (composition produces the same
     training artifacts the single-device path does)."""
-    from d4pg_tpu.learner.fused import make_fused_chunk
     from d4pg_tpu.replay.fused_buffer import FusedDeviceReplay
 
     mesh = make_mesh(MeshSpec(data_parallel=2, model_parallel=1),
@@ -115,7 +114,7 @@ def test_pixel_mesh_chunk_matches_single_device_shapes(rng):
     for b in (buf_m, buf_s):
         b.add(batch)
         b.drain()
-    fn_m = make_sharded_fused_chunk(config, mesh, k=2, batch_size=16,
+    fn_m = make_fused_chunk(config, mesh=mesh, k=2, batch_size=16,
                                     alpha=0.6, donate=False)
     fn_s = make_fused_chunk(config, k=2, batch_size=16, alpha=0.6,
                             donate=False)
@@ -139,7 +138,6 @@ def test_real_shape_pixel_mesh_update_matches_single_device(rng):
     the all-reduced loss only take their production shapes here."""
     from d4pg_tpu.learner.replica import PARAM_FIELDS
     from d4pg_tpu.learner.update import make_multi_update
-    from d4pg_tpu.parallel import make_sharded_multi_update
     from d4pg_tpu.parallel.data_parallel import (
         replicate_state,
         shard_stacked,
@@ -162,7 +160,7 @@ def test_real_shape_pixel_mesh_update_matches_single_device(rng):
 
     mesh = make_mesh(MeshSpec(data_parallel=2, model_parallel=2),
                      devices=jax.devices()[:4])
-    fn_mesh = make_sharded_multi_update(config, mesh, donate=False)
+    fn_mesh = make_multi_update(config, mesh=mesh, donate=False)
     s_mesh, m_mesh = fn_mesh(replicate_state(state0, mesh),
                              shard_stacked(batches, mesh),
                              shard_stacked(w, mesh))

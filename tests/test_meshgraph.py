@@ -508,8 +508,8 @@ def test_fused_learner_path_has_zero_reshards(rng):
         discount=jnp.full(cap, 0.99, jnp.float32),
     )
     trees = dper.insert(dper.init(cap), jnp.arange(cap), 0.6)
-    fn = make_fused_chunk(config, k=2, batch_size=8, prioritized=True,
-                          alpha=0.6, donate=False)
+    fn = make_fused_chunk(config, k=2, batch_size=8, alpha=0.6,
+                          donate=False)
     sentinel = ReshardSentinel()
     sentinel.inspect(fn, state, trees, storage, cap)
     sentinel.assert_clean("fused learner path")

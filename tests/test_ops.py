@@ -137,8 +137,8 @@ def test_update_step_with_shift_augmentation(rng):
             v_min=-5.0, v_max=0.0, n_atoms=11, hidden=(16, 16),
             augment=aug)
         state = init_state(config, jax.random.key(0))
-        update = make_update(config, donate=False, use_is_weights=False)
-        state, metrics = update(state, batch)
+        update = make_update(config, donate=False)
+        state, metrics = update(state, batch, None)
         assert np.isfinite(float(metrics["critic_loss"]))
         losses[aug] = float(metrics["critic_loss"])
     assert losses["none"] != losses["shift"]

@@ -15,7 +15,6 @@ from d4pg_tpu.learner import D4PGConfig, init_state, make_update
 from d4pg_tpu.parallel import (
     MeshSpec,
     make_mesh,
-    make_sharded_update,
     replicate_state,
     shard_batch,
 )
@@ -78,7 +77,7 @@ def test_sharded_update_matches_single_device(rng):
 
     mesh = make_mesh()
     sh_state = replicate_state(init_state(config, jax.random.key(42)), mesh)
-    sh_update = make_sharded_update(config, mesh, donate=False)
+    sh_update = make_update(config, mesh=mesh, donate=False)
     sh_next, sh_metrics = sh_update(sh_state, shard_batch(batch, mesh),
                                     shard_batch(jnp.asarray(w), mesh))
 
@@ -101,9 +100,9 @@ def test_sharded_update_multi_step_stability(rng):
     config = _config()
     mesh = make_mesh()
     state = replicate_state(init_state(config, jax.random.key(1)), mesh)
-    update = make_sharded_update(config, mesh, donate=False, use_is_weights=False)
+    update = make_update(config, mesh=mesh, donate=False)
     for _ in range(3):
-        state, metrics = update(state, shard_batch(_batch(rng), mesh))
+        state, metrics = update(state, shard_batch(_batch(rng), mesh), None)
     leaf = jax.tree_util.tree_leaves(state.actor_params)[0]
     assert leaf.sharding.is_fully_replicated
     assert np.isfinite(float(metrics["critic_loss"]))
@@ -113,7 +112,8 @@ def test_sharded_update_multi_step_stability(rng):
 def test_sharded_multi_update_matches_sequential(rng):
     """The production config (VERDICT r1 #3): K scanned updates sharded over
     the data axis == K sequential sharded updates on the same batches."""
-    from d4pg_tpu.parallel import make_sharded_multi_update, shard_stacked
+    from d4pg_tpu.learner import make_multi_update
+    from d4pg_tpu.parallel import shard_stacked
 
     config = _config()
     K = 4
@@ -122,7 +122,7 @@ def test_sharded_multi_update_matches_sequential(rng):
 
     mesh = make_mesh(MeshSpec(data_parallel=4), devices=jax.devices()[:4])
     seq_state = replicate_state(init_state(config, jax.random.key(7)), mesh)
-    seq_update = make_sharded_update(config, mesh, donate=False)
+    seq_update = make_update(config, mesh=mesh, donate=False)
     seq_tds = []
     for b in batches:
         seq_state, m = seq_update(seq_state, shard_batch(b, mesh),
@@ -131,7 +131,7 @@ def test_sharded_multi_update_matches_sequential(rng):
 
     stacked = TransitionBatch(*[np.stack(x) for x in zip(*batches)])
     multi_state = replicate_state(init_state(config, jax.random.key(7)), mesh)
-    multi_update = make_sharded_multi_update(config, mesh, donate=False)
+    multi_update = make_multi_update(config, mesh=mesh, donate=False)
     multi_state, ms = multi_update(
         multi_state,
         shard_stacked(stacked, mesh),
@@ -168,3 +168,72 @@ def test_train_mesh_with_updates_per_dispatch(tmp_path):
     metrics = train(cfg)
     assert np.isfinite(metrics["critic_loss"])
     assert "avg_test_reward" in metrics
+
+
+def _tree_equal(got, want):
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(want)):
+        if jnp.issubdtype(a.dtype, jax.dtypes.prng_key):
+            a, b = jax.random.key_data(a), jax.random.key_data(b)
+        np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(b).reshape(np.shape(a)),
+            jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize(
+    "program", ["update", "multi_update", "fused_uniform", "fused_per"])
+def test_a_mesh_of_one_device_is_no_mesh_bit_for_bit(program, rng):
+    """``mesh=`` is layout only: each of the three builders gives bitwise
+    the same state and metrics over a mesh of one device as without one
+    (PR 14 pinned this for ``mesh_replicas``). The fused chunk's mesh pair
+    folds the shard's index into the sampling key, so its draws differ by
+    design: its ring holds one row B times over, so that every draw is the
+    same batch (PER: one step, before a write-back tells rows apart)."""
+    from d4pg_tpu.learner import make_fused_chunk, make_multi_update
+    from d4pg_tpu.parallel import shard_stacked
+    from d4pg_tpu.replay.fused_buffer import FusedDeviceReplay
+    from d4pg_tpu.replay.sharded_per import ShardedFusedReplay
+
+    config = _config()
+    mesh = make_mesh(MeshSpec(data_parallel=1), devices=jax.devices()[:1])
+    state = init_state(config, jax.random.key(11))
+    on_mesh = replicate_state(state, mesh)
+    batch, w = _batch(rng), rng.uniform(0.5, 1.0, B).astype(np.float32)
+    if program == "update":
+        want = make_update(config, donate=False)(state, batch, w)
+        got = make_update(config, mesh=mesh, donate=False)(
+            on_mesh, shard_batch(batch, mesh), shard_batch(w, mesh))
+    elif program == "multi_update":
+        stack = lambda x: np.stack([x, x[::-1]])  # noqa: E731
+        batches = jax.tree_util.tree_map(stack, batch)
+        want = make_multi_update(config, donate=False)(
+            state, batches, stack(w))
+        got = make_multi_update(config, mesh=mesh, donate=False)(
+            on_mesh, shard_stacked(batches, mesh),
+            shard_stacked(stack(w), mesh))
+    else:
+        per = program == "fused_per"
+        rows = jax.tree_util.tree_map(lambda x: np.repeat(x[:1], B, 0),
+                                      batch)
+        kw = dict(k=1 if per else 3, batch_size=16, alpha=0.6, donate=False)
+        one = FusedDeviceReplay(B, OBS, ACT, alpha=0.6, prioritized=per,
+                                block_rows=B)
+        many = ShardedFusedReplay(B, OBS, ACT, mesh, alpha=0.6,
+                                  prioritized=per)
+        for buf in (one, many):
+            buf.add(rows)
+            buf.drain()
+        assert (one.trees is not None) == per == (many.trees is not None)
+        want = make_fused_chunk(config, **kw)(
+            state, one.trees, one.storage, one.size)
+        got = make_fused_chunk(config, mesh=mesh, **kw)(
+            on_mesh, many.trees, many.storage, many.size)
+        for out in (want, got):
+            out[2].pop("idx")  # which copies of the row: the draws differ
+        if per:  # as many leaves moved, to the same priority, whichever
+            leaves = [np.sort(np.asarray(t.sum_tree).reshape(-1)[B:])
+                      for t in (want[1], got[1])]
+            assert np.unique(leaves[0]).size == 2
+            np.testing.assert_array_equal(leaves[1], leaves[0])
+            want, got = want[::2], got[::2]
+    _tree_equal(got, want)

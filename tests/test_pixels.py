@@ -54,7 +54,7 @@ def test_pixel_learner_update(rng):
     )
     assert config.obs_spec == SHAPE
     state = init_state(config, jax.random.key(0))
-    update = make_update(config, donate=False, use_is_weights=False)
+    update = make_update(config, donate=False)
     n = 8
     batch = TransitionBatch(
         obs=rng.integers(0, 255, (n, *SHAPE), dtype=np.uint8),
@@ -64,7 +64,7 @@ def test_pixel_learner_update(rng):
         done=np.zeros(n, np.float32),
         discount=np.full(n, 0.99, np.float32),
     )
-    state, metrics = update(state, batch)
+    state, metrics = update(state, batch, None)
     assert np.isfinite(float(metrics["critic_loss"]))
     assert int(state.step) == 1
 
@@ -154,7 +154,7 @@ def test_shared_encoder_tie_and_detached_policy(rng):
         encoder_channels=(8, 8, 8, 8), share_encoder=True,
     )
     state = init_state(config, jax.random.key(0))
-    update = make_update(config, donate=False, use_is_weights=False)
+    update = make_update(config, donate=False)
     n = 8
     batch = TransitionBatch(
         obs=rng.integers(0, 255, (n, *SHAPE), dtype=np.uint8),
@@ -166,7 +166,7 @@ def test_shared_encoder_tie_and_detached_policy(rng):
     )
     prev = state
     for _ in range(2):
-        state, metrics = update(state, batch)
+        state, metrics = update(state, batch, None)
     tree = jax.tree_util.tree_leaves
     for a, c in zip(tree(state.actor_params["params"]["encoder"]),
                     tree(state.critic_params["params"]["encoder"])):
@@ -200,7 +200,7 @@ def test_shared_encoder_multi_update_donation(rng):
         encoder_channels=(8, 8, 8, 8), share_encoder=True,
     )
     state = init_state(config, jax.random.key(0))
-    update = make_multi_update(config, donate=True, use_is_weights=False)
+    update = make_multi_update(config, donate=True)
     k, n = 2, 8
     batch = TransitionBatch(
         obs=rng.integers(0, 255, (k, n, *SHAPE), dtype=np.uint8),
@@ -213,7 +213,7 @@ def test_shared_encoder_multi_update_donation(rng):
     # two consecutive donated dispatches: the second consumes the first's
     # outputs as donated inputs — where aliased subtrees blow up
     for _ in range(2):
-        state, metrics = update(state, batch)
+        state, metrics = update(state, batch, None)
     jax.block_until_ready(metrics["critic_loss"])
     assert np.isfinite(np.asarray(metrics["critic_loss"])).all()
     tree = jax.tree_util.tree_leaves
@@ -245,17 +245,17 @@ def test_shared_encoder_tie_survives_warm_moments(rng):
     # a few UNshared steps build nonzero encoder moments in the actor Adam
     unshared = D4PGConfig(**kw)
     state = init_state(unshared, jax.random.key(0))
-    update = make_update(unshared, donate=False, use_is_weights=False)
+    update = make_update(unshared, donate=False)
     for _ in range(3):
-        state, _ = update(state, batch)
+        state, _ = update(state, batch, None)
     tree = jax.tree_util.tree_leaves
     mu = state.actor_opt_state[0].mu["params"]["encoder"]
     assert any(np.any(np.asarray(x) != 0) for x in tree(mu))
     # "resume" the same state with the flag flipped on
     shared = D4PGConfig(**kw, share_encoder=True)
-    update_shared = make_update(shared, donate=False, use_is_weights=False)
+    update_shared = make_update(shared, donate=False)
     for _ in range(2):
-        state, _ = update_shared(state, batch)
+        state, _ = update_shared(state, batch, None)
         # online AND target tie hold immediately after the flip — the
         # target tie must not be left to the (1-tau)^t soft-update decay
         for a, c in zip(tree(state.actor_params["params"]["encoder"]),

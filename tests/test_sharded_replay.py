@@ -1,5 +1,5 @@
 """Sharded device-resident replay over the data-parallel mesh
-(replay/sharded_per.py + learner/fused.make_sharded_fused_chunk), on the
+(replay/sharded_per.py + learner/fused.make_fused_chunk), on the
 8-virtual-CPU-device mesh. The host segment trees serve as the oracle
 for the per-shard tree state."""
 
@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from d4pg_tpu.learner import D4PGConfig, init_state
-from d4pg_tpu.learner.fused import make_sharded_fused_chunk
+from d4pg_tpu.learner.fused import make_fused_chunk
 from d4pg_tpu.parallel import MeshSpec, make_mesh
 from d4pg_tpu.replay.sharded_per import ShardedFusedReplay
 from d4pg_tpu.replay.uniform import TransitionBatch
@@ -72,7 +72,7 @@ def test_sharded_fused_chunk_per(rng):
     buf = ShardedFusedReplay(64, 4, 2, mesh, alpha=0.6)
     buf.add(_batch(rng, 64))
     buf.drain()
-    fn = make_sharded_fused_chunk(config, mesh, k=3, batch_size=16,
+    fn = make_fused_chunk(config, mesh=mesh, k=3, batch_size=16,
                                   alpha=0.6, donate=False)
     s1, t1, m1 = fn(state, buf.trees, buf.storage, buf.size)
     assert int(jax.device_get(s1.step)) == 3
@@ -82,7 +82,7 @@ def test_sharded_fused_chunk_per(rng):
     # weights bounded by the global normalizer: max weight <= 1 (+eps)
     # run a fresh chunk (k=1) on untouched trees where all priorities are
     # equal -> all weights must be exactly 1
-    fn1 = make_sharded_fused_chunk(config, mesh, k=1, batch_size=16,
+    fn1 = make_fused_chunk(config, mesh=mesh, k=1, batch_size=16,
                                    alpha=0.6, donate=False)
     _, _, m = fn1(state, buf.trees, buf.storage, buf.size)
     # recompute weights is internal; instead check determinism + tree change
@@ -105,7 +105,7 @@ def test_sharded_fused_priorities_written_per_shard(rng):
     buf = ShardedFusedReplay(64, 4, 2, mesh, alpha=0.6)
     buf.add(_batch(rng, 64))
     buf.drain()
-    fn = make_sharded_fused_chunk(config, mesh, k=1, batch_size=16,
+    fn = make_fused_chunk(config, mesh=mesh, k=1, batch_size=16,
                                   alpha=0.6, donate=False)
     _, trees, m = fn(state, buf.trees, buf.storage, buf.size)
     idx = np.asarray(m["idx"][0]).reshape(4, 4)   # [shard, b_local]
@@ -131,7 +131,7 @@ def test_sharded_equal_priorities_weights_are_one(rng):
     buf.drain()
     loss = {}
     for b0 in (0.4, 1.0):
-        fn = make_sharded_fused_chunk(config, mesh, k=1, batch_size=8,
+        fn = make_fused_chunk(config, mesh=mesh, k=1, batch_size=8,
                                       alpha=0.6, beta0=b0, donate=False)
         _, _, m = fn(state, buf.trees, buf.storage, buf.size)
         loss[b0] = float(np.asarray(m["critic_loss"][0]))
@@ -146,9 +146,10 @@ def test_sharded_fused_uniform_chunk(rng):
     buf = ShardedFusedReplay(64, 4, 2, mesh, prioritized=False)
     buf.add(_batch(rng, 64))
     buf.drain()
-    fn = make_sharded_fused_chunk(config, mesh, k=2, batch_size=16,
-                                  prioritized=False, donate=False)
-    s1, m = fn(state, buf.storage, buf.size)
+    fn = make_fused_chunk(config, mesh=mesh, k=2, batch_size=16,
+                          donate=False)
+    s1, no_trees, m = fn(state, buf.trees, buf.storage, buf.size)
+    assert buf.trees is None and no_trees is None
     assert int(jax.device_get(s1.step)) == 2
     idx = np.asarray(m["idx"])
     assert idx.min() >= 0 and idx.max() < buf.cap_shard
@@ -198,7 +199,6 @@ def test_restored_trees_equal_the_live_ones_array_for_array(kind, rng):
     ``FusedDeviceReplay``, on the host by ``ShardedFusedReplay``), every
     other node what ``init`` gave it; the min root, all a reader reads of
     the min tree, is the smallest leaf saved."""
-    from d4pg_tpu.learner.fused import make_fused_chunk
     from d4pg_tpu.replay import device_per as dper
     from d4pg_tpu.replay.fused_buffer import FusedDeviceReplay
 
@@ -214,7 +214,7 @@ def test_restored_trees_equal_the_live_ones_array_for_array(kind, rng):
         mesh = _mesh(4)
         make = lambda: ShardedFusedReplay(1024, 4, 2, mesh,  # noqa: E731
                                           alpha=0.6)
-        fn = make_sharded_fused_chunk(config, mesh, k=3, batch_size=16,
+        fn = make_fused_chunk(config, mesh=mesh, k=3, batch_size=16,
                                       alpha=0.6, donate=False)
     src = make()
     src.add(_batch(rng, 700))

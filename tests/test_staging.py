@@ -57,14 +57,14 @@ def test_pipeline_depth_defers_but_never_drops_write_backs():
         n_sampled["n"] += 1
         return (np.full((2,), float(i), np.float32), None), ("aux", i)
 
-    def update(state, batch):
+    def update(state, batch, w):
         return state + 1, {"td_error": jnp.full((2,), float(np.asarray(batch)[0]))}
 
     flushed = []
     pipe = ChunkPipeline(update, sample,
                          write_back=lambda aux, td: flushed.append(
                              (aux[1], float(td[0]))),
-                         use_weights=False, depth=3)
+                         depth=3)
     state, _ = pipe.run(0, 8)
     assert state == 8
     # every chunk flushed exactly once, in order, with its own td
