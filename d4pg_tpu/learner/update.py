@@ -187,7 +187,9 @@ def _torso_critic_loss(config, state, batch, is_weights, key):
     ``select_counts`` ``[sparse layers, tokens / kv_chunk_size]`` int32
     (pass 2's selections by block of keys) and ``index_loss``; with a
     routing bias ``bias_swapped`` ``[layers with experts]`` int32, pass 2's
-    assignments that the bias changed. ``critic_loss`` stays the TD loss
+    assignments that the bias changed; with recurrent operators the mean
+    decay a layer of pass 2, float32 (``delta_kept`` ``[DeltaNet layers]``,
+    ``ssd_kept`` ``[Mamba blocks]``). ``critic_loss`` stays the TD loss
     alone.
 
     A looped torso (``total_ut_steps`` > 1) runs all its passes in each of

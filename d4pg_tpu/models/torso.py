@@ -8,14 +8,18 @@ transformer layers and pools them into one latent per row; D4PG's own heads
 state vector. ``D4PGConfig.torso`` names one (``TORSOS``) with its sizes
 (``TorsoSpec``, made from a configuration file's ``model.torso`` block).
 
-Five models share the one layer path, told apart by the data in the spec
-(``layer_types``, ``qk_norm``, ``sa_config``, ``num_dense_layers``,
-``router_scores``, ``use_expert_bias``, ``attn_output_gate``,
-``partial_rotary_factor``, ``shared_expert_intermediate_size``, the
-``linear_*`` sizes, ``num_experts`` 0, ``sandwich_norm``,
-``total_ut_steps``), not by code of their own. Every layer is ``x +
-Op(RMSNorm(x))`` then ``x + FF(RMSNorm(x))``, with ``sandwich_norm`` ``x +
-RMSNorm(Op(RMSNorm(x)))`` then ``x + RMSNorm(FF(RMSNorm(x)))``:
+Six models share the one layer path, told apart by the data in the spec
+(``layer_types`` or ``hybrid_override_pattern``, ``qk_norm``, ``sa_config``,
+``num_dense_layers``, ``router_scores``, ``use_expert_bias``,
+``attn_output_gate``, ``partial_rotary_factor``, ``rope_parameters``,
+``shared_expert_intermediate_size``, ``shared_expert_gated``,
+``mlp_hidden_act``, the ``linear_*`` and Mamba sizes, ``num_experts`` 0,
+``sandwich_norm``, ``total_ut_steps``), not by code of their own. A layer of
+two branches is ``x + Op(RMSNorm(x))`` then ``x + FF(RMSNorm(x))``, with
+``sandwich_norm`` ``x + RMSNorm(Op(RMSNorm(x)))`` then ``x +
+RMSNorm(FF(RMSNorm(x)))``; a block of one branch (``PATTERN``: ``mamba``,
+``attention``, ``moe``) is ``x + Op(RMSNorm(x))`` OR ``x +
+FF(RMSNorm(x))`` alone:
 
 - ``mellum2``, the Mellum2-12B-A2.5B layer: RMSNorm, grouped-query
   attention with rotary embeddings (default on ``sliding_attention``
@@ -77,6 +81,25 @@ RMSNorm(Op(RMSNorm(x)))`` then ``x + RMSNorm(FF(RMSNorm(x)))``:
   the TD loss over it less ``exit_entropy_beta`` times its entropy). A looped
   torso's layers are attention or conv with a dense feed-forward: it hands
   up no counters.
+- ``nemotronh``, Nemotron-H's blocks (the language tower of
+  Nemotron-Labs-TwoTower-30B-A3B), **each one branch**, a character a block
+  in ``hybrid_override_pattern`` (the published key; ``pattern_blocks``):
+  ``M`` a Mamba-2 mixer (``_mamba``: ``[z, xBC, dt] = h W_in``, a depthwise
+  causal convolution of ``conv_kernel`` taps WITH a bias and a SiLU over
+  ``xBC``, which splits into ``mamba_num_heads`` heads of ``mamba_head_dim``
+  and ``B``, ``C`` in ``n_groups`` groups of ``ssm_state_size``; ``dt =
+  softplus(dt + dt_bias)``, ``A = -exp(A_log)``; the state-space recurrence
+  of ``ops/ssd.py`` in chunks of ``chunk_size`` with the skip ``D``; the
+  output gated by ``silu(z)`` BEFORE an RMSNorm over each group of ``inner /
+  n_groups`` channels; ``out_proj``), ``*`` grouped-query attention with no
+  rotary embedding (no block for it in ``rope_parameters``: the Mamba blocks
+  carry position), ``E`` the expert layer under the ``sigmoid`` router with
+  its bias and ``routed_scaling_factor``, its experts ``down(relu(up h) **
+  2)`` (``mlp_hidden_act`` ``relu2``: two matrices, no ``gate`` leaf) and a
+  shared expert of the same form added whole and ungated
+  (``shared_expert_gated`` false). ``aux["ssd_kept"]`` is the mean of
+  ``exp(dt A)`` a Mamba block; ``route_counts`` and ``bias_swapped`` have a
+  row an ``E`` block.
 
 Leaves. A layer has only the leaves its kind has: the operator's are
 ``attn_norm``, ``q``, ``k``, ``v``, ``o`` (with ``qk_norm`` also ``q_norm``,
@@ -85,20 +108,26 @@ Leaves. A layer has only the leaves its kind has: the operator's are
 ``linear_norm``, ``in_proj_qkvz``, ``in_proj_ba``, ``conv`` (``[2 Wk + Wv,
 linear_conv_kernel_dim]``), ``A_log``, ``dt_bias``, ``out_norm``,
 ``out_proj`` (with ``attn_output_gate`` an attention layer's ``q`` is ``[D,
-2 H Dh]``); the feed-forward's ``mlp_norm``, ``w1``, ``w3``, ``w2`` or
+2 H Dh]``) or ``mamba_norm``, ``in_proj`` (``[D, 2 inner + 2 G N + H]``),
+``conv`` (taps ``[inner + 2 G N, conv_kernel]`` and a ``bias``), ``A_log``,
+``dt_bias``, ``D``, ``out_norm`` (``[inner]``), ``out_proj``; the
+feed-forward's ``mlp_norm``, ``w1``, ``w3``, ``w2`` or
 ``moe_norm``, ``router``, ``gate``, ``up``, ``down`` (with a shared expert
 also ``shared_gate``, ``shared_up``, ``shared_down``,
-``shared_expert_gate``); with ``sandwich_norm`` also ``op_post_norm`` and
+``shared_expert_gate``; with ``relu2`` no ``gate`` and no ``shared_gate``,
+ungated no ``shared_expert_gate``); a block of one branch has its branch's
+leaves and no others; with ``sandwich_norm`` also ``op_post_norm`` and
 ``ff_post_norm``, the gains behind the two branches. A looped torso has
 ``exit_gate`` beside ``embed`` and ``final_norm``. ``init`` draws eight keys
 a layer whatever its kind, so a layer's draws do not depend on its
-neighbours'; the decay's, the shared expert's and the exit gate's draws come
-from keys folded off the torso's own (gains draw nothing), so the older
-models' trees are bit for bit what they were.
+neighbours'; the decay's, the shared expert's, the exit gate's and the taps'
+bias's draws come from keys folded off the torso's own (gains draw nothing),
+so the older models' trees are bit for bit what they were.
 
 The expert layer: a float32 router over all ``num_experts`` experts,
-SwiGLU experts. ``softmax`` scores are a softmax over the experts with the
-largest ``num_experts_per_tok`` renormalised; ``sigmoid`` scores are one
+SwiGLU experts (``relu2``: ``down(relu(up h) ** 2)``). ``softmax`` scores
+are a softmax over the experts with the largest ``num_experts_per_tok``
+renormalised; ``sigmoid`` scores are one
 sigmoid an expert, the largest of score + ``router["bias"]`` selected and
 weighed by their *scores* over (their sum + 1e-6). That bias
 (``use_expert_bias``) is the one torso state no loss trains: it enters a
@@ -145,6 +174,18 @@ state a group of 4 chunks (64 x 2 MB a sequence, where a state a chunk
 would be 537 MB) and a group's intermediates for that group alone
 (``ops/delta_rule.py``); the expert layer takes the sequence in four parts
 of 40,960 assignments, of which about 1,280 land on the 16 experts held.
+At ``nemotronh``'s 8,192 tokens a block boundary is 88 MB a sequence and
+there is one a BLOCK, seven for ``MEMEM*E`` (2.5 GB at 4 sequences, where
+3.5 layers of two branches would keep half as many); a Mamba sequence's
+largest arrays are ``in_proj``'s ``[8192, 10304]`` in the compute dtype (169
+MB) and the float32 ``xBC`` behind the taps (``[8192, 6144]``, 201 MB), made
+again in the backward pass (``_mamba``'s ``front``); the scan keeps one
+``[64, 64, 128]`` state a group of 4 chunks (16 x 2 MB a sequence) and a
+group's decay-masked products (``[4, 64, 128, 128]``, 17 MB) for that group
+alone (``ops/ssd.py``); the attention block's 16 query heads a key/value head
+go to the splash kernel as one group; the expert layer takes the sequence in
+two parts of 24,576 assignments, of which about 1,536 land on the 8 experts
+held.
 
 A loop (``ouro``: 4 passes of 8 layers on 2 sequences of 4,096 tokens) is a
 ``lax.scan`` over the passes with the leaves closed over: one pass is
@@ -177,19 +218,28 @@ from d4pg_tpu.ops import delta_rule as delta_ops
 from d4pg_tpu.ops import grouped as grouped_ops
 from d4pg_tpu.ops import short_conv as conv_ops
 from d4pg_tpu.ops import sparse_attention as sparse_ops
+from d4pg_tpu.ops import ssd as ssd_ops
 
 HI = jax.lax.Precision.HIGHEST
 MU, M = 100.0, 256.0  # Gato's mu-law
 # the sorted expert buffer serves routing up to this multiple of an even
-# load before the every-assignment buffer takes over
+# load before the every-assignment buffer takes over (``TorsoSpec
+# .expert_buffer``'s default)
 EXPERT_BUFFER = 1.5
 # a longer sequence goes through the expert layer this many tokens at a
 # time (cell 4's whole sequence): the every-assignment buffer of 16,384
 # tokens, 131,072 rows, takes 4.5 GB that the chip does not have
 EXPERT_TOKENS = 4096
 LAYER_TYPES = ("sliding_attention", "full_attention", "sparse_attention",
-               "conv", "linear_attention")
+               "conv", "linear_attention", "mamba", "moe", "attention")
+# blocks that are one branch (Nemotron-H's): an operator OR a feed-forward
+# alone, written a character a block in ``hybrid_override_pattern``
+PATTERN = {"M": "mamba", "E": "moe", "*": "attention"}
+ONE_BRANCH = tuple(PATTERN.values())
+ROPED = ("sliding_attention", "full_attention", "sparse_attention")
 ROUTER_SCORES = ("softmax", "sigmoid")
+HIDDEN_ACTS = ("silu", "relu2")  # gated SwiGLU; down(relu(up h) ** 2)
+KEPT = ("delta_kept", "ssd_kept")  # a recurrent operator's mean decay
 SA_KEYS = ("indexer_head_dim", "indexer_num_heads", "indexer_num_kv_heads",
            "kv_chunk_size", "q_chunk_size", "topk")
 
@@ -219,7 +269,8 @@ class TorsoSpec:
     num_experts_per_tok: int
     moe_intermediate_size: int
     experts_held: tuple  # [lo, hi) of the experts this chip holds
-    rope_parameters: Any  # layer type -> rope block, frozen
+    # layer type -> rope block, frozen; a type without one turns nothing
+    rope_parameters: Any = None
     rms_norm_eps: float = 1e-6
     norm_topk_prob: bool = True
     bins: int = 1024
@@ -251,6 +302,21 @@ class TorsoSpec:
     # a norm with its own gain behind each operator and feed-forward too:
     # x + Norm(Op(Norm(x))), then x + Norm(FF(Norm(x)))
     sandwich_norm: bool = False
+    # one character a block (``PATTERN``): in place of ``layer_types``
+    hybrid_override_pattern: str = ""
+    # 'mamba' blocks (Mamba-2): heads, their width, the state a head, the
+    # groups that share B and C, taps a channel, tokens a chunk of the scan
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    n_groups: int = 0
+    conv_kernel: int = 0
+    chunk_size: int = ssd_ops.CHUNK
+    mlp_hidden_act: str = "silu"  # the experts' form, one of HIDDEN_ACTS
+    shared_expert_gated: bool = True  # a scalar sigmoid gate on the shared
+    # the sorted buffer's rows over an even load's (``even_load_rows``): a
+    # small share of the experts strays further from even than a large one
+    expert_buffer: float = EXPERT_BUFFER
 
     @classmethod
     def from_dict(cls, d: dict) -> "TorsoSpec":
@@ -258,6 +324,9 @@ class TorsoSpec:
         unknown = sorted(set(d) - names)
         if unknown:
             raise ValueError(f"unknown torso keys {unknown}")
+        d = dict(d)
+        d.setdefault("layer_types", pattern_blocks(
+            d.get("hybrid_override_pattern", "")))
         return cls(**{k: _freeze(v) for k, v in d.items()})
 
     def __post_init__(self):
@@ -298,6 +367,35 @@ class TorsoSpec:
                                   sa["kv_chunk_size"])
         if "conv" in self.layer_types and self.conv_L_cache < 1:
             raise ValueError("conv layers need conv_L_cache taps")
+        if self.hybrid_override_pattern and self.layer_types \
+                != pattern_blocks(self.hybrid_override_pattern):
+            raise ValueError(f"layer_types {self.layer_types} are not "
+                             f"hybrid_override_pattern "
+                             f"{self.hybrid_override_pattern!r}'s blocks")
+        if "mamba" in self.layer_types:
+            sizes = (self.mamba_num_heads, self.mamba_head_dim,
+                     self.ssm_state_size, self.n_groups, self.conv_kernel,
+                     self.chunk_size)
+            if min(sizes) < 1:
+                raise ValueError("mamba blocks need mamba_num_heads, "
+                                 "mamba_head_dim, ssm_state_size, n_groups, "
+                                 "conv_kernel and chunk_size")
+            if self.mamba_num_heads % self.n_groups:
+                raise ValueError("mamba heads do not divide into n_groups")
+        if set(self.layer_types) & set(ONE_BRANCH) \
+                and (dense_only or self.num_dense_layers):
+            raise ValueError("blocks of one branch come with experts and "
+                             "without leading dense layers")
+        if self.expert_buffer < 1.0:
+            raise ValueError("expert_buffer is a multiple of an even load: "
+                             "at least 1")
+        if self.mlp_hidden_act not in HIDDEN_ACTS:
+            raise ValueError(f"unknown mlp_hidden_act "
+                             f"{self.mlp_hidden_act!r}; one of {HIDDEN_ACTS}")
+        unroped = sorted(set(self.layer_types) & set(ROPED)
+                         - set(dict(self.rope_parameters or ())))
+        if unroped:
+            raise ValueError(f"rope_parameters has no block for {unroped}")
         if "linear_attention" in self.layer_types:
             sizes = (self.linear_num_key_heads, self.linear_num_value_heads,
                      self.linear_key_head_dim, self.linear_value_head_dim,
@@ -332,7 +430,7 @@ class TorsoSpec:
             raise ValueError("total_ut_steps counts the passes: at least 1")
         if self.total_ut_steps > 1 and (
                 self.num_experts or set(self.layer_types)
-                & {"sparse_attention", "linear_attention"}):
+                & {"sparse_attention", "linear_attention", "mamba"}):
             raise ValueError("a looped torso hands up one latent a pass and "
                              "no counters: its layers are attention or conv "
                              "with a dense feed-forward")
@@ -353,16 +451,39 @@ class TorsoSpec:
 
     @property
     def expert_layers(self) -> tuple:
-        """Indices of the layers whose feed-forward is the expert layer."""
-        return tuple(range(self.num_dense_layers, len(self.layer_types)))
+        """Indices of the layers that have the expert layer: a ``moe`` block,
+        or a layer of two branches behind the leading dense ones."""
+        return tuple(i for i, lt in enumerate(self.layer_types)
+                     if lt == "moe" or (lt not in ONE_BRANCH
+                                        and i >= self.num_dense_layers))
+
+    @property
+    def mamba_widths(self) -> tuple:
+        """``(inner width, B or C's width)`` of a ``mamba`` block."""
+        return (self.mamba_num_heads * self.mamba_head_dim,
+                self.n_groups * self.ssm_state_size)
 
     @property
     def rotary_dim(self) -> int:
         """How many of a head's ``head_dim`` RoPE turns (the first)."""
         return int(self.head_dim * self.partial_rotary_factor)
 
-    def rope_for(self, layer_type: str) -> dict:
-        return dict(dict(self.rope_parameters)[layer_type])
+    def rope_for(self, layer_type: str) -> dict | None:
+        """The layer type's rope block; ``None``: no rotary embedding."""
+        block = dict(self.rope_parameters or ()).get(layer_type)
+        return None if block is None else dict(block)
+
+
+def pattern_blocks(pattern: str) -> tuple:
+    """``layer_types`` of a ``hybrid_override_pattern``: ``M`` a Mamba-2
+    mixer, ``E`` the expert layer, ``*`` attention, each a block of its
+    own."""
+    unknown = sorted(set(pattern) - set(PATTERN))
+    if unknown:
+        raise ValueError(f"unknown blocks {unknown} in "
+                         f"hybrid_override_pattern {pattern!r}; one of "
+                         f"{sorted(PATTERN)}")
+    return tuple(PATTERN[c] for c in pattern)
 
 
 # -- tokens -------------------------------------------------------------------
@@ -522,13 +643,13 @@ def route(spec: TorsoSpec, h, router: dict):
 
 def even_load_rows(spec: TorsoSpec, t_len: int) -> int:
     """Rows of the sorted buffer that serve a sequence whose routing is
-    within ``EXPERT_BUFFER`` of even: a multiple of the kernels' row tile,
+    within ``expert_buffer`` of even: a multiple of the kernels' row tile,
     at most every assignment."""
     every = t_len * spec.num_experts_per_tok
     even = every * spec.n_held / spec.num_experts
     tile = grouped_ops.ROW_TILE
     # static sizes: Python numbers, never traced
-    rows = int(EXPERT_BUFFER * even)  # jaxlint: disable=host-sync-in-jit
+    rows = int(spec.expert_buffer * even)  # jaxlint: disable=host-sync-in-jit
     return min(every, -(-rows // tile) * tile)
 
 
@@ -583,13 +704,13 @@ def expert_share(spec: TorsoSpec, p: dict, h, dtype, grouped: str = "ragged"):
                 # (the buffer) and not where they are many (T * k)
                 w_rows = _to_sorted(w.reshape(-1, 1), top, inv, 1)
             with jax.named_scope("torso.experts"):
-                dot = lambda a, b: keep(grouped_ops.grouped_matmul(  # noqa
-                    keep(a), b, sizes, impl=grouped))
-                g = dot(xs, p["gate"]["kernel"])
-                u = dot(xs, p["up"]["kernel"])
-                mid = (jax.nn.silu(g.astype(jnp.float32))
-                       * u.astype(jnp.float32)).astype(dtype)
-                y = dot(mid, p["down"]["kernel"])
+                dot = lambda a, name: keep(grouped_ops.grouped_matmul(  # noqa
+                    keep(a), p[name]["kernel"], sizes, impl=grouped))
+                made = {name: dot(xs, name) for name in ("gate", "up")
+                        if name in p}
+                mid = _hidden(spec, lambda name: made[name].astype(
+                    jnp.float32), "gate", "up").astype(dtype)
+                y = dot(mid, "down")
                 y = (y.astype(jnp.float32) * w_rows).astype(dtype)
             with jax.named_scope("torso.route"):
                 y = _from_sorted(y, top, inv).reshape(t_len, k, -1)
@@ -606,13 +727,24 @@ def expert_share(spec: TorsoSpec, p: dict, h, dtype, grouped: str = "ragged"):
             hs = h.astype(dtype)
             proj = lambda name: jnp.dot(  # noqa: E731
                 hs, p[name]["kernel"], preferred_element_type=jnp.float32)
-            open_ = jax.nn.sigmoid(proj("shared_expert_gate"))  # [T, 1]
-            mid = (jax.nn.silu(proj("shared_gate"))
-                   * proj("shared_up")).astype(dtype)
+            gated = spec.shared_expert_gated
+            open_ = jax.nn.sigmoid(  # [T, 1]
+                proj("shared_expert_gate")) if gated else 1.0
+            mid = _hidden(spec, proj, "shared_gate", "shared_up").astype(dtype)
             out = out + open_ * jnp.dot(mid, p["shared_down"]["kernel"],
                                         preferred_element_type=jnp.float32)
-            stats["shared_gate"] = jnp.sum(open_)
+            if gated:
+                stats["shared_gate"] = jnp.sum(open_)
     return out, stats
+
+
+def _hidden(spec: TorsoSpec, proj, gate: str, up: str):
+    """An expert's hidden activation from its float32 projections
+    ``proj(name)``: ``silu(gate) * up``, or with ``relu2`` ``relu(up) ** 2``
+    (no gate matrix)."""
+    if spec.mlp_hidden_act == "relu2":
+        return jnp.square(jax.nn.relu(proj(up)))
+    return jax.nn.silu(proj(gate)) * proj(up)
 
 
 # -- the torso ----------------------------------------------------------------
@@ -665,6 +797,18 @@ class SequenceTorso:
             return {"kernel": jax.random.normal(key, shape, jnp.float32)
                     / math.sqrt(fan_in)}
 
+        def decay(i, heads, a_low):
+            """Layer ``i``'s ``A_log`` and ``dt_bias`` from keys of their
+            own, as the indexer's below; Mamba-2's and the published Gated
+            DeltaNet's draw: A ~ U(a_low, 16), dt log-uniform on [1e-3,
+            1e-1] behind an inverse softplus."""
+            k_a, k_dt = jax.random.split(jax.random.fold_in(key, i + 1), 2)
+            dt = jnp.exp(jax.random.uniform(
+                k_dt, (heads,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+            return {"A_log": {"value": jnp.log(jax.random.uniform(
+                        k_a, (heads,), jnp.float32, a_low, 16.0))},
+                    "dt_bias": {"value": dt + jnp.log(-jnp.expm1(-dt))}}
+
         keys = iter(jax.random.split(key, 1 + 8 * len(s.layer_types)))
         gain = lambda n=d: {"scale": jnp.ones((n,), jnp.float32)}  # noqa
         params = {"embed": normal(next(keys), (s.vocab_rows, d), 1.0),
@@ -674,24 +818,34 @@ class SequenceTorso:
             # not depend on the kinds of the layers before it
             k_q, k_k, k_v, k_o, k_router, k_gate, k_up, k_down = (
                 next(keys) for _ in range(8))
-            if layer_type == "linear_attention":
+            if layer_type == "moe":
+                op = {}
+            elif layer_type == "mamba":
+                hm, tap = s.mamba_num_heads, s.conv_kernel
+                wide, bc = s.mamba_widths
+                # the taps' bias: U(-1, 1) / sqrt(taps), a 1-D convolution's
+                # usual draw, from a key behind the decay's
+                k_bias = jax.random.fold_in(
+                    key, 3 * len(s.layer_types) + 2 + i)
+                op = {"mamba_norm": gain(),
+                      "in_proj": normal(k_q, (d, 2 * wide + 2 * bc + hm), d),
+                      "conv": {**normal(k_k, (wide + 2 * bc, tap), tap),
+                               "bias": jax.random.uniform(
+                                   k_bias, (wide + 2 * bc,), jnp.float32,
+                                   -1.0, 1.0) / math.sqrt(tap)},
+                      **decay(i, hm, 1.0),
+                      "D": {"value": jnp.ones((hm,), jnp.float32)},
+                      "out_norm": gain(wide),
+                      "out_proj": normal(k_o, (wide, d), wide)}
+            elif layer_type == "linear_attention":
                 hv, tap = s.linear_num_value_heads, s.linear_conv_kernel_dim
                 wk = s.linear_num_key_heads * s.linear_key_head_dim
                 wv = hv * s.linear_value_head_dim
-                # keys of their own, as the indexer's below; Mamba-2's and
-                # the published Gated DeltaNet's draw: A ~ U(0, 16), dt
-                # log-uniform on [1e-3, 1e-1] behind an inverse softplus
-                k_a, k_dt = jax.random.split(
-                    jax.random.fold_in(key, i + 1), 2)
-                dt = jnp.exp(jax.random.uniform(
-                    k_dt, (hv,), jnp.float32, math.log(1e-3), math.log(1e-1)))
                 op = {"linear_norm": gain(),
                       "in_proj_qkvz": normal(k_q, (d, 2 * wk + 2 * wv), d),
                       "in_proj_ba": normal(k_k, (d, 2 * hv), d),
                       "conv": normal(k_v, (2 * wk + wv, tap), tap),
-                      "A_log": {"value": jnp.log(jax.random.uniform(
-                          k_a, (hv,), jnp.float32, 1e-6, 16.0))},
-                      "dt_bias": {"value": dt + jnp.log(-jnp.expm1(-dt))},
+                      **decay(i, hv, 1e-6),
                       "out_norm": gain(s.linear_value_head_dim),
                       "out_proj": normal(k_o, (wv, d), wv)}
             elif layer_type == "conv":
@@ -722,7 +876,9 @@ class SequenceTorso:
                     index_k_norm={**gain(di),
                                   "bias": jnp.zeros((di,), jnp.float32)},
                     index_w=normal(k_w, (d, hi), d))
-            if i < s.num_dense_layers:
+            if layer_type in ("mamba", "attention"):
+                ff = {}  # an operator alone
+            elif i < s.num_dense_layers:
                 wide = s.intermediate_size
                 ff = {"mlp_norm": gain(),
                       "w1": normal(k_gate, (d, wide), d),
@@ -732,19 +888,22 @@ class SequenceTorso:
                 router = normal(k_router, (d, s.num_experts), d)
                 if s.use_expert_bias:
                     router["bias"] = jnp.zeros((s.num_experts,), jnp.float32)
+                swiglu = s.mlp_hidden_act == "silu"  # else two matrices
                 ff = {"moe_norm": gain(), "router": router,
-                      "gate": normal(k_gate, (n, d, f), d),
                       "up": normal(k_up, (n, d, f), d),
                       "down": normal(k_down, (n, f, d), f)}
+                if swiglu:
+                    ff["gate"] = normal(k_gate, (n, d, f), d)
                 if s.shared_expert_intermediate_size:
                     fs = s.shared_expert_intermediate_size
                     ks = jax.random.split(jax.random.fold_in(
                         key, len(s.layer_types) + 1 + i), 4)
-                    ff.update(
-                        shared_gate=normal(ks[0], (d, fs), d),
-                        shared_up=normal(ks[1], (d, fs), d),
-                        shared_down=normal(ks[2], (fs, d), fs),
-                        shared_expert_gate=normal(ks[3], (d, 1), d))
+                    ff.update(shared_up=normal(ks[1], (d, fs), d),
+                              shared_down=normal(ks[2], (fs, d), fs))
+                    if swiglu:
+                        ff["shared_gate"] = normal(ks[0], (d, fs), d)
+                    if s.shared_expert_gated:
+                        ff["shared_expert_gate"] = normal(ks[3], (d, 1), d)
             if s.sandwich_norm:  # gains draw nothing
                 ff.update(op_post_norm=gain(), ff_post_norm=gain())
             params[f"layer_{i}"] = {**op, **ff}
@@ -769,20 +928,25 @@ class SequenceTorso:
         the output gate's logits ``[T, H * D]`` float32, ``None`` without
         ``attn_output_gate`` (with it a head's ``2 D`` outputs of ``q`` are
         its query, then its gate). RoPE turns the first ``rotary_dim`` of a
-        head and passes the rest."""
+        head and passes the rest; a layer type without a rope block turns
+        nothing."""
         s, dtype = self.spec, self.dtype
         t_len = h.shape[0]
         hkv, dh = s.num_key_value_heads, s.head_dim
         group = s.num_attention_heads // hkv
         proj = lambda name, out: jnp.dot(  # noqa: E731
             h, p[name]["kernel"], preferred_element_type=out)
-        cos, sin = rope_tables(s.rope_for(layer_type), s.rotary_dim, t_len)
-        if s.rotary_dim == dh:
-            turn = lambda x: apply_rope(x, cos, sin)  # noqa: E731
+        rope = s.rope_for(layer_type)
+        if rope is None:  # position comes from elsewhere (Mamba blocks)
+            turn = lambda x: x  # noqa: E731
         else:
-            turn = lambda x: jnp.concatenate([  # noqa: E731
-                apply_rope(x[..., :s.rotary_dim], cos, sin),
-                x[..., s.rotary_dim:]], axis=-1)
+            cos, sin = rope_tables(rope, s.rotary_dim, t_len)
+            if s.rotary_dim == dh:
+                turn = lambda x: apply_rope(x, cos, sin)  # noqa: E731
+            else:
+                turn = lambda x: jnp.concatenate([  # noqa: E731
+                    apply_rope(x[..., :s.rotary_dim], cos, sin),
+                    x[..., s.rotary_dim:]], axis=-1)
         q, gate = proj("q", jnp.float32), None
         if s.attn_output_gate:
             q = q.reshape(t_len, hkv * group, 2 * dh)
@@ -801,7 +965,7 @@ class SequenceTorso:
     def _attend(self, p: dict, x, layer_type: str):
         """Attention of one sequence ``x [T, D]`` added to it."""
         s = self.spec
-        full = layer_type == "full_attention"
+        full = layer_type != "sliding_attention"
         with jax.named_scope("torso.attn_full" if full
                              else "torso.attn_window"):
             h = rms_norm(x, p["attn_norm"]["scale"], s.rms_norm_eps).astype(
@@ -919,6 +1083,59 @@ class SequenceTorso:
                 y.astype(dtype).reshape(t_len, wv), p["out_proj"]["kernel"],
                 preferred_element_type=jnp.float32), "op_post_norm"), kept
 
+    def _mamba(self, p: dict, x):
+        """Nemotron-H's Mamba-2 mixer of one sequence ``x [T, D]`` added to
+        it: ``(x, kept)``, ``kept`` the mean of ``exp(dt A)`` over heads and
+        tokens. ``[z, xBC, dt] = h W_in`` in that order; ``xBC`` goes through
+        a depthwise causal convolution with a bias and a SiLU and splits into
+        ``xs`` (``mamba_num_heads`` heads of ``mamba_head_dim``), ``B`` and
+        ``C`` (``n_groups`` groups of ``ssm_state_size``, group ``g`` serving
+        the heads from ``g H / G`` on); ``dt = softplus(dt + dt_bias)``, ``A =
+        -exp(A_log)`` a head; the recurrence is ``ops/ssd.py``'s; its output
+        is gated by ``silu(z)``, then RMS-normalised a group of ``inner /
+        n_groups`` channels, and projected."""
+        s, dtype = self.spec, self.dtype
+        t_len = x.shape[0]
+        hm, dm = s.mamba_num_heads, s.mamba_head_dim
+        wide, bc = s.mamba_widths
+        with jax.named_scope("torso.mamba"):
+            h = rms_norm(x, p["mamba_norm"]["scale"], s.rms_norm_eps).astype(
+                dtype)
+            zxbcdt = jnp.dot(h, p["in_proj"]["kernel"],
+                             preferred_element_type=dtype)
+            z = zxbcdt[:, :wide]
+
+        # the float32 xs, B, C behind the taps ([T, 6144], 201 MB) are made
+        # again in the backward pass, as ``_delta``'s ``front``
+        @jax.checkpoint
+        def front(xbc, conv):
+            with jax.named_scope("torso.mamba"):
+                xbc = jax.nn.silu(conv_ops.depthwise_causal(
+                    xbc, conv["kernel"], conv["bias"]))
+                groups = lambda a: a.reshape(  # noqa: E731
+                    t_len, s.n_groups, s.ssm_state_size)
+                return (xbc[:, :wide].reshape(t_len, hm, dm),
+                        groups(xbc[:, wide:wide + bc]),
+                        groups(xbc[:, wide + bc:]))
+
+        xs, b, c = front(zxbcdt[:, wide:2 * wide + 2 * bc], p["conv"])
+        with jax.named_scope("torso.ssd_scan"):
+            dt = jax.nn.softplus(
+                zxbcdt[:, 2 * wide + 2 * bc:].astype(jnp.float32)
+                + p["dt_bias"]["value"])
+            a = -jnp.exp(p["A_log"]["value"])
+            y = ssd_ops.ssd(xs, dt, a, b, c, p["D"]["value"], dtype=dtype,
+                            chunk=s.chunk_size)
+            kept = jnp.mean(jnp.exp(dt * a))
+        with jax.named_scope("torso.mamba"):
+            y = y.reshape(t_len, wide) * jax.nn.silu(z.astype(jnp.float32))
+            y = rms_norm(y.reshape(t_len, s.n_groups, -1), 1.0,
+                         s.rms_norm_eps).reshape(t_len, wide) \
+                * p["out_norm"]["scale"]
+            return x + self._post(p, jnp.dot(
+                y.astype(dtype), p["out_proj"]["kernel"],
+                preferred_element_type=jnp.float32), "op_post_norm"), kept
+
     def _conv(self, p: dict, x):
         """LFM2's gated short convolution of one sequence ``x [T, D]``
         added to it (ops/short_conv.py)."""
@@ -950,17 +1167,24 @@ class SequenceTorso:
         """One layer on one sequence: ``x [T, D] -> (x, stats, selected)``;
         ``stats`` is the router's (none of a dense layer) with a
         ``linear_attention`` layer's ``delta_kept`` beside it, ``selected``
-        ``()`` but for a sparse layer."""
+        ``()`` but for a sparse layer. A block of one branch (``PATTERN``)
+        runs its operator (``mamba`` with its ``ssd_kept``, ``attention``)
+        or the expert layer (``moe``) and nothing else."""
         selected, op_stats = (), {}
         if layer_type == "conv":
             x = self._conv(p, x)
         elif layer_type == "linear_attention":
             x, kept = self._delta(p, x)
             op_stats = {"delta_kept": kept}
+        elif layer_type == "mamba":
+            x, kept = self._mamba(p, x)
+            op_stats = {"ssd_kept": kept}
         elif layer_type == "sparse_attention":
             x, selected = self._attend_sparse(p, x, train)
-        else:
+        elif layer_type != "moe":
             x = self._attend(p, x, layer_type)
+        if layer_type in ("mamba", "attention"):
+            return x, op_stats, selected
         if dense:
             return x + self._mlp(p, x), op_stats, selected
         with jax.named_scope("torso.route"):
@@ -999,16 +1223,17 @@ class SequenceTorso:
 
     def _stack(self, params: dict, x, train: bool):
         """Every layer once, in order, on the batch ``x [B, T, D]``: ``(x,
-        stats, selected, kept)``, one entry a layer that has any."""
+        stats, selected, kept)``, one entry a layer that has any (``kept``
+        by counter: ``KEPT``)."""
         s = self.spec
-        stats, selected, kept = [], [], []
+        stats, selected, kept = [], [], {}
         for i, layer_type in enumerate(s.layer_types):
             layer = jax.checkpoint(
                 lambda p, x, lt=layer_type, dense=i < s.num_dense_layers:
                 self._layer(p, x, lt, dense, train))
             x, st, sel = layer(params[f"layer_{i}"], x)
-            if "delta_kept" in st:  # a mean a sequence, summed over them
-                kept.append(st.pop("delta_kept") / x.shape[0])
+            for name in set(st) & set(KEPT):  # a mean a sequence, summed
+                kept.setdefault(name, []).append(st.pop(name) / x.shape[0])
             if st:
                 stats.append(st)
             if sel:
@@ -1051,8 +1276,8 @@ class SequenceTorso:
             latent = jnp.mean(x, axis=1)
         aux = {name: jnp.stack([st[name] for st in stats])
                for name in (stats[0] if stats else ())}
-        if kept:
-            aux["delta_kept"] = jnp.stack(kept)
+        for name, values in kept.items():
+            aux[name] = jnp.stack(values)
         if "shared_gate" in aux:  # summed over tokens and sequences
             aux["shared_gate"] = aux["shared_gate"] / (obs.shape[0] * s.tokens)
         if selected and train:
@@ -1153,7 +1378,7 @@ class TorsoCritic:
 
 TORSOS = {"mellum2": SequenceTorso, "keye2": SequenceTorso,
           "lfm2": SequenceTorso, "qwen3next": SequenceTorso,
-          "ouro": SequenceTorso}
+          "ouro": SequenceTorso, "nemotronh": SequenceTorso}
 
 
 def build_torso(spec: TorsoSpec, dtype=jnp.float32):

@@ -14,7 +14,12 @@ was, NaN included); a caller selects them away on both sides.
   in 16 groups of ~512, K 2304, N 896; my chip run, PR 27) ``gmm`` takes
   0.37 ms at tiling (256, K, N) where XLA's own lowering of
   ``jax.lax.ragged_dot`` takes 1.34 ms, and the two gradients 0.97 ms
-  against 2.83 ms. TPU only; K and N multiples of 128.
+  against 2.83 ms. TPU only; the kernels take K and N in multiples of 128:
+  widths a little off one (Nemotron-H's experts are 1,856 wide, 14.5 x 128)
+  are zero-padded up to it round the call (``PAD_WITHIN`` of the width at
+  most; the padded columns of ``w`` and of ``x`` multiply zeros and the
+  padded outputs are cut off again, so the result and both gradients are
+  the unpadded product's).
 - ``ragged``: ``jax.lax.ragged_dot`` and its own gradients. Runs anywhere
   (the CPU tests, sizes the kernels' tiling refuses).
 """
@@ -61,8 +66,15 @@ def _tiling(k: int, n: int, elems: int) -> tuple:
     return ROW_TILE, k, _divisor(n, max(128, elems // k))
 
 
+PAD_WITHIN = 1.125  # a width is padded to a multiple of 128 up to this much
+
+
+def _padded(width: int) -> int:
+    return -(-width // 128) * 128
+
+
 def megablox_fits(k: int, n: int) -> bool:
-    return k % 128 == 0 and n % 128 == 0
+    return all(_padded(width) <= PAD_WITHIN * width for width in (k, n))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -97,7 +109,13 @@ def grouped_matmul(x, w, sizes, *, impl: str, interpret: bool = False):
     if impl not in IMPLS:
         raise ValueError(f"unknown grouped impl {impl!r}; one of {IMPLS}")
     if impl == "megablox":
-        return _megablox(x, w.astype(x.dtype), sizes.astype(jnp.int32),
-                         interpret)
+        _g, k, n = w.shape
+        more_k, more_n = _padded(k) - k, _padded(n) - n
+        w, sizes = w.astype(x.dtype), sizes.astype(jnp.int32)
+        if not (more_k or more_n):
+            return _megablox(x, w, sizes, interpret)
+        w = jnp.pad(w, ((0, 0), (0, more_k), (0, more_n)))
+        x = jnp.pad(x, ((0, 0), (0, more_k)))
+        return _megablox(x, w, sizes, interpret)[:, :n]
     return jax.lax.ragged_dot(x, w.astype(x.dtype), sizes,
                               preferred_element_type=x.dtype)

@@ -30,13 +30,15 @@ def shifted(g, back: int):
     return jnp.pad(g, ((back, 0), (0, 0)))[:g.shape[0]]
 
 
-def depthwise_causal(g, taps):
+def depthwise_causal(g, taps, bias=None):
     """``s[t] = sum_j taps[:, j] * g[t - (L - 1 - j)]`` in float32 on ``g [T,
     C]`` of any dtype: the convolution alone (Qwen3-Next's Gated DeltaNet
-    puts a SiLU behind it and no gates round it)."""
+    puts a SiLU behind it and no gates round it), with ``bias [C]`` added at
+    every position (Nemotron-H's Mamba-2 mixer)."""
     g, taps = g.astype(jnp.float32), taps.astype(jnp.float32)
     n_taps = taps.shape[1]
-    return sum(taps[:, j] * shifted(g, n_taps - 1 - j) for j in range(n_taps))
+    s = sum(taps[:, j] * shifted(g, n_taps - 1 - j) for j in range(n_taps))
+    return s if bias is None else s + bias
 
 
 def gated_short_conv(bcu, taps):

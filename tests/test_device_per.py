@@ -558,6 +558,9 @@ CELL_PLANS = {
     "humanoid-ouro-ut4.learn-static": (
         "15>8 rows(min from sum),8>1 whole,1>root whole",
         "15>8 whole(min from sum),8>1 whole,1>root whole"),
+    "humanoid-nemotronh-ep16.learn-static": (
+        "15>8 rows(min from sum),8>1 whole,1>root whole",
+        "15>8 whole(min from sum),8>1 whole,1>root whole"),
 }
 
 

@@ -741,3 +741,83 @@ def test_the_fused_chunk_of_the_loop_cell_compiles_for_the_chip(
     # eight layers a pass, forward three times and once more with its
     # backward: a pass traced four times would hold four times as many
     assert 32 <= text.count("tpu_custom_call") <= 64
+
+
+def _nemotronh_spec(monkeypatch):
+    with open(os.path.join(
+            REPO, "benchmark/configs/humanoid-nemotronh-ep16.json")) as f:
+        block = json.load(f)["model"]["torso"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return torso_lib.TorsoSpec.from_dict(block)
+
+
+def test_the_ssd_scan_and_its_backward_compile_at_real_widths(one_chip):
+    """``ops/ssd.py`` at Nemotron-H's sizes (8,192 tokens, 64 heads of 64, a
+    ``[64, 128]`` state, 8 groups), bfloat16 products, forward and gradients:
+    plain XLA products and one scan over groups of chunks, no kernel."""
+    from d4pg_tpu.ops import ssd as ssd_ops
+
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32,  # noqa: E731
+                                          sharding=one_chip)
+    args = (f32(8192, 64, 64), f32(8192, 64), f32(64), f32(8192, 8, 128),
+            f32(8192, 8, 128), f32(64))
+
+    def loss(x, dt, a, b, c, d):
+        return jnp.sum(ssd_ops.ssd(x, dt, a, b, c, d, dtype=jnp.bfloat16))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text and "while" in text
+    # a state a group of 4 chunks is kept (16 x 2 MB), not one a chunk, and
+    # nothing chunk-by-chunk-by-head square outlives its group
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def test_relu2_experts_of_1856_reach_megablox_padded(one_chip, monkeypatch):
+    """1,856 is 14.5 x 128: the grouped products go to the megablox kernels
+    zero-padded to 1,920 (``ops/grouped.py``), not to XLA's ``ragged_dot``;
+    two products an expert forward and four backward, in both buffers."""
+    spec = _nemotronh_spec(monkeypatch)
+    torso = torso_lib.build_torso(spec, jnp.bfloat16)
+    assert torso.grouped_impl() == "megablox"
+    assert torso.attention_impl() == "splash"
+    d, f, n = spec.hidden_size, spec.moe_intermediate_size, spec.n_held
+    fs = spec.shared_expert_intermediate_size
+    bf = lambda *s: {"kernel": jax.ShapeDtypeStruct(s, jnp.bfloat16)}  # noqa
+    p = {"router": {"kernel": jax.ShapeDtypeStruct((d, spec.num_experts),
+                                                   jnp.float32),
+                    "bias": jax.ShapeDtypeStruct((spec.num_experts,),
+                                                 jnp.float32)},
+         "up": bf(n, d, f), "down": bf(n, f, d), "shared_up": bf(d, fs),
+         "shared_down": bf(fs, d)}
+    h = jax.ShapeDtypeStruct((torso_lib.EXPERT_TOKENS, d), jnp.float32)
+
+    def loss(p, h):
+        out, _stats = torso_lib.expert_share(spec, p, h, jnp.bfloat16,
+                                             "megablox")
+        return jnp.sum(out)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        *on(one_chip, (p, h))).compile().as_text()
+    assert text.count("gmm") >= 12 and "ragged-dot" not in text
+    assert "1920" in text
+
+
+def test_sixteen_query_heads_a_key_value_head_go_to_the_splash_kernel(
+        one_chip):
+    """Nemotron-H's attention block: 32 query heads on 2 key/value heads of
+    128 at 8,192 tokens, one group of 16 a kernel call, forward and
+    backward."""
+    q = jax.ShapeDtypeStruct((1, 2, 16, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 2, 8192, 128), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        out = attn_ops.causal_attention(q, k, v, window=None, impl="splash")
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
