@@ -40,32 +40,45 @@ or ``GROUPS``.
 
 ``masked_attention`` implementations:
 
-- ``splash``: Pallas kernels, TPU only (blocks of 512). The forward pass
-  is this repo's (``group_masked_forward``, since PR 43): a grid step holds
-  the ``G`` query heads of a key/value head (eight in ``humanoid-keye2-
-  ep8``), one tile of keys, one of values and ONE int8 tile of the
-  selection for all of them, read from ``keep`` as it is (a byte a pair).
-  It hands back the output and every head's log-sum-exp, which is both the
-  backward's residual and the alignment target's, so one call serves the
-  attention, its gradient and the loss, and the passes that need neither
-  drop it. The backward is the splash-attention kernels jax ships (0.9.0;
-  dq and dkv apart, reached through the private
-  ``_splash_attention_bwd``), in their dynamic-mask form:
-  ``process_dynamic_mask`` would lay the mask out once a query head; the
-  selection is one for all heads, so its blocks are laid out once, by
-  query and by key, as int32, and every head's block table points at them.
-  Both halves skip the blocks that keep no pair (above the diagonal, all of
-  them) and mask inside the others: they visit every causal block whatever
-  was selected. What is assumed of ``keep``: a bool ``[T, T]`` in which
-  every query keeps at least one key; not that it is causal. A sequence
-  alone on the v5e (my chip runs, PR 43): forward 16.5 ms of which the
-  kernel 14.6-15.2, the int8 copy 1.6 and the block table 1.0 (jax's
-  dynamic-mask forward: 35.0 with its layout, 33.9 without; it steps a head
-  at a time and reads the mask as int32, 17.7 GB a call; a static causal
-  mask over the same pairs: 17.3); forward and backward 93.8 (110 with
-  jax's forward). Blocks of 1,024 x 512, 512 x 1,024 and 1,024 x 1,024
-  read within 0.3 ms of 512 x 512; 256 x 512 is 1.3 ms slower; one int8
-  tile a head in place of a group 21.2-27.1 ms.
+- ``splash``: Pallas kernels of this repo, TPU only (blocks of 512), all
+  three built alike: a grid step holds the ``G`` query heads of a key/value
+  head (eight in ``humanoid-keye2-ep8``), one tile of keys, one of values
+  and ONE int8 tile of the selection for all of them, read from ``keep`` as
+  it is (a byte a pair), and the blocks that keep no pair (above the
+  diagonal, all of them) are neither fetched nor run; inside the others
+  the mask is applied, so every causal block is visited whatever was
+  selected. The forward (``group_masked_forward``, since PR 43) hands back
+  the output and every head's log-sum-exp, which is both the backward's
+  residual and the alignment target's, so one call serves the attention,
+  its gradient and the loss, and the passes that need neither drop it. The
+  backward (since PR 46) is ``group_masked_dq``, on the forward's grid and
+  block table, and ``group_masked_dkv``, queries innermost, which holds a
+  tile of keys and of values across the queries and sums ``dk`` and ``dv``
+  over the blocks of queries and the ``G`` heads in VMEM; it reads the
+  forward's int8 copy of the mask, a tile turned in the kernel for all
+  ``G`` heads, by a block table by columns. The arithmetic is that of the splash-attention kernels
+  jax ships (0.9.0), step for step; those step one query head at a time
+  and read the mask as int32 blocks that ``process_dynamic_mask`` lays out
+  (1 GiB a layout at 16,384 tokens: 17.7 GB of mask a call where these
+  read 0.55). What is assumed of ``keep``: a bool ``[T, T]`` in which
+  every query keeps at least one key; not that it is causal, nor that
+  every key is kept. A sequence alone on the v5e (my chip runs, PR 43):
+  forward 16.5 ms of which the kernel 14.6-15.2, the int8 copy 1.6 and the
+  block table 1.0 (jax's dynamic-mask forward: 35.0 with its layout, 33.9
+  without; a static causal mask over the same pairs: 17.3). Blocks of
+  1,024 x 512, 512 x 1,024 and 1,024 x 1,024 read within 0.3 ms of 512 x
+  512; 256 x 512 is 1.3 ms slower; one int8 tile a head in place of a
+  group 21.2-27.1 ms. The backward alone (my chip runs, PR 46): ``dq`` 20.4
+  ms and ``dkv`` 25.7, 19.3 and 24.2 a call inside the chunk (90 and 95 %
+  of the three and four products a causal block at the chip's peak),
+  ``di`` 1.0, where jax's pair took 78.9 with its two layouts (3.7 to
+  make); forward and backward 59.9 (93.7). Blocks of 1,024 on either axis read within
+  0.3 ms of 512 x 512 in both kernels and 256 is 0.8-1.0 slower; with the
+  heads in a ``fori_loop`` (one, two, four a loop step) ``dq`` takes 22.8
+  / 21.7 / 21.2 and ``dkv`` 28.2 / 27.1 / 26.5, and the cell's warm
+  set-up did not move with the heads unrolled (114.8 s against 115.2), so
+  they stay unrolled. ``dq`` is the bits of jax's; ``dk`` and ``dv`` are
+  summed in another order (``group_masked_dkv``).
 - ``blockwise``: plain ``jax.numpy``, a block of queries against the keys
   its group sees, rematerialised in the backward pass. Runs anywhere (441
   ms forward there).
@@ -86,11 +99,13 @@ import jax.numpy as jnp
 IMPLS = ("splash", "blockwise")
 GROUPS = 8
 SPLASH_BLOCK = 512
-# the forward kernel's name in a compiled program and a trace, and the fast
-# memory it may take: a group's accumulators (6 MB at 8 heads and blocks of
-# 512) and the unrolled heads' score tiles are refused under the compiler's
+# the kernels' names in a compiled program and a trace, and the fast memory
+# each may take: a group's accumulators (6 MB at 8 heads and blocks of 512)
+# and the unrolled heads' score tiles are refused under the compiler's
 # default of 16 MiB and fit in 32 (the v5e has 128)
 FORWARD_KERNEL = "group_masked_fwd"
+DQ_KERNEL = "group_masked_dq"
+DKV_KERNEL = "group_masked_dkv"
 VMEM_LIMIT = 64 * 2 ** 20
 
 
@@ -343,53 +358,84 @@ def splash_fits(t_len: int, head_dim: int, kv_chunk: int) -> bool:
             and kv_chunk % 128 == 0)
 
 
-def _block_table(keep, bq: int, bkv: int):
-    """``[T / bq, T / bkv]`` int32, a block of keys for every grid step of
-    ``group_masked_forward`` to hold: the step's own where the block keeps
-    a pair (the step runs), else the last one before it that does (the
-    first, before any), which the step before holds already, so nothing is
-    fetched and nothing run."""
+def _kept_blocks(keep, bq: int, bkv: int):
+    """``[T / bq, T / bkv]`` bool: the blocks of ``keep`` that keep a
+    pair."""
     t_len = keep.shape[0]
     # down the rows first, then along them: 0.96 ms on the v5e at 16,384
     # positions where both axes at once take 1.93 (my chip run, PR 43)
     some = jnp.any(keep.reshape(t_len // bq, bq, t_len), axis=1)
-    some = jnp.any(some.reshape(t_len // bq, t_len // bkv, bkv), axis=2)
-    j = jnp.arange(t_len // bkv, dtype=jnp.int32)
+    return jnp.any(some.reshape(t_len // bq, t_len // bkv, bkv), axis=2)
+
+
+def _block_table(some):
+    """``[rows, steps]`` int32 from the kept blocks ``some [rows, steps]``,
+    a block for every grid step of a kernel that walks a row of them to
+    hold: the step's own where the block keeps a pair (the step runs),
+    else the last one before it that does (the first, before any), which
+    the step before holds already, so nothing is fetched and nothing run.
+    ``group_masked_forward`` and ``group_masked_dq`` walk the keys of a
+    block of queries (``some`` as ``_kept_blocks`` makes it),
+    ``group_masked_dkv`` the queries of a block of keys (its transpose)."""
+    j = jnp.arange(some.shape[1], dtype=jnp.int32)
     last = jax.lax.cummax(jnp.where(some, j, -1), axis=1)
     first = jnp.argmax(some, axis=1).astype(jnp.int32)
     return jnp.where(last >= 0, last, first[:, None])
 
 
-def group_masked_forward(q, k, v, keep, *, block_q: int | None = None,
-                         block_kv: int | None = None,
-                         interpret: bool = False):
-    """``(out [Hkv, G, T, D], lse [Hkv, G, T] float32)``: softmax attention
-    under ``keep [T, T]`` and every query head's log-sum-exp over its
-    selection, the forward pass as a Pallas kernel of this repo. One grid
-    step holds a key/value head's ``G`` tiles of queries, one tile of keys,
-    one of values and ONE tile of ``keep`` as int8 for all ``G`` heads,
-    which are taken in a loop (jax 0.9.0's kernel steps a query head at a
-    time and reads the mask as int32: eight fetches of 1 MB where this
-    makes one of 256 KB). Blocks that keep no pair are neither fetched nor
-    run (``_block_table``); ``keep`` is any bool matrix in which every
-    query keeps a key, causal or not. The arithmetic is jax's kernel's,
-    step for step: products of the inputs' dtype summed in float32, the
-    softmax in float32 under a running maximum, ``DEFAULT_MASK_VALUE`` for
-    what is not kept, ``lse = m + log(l)``."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+def _blocks(t_len: int, block_q: int | None, block_kv: int | None):
+    return (min(block_q or SPLASH_BLOCK, t_len),
+            min(block_kv or SPLASH_BLOCK, t_len))
+
+
+def _wide(a, n: int):
+    """A row's value on every lane of ``a [rows, lanes]``, on ``n`` lanes."""
+    lanes = a.shape[-1]
+    return a if n == lanes else jnp.tile(a, (1, n // lanes))
+
+
+def _mask_value():
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sa)
+    return sa.DEFAULT_MASK_VALUE
+
+
+def _group_call(kernel, name: str, *, grid, table, in_specs, out_specs,
+                out_shape, scratch, operands, interpret: bool):
+    """One of this module's kernels over ``grid`` (key/value heads first,
+    then the axis a step holds still, then the axis it walks by
+    ``table``), under its name in a compiled program and a trace."""
+    from jax.experimental.pallas import tpu as pltpu
+    from jax.experimental import pallas as pl
+
+    # (the scope keeps the call's name in a compiled program the kernel's
+    # own under a transformation too, as jax's kernels keep theirs)
+    with jax.named_scope(name):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+                out_specs=out_specs, scratch_shapes=scratch),
+            out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+                vmem_limit_bytes=VMEM_LIMIT),
+            interpret=interpret, name=name)(table, *operands)
+
+
+def _forward(q, k, v, keep, block_q, block_kv, interpret: bool):
+    """``group_masked_forward``'s ``(out, lse)``, and what it made of
+    ``keep`` that the backward reads again: the int8 copy and the kept
+    blocks."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     hkv, group, t_len, d = q.shape
-    bq = min(block_q or SPLASH_BLOCK, t_len)
-    bkv = min(block_kv or SPLASH_BLOCK, t_len)
+    bq, bkv = _blocks(t_len, block_q, block_kv)
+    kept, some = keep.astype(jnp.int8), _kept_blocks(keep, bq, bkv)
     lanes = min(128, bkv)
     steps = t_len // bkv
-    mask_value = sa.DEFAULT_MASK_VALUE
-
-    def wide(a, n):  # a row's value on ``lanes`` lanes, on ``n`` of them
-        return a if n == lanes else jnp.tile(a, (1, n // lanes))
+    mask_value = _mask_value()
 
     # (the steps take the refs they write as arguments: a Pallas kernel
     # hands its outputs and scratch back through them)
@@ -408,19 +454,19 @@ def group_masked_forward(q, k, v, keep, *, block_q: int | None = None,
             s = jnp.where(kept, s, mask_value)
             m_prev, l_prev = m_ref[g], l_ref[g]
             m_next = jnp.maximum(m_prev, s.max(axis=-1)[:, None])
-            p = jnp.exp(s - wide(m_next, bkv))
+            p = jnp.exp(s - _wide(m_next, bkv))
             alpha = jnp.exp(m_prev - m_next)
             m_ref[g] = m_next
             l_ref[g] = alpha * l_prev + jax.lax.broadcast_in_dim(
                 p.sum(axis=-1), l_prev.shape, (0,))
-            acc_ref[g] = wide(alpha, d) * acc_ref[g] + jax.lax.dot_general(
+            acc_ref[g] = _wide(alpha, d) * acc_ref[g] + jax.lax.dot_general(
                 p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
     def finish(out_ref, lse_ref, m_ref, l_ref, acc_ref):
         for g in range(group):
             l = l_ref[g]
-            out_ref[0, g] = (acc_ref[g] * wide(1.0 / l, d)).astype(
+            out_ref[0, g] = (acc_ref[g] * _wide(1.0 / l, d)).astype(
                 out_ref.dtype)
             # a query's value fills its row of lanes: one column of it,
             # with the queries along the lanes
@@ -435,119 +481,247 @@ def group_masked_forward(q, k, v, keep, *, block_q: int | None = None,
             lambda: visit(q_ref, k_ref, v_ref, keep_ref, *scratch))
         pl.when(j == steps - 1)(lambda: finish(out_ref, lse_ref, *scratch))
 
-    # (the scope keeps the call's name in a compiled program the kernel's
-    # own under a transformation too, as jax's kernels keep theirs)
-    with jax.named_scope(FORWARD_KERNEL):
-        return pl.pallas_call(
-            kernel,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1, grid=(hkv, t_len // bq, steps),
-                in_specs=[
-                    pl.BlockSpec((1, group, bq, d),
-                                 lambda h, i, j, t: (h, 0, i, 0)),
-                    pl.BlockSpec((1, bkv, d),
-                                 lambda h, i, j, t: (h, t[i, j], 0)),
-                    pl.BlockSpec((1, bkv, d),
-                                 lambda h, i, j, t: (h, t[i, j], 0)),
-                    pl.BlockSpec((bq, bkv),
-                                 lambda h, i, j, t: (i, t[i, j]))],
-                out_specs=[
-                    pl.BlockSpec((1, group, bq, d),
-                                 lambda h, i, j, t: (h, 0, i, 0)),
-                    pl.BlockSpec((1, group, bq),
-                                 lambda h, i, j, t: (h, 0, i))],
-                scratch_shapes=[
-                    pltpu.VMEM((group, bq, lanes), jnp.float32),
-                    pltpu.VMEM((group, bq, lanes), jnp.float32),
-                    pltpu.VMEM((group, bq, d), jnp.float32)]),
-            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                       jax.ShapeDtypeStruct(q.shape[:3], jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-                vmem_limit_bytes=VMEM_LIMIT),
-            interpret=interpret, name=FORWARD_KERNEL)(
-                _block_table(keep, bq, bkv), q, k, v, keep.astype(jnp.int8))
+    heads = pl.BlockSpec((1, group, bq, d), lambda h, i, j, t: (h, 0, i, 0))
+    keys = pl.BlockSpec((1, bkv, d), lambda h, i, j, t: (h, t[i, j], 0))
+    return _group_call(
+        kernel, FORWARD_KERNEL, grid=(hkv, t_len // bq, steps),
+        table=_block_table(some),
+        in_specs=[heads, keys, keys,
+                  pl.BlockSpec((bq, bkv), lambda h, i, j, t: (i, t[i, j]))],
+        out_specs=[heads, pl.BlockSpec((1, group, bq),
+                                       lambda h, i, j, t: (h, 0, i))],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(q.shape[:3], jnp.float32)],
+        scratch=[pltpu.VMEM((group, bq, lanes), jnp.float32),
+                 pltpu.VMEM((group, bq, lanes), jnp.float32),
+                 pltpu.VMEM((group, bq, d), jnp.float32)],
+        operands=(q, k, v, kept), interpret=interpret), (kept, some)
 
 
-def _shared_mask_info(keep, heads: int, block: int, dkv: bool):
-    """``keep``'s ``MaskInfo`` for ``heads`` query heads that share it: the
-    mask's blocks laid out once (as the kernels take them: ``[blocks,
-    block, block]``), every head's tables pointing at them."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_mask_info as mask_info)
-
-    process = (mask_info.process_dynamic_mask_dkv if dkv
-               else mask_info.process_dynamic_mask)
-    info, _ = process(keep[None], (block, block), downcast_smem_data=True,
-                      head_shards=1, q_seq_shards=1)
-    every = lambda a: jnp.broadcast_to(a, (heads,) + a.shape[1:])  # noqa
-    return info._replace(
-        data_next=every(info.data_next), mask_next=every(info.mask_next),
-        block_mask=every(info.block_mask),
-        partial_mask_blocks=info.partial_mask_blocks.reshape(
-            -1, block, block))
-
-
-def _backward_layout(keep, group: int, t_len: int):
-    """``(block sizes, keep's MaskInfo by query, by key)``: what jax's
-    backward kernels of one key/value head and its ``group`` query heads
-    take (by query: dq; by key: dkv)."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as sa)
-
-    b = min(SPLASH_BLOCK, t_len)
-    # the fused backward hands dq back once a block of keys ([T / b, G, T,
-    # D] a key/value head: 4 GB at 16,384 tokens); dq and dkv apart do not
-    sizes = sa.BlockSizes(
-        block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b,
-        block_kv_dkv=b, block_kv_dkv_compute=b, block_q_dq=b, block_kv_dq=b)
-    return (sizes, _shared_mask_info(keep, group, b, False),
-            _shared_mask_info(keep, group, b, True))
+def group_masked_forward(q, k, v, keep, *, block_q: int | None = None,
+                         block_kv: int | None = None,
+                         interpret: bool = False):
+    """``(out [Hkv, G, T, D], lse [Hkv, G, T] float32)``: softmax attention
+    under ``keep [T, T]`` and every query head's log-sum-exp over its
+    selection, the forward pass as a Pallas kernel of this repo. One grid
+    step holds a key/value head's ``G`` tiles of queries, one tile of keys,
+    one of values and ONE tile of ``keep`` as int8 for all ``G`` heads,
+    which are taken in a loop (jax 0.9.0's kernel steps a query head at a
+    time and reads the mask as int32: eight fetches of 1 MB where this
+    makes one of 256 KB). Blocks that keep no pair are neither fetched nor
+    run (``_block_table``); ``keep`` is any bool matrix in which every
+    query keeps a key, causal or not. The arithmetic is jax's kernel's,
+    step for step: products of the inputs' dtype summed in float32, the
+    softmax in float32 under a running maximum, ``DEFAULT_MASK_VALUE`` for
+    what is not kept, ``lse = m + log(l)``."""
+    return _forward(q, k, v, keep, block_q, block_kv, interpret)[0]
 
 
-# The forward pass is this module's kernel (``group_masked_forward``); the
-# backward is jax 0.9.0's: ``_splash_attention_bwd``, a private name of its
-# splash-attention module, runs its dq and dkv kernels on ``(q, k, v, out,
-# lse)`` and the two int32 layouts of ``keep`` that ``_backward_layout``
-# makes (``tests/test_torso_v5e_compile.py`` compiles both halves). The
-# layouts only ride to the backward rule: a program that is not
-# differentiated never makes them. ``keep`` is any bool ``[T, T]`` in which
-# every query keeps a key; neither half assumes the causal order.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _splash_out_and_lse(sizes, interpret, keep, by_query, by_key, q, k, v):
+def group_masked_dq(q, k, v, kept, table, lse, di, d_out, *,
+                    block_q: int | None = None, block_kv: int | None = None,
+                    interpret: bool = False):
+    """``dq [Hkv, G, T, D]``: the queries' gradient of attention under the
+    mask whose int8 copy is ``kept [T, T]``, from the forward's ``lse``,
+    ``di = sum(out * d_out, -1)`` (both ``[Hkv, G, T]`` float32) and the
+    output's cotangent. The forward's grid and the forward's block table
+    (``table``, by rows): a step holds the group's tiles of ``q``,
+    ``d_out``, ``lse`` and ``di`` across the keys, streams one tile of
+    keys, one of values and one int8 tile of the mask for all ``G`` heads,
+    and sums ``dq`` in float32 in VMEM, written at the last block of keys.
+    jax's ``dq`` kernel's arithmetic, step for step: ``p = exp(s - lse)``
+    under ``DEFAULT_MASK_VALUE``, ``ds = (dp - di) p``, ``ds`` cast to the
+    inputs' dtype before its product, the sums in the same order (the same
+    bits)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    hkv, group, t_len, d = q.shape
+    bq, bkv = _blocks(t_len, block_q, block_kv)
+    lanes = min(128, bkv)
+    steps = t_len // bkv
+    mask_value = _mask_value()
+
+    def start(lse_ref, di_ref, dq_acc, lse_rows, di_rows):
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        # the queries lie along the lanes in ``lse`` and ``di`` and down
+        # the rows of a score tile: turned once a block of queries
+        for g in range(group):
+            for ref, rows in ((lse_ref, lse_rows), (di_ref, di_rows)):
+                rows[g] = jnp.transpose(jnp.broadcast_to(
+                    ref[0, pl.ds(g, 1), :], (lanes, bq)))
+
+    def visit(q_ref, do_ref, k_ref, v_ref, keep_ref, dq_acc, lse_rows,
+              di_rows):
+        kept = keep_ref[...].astype(jnp.int32) != 0
+        keys, values = k_ref[0], v_ref[0]
+        for g in range(group):
+            s = jax.lax.dot_general(
+                q_ref[0, g], keys, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            p = jnp.exp(jnp.where(kept, s, mask_value)
+                        - _wide(lse_rows[g], bkv))
+            dp = jax.lax.dot_general(
+                do_ref[0, g].astype(values.dtype), values,
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            ds = (dp - _wide(di_rows[g], bkv)) * p
+            dq_acc[g] += jax.lax.dot_general(
+                ds.astype(keys.dtype), keys, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    def finish(dq_ref, dq_acc):
+        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+
+    def kernel(table_ref, q_ref, do_ref, lse_ref, di_ref, k_ref, v_ref,
+               keep_ref, dq_ref, dq_acc, lse_rows, di_rows):
+        i, j = pl.program_id(1), pl.program_id(2)
+        pl.when(j == 0)(lambda: start(lse_ref, di_ref, dq_acc, lse_rows,
+                                      di_rows))
+        pl.when(table_ref[i, j] == j)(
+            lambda: visit(q_ref, do_ref, k_ref, v_ref, keep_ref, dq_acc,
+                          lse_rows, di_rows))
+        pl.when(j == steps - 1)(lambda: finish(dq_ref, dq_acc))
+
+    heads = pl.BlockSpec((1, group, bq, d), lambda h, i, j, t: (h, 0, i, 0))
+    rows = pl.BlockSpec((1, group, bq), lambda h, i, j, t: (h, 0, i))
+    keys = pl.BlockSpec((1, bkv, d), lambda h, i, j, t: (h, t[i, j], 0))
+    return _group_call(
+        kernel, DQ_KERNEL, grid=(hkv, t_len // bq, steps), table=table,
+        in_specs=[heads, heads, rows, rows, keys, keys,
+                  pl.BlockSpec((bq, bkv), lambda h, i, j, t: (i, t[i, j]))],
+        out_specs=heads, out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch=[pltpu.VMEM((group, bq, d), jnp.float32),
+                 pltpu.VMEM((group, bq, lanes), jnp.float32),
+                 pltpu.VMEM((group, bq, lanes), jnp.float32)],
+        operands=(q, d_out, lse, di, k, v, kept), interpret=interpret)
+
+
+def group_masked_dkv(q, k, v, kept, table, lse, di, d_out, *,
+                     block_q: int | None = None,
+                     block_kv: int | None = None, interpret: bool = False):
+    """``(dk, dv) [Hkv, T, D]``: the keys' and values' gradients, queries
+    innermost: grid ``(Hkv, T / bkv, T / bq)``. A step holds one tile of
+    keys and one of values across the queries (fetched once a block of
+    keys, not once a head and block), streams the group's tiles of ``q``,
+    ``d_out``, ``lse``, ``di`` and one int8 tile of the mask by the block
+    table by columns (``table [T / bkv, T / bq]``), and sums ``dk`` and
+    ``dv`` in float32 in VMEM over the blocks of queries AND the ``G``
+    heads, written at the last block of queries: multi-query's sum over
+    heads is that accumulation, and no ``[G, ...]`` partial reaches HBM.
+    The score tiles stand keys by queries, as in jax's ``dkv`` kernel, so
+    every product is one the MXU takes as it is and ``lse`` and ``di``
+    are read as they lie (queries along the lanes); the mask's tile is the
+    forward's (``kept``, queries by keys), turned in the kernel once a
+    step for all ``G`` heads (a second copy of the mask by keys, made by
+    XLA, reads 0.1 ms slower a call and costs 1.3-1.8 ms and 268 MB to
+    make; my chip run, PR 46). jax's kernel's arithmetic, step for step; it
+    sums a head's blocks of queries and then the next head's, this a
+    block's heads and then the next block's: the same terms in float32 in
+    another order (on the chip 0.03 % of the bfloat16 elements of ``dk``
+    and of ``dv`` differ, by at most 2^-7 on values up to 6.5 and 14.75). A
+    block of keys that no query keeps runs once, on a tile that masks
+    everything: ``p`` is 0 there and so are its sums."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    hkv, group, t_len, d = q.shape
+    bq, bkv = _blocks(t_len, block_q, block_kv)
+    steps = t_len // bq
+    mask_value = _mask_value()
+
+    def start(dk_acc, dv_acc):
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def visit(q_ref, do_ref, lse_ref, di_ref, k_ref, v_ref, keep_ref, dk_acc,
+              dv_acc):
+        kept = jnp.transpose(keep_ref[...].astype(jnp.float32)) != 0
+        keys, values = k_ref[0], v_ref[0]
+        for g in range(group):
+            queries, do = q_ref[0, g], do_ref[0, g]
+            s = jax.lax.dot_general(
+                keys, queries, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            p = jnp.exp(jnp.where(kept, s, mask_value)
+                        - lse_ref[0, pl.ds(g, 1), :])
+            dv_acc[...] += jax.lax.dot_general(
+                p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(
+                values, do, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ds = (dp - di_ref[0, pl.ds(g, 1), :]) * p
+            dk_acc[...] += jax.lax.dot_general(
+                ds.astype(do.dtype), queries, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    def finish(dk_ref, dv_ref, dk_acc, dv_acc):
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    def kernel(table_ref, q_ref, do_ref, lse_ref, di_ref, k_ref, v_ref,
+               keep_ref, dk_ref, dv_ref, dk_acc, dv_acc):
+        j, i = pl.program_id(1), pl.program_id(2)
+        pl.when(i == 0)(lambda: start(dk_acc, dv_acc))
+        pl.when(table_ref[j, i] == i)(
+            lambda: visit(q_ref, do_ref, lse_ref, di_ref, k_ref, v_ref,
+                          keep_ref, dk_acc, dv_acc))
+        pl.when(i == steps - 1)(
+            lambda: finish(dk_ref, dv_ref, dk_acc, dv_acc))
+
+    heads = pl.BlockSpec((1, group, bq, d),
+                         lambda h, j, i, t: (h, 0, t[j, i], 0))
+    rows = pl.BlockSpec((1, group, bq), lambda h, j, i, t: (h, 0, t[j, i]))
+    keys = pl.BlockSpec((1, bkv, d), lambda h, j, i, t: (h, j, 0))
+    return _group_call(
+        kernel, DKV_KERNEL, grid=(hkv, t_len // bkv, steps), table=table,
+        in_specs=[heads, heads, rows, rows, keys, keys,
+                  pl.BlockSpec((bq, bkv), lambda h, j, i, t: (t[j, i], j))],
+        out_specs=[keys, keys],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch=[pltpu.VMEM((bkv, d), jnp.float32),
+                 pltpu.VMEM((bkv, d), jnp.float32)],
+        operands=(q, d_out, lse, di, k, v, kept), interpret=interpret)
+
+
+# Forward and backward are this module's kernels. The forward's int8 copy
+# of ``keep`` and its kept blocks ride to the backward rule, which makes of
+# the blocks ``group_masked_dkv``'s table by columns: no program lays the
+# mask out as int32 or copies it a second time
+# (``tests/test_torso_v5e_compile.py`` compiles all three kernels).
+# ``keep`` is any bool ``[T, T]`` in which every query keeps a key; no
+# kernel assumes the causal order.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _splash_out_and_lse(interpret, keep, q, k, v):
     return group_masked_forward(q, k, v, keep, interpret=interpret)
 
 
-def _splash_out_and_lse_fwd(sizes, interpret, *primals):
+def _splash_out_and_lse_fwd(interpret, *primals):
     from jax.custom_derivatives import CustomVJPPrimal
 
-    keep, by_query, by_key, q, k, v = jax.tree_util.tree_map(
+    keep, q, k, v = jax.tree_util.tree_map(
         lambda p: p.value, primals,
         is_leaf=lambda p: isinstance(p, CustomVJPPrimal))
-    out, lse = _splash_out_and_lse(sizes, interpret, keep, by_query, by_key,
-                                   q, k, v)
-    return (out, lse), (q, k, v, out, lse, by_query, by_key)
+    (out, lse), (kept, some) = _forward(q, k, v, keep, None, None, interpret)
+    return (out, lse), (q, k, v, out, lse, kept, some)
 
 
-def _splash_out_and_lse_bwd(sizes, interpret, res, cts):
+def _splash_out_and_lse_bwd(interpret, res, cts):
     from jax.custom_derivatives import SymbolicZero
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as sa)
 
-    q, k, v, out, lse, by_query, by_key = res
+    q, k, v, out, lse, kept, some = res
     d_out, d_lse = cts
     if not isinstance(d_lse, SymbolicZero):
         raise TypeError("the log-sum-exp is handed out as a constant: its "
                         "backward is not the kernel's (stop_gradient it)")
-
-    def head(q, k, v, out, lse, d_out):
-        return sa._splash_attention_bwd(
-            False, sa.DEFAULT_MASK_VALUE, True, sizes, None, None, None,
-            interpret, (q, k, v, None, None, out, lse, by_query, by_key),
-            d_out)[3:6]
-
-    return (None, None, None) + tuple(
-        jax.vmap(head)(q, k, v, out, lse, d_out))
+    # as jax's backward makes it: float32, plain XLA, once a call
+    di = jnp.einsum("hgsd,hgsd->hgs", out.astype(jnp.float32),
+                    d_out.astype(jnp.float32))
+    dq = group_masked_dq(q, k, v, kept, _block_table(some), lse, di, d_out,
+                         interpret=interpret)
+    dk, dv = group_masked_dkv(q, k, v, kept, _block_table(some.T), lse, di,
+                              d_out, interpret=interpret)
+    return None, dq, dk, dv
 
 
 _splash_out_and_lse.defvjp(_splash_out_and_lse_fwd, _splash_out_and_lse_bwd,
@@ -557,12 +731,10 @@ _splash_out_and_lse.defvjp(_splash_out_and_lse_fwd, _splash_out_and_lse_bwd,
 def splash_attention_and_lse(q, k, v, keep, *, interpret: bool = False):
     """Attention under ``keep`` by the kernels, each key/value head with
     its ``G`` query heads: the output, differentiable (this module's
-    forward, jax's dq and dkv), and ``lse [Hkv, G, T]`` float32, every
+    forward, ``dq`` and ``dkv``), and ``lse [Hkv, G, T]`` float32, every
     query head's log-sum-exp over its selection, from the one forward call
     (a constant: the backward rule refuses a cotangent on it)."""
-    sizes, by_query, by_key = _backward_layout(keep, q.shape[1], q.shape[2])
-    out, lse = _splash_out_and_lse(sizes, interpret, keep, by_query, by_key,
-                                   q, k, v)
+    out, lse = _splash_out_and_lse(interpret, keep, q, k, v)
     return out, jax.lax.stop_gradient(lse)
 
 

@@ -239,8 +239,8 @@ def test_blockwise_masked_attention_and_its_gradient_match_a_naive_mask():
 
 
 def test_the_kernels_dynamic_mask_form_matches_the_blockwise_form():
-    """The splash kernel in interpret mode, one layout of the mask for all
-    the heads of a group, forward and its own backward."""
+    """The three kernels in interpret mode, one int8 tile of the mask for
+    all the heads of a group, forward and backward."""
     q, k, v, ct = attention_inputs(256, seed=2, d=128)
     qi, ki, wi = index_inputs(256, seed=2)
     keep, _ = sparse.select_keys(qi, ki, wi, topk=64, q_chunk=64,
@@ -289,14 +289,40 @@ def test_the_alignment_loss_through_the_kernels_matches_the_jnp_form():
 
 
 # -- one forward call for the output and the loss -----------------------------
+def jax_layouts(keep, group, t_len):
+    """``(block sizes, keep's MaskInfo by query, by key)`` as jax's own
+    dynamic-mask kernels of one key/value head and its ``group`` query
+    heads take them (until PR 46 the module made these for its backward):
+    ``process_dynamic_mask`` lays the mask out as int32 blocks, once for
+    all the heads, every head's tables pointing at them."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sa, splash_attention_mask_info as info)
+
+    b = min(sparse.SPLASH_BLOCK, t_len)
+
+    def shared(process):
+        laid, _ = process(keep[None], (b, b), downcast_smem_data=True,
+                          head_shards=1, q_seq_shards=1)
+        every = lambda a: jnp.broadcast_to(a, (group,) + a.shape[1:])  # noqa
+        return laid._replace(
+            data_next=every(laid.data_next), mask_next=every(laid.mask_next),
+            block_mask=every(laid.block_mask),
+            partial_mask_blocks=laid.partial_mask_blocks.reshape(-1, b, b))
+
+    sizes = sa.BlockSizes(
+        block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b,
+        block_kv_dkv=b, block_kv_dkv_compute=b, block_q_dq=b, block_kv_dq=b)
+    return (sizes, shared(info.process_dynamic_mask),
+            shared(info.process_dynamic_mask_dkv))
+
+
 def residual_forward(q, k, v, keep):
     """Until PR 42 the loss's own pass: jax's kernel object asked to keep
     its residuals, ``(out, lse)``, not differentiable."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sa)
 
-    sizes, by_query, _ = sparse._backward_layout(keep, q.shape[1],
-                                                 q.shape[2])
+    sizes, by_query, _ = jax_layouts(keep, q.shape[1], q.shape[2])
     kernel = sa.SplashAttentionKernel(
         by_query, None, None, block_sizes=sizes, is_mqa=True,
         save_residuals=True,
@@ -304,6 +330,24 @@ def residual_forward(q, k, v, keep):
         residual_checkpoint_name=None, mask_function=None, interpret=True)
     out, (lse,) = jax.vmap(kernel)(q, k, v)
     return out, lse
+
+
+def jax_backward(q, k, v, out, lse, d_out, keep):
+    """``(dq, dk, dv)`` by jax 0.9.0's own ``dq`` and ``dkv`` kernels in
+    interpret mode (``_splash_attention_bwd``, what the module's backward
+    rule called until PR 46) on ``jax_layouts``."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sa)
+
+    sizes, by_query, by_key = jax_layouts(keep, q.shape[1], q.shape[2])
+
+    def head(q, k, v, out, lse, d_out):
+        return sa._splash_attention_bwd(
+            False, sa.DEFAULT_MASK_VALUE, True, sizes, None, None, None,
+            True, (q, k, v, None, None, out, lse, by_query, by_key),
+            d_out)[3:6]
+
+    return jax.vmap(head)(q, k, v, out, lse, d_out)
 
 
 @pytest.fixture(scope="module")
@@ -327,16 +371,21 @@ def same_bits(a, b):
 @pytest.mark.parametrize("part", ["out", "dq", "dk", "dv"])
 def test_the_one_call_gives_the_kernels_own_output_and_gradients(selected,
                                                                  part):
-    """``splash_attention_and_lse`` against ``splash_masked_attention``
-    (jax's own ``custom_vjp``): bit for bit."""
-    (q, k, v, ct), _index, keep, _new, _old = selected
+    """``splash_attention_and_lse`` differentiated (this module's three
+    kernels) against jax's own kernels on the mask's int32 layouts, the
+    forward that keeps its residuals and ``_splash_attention_bwd``: bit for
+    bit (256 tokens are one block, so the sums' order is jax's too)."""
+    (q, k, v, ct), _index, keep, (out, lse), (old_out, _old_lse) = selected
     got = with_gradients(lambda q, k, v: sparse.splash_attention_and_lse(
         q, k, v, keep, interpret=True)[0], q, k, v, ct)
-    want = with_gradients(lambda q, k, v: sparse.splash_masked_attention(
-        q, k, v, keep, interpret=True), q, k, v, ct)
+    want = (old_out,) + tuple(jax_backward(q, k, v, out, lse, ct, keep))
     i = ["out", "dq", "dk", "dv"].index(part)
     same_bits(got[i], want[i])
     assert float(jnp.max(jnp.abs(got[i]))) > 0
+    # the pass that keeps no lse has the same gradients
+    same_bits(got[i], with_gradients(
+        lambda q, k, v: sparse.splash_masked_attention(
+            q, k, v, keep, interpret=True), q, k, v, ct)[i])
 
 
 def test_the_one_calls_lse_is_the_residual_keeping_forwards(selected):
@@ -386,9 +435,7 @@ def both_losses(keep, ct, constant=True):
             out, lse = sparse.splash_attention_and_lse(q, k, v, keep,
                                                        interpret=True)
         else:  # the custom_vjp itself, with nothing between it and a reader
-            sizes, *infos = sparse._backward_layout(keep, 2, q.shape[2])
-            out, lse = sparse._splash_out_and_lse(sizes, True, keep, *infos,
-                                                  q, k, v)
+            out, lse = sparse._splash_out_and_lse(True, keep, q, k, v)
         return (jnp.sum(out * ct) + jnp.sum(lse) * (not constant)
                 + sparse.alignment_loss(qi, ki, wi, keep, q, k, lse,
                                         q_chunk=128, kv_chunk=128,
@@ -457,8 +504,10 @@ def selection(kind):
     queries 128.. keeping none of keys ..127 (blocks of 128: one below the
     diagonal is empty; every query keeps itself); with those queries keeping
     all of them (that block is kept whole, as is the first 64 queries'
-    causal triangle); and a seeded third of ALL pairs, above the diagonal
-    too."""
+    causal triangle); a seeded third of ALL pairs, above the diagonal
+    too; and the top 64 with keys 128.. and key 5 kept by no query (a whole
+    block of keys, and one column of a kept block; every query keeps key
+    0)."""
     rows = jnp.arange(T_KERNEL)[:, None]
     cols = jnp.arange(T_KERNEL)[None, :]
     if kind == "not_causal":
@@ -467,6 +516,8 @@ def selection(kind):
     keep, _ = sparse.select_keys(*index_inputs(T_KERNEL, seed=6), topk=64,
                                  q_chunk=64, kv_chunk=64)
     corner = (rows >= 128) & (cols < 128)
+    if kind == "empty_columns":
+        return (keep & (cols < 128) & (cols != 5)) | (cols == 0)
     if kind == "empty_block":
         return (keep & ~corner) | (rows == cols)
     return keep | corner if kind == "full_block" else keep
@@ -513,7 +564,8 @@ def test_the_forward_kernel_is_the_masked_attention_and_its_lse(
     whole, and a selection that is not causal."""
     q, k, v, _ = grouped_inputs(group)
     keep = selection(kind)
-    table = np.asarray(sparse._block_table(keep, block_q, block_kv))
+    table = np.asarray(sparse._block_table(
+        sparse._kept_blocks(keep, block_q, block_kv)))
     visited = table == np.arange(table.shape[1])
     some = np.asarray(keep).reshape(T_KERNEL // block_q, block_q,
                                     T_KERNEL // block_kv, block_kv).any(
@@ -542,26 +594,6 @@ def test_the_forward_kernel_is_the_masked_attention_and_its_lse(
             q, k, v, keep, q_chunk=64, kv_chunk=64)
         np.testing.assert_allclose(np.asarray(out), np.asarray(blockwise),
                                    rtol=2e-3, atol=2e-3)
-
-
-@pytest.mark.parametrize("kind", KINDS[:3])
-@pytest.mark.parametrize("group", [1, 2, 4])
-def test_the_new_forward_under_jaxs_backward_gives_the_blockwise_gradients(
-        group, kind, monkeypatch):
-    """``splash_attention_and_lse`` differentiated (this module's forward,
-    jax's dq and dkv kernels on its ``out`` and ``lse``), in blocks of 128
-    so that there are four, against the blockwise form's gradients."""
-    monkeypatch.setattr(sparse, "SPLASH_BLOCK", 128)
-    q, k, v, ct = grouped_inputs(group)
-    keep = selection(kind)
-    got = with_gradients(lambda q, k, v: sparse.splash_attention_and_lse(
-        q, k, v, keep, interpret=True)[0], q, k, v, ct)
-    want = with_gradients(lambda q, k, v: sparse.blockwise_masked_attention(
-        q, k, v, keep, q_chunk=64, kv_chunk=64), q, k, v, ct)
-    for g, w in zip(got, want):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-3,
-                                   atol=2e-3)
-        assert float(jnp.max(jnp.abs(g))) > 0
 
 
 @pytest.mark.parametrize("kind", KINDS)

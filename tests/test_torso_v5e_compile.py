@@ -139,7 +139,30 @@ def test_the_fused_chunk_of_the_benchmark_cell_fits_the_chip(one_chip,
     assert "gmm" in text and "splash" in text and "ragged-dot" not in text
 
 
+# what jax 0.9.0's dynamic-mask kernels read (until PR 46 the backward's):
+# the mask's 512 x 512 blocks laid out as int32, 1 GiB a layout
 MASK_LAYOUT = "s32[1024,512,512]"
+KERNELS = ("group_masked_fwd", "group_masked_dq", "group_masked_dkv")
+
+
+def vmem_limit(line):
+    """The fast memory a Pallas call site asks for, bytes."""
+    (limit,) = re.findall(r'"scoped_memory_configs":\[\{"memory_space":"1",'
+                          r'"offset":"0","size":"(\d+)"', line)
+    return int(limit)
+
+
+def assert_the_repos_kernels_alone(text):
+    """One call site each of this repo's forward, ``dq`` and ``dkv``
+    kernels, each on the int8 mask and within ``VMEM_LIMIT``; none of
+    jax's splash kernels and no int32 layout of the mask anywhere."""
+    for name in KERNELS:
+        (site,) = call_sites(text, name)
+        assert "s8[16384,16384]" in site and "s32[32,32]" in site
+        assert vmem_limit(site) <= sparse_ops.VMEM_LIMIT
+    for name in ("splash_mqa_fwd", "splash_mqa_dq", "splash_mqa_dkv"):
+        assert kernel_calls(text, name) == 0
+    assert MASK_LAYOUT not in text
 
 
 def _masked(differentiated):
@@ -168,36 +191,30 @@ def masked_operands(one_chip):
 def test_the_kernels_dynamic_mask_form_compiles_at_real_widths(
         masked_operands):
     """Attention under a mask that is an argument, differentiated: this
-    repo's forward kernel (one int8 tile of the mask for the eight heads)
-    and jax's dq and dkv kernels, the mask's blocks laid out once for the
-    eight heads (two layouts, by query and by key: not one a head)."""
+    repo's three kernels (forward, ``dq``, ``dkv``), each a grid step over
+    one int8 tile of the mask for the eight heads; the mask is copied as
+    int8 once, for all three, and never laid out as int32 (until PR 46
+    jax's backward kernels read two 1 GiB layouts)."""
     compiled = jax.jit(_masked(True)).lower(*masked_operands).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 3
-    # jax's backward kernels read the mask as int32 blocks: 1 GiB a layout,
-    # two layouts; a layout a head would be eight times that
-    assert text.count(MASK_LAYOUT) >= 2
-    assert compiled.memory_analysis().temp_size_in_bytes < 4.5e9
-    # the forward reads the mask as it is, a byte a pair, and no layout
-    (forward,) = call_sites(text, "group_masked_fwd")
-    assert "s8[16384,16384]" in forward and MASK_LAYOUT not in forward
-    assert kernel_calls(text, "splash_mqa_fwd") == 0
-    for name in ("splash_mqa_dq", "splash_mqa_dkv"):
-        (backward,) = call_sites(text, name)
-        assert MASK_LAYOUT in backward
+    assert text.count("tpu_custom_call") == 3
+    assert_the_repos_kernels_alone(text)
+    # the int8 copy, the output, `lse` and `di`: 0.40 GB (3.9 with the
+    # layouts)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
 
 
 def test_the_forward_alone_lays_out_no_int32_mask(masked_operands):
     """The passes that are not differentiated (1 and 3 of a step, every
     ``train=False`` call): one call of the forward kernel on the int8 mask
-    and a 32 x 32 block table; the backward's two 1 GiB layouts are never
-    made (until PR 43 the forward read one of them: 1 MB a block and head
-    where this reads 256 KB a block and group)."""
+    and a 32 x 32 block table, and no second copy of the mask by keys
+    (the backward rule's; until PR 43 the forward read an int32 layout: 1
+    MB a block and head where this reads 256 KB a block and group)."""
     compiled = jax.jit(_masked(False)).lower(*masked_operands).compile()
     text = compiled.as_text()
     assert kernel_calls(text, "group_masked_fwd") == 1
     assert text.count("tpu_custom_call") == 1
-    assert "s32[1024" not in text and "s8[16384,16384]" in text
+    assert "s32[1024," not in text and "s8[16384,16384]" in text
     assert "s32[32,32]" in text
     # the int8 copy of the mask (268 MB) and little else
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
@@ -230,7 +247,7 @@ def test_a_sparse_attention_layer_and_its_backward_compile_at_real_widths(
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         *on(one_chip, (params, x))).compile()
     text = compiled.as_text()
-    assert "gmm" in text and "splash" in text and "ragged-dot" not in text
+    assert "gmm" in text and "ragged-dot" not in text
     # the every-assignment buffer is a 4,096-token part's, not a sequence's
     assert "[131072,2048]" not in text and "[32768,2048]" in text
     # the attention's forward runs ONCE a forward evaluation: that call's
@@ -239,16 +256,9 @@ def test_a_sparse_attention_layer_and_its_backward_compile_at_real_widths(
     # up here, so the program holds the rematerialised evaluation alone: 1
     # (2 until PR 42, when the loss made a pass of its own; the cell's
     # chunk, which also holds passes 1 and 3, 6 -> 4 a layer). Since PR 43
-    # it is this repo's kernel on the int8 mask; the backward is jax's, on
-    # the mask's two int32 layouts
-    (forward,) = call_sites(text, "group_masked_fwd")
-    assert "s8[16384,16384]" in forward and MASK_LAYOUT not in forward
-    assert kernel_calls(text, "splash_mqa_fwd") == 0
-    assert kernel_calls(text, "splash_mqa_dkv") == 1
-    assert kernel_calls(text, "splash_mqa_dq") == 1
-    assert all(MASK_LAYOUT in line for name in ("splash_mqa_dq",
-                                                "splash_mqa_dkv")
-               for line in call_sites(text, name))
+    # it is this repo's kernel on the int8 mask, and since PR 46 so are the
+    # backward's two: no kernel of jax's splash module, no int32 layout
+    assert_the_repos_kernels_alone(text)
     # 5.98 GB (6.48 with the second pass)
     assert compiled.memory_analysis().temp_size_in_bytes < 6.3e9
 
