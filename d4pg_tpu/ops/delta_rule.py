@@ -39,9 +39,16 @@ block of ``BLOCK`` tokens has the finite inverse ``(I - A)(I + A^2)(I + A^4)``
 over a whole chunk would take sums of binomial size through float32), and
 two inverted neighbours ``T``, ``B`` under the block ``F`` between them merge
 exactly into ``[[T, 0], [-B F T, B]]``, by halves up to the chunk: the
-published kernels' scheme. On the chip the scan takes 0.6 of its time with
-XLA's ``triangular-solve`` custom call, for the same numbers to 2e-7
-(PERF.md section 6, PR 38).
+published kernels' scheme. These blocks are 8 to 32 wide, a fraction of a
+lane tile, so they are laid ``[blocks, rows, columns, batch]`` with a
+group's chunks and heads as the batch, last: a product of blocks is a
+broadcast multiply summed over the middle index, elementwise along the
+batch (exact float32, no pass of the MXU), all blocks of a level in one
+launch. Laid ``[batch, 8, 8]`` every block was a lane tile of its own,
+sixteen times its size, and every product of two blocks a launch: 5 of the
+14.5 ms a forward pass of 16,384 tokens took on the chip (10.2 so; PERF.md
+section 6, PR 47). XLA's ``triangular-solve`` custom call gives the same
+numbers to 2e-7 and took the scan 1.7 times as long (PR 38).
 
 Memory and the backward pass. Chunks are taken ``GROUP`` at a time: the
 solve and the other state-free products of a group run batched, then its
@@ -64,7 +71,9 @@ import jax.numpy as jnp
 HI = jax.lax.Precision.HIGHEST
 CHUNK = 64  # tokens a chunk: the published kernels'
 # chunks a rematerialised group: forward and backward of a 16,384-token
-# sequence take 72.7 ms at 4, 75.2 at 8, 87.6 at 16, 104.5 at 32 on the chip
+# sequence take 40.2 ms at 4 and 47.9 at 8 on the chip (PR 47: at 4 a group
+# of 32 heads is the 128 lanes of the solve's batch and its backward stays
+# in VMEM; 72.7 / 75.2 / 87.6 / 104.5 at 4 / 8 / 16 / 32 at PR 38)
 GROUP = 4
 BLOCK = 8  # tokens a diagonal block of the solve is inverted by its series
 
@@ -72,31 +81,29 @@ BLOCK = 8  # tokens a diagonal block of the solve is inverted by its series
 def _inverse(a):
     """``(I + a)^-1`` of strictly lower-triangular ``a [..., C, C]``, ``C``
     ``BLOCK`` times a power of two (or less than ``BLOCK``): the module
-    docstring's series on the diagonal blocks, merged by halves. A block is
-    an array of its own throughout (on the chip a stacked ``[..., C / 8, 8,
-    8]`` takes a third longer: PERF.md section 6, PR 38)."""
+    docstring's series on the diagonal blocks, merged by halves, the blocks
+    of a level stacked ``[blocks, b, b, batch]`` with every leading
+    dimension of ``a`` as the batch."""
     size = a.shape[-1]
-    mm = lambda x, y: jnp.einsum(  # noqa: E731
-        "...ab,...bc->...ac", x, y, precision=HI)
+    at = jnp.moveaxis(a.reshape((-1, size, size)), 0, -1)  # [C, C, batch]
+    mm = lambda x, y: jnp.sum(  # noqa: E731
+        x[:, :, :, None] * y[:, None, :, :], axis=2)
     b = min(BLOCK, size)
-    invs = []
-    for i in range(0, size, b):
-        m = a[..., i:i + b, i:i + b]
-        inv, power = jnp.eye(b, dtype=a.dtype) - m, m
-        for _ in range((b - 1).bit_length() - 1):
-            power = mm(power, power)
-            inv = inv + mm(inv, power)
-        invs.append(inv)
-    while len(invs) > 1:
-        merged = []
-        for i, (top, bottom) in enumerate(zip(invs[0::2], invs[1::2])):
-            lo = 2 * b * i
-            under = -mm(mm(bottom, a[..., lo + b:lo + 2 * b, lo:lo + b]), top)
-            merged.append(jnp.concatenate([
-                jnp.concatenate([top, jnp.zeros_like(top)], axis=-1),
-                jnp.concatenate([under, bottom], axis=-1)], axis=-2))
-        invs, b = merged, 2 * b
-    return invs[0]
+    m = jnp.stack([at[i:i + b, i:i + b] for i in range(0, size, b)])
+    inv, power = jnp.eye(b, dtype=a.dtype)[:, :, None] - m, m
+    for _ in range((b - 1).bit_length() - 1):
+        power = mm(power, power)
+        inv = inv + mm(inv, power)
+    while inv.shape[0] > 1:
+        top, bottom = inv[0::2], inv[1::2]
+        between = jnp.stack([at[lo + b:lo + 2 * b, lo:lo + b]
+                             for lo in range(0, size, 2 * b)])
+        under = -mm(mm(bottom, between), top)
+        inv = jnp.concatenate([
+            jnp.concatenate([top, jnp.zeros_like(top)], axis=2),
+            jnp.concatenate([under, bottom], axis=2)], axis=1)
+        b *= 2
+    return jnp.moveaxis(inv[0], -1, 0).reshape(a.shape)
 
 
 def _group(state, xs):
@@ -104,7 +111,7 @@ def _group(state, xs):
     Hk, Dk]``, ``v [n, C, H, Dv]``, ``g, beta [n, C, H]``; returns the state
     after them and ``o [n, C, H, Dv]``."""
     q, k, v, g, beta = xs
-    q, k = (jnp.repeat(x, v.shape[2] // x.shape[2], axis=2) for x in (q, k))
+    served = v.shape[2] // q.shape[2]  # value heads a key head
     size = q.shape[1]
     dot = lambda spec, a, b: jnp.einsum(spec, a, b, precision=HI)  # noqa
     c = jnp.cumsum(g, axis=1)  # [n, C, H]
@@ -113,12 +120,17 @@ def _group(state, xs):
     # exp(c_i - c_j) where j <= i; the upper triangle never reaches exp
     decay = jnp.exp(jnp.where(cols <= rows,
                               ch[..., :, None] - ch[..., None, :], -jnp.inf))
+    # k_i . k_j and q_i . k_j are a key head's: made once for the value
+    # heads it serves
+    kk = jnp.repeat(dot("nchd,nehd->nhce", k, k), served, axis=1)
+    qk = jnp.repeat(dot("nchd,nehd->nhce", q, k), served, axis=1) * decay
+    a = jnp.where(cols < rows,
+                  kk * beta.transpose(0, 2, 1)[..., None] * decay, 0.0)
+    q, k = (jnp.repeat(x, served, axis=2) for x in (q, k))
     kb, vb = k * beta[..., None], v * beta[..., None]
-    a = jnp.where(cols < rows, dot("nchd,nehd->nhce", kb, k) * decay, 0.0)
     rhs = jnp.concatenate([vb, kb * jnp.exp(c)[..., None]], axis=-1)
     sol = dot("nhce,nehd->nhcd", _inverse(a), rhs)  # [n, H, C, Dv + Dk]
     u, w = sol[..., :v.shape[-1]], sol[..., v.shape[-1]:]
-    qk = dot("nchd,nehd->nhce", q, k) * decay
     last = c[:, -1]  # [n, H]
     q_in = q * jnp.exp(c)[..., None]
     k_out = k * jnp.exp(last[:, None] - c)[..., None]

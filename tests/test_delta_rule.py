@@ -1,6 +1,8 @@
 """``ops/delta_rule.gated_delta_rule`` against the token-by-token recurrence
 in float64 ``numpy``, value and gradients, at lengths that are not one chunk
-and with the decay both near none and nearly total."""
+and with the decay both near none and nearly total: at small heads, a key
+head a value head, and at the chip's lane-wide heads with one key head
+serving two value heads."""
 
 import jax
 import jax.numpy as jnp
@@ -10,6 +12,26 @@ import pytest
 from d4pg_tpu.ops import delta_rule
 
 T, H, DK, DV = 200, 3, 8, 6  # 200 tokens: four chunks of 64, the last short
+# the sizes a test runs at: value heads, key heads, Dk, Dv
+SIZES = {"small": (H, H, DK, DV), "lane_wide": (2, 1, 128, 128)}
+both_sizes = pytest.mark.parametrize("sizes", sorted(SIZES))
+
+
+def by_value_head(sizes, q, k, *rest):
+    """The inputs as the recurrence reads them: a key head repeated to the
+    value heads it serves."""
+    heads, key_heads = SIZES[sizes][:2]
+    return (np.repeat(q, heads // key_heads, axis=1),
+            np.repeat(k, heads // key_heads, axis=1)) + rest
+
+
+def by_key_head(sizes, grads):
+    """The recurrence's gradients as the inputs take them: those of
+    ``q`` and ``k`` summed over the value heads a key head serves."""
+    heads, key_heads = SIZES[sizes][:2]
+    fold = lambda x: x.reshape(  # noqa: E731
+        x.shape[0], key_heads, heads // key_heads, -1).sum(axis=2)
+    return [fold(grads[0]), fold(grads[1])] + list(grads[2:])
 
 
 def recurrence(q, k, v, g, beta, reset_every=None):
@@ -61,16 +83,17 @@ def recurrence_grads(q, k, v, g, beta, ct):
     return grads
 
 
-def inputs(seed, log_decay, t_len=T):
+def inputs(seed, log_decay, t_len=T, sizes="small"):
+    heads, key_heads, dk, dv = SIZES[sizes]
     r = np.random.default_rng(seed)
     unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)  # noqa
-    q = unit(r.normal(size=(t_len, H, DK))) * DK ** -0.5
+    q = unit(r.normal(size=(t_len, key_heads, dk))) * dk ** -0.5
     # keys that are not zero-mean, as behind a SiLU: their products are not
     # small and the solve has work to do
-    k = unit(r.normal(size=(t_len, H, DK)) + 0.7)
-    v = r.normal(size=(t_len, H, DV))
-    g = -np.exp(r.normal(size=(t_len, H)) + log_decay)
-    beta = 1.0 / (1.0 + np.exp(-r.normal(size=(t_len, H))))
+    k = unit(r.normal(size=(t_len, key_heads, dk)) + 0.7)
+    v = r.normal(size=(t_len, heads, dv))
+    g = -np.exp(r.normal(size=(t_len, heads)) + log_decay)
+    beta = 1.0 / (1.0 + np.exp(-r.normal(size=(t_len, heads))))
     return tuple(jnp.asarray(x, jnp.float32) for x in (q, k, v, g, beta))
 
 
@@ -79,26 +102,29 @@ def inputs(seed, log_decay, t_len=T):
 DECAYS = {"near_none": -5.0, "seeded": -1.5, "strong": 1.5}
 
 
+@both_sizes
 @pytest.mark.parametrize("log_decay", sorted(DECAYS.values()),
                          ids=sorted(DECAYS, key=DECAYS.get))
-def test_the_chunked_form_is_the_recurrence(log_decay):
-    xs = inputs(1, log_decay)
-    want, _ = recurrence(*xs)
+def test_the_chunked_form_is_the_recurrence(log_decay, sizes):
+    xs = inputs(1, log_decay, sizes=sizes)
+    want, _ = recurrence(*by_value_head(sizes, *xs))
     got = np.asarray(delta_rule.gated_delta_rule(*xs))
-    assert got.shape == (T, H, DV) and got.dtype == np.float32
+    assert got.shape == (T,) + xs[2].shape[1:] and got.dtype == np.float32
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
     assert np.abs(want).max() > 0.05  # not a comparison of zeros
 
 
+@both_sizes
 @pytest.mark.parametrize("log_decay", sorted(DECAYS.values()),
                          ids=sorted(DECAYS, key=DECAYS.get))
-def test_its_gradients_are_the_recurrences_for_all_five_inputs(log_decay):
-    xs = inputs(2, log_decay)
-    ct = jnp.asarray(np.random.default_rng(3).normal(size=(T, H, DV)),
+def test_its_gradients_are_the_recurrences_for_all_five_inputs(log_decay,
+                                                               sizes):
+    xs = inputs(2, log_decay, sizes=sizes)
+    ct = jnp.asarray(np.random.default_rng(3).normal(size=xs[2].shape),
                      jnp.float32)
     got = jax.grad(lambda *a: jnp.sum(delta_rule.gated_delta_rule(*a) * ct),
                    argnums=(0, 1, 2, 3, 4))(*xs)
-    want = recurrence_grads(*xs, ct)
+    want = by_key_head(sizes, recurrence_grads(*by_value_head(sizes, *xs), ct))
     for name, a, b in zip("q k v g beta".split(), got, want):
         scale = np.abs(b).max()
         assert scale > 1e-3, name
@@ -129,16 +155,19 @@ def test_the_hand_written_backward_is_autodiffs_of_the_recurrence():
         np.testing.assert_allclose(np.asarray(a), b, rtol=1e-3, atol=1e-5)
 
 
+@both_sizes
 @pytest.mark.parametrize("t_len", [1, 63, 64, 65, 16 * 64 + 1])
-def test_any_length_is_served(t_len):
-    xs = inputs(6, -1.5, t_len)
+def test_any_length_is_served(t_len, sizes):
+    xs = inputs(6, -1.5, t_len, sizes)
     np.testing.assert_allclose(
-        np.asarray(delta_rule.gated_delta_rule(*xs)), recurrence(*xs)[0],
-        rtol=3e-4, atol=3e-5)
+        np.asarray(delta_rule.gated_delta_rule(*xs)),
+        recurrence(*by_value_head(sizes, *xs))[0], rtol=3e-4, atol=3e-5)
 
 
-def test_nothing_before_a_token_reads_it_and_chunks_later_it_is_read():
-    xs = inputs(7, -4.0)  # a slow decay: token 0 is still in the state
+@both_sizes
+def test_nothing_before_a_token_reads_it_and_chunks_later_it_is_read(sizes):
+    # a slow decay: token 0 is still in the state
+    xs = inputs(7, -4.0, sizes=sizes)
     base = np.asarray(delta_rule.gated_delta_rule(*xs))
     at = 70  # inside the second chunk
     for i, x in enumerate(xs):
@@ -152,24 +181,26 @@ def test_nothing_before_a_token_reads_it_and_chunks_later_it_is_read():
             np.testing.assert_array_equal(out[at + 1:], base[at + 1:])
             assert np.abs(out[at] - base[at]).max() > 1e-6
     # memory: the first token's value moves the output three chunks later
-    # (weak writes: 192 tokens do not overwrite an 8-wide key space)
+    # (weak writes: 192 tokens do not overwrite a key space)
     xs = xs[:4] + (0.05 * xs[4],)
     base = np.asarray(delta_rule.gated_delta_rule(*xs))
     moved = list(xs)
     moved[2] = xs[2].at[0].add(20.0)
     out = np.asarray(delta_rule.gated_delta_rule(*moved))
-    assert np.abs(out[3 * 64:] - base[3 * 64:]).max() > 1e-4
+    scale = np.abs(base).max()  # 0.05 at 8-wide heads, 0.006 at 128-wide
+    assert np.abs(out[3 * 64:] - base[3 * 64:]).max() > 1e-3 * scale
     # and a state reset at every chunk's first token is another function
-    reset, _ = recurrence(*xs, reset_every=64)
-    assert np.abs(reset[:64] - base[:64]).max() < 1e-4
-    assert np.abs(reset[64:] - base[64:]).max() > 1e-2
+    reset, _ = recurrence(*by_value_head(sizes, *xs), reset_every=64)
+    assert np.abs(reset[:64] - base[:64]).max() < 2e-3 * scale
+    assert np.abs(reset[64:] - base[64:]).max() > 0.2 * scale
 
 
-def test_a_padded_tail_leaves_the_state_alone():
+@both_sizes
+def test_a_padded_tail_leaves_the_state_alone(sizes):
     """Two groups where one would do (a group of one chunk): the same
     numbers, so the zero padding behind the last token changes nothing and
     the state crosses a group's edge as it crosses a chunk's."""
-    xs = inputs(8, -1.5, t_len=130)
+    xs = inputs(8, -1.5, t_len=130, sizes=sizes)
     a = delta_rule.gated_delta_rule(*xs)
     b = delta_rule.gated_delta_rule(*xs, group=1)
     c = delta_rule.gated_delta_rule(*xs, chunk=16, group=3)
@@ -198,3 +229,30 @@ def test_a_chunk_the_solve_cannot_halve_is_refused():
     with pytest.raises(ValueError, match="power of two"):
         delta_rule.gated_delta_rule(*xs, chunk=24)
     assert delta_rule.gated_delta_rule(*xs, chunk=4).shape == (48, H, DV)
+
+
+def test_the_solves_gradient_is_the_inverses():
+    """``d sum(T * ct) / d a = -T^T ct T^T`` for ``T = (I + a)^-1``, under
+    the diagonal: autodiff through the stacked, lane-batched blocks."""
+    r = np.random.default_rng(11)
+    a = np.tril(r.uniform(0.0, 1.0, (2, 3, 64, 64)), -1).astype(np.float32)
+    ct = r.normal(size=a.shape).astype(np.float32)
+    got = jax.grad(lambda a: jnp.sum(delta_rule._inverse(a) * ct))(
+        jnp.asarray(a))
+    inv = np.linalg.inv(np.eye(64) + a.astype(np.float64))
+    want = np.tril(
+        -np.swapaxes(inv, -1, -2) @ ct @ np.swapaxes(inv, -1, -2), -1)
+    np.testing.assert_allclose(np.tril(np.asarray(got), -1), want,
+                               atol=1e-5 * np.abs(want).max())
+
+
+def test_a_key_heads_products_serve_its_value_heads():
+    """One key head for two value heads is two key heads that agree: the
+    products ``k_i . k_j`` and ``q_i . k_j`` are made a key head, then
+    handed to the heads it serves."""
+    q, k, v, g, beta = inputs(12, -1.5, sizes="lane_wide")
+    shared = delta_rule.gated_delta_rule(q, k, v, g, beta)
+    apart = delta_rule.gated_delta_rule(
+        jnp.repeat(q, 2, axis=1), jnp.repeat(k, 2, axis=1), v, g, beta)
+    np.testing.assert_allclose(np.asarray(shared), np.asarray(apart),
+                               atol=1e-7)

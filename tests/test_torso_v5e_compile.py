@@ -304,15 +304,23 @@ def test_each_kind_of_lfm2_layer_and_its_backward_compile_at_real_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
 
 
+# the gated delta rule's solve as the compiled text shows it: the eight
+# 8-token diagonal blocks of a group's 4 chunks of 32 heads stacked with
+# that batch of 128 on the lanes, and what a block a ``[8, 8]`` lane tile
+# of its own (the parent's layout) would read
+SOLVE_BLOCKS, PADDED_BLOCKS = "f32[8,8,8,128]", "f32[4,32,8,8]"
+
+
 def test_the_delta_rule_scan_and_its_backward_compile_at_real_widths(
         one_chip):
     """``ops/delta_rule.gated_delta_rule`` on one 16,384-token sequence of
     ``humanoid-qwen3next-ep32`` (16 key heads serving 32 value heads of
     128), differentiated in all five inputs: 256 chunks in 64 rematerialised
     groups, so two nested loops forward and again backward, the solve inside
-    a chunk products like the rest (no ``triangular-solve`` custom call);
-    its temporaries are a group's, not the sequence's (every chunk's state
-    kept would alone be 537 MB)."""
+    a chunk products like the rest (no ``triangular-solve`` custom call),
+    its blocks stacked with a group's 4 x 32 chunks and heads on the lanes
+    (no block a lane tile of its own); its temporaries are a group's, not
+    the sequence's (every chunk's state kept would alone be 537 MB)."""
     from d4pg_tpu.ops import delta_rule
 
     f32 = lambda *s: jax.ShapeDtypeStruct(  # noqa: E731
@@ -327,7 +335,8 @@ def test_the_delta_rule_scan_and_its_backward_compile_at_real_widths(
         f32(t_len, hv), f32(t_len, hv)).compile()
     text = compiled.as_text()
     assert text.count(" while(") >= 4 and "triangular" not in text
-    assert "tpu_custom_call" not in text  # plain XLA: no kernel yet
+    assert "tpu_custom_call" not in text  # plain XLA: no kernel
+    assert SOLVE_BLOCKS in text and PADDED_BLOCKS not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
@@ -398,7 +407,7 @@ def test_the_fused_chunk_of_the_linear_cell_compiles_for_the_chip(
     """The whole chunk of ``humanoid-qwen3next-ep32`` at the cell's sizes (2
     sequences of 16,384 tokens, K=1, a 16,384-row ring): the compiler
     refuses a program that does not fit the chip, and takes this one. Its
-    own count of arguments and temporaries together (8.34 + 8.74 GB) is
+    own count of arguments and temporaries together (8.34 + 8.69 GB) is
     over ``HBM_BYTES`` as the chunks of ``humanoid-lfm2-ep4`` (10.30 + 7.01)
     and ``humanoid-keye2-ep8`` (8.99 + 9.99) are, which run: the buffer
     assignment packs tighter than the sum."""
@@ -421,6 +430,7 @@ def test_the_fused_chunk_of_the_linear_cell_compiles_for_the_chip(
     text = compiled.as_text()
     assert "gmm" in text and "splash" in text
     assert "ragged-dot" not in text and "triangular" not in text
+    assert SOLVE_BLOCKS in text and PADDED_BLOCKS not in text
 
 
 def _loop_body(text):
