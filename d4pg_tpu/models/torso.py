@@ -8,13 +8,14 @@ transformer layers and pools them into one latent per row; D4PG's own heads
 state vector. ``D4PGConfig.torso`` names one (``TORSOS``) with its sizes
 (``TorsoSpec``, made from a configuration file's ``model.torso`` block).
 
-Six models share the one layer path, told apart by the data in the spec
+Seven models share the one layer path, told apart by the data in the spec
 (``layer_types`` or ``hybrid_override_pattern``, ``qk_norm``, ``sa_config``,
 ``num_dense_layers``, ``router_scores``, ``use_expert_bias``,
 ``attn_output_gate``, ``partial_rotary_factor``, ``rope_parameters``,
 ``shared_expert_intermediate_size``, ``shared_expert_gated``,
 ``mlp_hidden_act``, the ``linear_*`` and Mamba sizes, ``num_experts`` 0,
-``sandwich_norm``, ``total_ut_steps``), not by code of their own. A layer of
+``sandwich_norm``, ``total_ut_steps``, ``embedding_multiplier``), not by code
+of their own. A layer of
 two branches is ``x + Op(RMSNorm(x))`` then ``x + FF(RMSNorm(x))``, with
 ``sandwich_norm`` ``x + RMSNorm(Op(RMSNorm(x)))`` then ``x +
 RMSNorm(FF(RMSNorm(x)))``; a block of one branch (``PATTERN``: ``mamba``,
@@ -100,6 +101,19 @@ FF(RMSNorm(x))`` alone:
   (``shared_expert_gated`` false). ``aux["ssd_kept"]`` is the mean of
   ``exp(dt A)`` a Mamba block; ``route_counts`` and ``bias_swapped`` have a
   row an ``E`` block.
+- ``trinity``, Trinity-Mini's layers (``afmoe``), every mechanism one of the
+  flags above, here together: the embedding times ``embedding_multiplier``
+  (``sqrt(hidden_size)``); ``sandwich_norm`` round BOTH branches of every
+  layer, the leading dense one (``num_dense_layers``) and the expert layers
+  alike, the feed-forward's post-norm on routed + shared as one sum;
+  ``sliding_attention`` layers with rotary embedding round ``full_attention``
+  layers with NONE (``rope_parameters`` names the type with an explicit
+  ``null``: a type left out stays an error), both with ``qk_norm`` before the
+  rotation and ``attn_output_gate`` (a head's query then its gate in ``q``'s
+  columns: afmoe's separate ``gate_proj`` with the columns of ``[Wq | Wg]``
+  in another order); the ``sigmoid`` router with its bias and
+  ``routed_scaling_factor``, gated-SiLU experts and an ungated shared expert
+  of the same form.
 
 Leaves. A layer has only the leaves its kind has: the operator's are
 ``attn_norm``, ``q``, ``k``, ``v``, ``o`` (with ``qk_norm`` also ``q_norm``,
@@ -185,7 +199,13 @@ group's decay-masked products (``[4, 64, 128, 128]``, 17 MB) for that group
 alone (``ops/ssd.py``); the attention block's 16 query heads a key/value head
 go to the splash kernel as one group; the expert layer takes the sequence in
 two parts of 24,576 assignments, of which about 1,536 land on the 8 experts
-held.
+held. At ``trinity``'s 16,384 tokens a layer boundary is 134 MB a sequence;
+inside a sequence the largest arrays are the ``q`` leaf's ``[16384, 8192]``
+float32 output (a head's query and gate, 537 MB), its halves behind the
+heads' norm and the rotation (268 MB each) and the dense layer's two
+``[16384, 6144]`` float32 products (403 MB each); the expert layer takes
+the sequence in four parts of 32,768 assignments, of which about 2,048 land
+on the 8 experts held.
 
 A loop (``ouro``: 4 passes of 8 layers on 2 sequences of 4,096 tokens) is a
 ``lax.scan`` over the passes with the leaves closed over: one pass is
@@ -269,7 +289,8 @@ class TorsoSpec:
     num_experts_per_tok: int
     moe_intermediate_size: int
     experts_held: tuple  # [lo, hi) of the experts this chip holds
-    # layer type -> rope block, frozen; a type without one turns nothing
+    # layer type -> rope block, frozen; a roped type (``ROPED``) is named
+    # here, with its block or with an explicit ``None``: no rotary embedding
     rope_parameters: Any = None
     rms_norm_eps: float = 1e-6
     norm_topk_prob: bool = True
@@ -317,6 +338,7 @@ class TorsoSpec:
     # the sorted buffer's rows over an even load's (``even_load_rows``): a
     # small share of the experts strays further from even than a large one
     expert_buffer: float = EXPERT_BUFFER
+    embedding_multiplier: float = 1.0  # on the embedding's rows (muP's)
 
     @classmethod
     def from_dict(cls, d: dict) -> "TorsoSpec":
@@ -389,6 +411,9 @@ class TorsoSpec:
         if self.expert_buffer < 1.0:
             raise ValueError("expert_buffer is a multiple of an even load: "
                              "at least 1")
+        if not self.embedding_multiplier > 0.0:
+            raise ValueError("embedding_multiplier scales the embedding: "
+                             "above 0")
         if self.mlp_hidden_act not in HIDDEN_ACTS:
             raise ValueError(f"unknown mlp_hidden_act "
                              f"{self.mlp_hidden_act!r}; one of {HIDDEN_ACTS}")
@@ -1266,6 +1291,8 @@ class SequenceTorso:
         with jax.named_scope("torso.embed"):
             tokens = tokenise(s, obs)
             x = params["embed"]["kernel"][tokens]
+            if s.embedding_multiplier != 1.0:
+                x = x * s.embedding_multiplier
         if s.total_ut_steps > 1:
             latents, logits = self._passes(params, x)
             return latents[-1], ({"pass_latents": latents,
@@ -1378,7 +1405,8 @@ class TorsoCritic:
 
 TORSOS = {"mellum2": SequenceTorso, "keye2": SequenceTorso,
           "lfm2": SequenceTorso, "qwen3next": SequenceTorso,
-          "ouro": SequenceTorso, "nemotronh": SequenceTorso}
+          "ouro": SequenceTorso, "nemotronh": SequenceTorso,
+          "trinity": SequenceTorso}
 
 
 def build_torso(spec: TorsoSpec, dtype=jnp.float32):

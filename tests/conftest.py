@@ -29,34 +29,3 @@ import pytest
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
-
-
-# ``tests/benchmark/test_hybrid_cell.py`` (PR 34) asserts that its cell and
-# configuration are the LAST of ``workloads`` and ``configs``: true the day
-# they were appended, false as soon as a later ``model_config`` PR appends
-# behind them, which is the only place it may. Such a PR may edit no file
-# under ``tests/benchmark/`` (its ``conftest.py`` does the same for
-# ``per_layer``), so that one test is handed the two lists as PR 34 left them
-# and keeps saying what it meant: PR 34's entries follow everything older.
-# The next ``benchmark`` issue anchors the assertion and deletes both shims
-# (PERF.md section 7).
-_PR34_TEST = "test_the_cell_is_one_chip_and_lists_its_eleven_layer_metrics"
-
-
-@pytest.fixture(autouse=True)
-def _cells_and_configs_as_pr34_left_them(request, monkeypatch):
-    if request.node.name != _PR34_TEST:
-        return
-    from benchmark import manifest
-
-    load = manifest.load
-
-    def cut(path=None):
-        man = load(path)
-        for key, last in (("workloads", "humanoid-lfm2-ep4.learn-static"),
-                          ("configs", "humanoid-lfm2-ep4")):
-            names = [entry["name"] for entry in man[key]]
-            man[key] = man[key][:names.index(last) + 1]
-        return man
-
-    monkeypatch.setattr(manifest, "load", cut)

@@ -561,6 +561,9 @@ CELL_PLANS = {
     "humanoid-nemotronh-ep16.learn-static": (
         "15>8 rows(min from sum),8>1 whole,1>root whole",
         "15>8 whole(min from sum),8>1 whole,1>root whole"),
+    "humanoid-trinity-ep16.learn-static": (
+        "14>7 rows(min from sum),7>root whole",
+        "14>7 whole(min from sum),7>root whole"),
 }
 
 

@@ -841,3 +841,40 @@ def test_sixteen_query_heads_a_key_value_head_go_to_the_splash_kernel(
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).compile().as_text()
     assert text.count("tpu_custom_call") >= 2
+
+
+def test_trinitys_windowed_gated_attention_compiles_at_real_widths(
+        one_chip, monkeypatch):
+    """The attention of one sliding layer of ``humanoid-trinity-ep16`` on one
+    16,384-token sequence, differentiated: the one ``q`` leaf with a head's
+    query and gate, the q/k norms and the rotation round the splash kernel
+    under a 2,048 window (``LocalMask``: 8 query heads a key/value head and
+    call, the backward as ``dq`` and ``dkv`` apart), the gate, ``o`` and the
+    post-norm."""
+    with open(os.path.join(
+            REPO, "benchmark/configs/humanoid-trinity-ep16.json")) as f:
+        model = json.load(f)["model"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    torso = D4PGConfig(**model).build_critic().torso
+    spec = torso.spec
+    assert torso.attention_impl() == "splash"
+    assert torso.grouped_impl() == "megablox"
+    assert spec.rope_for("full_attention") is None
+    assert spec.rope_for("sliding_attention")["rope_theta"] == 10000
+    params = jax.eval_shape(lambda: torso.init(jax.random.key(0)))
+    p = {name: ({"kernel": jax.ShapeDtypeStruct(
+        leaf["kernel"].shape, jnp.bfloat16)} if "kernel" in leaf else leaf)
+        for name, leaf in params["layer_1"].items()}
+    assert p["q"]["kernel"].shape == (2048, 2 * 4096)
+    x = jax.ShapeDtypeStruct((spec.tokens, 2048), jnp.float32)
+    loss = lambda p, x: jnp.sum(  # noqa: E731
+        torso._attend(p, x, "sliding_attention"))
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        *on(one_chip, (p, x))).compile()
+    text = compiled.as_text()
+    assert kernel_calls(text, "splash_mqa_fwd") == 1
+    assert kernel_calls(text, "splash_mqa_dq") == 1
+    assert kernel_calls(text, "splash_mqa_dkv") == 1
+    # the largest arrays are the q leaf's [16384, 8192] float32 output (537
+    # MB) and what the norms, the rotation and the gate make of its halves
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
