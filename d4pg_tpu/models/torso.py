@@ -154,7 +154,16 @@ assignments it changed. The layer is told which experts it holds
 ``parallel/partition.expert_share``): it routes over all of them and adds
 its own experts' part of the result; on one chip that is the whole layer
 without the exchange. No capacity, no dropped token: every assignment to a
-held expert is computed whatever the routing.
+held expert is computed whatever the routing. The selection and its
+bookkeeping are dense vector work: no sort of a token's experts
+(``_largest``), no scalar gather of the selected scores (``_picked``), no
+scatter of the assignments' rows (``_places``) or of the selection's
+cotangent (``_placed``); what is left index by index is one sort of the
+``T * k`` expert numbers (the sorted buffer's order) and the row gathers
+into and out of that buffer (``_to_sorted``, ``_from_sorted``). The scope
+``torso.route`` is split for a trace reader by what it holds:
+``torso.route.norm``, ``.router``, ``.select``, ``.counts``, ``.order``,
+``.gather``, ``.combine``.
 
 Tokens are Gato's (Reed et al. 2022, sec. 2.1): mu-law, clip to [-1, 1],
 ``bins`` uniform bins, on the float32 values (bfloat16 cannot tell 1,024
@@ -584,8 +593,9 @@ def layer_norm(x, p: dict, eps: float):
 def _to_sorted(h, top, inv, k: int):
     """Token ``top[r] // k``'s row of ``h [T, D]`` for each of the first
     ``rows`` sorted assignments ``top = order[:rows]``. The backward pass
-    is the inverse gather and a sum over each token's ``k`` assignments,
-    not a scatter-add."""
+    is the inverse gather (``inv``, ``_places``: an assignment's row, past
+    the buffer where it has none) and a sum over each token's ``k``
+    assignments, not a scatter-add."""
     return h[top // k]
 
 
@@ -622,6 +632,104 @@ def _from_sorted_bwd(res, g):
 _from_sorted.defvjp(_from_sorted_fwd, _from_sorted_bwd)
 
 
+def _largest(x, k: int):
+    """``lax.top_k(x [T, E], k)`` without a sort of all ``E``: ``k`` passes
+    over ``[T, E]``, each one reduction to the row's (largest value, lowest
+    index that holds it) among the positions behind the pass before in
+    that order. The same values and indices bit for bit (a tie goes to the
+    lower index), for ``x`` without a NaN and above ``-inf``."""
+    at = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+
+    def ahead(a, b):
+        (va, ia), (vb, ib) = a, b
+        first = (va > vb) | ((va == vb) & (ia < ib))
+        return jnp.where(first, va, vb), jnp.where(first, ia, ib)
+
+    vals, idx, left = [], [], x
+    for j in range(k):
+        if j:  # what the last pass took, and everything ahead of it, is out
+            left = jnp.where((x < top) | ((x == top) & (at > i)), x, -jnp.inf)
+        top, i = jax.lax.reduce(
+            (left, at), (jnp.asarray(-jnp.inf, x.dtype),
+                         jnp.int32(x.shape[-1])), ahead, (1,))
+        top, i = top[:, None], i[:, None]
+        vals.append(top)
+        idx.append(i)
+    return jnp.concatenate(vals, axis=-1), jnp.concatenate(idx, axis=-1)
+
+
+def _placed(g, e, n_exp: int):
+    """``g [T, k]`` at the columns ``e [T, k]`` of a ``[T, n_exp]`` of
+    zeros, as a compare and a select-sum over ``[T, k, n_exp]``: a row's
+    ``e`` are distinct, so each position takes at most one term and the
+    values are a scatter-add's. On the chip the scatter-add of 40,960
+    scalars into ``[4096, 512]`` takes 200-360 us and this 56-70 (a trace
+    books a scatter under no scope of the torso's: PERF.md section 6,
+    PR 50)."""
+    hit = e[:, :, None] == jnp.arange(n_exp, dtype=e.dtype)
+    return jnp.sum(jnp.where(hit, g[:, :, None], 0), axis=1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _top_k(x, k: int):
+    """``lax.top_k`` on ``x [T, E]`` (``_largest``); the values' cotangent
+    goes back through ``_placed``, the indices the only residual."""
+    return _largest(x, k)
+
+
+def _top_k_fwd(x, k):
+    vals, idx = _largest(x, k)
+    return (vals, idx), (idx, x.shape[-1])
+
+
+def _top_k_bwd(k, res, g):
+    idx, n_exp = res
+    return (_placed(g[0], idx, n_exp),)
+
+
+_top_k.defvjp(_top_k_fwd, _top_k_bwd)
+
+
+@jax.custom_vjp
+def _picked(x, e):
+    """``take_along_axis(x [T, E], e [T, k], -1)`` for distinct ``e`` a row,
+    as a one-hot select-sum over ``[T, k, E]`` (no scalar gather: 106 us an
+    evaluation at ``[4096, 8]`` of 128 where the gather took 260); its
+    cotangent goes back through ``_placed``."""
+    hit = e[:, :, None] == jnp.arange(x.shape[-1], dtype=e.dtype)
+    return jnp.sum(jnp.where(hit, x[:, None, :], 0), axis=-1)
+
+
+def _picked_fwd(x, e):
+    return _picked(x, e), (e, x.shape[-1])
+
+
+def _picked_bwd(res, g):
+    e, n_exp = res
+    return _placed(g, e, n_exp), None
+
+
+_picked.defvjp(_picked_fwd, _picked_bwd)
+
+
+def _places(e, lo: int, sizes):
+    """Each assignment's row in the sorted buffer, ``[T * k]`` in assignment
+    order, without the sort: a held expert's offset plus the assignment's
+    rank among that expert's, and ``T * k`` (past any buffer: ``mode="fill"``
+    reads zero there) for an assignment to an absent expert. A token's
+    ``k`` experts are distinct, so the rank is the number of earlier TOKENS
+    routed to the expert: a cumulative sum over ``[T, held]``, not over
+    ``[T * k, held]``, and no scatter. ``e [T, k]``; ``sizes [held]`` the
+    held experts' counts, the first of them expert ``lo``."""
+    held = jnp.arange(sizes.shape[0], dtype=e.dtype) + lo
+    hit = e[:, :, None] == held  # [T, k, held]
+    routed = jnp.any(hit, axis=1).astype(jnp.int32)  # [T, held]
+    before = jnp.cumsum(routed, axis=0) - routed
+    row = jnp.cumsum(sizes) - sizes + before  # [T, held]
+    place = jnp.sum(jnp.where(hit, row[:, None, :], 0), axis=-1)
+    return jnp.where(jnp.any(hit, axis=-1), place, e.size).reshape(-1)
+
+
 def route(spec: TorsoSpec, h, router: dict):
     """The router (``{"kernel": [D, num_experts]}``, with
     ``use_expert_bias`` also ``"bias" [num_experts]``) on float32
@@ -634,35 +742,56 @@ def route(spec: TorsoSpec, h, router: dict):
     enters the selection only, so its gradient is exactly zero), divided by
     their sum + 1e-6, times ``routed_scaling_factor``. With a bias
     ``stats["bias_swapped"]`` counts the assignments it changed: selected,
-    and not among the ``k`` largest scores."""
-    k = spec.num_experts_per_tok
-    logits = jnp.dot(h, router["kernel"], precision=HI)
+    and not among the ``k`` largest scores.
+
+    The selection is ``_top_k`` (``k`` reductions over ``[T, E]``, a tie to
+    the lower index as ``lax.top_k``'s; its cotangent placed by a compare
+    and a select-sum, ``_placed``), the selected scores are read by the
+    same one-hot (``_picked``): no sort of the experts, no scalar gather
+    or scatter.
+    A NaN among a token's scores gives unspecified experts (``lax.top_k``
+    ranks it first): it comes of a NaN in ``h`` or in the router's leaves,
+    which no selection mends. The router's product is float32 at
+    ``HIGHEST``."""
+    k, n_exp = spec.num_experts_per_tok, spec.num_experts
+    with jax.named_scope("torso.route.router"):
+        logits = jnp.dot(h, router["kernel"], precision=HI)
     stats = {}
-    if spec.router_scores == "sigmoid":
-        scores = jax.nn.sigmoid(logits)
-        biased = scores + router["bias"] if spec.use_expert_bias else scores
-        _, e = jax.lax.top_k(biased, k)
-        w = jnp.take_along_axis(scores, e, axis=-1)
-        if spec.use_expert_bias:
+    with jax.named_scope("torso.route.select"):
+        if spec.router_scores == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            if spec.use_expert_bias:
+                _, e = _top_k(jax.lax.stop_gradient(
+                    scores + router["bias"]), k)
+                w = _picked(scores, e)
+            else:
+                w, e = _top_k(scores, k)
+            chosen = w
+            if spec.norm_topk_prob:
+                w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+            w = w * spec.routed_scaling_factor
+        else:
+            w, e = _top_k(jax.nn.softmax(logits, axis=-1), k)
+            if spec.norm_topk_prob:
+                w = w / jnp.sum(w, axis=-1, keepdims=True)
+        # the weights are made here, once: left to fuse into their readers
+        # the compiler lays them and their cotangent k-major, the branches
+        # of ``expert_share`` hand the gradient back in float32 and a layer's
+        # temporaries pack 0.3-0.5 GB worse (tests/test_torso_v5e_compile)
+        w = jax.lax.optimization_barrier(w)
+    with jax.named_scope("torso.route.counts"):
+        if spec.router_scores == "sigmoid" and spec.use_expert_bias:
             # an assignment is outside the k largest scores when k experts
             # are ahead of it (a tie goes to the lower index, as top_k's):
             # compares, not a second sort
-            rivals, mine = scores[:, None, :], w[:, :, None]
-            first = jnp.arange(spec.num_experts) < e[:, :, None]
+            rivals, mine = scores[:, None, :], chosen[:, :, None]
+            first = jnp.arange(n_exp) < e[:, :, None]
             ahead = jnp.sum((rivals > mine) | ((rivals == mine) & first),
                             axis=-1)
             stats["bias_swapped"] = jnp.sum(ahead >= k, dtype=jnp.int32)
-        if spec.norm_topk_prob:
-            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
-        w = w * spec.routed_scaling_factor
-    else:
-        p = jax.nn.softmax(logits, axis=-1)
-        w, e = jax.lax.top_k(p, k)
-        if spec.norm_topk_prob:
-            w = w / jnp.sum(w, axis=-1, keepdims=True)
-    stats["route_counts"] = jnp.sum(
-        e.reshape(-1, 1) == jnp.arange(spec.num_experts)[None], axis=0,
-        dtype=jnp.int32)
+        stats["route_counts"] = jnp.sum(
+            e.reshape(-1, 1) == jnp.arange(n_exp)[None], axis=0,
+            dtype=jnp.int32)
     return w, e.astype(jnp.int32), stats
 
 
@@ -684,8 +813,11 @@ def expert_share(spec: TorsoSpec, p: dict, h, dtype, grouped: str = "ragged"):
     ``route``'s.
 
     Assignments are sorted with the held experts first, so the held ones
-    are the first ``n`` rows of the sorted order whatever the routing; a
-    grouped product multiplies each held expert's rows by its matrices.
+    are the first ``n`` rows of the sorted order whatever the routing
+    (``order``, the one sort left: ``T * k`` expert numbers; each
+    assignment's row the other way is ``_places``, a cumulative sum and no
+    scatter); a grouped product multiplies each held expert's rows by its
+    matrices.
     Nothing is dropped: the sorted buffer holds every assignment
     (``T * k`` rows) when it must. When the ``n`` landing here fit
     ``even_load_rows`` (the usual case) the same computation runs on that
@@ -703,12 +835,11 @@ def expert_share(spec: TorsoSpec, p: dict, h, dtype, grouped: str = "ragged"):
     with jax.named_scope("torso.route"):
         w, e, stats = route(spec, h, p["router"])
         counts = stats["route_counts"]
-        key = jnp.mod(e.reshape(-1) - lo, n_exp)
-        order = jnp.argsort(key, stable=True)
-        inv = jnp.zeros_like(order).at[order].set(
-            jnp.arange(order.shape[0], dtype=order.dtype))
+    with jax.named_scope("torso.route.order"):
         sizes = counts[lo:hi]
         n_held = jnp.sum(sizes)
+        order = jnp.argsort(jnp.mod(e.reshape(-1) - lo, n_exp), stable=True)
+        inv = _places(e, lo, sizes)
 
     def on(rows: int):
         def part(h, w):
@@ -723,7 +854,7 @@ def expert_share(spec: TorsoSpec, p: dict, h, dtype, grouped: str = "ragged"):
             keep = lambda a: jnp.where(  # noqa: E731
                 valid, a, jnp.zeros((), a.dtype))
             top = order[:rows]
-            with jax.named_scope("torso.route"):
+            with jax.named_scope("torso.route.gather"):
                 xs = _to_sorted(h.astype(dtype), top, inv, k)
                 # each row's router weight, applied where the rows are few
                 # (the buffer) and not where they are many (T * k)
@@ -737,7 +868,7 @@ def expert_share(spec: TorsoSpec, p: dict, h, dtype, grouped: str = "ragged"):
                     jnp.float32), "gate", "up").astype(dtype)
                 y = dot(mid, "down")
                 y = (y.astype(jnp.float32) * w_rows).astype(dtype)
-            with jax.named_scope("torso.route"):
+            with jax.named_scope("torso.route.combine"):
                 y = _from_sorted(y, top, inv).reshape(t_len, k, -1)
                 return jnp.sum(y, axis=1, dtype=jnp.float32)
         return part
@@ -1212,10 +1343,10 @@ class SequenceTorso:
             return x, op_stats, selected
         if dense:
             return x + self._mlp(p, x), op_stats, selected
-        with jax.named_scope("torso.route"):
+        with jax.named_scope("torso.route.norm"):
             h = rms_norm(x, p["moe_norm"]["scale"], self.spec.rms_norm_eps)
         out, stats = self._experts(p, h)
-        with jax.named_scope("torso.route"):
+        with jax.named_scope("torso.route.norm"):
             out = self._post(p, out, "ff_post_norm")
         return x + out, {**stats, **op_stats}, selected
 

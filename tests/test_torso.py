@@ -354,6 +354,111 @@ def test_the_four_shares_add_up_to_the_uncut_layer(bias, shares, router):
         assert int(np.asarray(counts).sum()) == 32 * top
 
 
+def _parent_route(spec, h, router):
+    """``models/torso.route`` as it stood before PR 50: a full sort a token
+    (``lax.top_k``) and, on the sigmoid path, a scalar gather of the
+    selected scores. The new selection is held to it bit for bit."""
+    k = spec.num_experts_per_tok
+    logits = jnp.dot(h, router["kernel"], precision=torso_lib.HI)
+    stats = {}
+    if spec.router_scores == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        biased = scores + router["bias"] if spec.use_expert_bias else scores
+        _, e = jax.lax.top_k(biased, k)
+        w = jnp.take_along_axis(scores, e, axis=-1)
+        if spec.use_expert_bias:
+            rivals, mine = scores[:, None, :], w[:, :, None]
+            first = jnp.arange(spec.num_experts) < e[:, :, None]
+            ahead = jnp.sum((rivals > mine) | ((rivals == mine) & first),
+                            axis=-1)
+            stats["bias_swapped"] = jnp.sum(ahead >= k, dtype=jnp.int32)
+        if spec.norm_topk_prob:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+        w = w * spec.routed_scaling_factor
+    else:
+        w, e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        if spec.norm_topk_prob:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+    stats["route_counts"] = jnp.sum(
+        e.reshape(-1, 1) == jnp.arange(spec.num_experts)[None], axis=0,
+        dtype=jnp.int32)
+    return w, e.astype(jnp.int32), stats
+
+
+# (experts, a token's, scores, a routing bias, held) of the benchmark's six
+# routed cells: 4, 5, 6, 7, 9, 10
+ROUTED_CELLS = {
+    "mellum2": (64, 8, "softmax", False, (16, 32)),
+    "keye2": (128, 8, "softmax", False, (0, 16)),
+    "lfm2": (32, 4, "sigmoid", True, (8, 16)),
+    "qwen3next": (512, 10, "softmax", False, (496, 512)),
+    "nemotronh": (128, 6, "sigmoid", True, (0, 8)),
+    "trinity": (128, 8, "sigmoid", True, (4, 12)),
+}
+
+
+@pytest.mark.parametrize("logits", ["random", "ties"])
+@pytest.mark.parametrize("cell", sorted(ROUTED_CELLS))
+def test_the_selection_and_the_bookkeeping_are_the_sorts_and_scatters_bit_for_bit(
+        cell, logits):
+    """``route`` (k passes of max / lowest index / mask, the cotangent
+    placed by a compare and a select-sum, the selected scores read by the
+    same one-hot) against ``lax.top_k`` / ``take_along_axis`` at each routed
+    cell's experts and selection, op by op (a fused program may sum a
+    token's ``k`` weights in another order): weights, experts, both counters
+    and the gradients with respect to ``h`` and the router's kernel are
+    equal bit for bit, on seeded logits and on logits with exact ties (every
+    expert's column twice, scores saturated to exactly 1 and 0: the lower
+    index wins). ``_places`` is the scattered inverse of the stable sort on
+    the held assignments and past the buffer on the others."""
+    n_exp, k, scores, biased, (lo, hi) = ROUTED_CELLS[cell]
+    spec = small_config(
+        num_experts=n_exp, num_experts_per_tok=k, router_scores=scores,
+        use_expert_bias=biased, experts_held=[lo, hi],
+        routed_scaling_factor=2.5 if biased else 1.0).torso
+    t_len, d = 96, 64
+    keys = jax.random.split(jax.random.key(n_exp + k), 4)
+    h = jax.random.normal(keys[0], (t_len, d))
+    kernel = jax.random.normal(keys[1], (d, n_exp)) / math.sqrt(d)
+    bias = 0.05 * jax.random.normal(keys[2], (n_exp,))
+    if logits == "ties":
+        kernel = 40.0 * jnp.repeat(kernel[:, ::2], 2, axis=1)
+        bias = jnp.repeat(bias[::2], 2)
+    router = {"kernel": kernel, **({"bias": bias} if biased else {})}
+    weigh = jax.random.normal(keys[3], (t_len, k))
+
+    def run(route):
+        def loss(h, kernel):
+            w, e, stats = route(spec, h, {**router, "kernel": kernel})
+            return jnp.sum(w * weigh), (w, e, stats)
+        return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
+            h, kernel)
+
+    (_, (w, e, stats)), grads = run(torso_lib.route)
+    (_, (w0, e0, stats0)), grads0 = run(_parent_route)
+    if logits == "ties":  # a tie at the selection's edge, somewhere
+        logit = jnp.dot(h, kernel, precision=torso_lib.HI)
+        assert bool(jnp.any(logit[:, ::2] == logit[:, 1::2]))
+    np.testing.assert_array_equal(e, e0)
+    np.testing.assert_array_equal(w, w0)
+    assert sorted(stats) == sorted(stats0)
+    for name in stats:
+        np.testing.assert_array_equal(stats[name], stats0[name])
+    for g, g0 in zip(grads, grads0):
+        assert float(jnp.max(jnp.abs(g0))) > 0
+        np.testing.assert_array_equal(g, g0)
+
+    every = t_len * k
+    order = jnp.argsort(jnp.mod(e.reshape(-1) - lo, n_exp), stable=True)
+    scattered = np.asarray(jnp.zeros_like(order).at[order].set(
+        jnp.arange(every, dtype=order.dtype)))
+    inv = np.asarray(torso_lib._places(e, lo, stats["route_counts"][lo:hi]))
+    here = np.asarray((e >= lo) & (e < hi)).reshape(-1)
+    assert here.any() and not here.all()
+    np.testing.assert_array_equal(inv[here], scattered[here])
+    assert (inv[~here] >= every).all()
+
+
 def test_expert_share_names_a_contiguous_range():
     assert [partition.expert_share(64, 4, i) for i in range(4)] == [
         (0, 16), (16, 32), (32, 48), (48, 64)]

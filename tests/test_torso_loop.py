@@ -157,13 +157,16 @@ def test_a_looped_layer_and_torso_have_the_leaves_their_kind_has():
 # log-sum-exp it can now be handed; "7588efd0692b8543" before), and
 # ``humanoid-qwen3next-ep32``'s at PR 47, which edits its scan alone
 # (``ops/delta_rule.py``: the solve's blocks with the batch last, the
-# products of keys a key head; "ad071692d07dcb4e" before); the trees and
-# the two other models are as they were
+# products of keys a key head; "ad071692d07dcb4e" before); all four programs
+# were taken again at PR 50, which edits the expert layer they share (``route``
+# selects by reductions, ``expert_share`` places without a scatter;
+# "2d2d8eaea6f42e6a", "70ec5a3705b72e2d", "a3d52dcb90b967fb",
+# "3993bba7799b77dd" before, in this order); the trees are as they were
 PARENT = {
-    "humanoid-mellum2-ep4": ("5f8baada6f98f565", "2d2d8eaea6f42e6a"),
-    "humanoid-keye2-ep8": ("85b75a256c67cb06", "70ec5a3705b72e2d"),
-    "humanoid-lfm2-ep4": ("8b83ae2d356957ee", "a3d52dcb90b967fb"),
-    "humanoid-qwen3next-ep32": ("5f8f51228059fb1e", "3993bba7799b77dd")}
+    "humanoid-mellum2-ep4": ("5f8baada6f98f565", "dd637b4a6bc6ad6f"),
+    "humanoid-keye2-ep8": ("85b75a256c67cb06", "229928489756fbec"),
+    "humanoid-lfm2-ep4": ("8b83ae2d356957ee", "d933c3f4c29d22f1"),
+    "humanoid-qwen3next-ep32": ("5f8f51228059fb1e", "1d2d3c5742a08d09")}
 
 
 @pytest.mark.parametrize("name", sorted(PARENT))

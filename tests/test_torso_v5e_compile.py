@@ -361,6 +361,9 @@ def test_splash_attention_compiles_at_256_wide_heads_and_two_kv_heads(
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
 
 
+# the layer alone: 6.23 and 4.25 GB (6.25 and 4.26 until PR 50; 6.55 and
+# 4.78 where ``route`` hands its weights on without its barrier: the same
+# peak of live bytes, 5.02 GB, in a heap packed worse)
 @pytest.mark.parametrize("index, layer_type, kernels, temp", [
     (0, "linear_attention", ("gmm",), 6.5e9),
     (3, "full_attention", ("splash", "gmm"), 4.6e9)],
@@ -400,6 +403,47 @@ def test_each_kind_of_qwen3next_layer_and_its_backward_compile_at_real_widths(
     # a 4,096-token part's every-assignment buffer: ten rows a token
     assert "[40960,2048]" in text and "[163840,2048]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < temp
+
+
+def test_the_routing_of_the_linear_cell_sorts_no_experts_and_scatters_no_scalars(
+        one_chip):
+    """``route`` and the sorted buffer's places with their gradient at a
+    4,096-token part of ``humanoid-qwen3next-ep32`` (``[4096, 2048] x [2048,
+    512]``, ten of 512 experts a token, 16 held), alone: the selection is
+    dense passes over ``[4096, 512]``, not a sort of every token's 512
+    experts; its cotangent is placed by a compare and a sum, not a scatter
+    of 40,960 scalars into the ``[4096 x 512]`` (on the chip 200-360 us a
+    part, and booked by a trace under no scope of the torso's); the places
+    are a cumulative sum, not a scatter into ``s32[40960]`` (all three were
+    in the chunk until PR 50)."""
+    with open(os.path.join(
+            REPO, "benchmark/configs/humanoid-qwen3next-ep32.json")) as f:
+        spec = D4PGConfig(**json.load(f)["model"]).torso
+    lo, hi = spec.experts_held
+    t_len, k = torso_lib.EXPERT_TOKENS, spec.num_experts_per_tok
+    f32 = lambda *s: jax.ShapeDtypeStruct(  # noqa: E731
+        s, jnp.float32, sharding=one_chip)
+
+    def loss(h, kernel, weigh):
+        with jax.named_scope("torso.route"):
+            w, e, stats = torso_lib.route(spec, h, {"kernel": kernel})
+            places = torso_lib._places(e, lo, stats["route_counts"][lo:hi])
+        return jnp.sum(w * weigh), (e, places, stats)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True)).lower(
+        f32(t_len, spec.hidden_size), f32(spec.hidden_size, spec.num_experts),
+        f32(t_len, k)).compile().as_text()
+    routed = [line for line in text.splitlines() if "torso.route" in line]
+    assert len(routed) > 20  # the scope's name reaches the compiled text
+    wide = f"[{t_len},{spec.num_experts}]"
+    sorts = [line for line in routed if " sort(" in line]
+    assert not [line for line in sorts if wide in line], sorts
+    # a scatter's metadata may not name the scope: every line counts
+    scatters = [line for line in text.splitlines() if " scatter(" in line]
+    for result in (f"f32[{t_len * spec.num_experts}]", f"f32{wide}",
+                   f"s32[{t_len * k}]"):
+        assert not [line for line in scatters
+                    if line.split(" scatter(")[0].count(result)], result
 
 
 def test_the_fused_chunk_of_the_linear_cell_compiles_for_the_chip(
