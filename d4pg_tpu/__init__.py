@@ -20,4 +20,15 @@ A ground-up JAX/XLA/Pallas re-design of the capabilities of
 See SURVEY.md for the reference analysis this build follows.
 """
 
+# The first statements of the first module of the package an entry point
+# imports: the epoch of the start-up log (``obs/startup_log.py``; stdlib
+# only), and the hook that times first imports from here on.
+import time as _time
+
+_EPOCH = _time.monotonic()
+
+from d4pg_tpu.obs import startup_log as _startup_log  # noqa: E402
+
+_startup_log.open_log(_EPOCH)
+
 __version__ = "0.1.0"

@@ -33,6 +33,7 @@ import numpy as np
 from d4pg_tpu.io.profiling import abstract_args
 from d4pg_tpu.learner.pipeline import IngestOverlap
 from d4pg_tpu.learner.state import D4PGConfig, D4PGState
+from d4pg_tpu.obs import startup_log
 from d4pg_tpu.obs import trace as obs_trace
 from d4pg_tpu.obs.trace import RECORDER as _trace_recorder
 
@@ -141,10 +142,15 @@ class FusedLoop:
                 if self.ingest is not None:
                     self.ingest.commit()
                 args = (state, buffer.trees, buffer.storage, buffer.size)
+                first = obs_trace.NULL_SPAN
                 if not self._tabled:  # first dispatch: enter the table
                     obs_trace.register_program("learner.chunk", fn,
                                      abstract_args(args))
                     self._tabled = True
+                    # the call that traces, lowers and compiles (or loads)
+                    # the program: a phase of the start-up log
+                    first = obs_trace.span("learner.first_dispatch",
+                                           program="learner.chunk")
                 # the jitted call alone: where the host blocks once the
                 # runtime's queue of programs in flight is full. ``landed``:
                 # the position in host staging up to which rows are in the
@@ -152,9 +158,12 @@ class FusedLoop:
                 # the flush before it have dispatched; 0 from the sharded
                 # buffer, which keeps no positions).
                 landed = buffer.landed
-                with obs_trace.span("learner.dispatch", chunk=chunk,
-                                    landed=landed):
+                with first, obs_trace.span("learner.dispatch", chunk=chunk,
+                                           landed=landed):
                     state, buffer.trees, metrics = fn(*args)
+                if first is not obs_trace.NULL_SPAN:
+                    # start-up is over: the log's import hook comes out
+                    startup_log.LOG.unwatch_imports()
                 del args  # state and trees were donated
                 if self.ingest is not None:
                     self.ingest.stage()

@@ -25,6 +25,7 @@ from d4pg_tpu.models.actor import Actor
 from d4pg_tpu.models.critic import CategoricalCritic, MixtureOfGaussianCritic
 from d4pg_tpu.models.encoder import PixelActor, PixelCategoricalCritic
 from d4pg_tpu.models.torso import TorsoCritic, TorsoSpec, build_torso
+from d4pg_tpu.obs import trace as obs_trace
 
 
 @dataclasses.dataclass(frozen=True)
@@ -202,6 +203,11 @@ class D4PGState(NamedTuple):
 def init_state(config: D4PGConfig, key: Array) -> D4PGState:
     """Initialize networks, targets (hard-copied, ``ddpg.py:92-94``) and
     optimizer states."""
+    with obs_trace.span("learner.init_state"):
+        return _init_state(config, key)
+
+
+def _init_state(config: D4PGConfig, key: Array) -> D4PGState:
     k_actor, k_critic, k_state = jax.random.split(key, 3)
     obs = config.dummy_obs()
     act = jnp.zeros((1, config.act_dim), jnp.float32)

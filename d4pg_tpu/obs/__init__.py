@@ -1,7 +1,7 @@
-"""Observability plane: wire-to-grad trace spans, the unified metrics
-registry, and the chaos flight recorder.
+"""Observability plane: wire-to-grad trace spans, the start-up log, the
+unified metrics registry, and the chaos flight recorder.
 
-Five stdlib-only modules (nothing here may import jax — the plane must
+Six stdlib-only modules (nothing here may import jax — the plane must
 be importable from the transport/locking layers that run before any
 backend exists):
 
@@ -12,8 +12,17 @@ backend exists):
   ``fused_buffer``, ``core.locking``, ``ReshardSentinel`` and the fleet
   harness all publish here; the bespoke
   ``*_stats()`` dicts survive as thin views over the same snapshots.
+- ``obs.startup_log`` — a bounded log of where a process's start goes
+  (``LOG``; always on, no switch): opened on the first line of the package,
+  so it exists before ``import jax`` and can time it. It keeps every program
+  span until the span's name has had its share (16) or the log is full, books first imports by package and self time
+  through one hook that leaves at the chunk program's first dispatch, and
+  takes ``jax.monitoring``'s compile-pipeline events from the listeners
+  ``startup.configure`` installs (jax lives there, not here). ``train``
+  prints its phases; a traced benchmark run reads them under ``setup_s``.
 - ``obs.trace`` — program spans (``span()``: the learner's and the ingest
-  plane's own boundaries, handed to the profiler's trace) and the program
+  plane's own boundaries, handed to the profiler's trace and kept in the
+  start-up log) and the program
   table (a named scope inside a compiled program -> device time); and
   sampled per-frame trace spans riding the v2 wire
   codec's header extension: birth timestamp at the actor's socket
@@ -41,7 +50,8 @@ observability plane can be called from under any tiered lock without
 adding an edge the lock graph could cycle through.
 """
 
-from d4pg_tpu.obs import containment, draw_ledger, flight, registry, trace
+from d4pg_tpu.obs import (containment, draw_ledger, flight, registry,
+                          startup_log, trace)
 from d4pg_tpu.obs.containment import contained_crash
 from d4pg_tpu.obs.draw_ledger import LEDGER, DrawLedger
 from d4pg_tpu.obs.flight import FlightRecorder, record_event
@@ -49,7 +59,8 @@ from d4pg_tpu.obs.registry import REGISTRY, MetricsRegistry
 from d4pg_tpu.obs.trace import DEFAULT_SAMPLE, TraceRecorder
 
 __all__ = [
-    "containment", "draw_ledger", "flight", "registry", "trace",
+    "containment", "draw_ledger", "flight", "registry", "startup_log",
+    "trace",
     "FlightRecorder", "record_event", "contained_crash",
     "REGISTRY", "MetricsRegistry",
     "DEFAULT_SAMPLE", "TraceRecorder",

@@ -69,11 +69,13 @@ at the default 2% sample over 16-row frames that is ~1.3 ns/row —
 unmeasurable against the ~190 us/row ingest budget. The recorder is
 disabled by default; ``enable()`` is the only switch.
 
-Program spans and the program table (below the recorder) are a separate,
-stateless mechanism: ``span()`` hands the learner's and the ingest
-plane's own boundaries to the profiler (the profiler is the span store;
-nothing is kept here), and the table lets a trace reader turn a
-``jax.named_scope`` inside a compiled program into device time.
+Program spans and the program table (below the recorder) are a separate
+mechanism: ``span()`` hands the learner's and the ingest plane's own
+boundaries to the profiler (the span store of a traced window) and keeps
+them in the start-up log (``obs/startup_log.py``) until their name has had
+its share there or the log is full, and
+the table lets a trace reader turn a ``jax.named_scope`` inside a compiled
+program into device time.
 """
 
 from __future__ import annotations
@@ -85,6 +87,7 @@ from collections import OrderedDict, deque
 
 from d4pg_tpu.obs.containment import contained_crash
 from d4pg_tpu.obs.registry import percentile_summary
+from d4pg_tpu.obs.startup_log import LOG as _LOG
 
 # The default sampling rate the --trace_sample knobs document: dense
 # enough for stable p99s over a 10 s fleet run, sparse enough that the
@@ -396,11 +399,20 @@ RECORDER = TraceRecorder()
 # -- program spans ------------------------------------------------------------
 # Host spans at the learner's and the ingest plane's layer boundaries go
 # to the profiler's own trace, beside the device's op line, whenever a
-# profiler session is on; with none on, an annotation costs about a
-# microsecond and stores nothing. ``startup.describe()`` installs
+# profiler session is on, AND into the start-up log (``startup_log.LOG``:
+# name, both ends on ``time.monotonic()``, thread, the span open round it,
+# stats), profiler or none, until the span's name has been kept
+# ``per_name`` times or the log has reached its bound: the log is how a
+# process accounts for its start-up, and the spans a traced window's first
+# chunks leave in both are what puts the log's clock and the profiler's on
+# one axis. After that a span costs a compare and a set lookup more than
+# before the log existed (a loop's names are past their share within its
+# first sixteen chunks, so a steady state runs as it did without the log),
+# and with no profiler on an annotation costs about a microsecond and
+# stores nothing. ``startup.describe()`` installs
 # ``jax.profiler.TraceAnnotation`` once the backend is up (this package
 # imports no jax); processes that never start a backend (actors, unit
-# tests) get the shared null span.
+# tests) keep the log alone.
 
 
 class _NullSpan:
@@ -412,6 +424,33 @@ class _NullSpan:
 
     def set_metadata(self, **stats) -> None:
         """Stats known only inside the span (rows moved, time waited)."""
+
+
+class _LoggedSpan:
+    """A span kept in the start-up log and, where there is an annotator,
+    handed to it as well (``inner``)."""
+
+    __slots__ = ("_name", "_stats", "_inner", "_index")
+
+    def __init__(self, name: str, stats: dict, inner):
+        self._name, self._stats, self._inner = name, stats, inner
+        self._index = -1
+
+    def __enter__(self):
+        self._index = _LOG.begin(self._name, self._stats)
+        if self._inner is not None:
+            self._inner.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._inner is not None:
+            self._inner.__exit__(*exc)
+        _LOG.end(self._index)
+
+    def set_metadata(self, **stats) -> None:
+        _LOG.annotate(self._index, stats)
+        if self._inner is not None:
+            self._inner.set_metadata(**stats)
 
 
 NULL_SPAN = _NullSpan()
@@ -427,7 +466,11 @@ def set_annotator(factory) -> None:
 
 
 def span(name: str, **stats):
-    return NULL_SPAN if _annotator is None else _annotator(name, **stats)
+    inner = None if _annotator is None else _annotator(name, **stats)
+    if _LOG.full or name in _LOG.closed:
+        _LOG.dropped()
+        return NULL_SPAN if inner is None else inner
+    return _LoggedSpan(name, stats, inner)
 
 
 # -- program table ------------------------------------------------------------

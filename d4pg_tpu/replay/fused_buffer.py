@@ -276,9 +276,16 @@ class FusedDeviceReplay:
         self.block_rows = int(block_rows if block_rows is not None
                               else min(4096, self.capacity))
         self._device = device
-        self._store = DeviceStore(self.capacity, obs_shape, act_dim,
-                                  obs_dtype, device=device,
-                                  block_rows=self.block_rows)
+        with obs_trace.span("replay.allocate", rows=self.capacity):
+            self._store = DeviceStore(self.capacity, obs_shape, act_dim,
+                                      obs_dtype, device=device,
+                                      block_rows=self.block_rows)
+            # what the buffer keeps on the device is committed to the
+            # store's device, like the ring (device_ring.py): trees that
+            # came back committed from their first commit would compile it
+            # a second time
+            self.trees = self._own(dper.init(self.capacity)) \
+                if prioritized else None
         # The CPU backend's device_put hands back arrays that ALIAS aligned
         # host memory (``may_alias=False`` does not stop it), and the host
         # staging ring rewrites a frame's rows before an asynchronous commit
@@ -288,11 +295,6 @@ class FusedDeviceReplay:
             self._store.home.device_set)).platform == "cpu"
         self.prioritized = bool(prioritized)
         self.alpha = float(alpha)
-        # what the buffer keeps on the device is committed to the store's
-        # device, like the ring (device_ring.py): trees that came back
-        # committed from their first commit would compile it a second time
-        self.trees = self._own(dper.init(self.capacity)) if prioritized \
-            else None
         self.size = 0
         self.head = 0
         # Generation-tracked mode (the device-dealt sample plane,
@@ -516,14 +518,18 @@ class FusedDeviceReplay:
                     np.int32(n))
         else:
             args = (self._store.pinned(), frame, start, np.int32(n))
+        first = obs_trace.NULL_SPAN
         if not self._commit_tabled:  # first dispatch: enter the table
             from d4pg_tpu.io.profiling import abstract_args
 
             obs_trace.register_program("ingest.commit", self._commit_fn,
                              abstract_args(args))
             self._commit_tabled = True
-        with obs_trace.span("fused.commit_staged", block=block, rows=n,
-                            inflight_ms=inflight_ms, through=through):
+            first = obs_trace.span("learner.first_dispatch",
+                                   program="ingest.commit")
+        with first, obs_trace.span("fused.commit_staged", block=block,
+                                   rows=n, inflight_ms=inflight_ms,
+                                   through=through):
             out = self._commit(*args)
         self.landed = through
         _tracer.mark_through("land", through)

@@ -44,6 +44,7 @@ from d4pg_tpu.envs import (
     get_preset,
 )
 from d4pg_tpu.io import CheckpointManager, CsvLogger, MetricsBus, TensorBoardSink
+from d4pg_tpu.obs import startup_log
 from d4pg_tpu.obs.containment import contained_crash
 from d4pg_tpu.io.profiling import RecompileSentinel, StepTimer, xla_trace
 from d4pg_tpu.learner import (
@@ -1473,6 +1474,10 @@ def train(cfg: ExperimentConfig) -> dict:
                 jax.block_until_ready(metrics)
             rate = timer.stop(cfg.train_steps_per_cycle)
             compiles_by_cycle.append(compiles.compilations)
+            if nth_cycle == 0:
+                # the first chunks are done: where this process's start-up
+                # went, once (obs/startup_log.py; README, "Start-up")
+                print(startup_log.LOG.table(), flush=True)
             # weight staleness actors saw this cycle, measured before the
             # cycle-end publish (<= K in async mode, one cycle in sync mode)
             weight_lag = lstep - weights.step
